@@ -58,15 +58,12 @@ class MapData:
         self.vvalid = vvalid
 
 
-# nested payloads cross jit boundaries as ordinary pytrees (registration
-# rides the shim layer — the pytree API has moved across JAX releases)
-from spark_rapids_tpu.shims import get_shim as _get_shim  # noqa: E402
-
-_get_shim().register_pytree_node(
+# nested payloads cross jit boundaries as ordinary pytrees
+jax.tree_util.register_pytree_node(
     StructData,
     lambda sd: (sd.fields, None),
     lambda _, fields: StructData(tuple(fields)))
-_get_shim().register_pytree_node(
+jax.tree_util.register_pytree_node(
     MapData,
     lambda md: ((md.offsets, md.kdata, md.kvalid, md.vdata, md.vvalid),
                 None),

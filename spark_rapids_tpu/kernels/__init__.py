@@ -24,10 +24,14 @@ natively in ONE fused Pallas program:
 
 Contract, enforced per primitive:
 
-  * gated by ``spark.rapids.tpu.kernels.<name>.enabled`` ('auto' =
-    non-CPU backends; the CPU backend runs Pallas in INTERPRET mode —
-    bit-identical, which is how tier-1 pins identity without TPU
-    hardware — but slower than XLA:CPU, so auto keeps it off there);
+  * gated by ``spark.rapids.tpu.kernels.<name>.enabled`` ('auto' = on
+    on the TPU backend for every program the Mosaic compiler accepts
+    and that matches HLO bit for bit there — the rest stand down by
+    the ``TPU_AUTO_OFF`` table below, so no process pays a failing
+    compile and a query replay to find out; the CPU backend runs Pallas
+    in INTERPRET mode — bit-identical, which is how tier-1 pins
+    identity without TPU hardware — but slower than XLA:CPU, so auto
+    keeps it off there);
   * the HLO path remains the fallback for every ineligible shape
     (``KernelIneligible``) and is BIT-IDENTICAL by construction —
     pinned by tests/test_kernels.py;
@@ -69,17 +73,47 @@ _ENABLE_ENTRIES = {
 }
 
 
-class KernelsConfig:
-    """Resolved per-query kernel configuration (immutable snapshot)."""
+#: What 'auto' leaves OFF on the TPU backend: primitive (every program
+#: of it) or "<primitive>.<program>" -> what the toolchain said when the
+#: program was built for a v5e, not interpreted, at the shapes the SF1
+#: smoke produces (jax 0.9.0 / libtpu 0.0.34). These are structural
+#: refusals: rewriting the kernels is ROADMAP A2's, with a cell to judge
+#: the result. An explicit ``=true`` still forces a primitive on.
+TPU_AUTO_OFF: Dict[str, str] = {
+    "sort": (
+        "MosaicError: INTERNAL: Mosaic failed to compile TPU kernel: "
+        "infer-vector-layout: unsupported shape cast — \"tpu.reshape\" "
+        "(vector<1024xi32>) -> vector<512x2x1xi32> (the whole-array "
+        "(n/2d, 2, d) compare-exchange reshape; same at n=65536 and 1M)"),
+    "compact": (
+        "NotImplementedError: Only 2D gather is supported (jnp.take of "
+        "each 1-D limb stream through the gather map)"),
+    "hashprobe": (
+        "NotImplementedError: Only 2D gather is supported (three 1-D "
+        "jnp.take into the table per attempt)"),
+    "segreduce.onehot_partials": (
+        "compiles (once its index maps stopped returning an i64: "
+        "\"failed to legalize operation 'func.return' ... (i32, i64)\"), "
+        "but its f32 contraction is NOT bit-identical to the HLO einsum "
+        "on the chip (capacity 1024 and 65536, 8 segments)"),
+}
 
-    __slots__ = ("enabled", "vmem_budget", "max_segments", "attempts")
+
+class KernelsConfig:
+    """Resolved per-query kernel configuration (immutable snapshot).
+    ``declined`` names single programs of an enabled primitive that
+    stand down by TPU_AUTO_OFF (they decline per call, to HLO)."""
+
+    __slots__ = ("enabled", "vmem_budget", "max_segments", "attempts",
+                 "declined")
 
     def __init__(self, enabled=frozenset(), vmem_budget=64 << 20,
-                 max_segments=8192, attempts=4):
+                 max_segments=8192, attempts=4, declined=frozenset()):
         self.enabled = frozenset(enabled)
         self.vmem_budget = int(vmem_budget)
         self.max_segments = int(max_segments)
         self.attempts = int(attempts)
+        self.declined = frozenset(declined)
 
 
 #: per-query resolved config, set by the placement layer at drain (the
@@ -92,25 +126,35 @@ KERNELS_ENABLED = contextvars.ContextVar("rapids_pallas_kernels",
 
 def resolve_enabled(conf) -> KernelsConfig:
     """Resolve the spark.rapids.tpu.kernels.* keys for one query.
-    'auto' means on for non-CPU backends (where 64-bit emulation is
-    the tax) and off on CPU (native 64-bit; Pallas would run in
-    interpret mode)."""
+    'auto' means on on the TPU backend (where 64-bit emulation is the
+    tax) except what TPU_AUTO_OFF stands down, and off elsewhere (CPU:
+    native 64-bit; Pallas would run in interpret mode). The VMEM budget
+    is what the kernels both admit against and hand the compiler as
+    its scoped limit, so it is held to what the device has."""
     import jax
-    on_device = jax.default_backend() != "cpu"
-    names = []
+    on_tpu = jax.default_backend() == "tpu"
+    names, declined = [], []
     for name, entry in _ENABLE_ENTRIES.items():
         mode = str(conf.get_entry(entry)).strip().lower()
         if mode in ("true", "1", "on"):
             names.append(name)
         elif mode in ("false", "0", "off"):
             pass
-        elif on_device:  # auto
+        elif on_tpu and name not in TPU_AUTO_OFF:  # auto
             names.append(name)
+            declined += [k for k in TPU_AUTO_OFF
+                         if k.startswith(name + ".")]
+    vmem_budget = conf.get_entry(KERNELS_VMEM_BUDGET)
+    if on_tpu:
+        from jax.experimental.pallas import tpu as pltpu
+        vmem_budget = min(vmem_budget,
+                          pltpu.get_tpu_info().vmem_capacity_bytes)
     return KernelsConfig(
         enabled=names,
-        vmem_budget=conf.get_entry(KERNELS_VMEM_BUDGET),
+        vmem_budget=vmem_budget,
         max_segments=conf.get_entry(KERNELS_SEGREDUCE_MAX_SEGMENTS),
-        attempts=conf.get_entry(KERNELS_HASHPROBE_ATTEMPTS))
+        attempts=conf.get_entry(KERNELS_HASHPROBE_ATTEMPTS),
+        declined=declined)
 
 
 # -- per-primitive circuit breaker ------------------------------------------
@@ -195,7 +239,8 @@ def trace_token() -> tuple:
     cfg = KERNELS_ENABLED.get()
     with _LOCK:
         live = tuple(sorted(n for n in cfg.enabled if n not in _DEMOTED))
-    return (live, cfg.vmem_budget, cfg.max_segments, cfg.attempts)
+    return (live, cfg.vmem_budget, cfg.max_segments, cfg.attempts,
+            tuple(sorted(cfg.declined)))
 
 
 # -- dispatch helpers -------------------------------------------------------
@@ -248,6 +293,15 @@ def note_used(name: str) -> None:
         _TRACE_CAPTURE.stack[-1].add(name)
 
 
+def decline_if_auto_off(program: str) -> None:
+    """Called by a program that TPU_AUTO_OFF may stand down while its
+    primitive stays on: declines this call (HLO path, no demotion)."""
+    if program in KERNELS_ENABLED.get().declined:
+        raise KernelIneligible(
+            f"{program} is auto-off on the TPU backend: "
+            f"{TPU_AUTO_OFF[program]}")
+
+
 def count_fallback(name: str, fallback: Callable):
     """Run (and count) the HLO path for a primitive that is disabled
     or ineligible. Counting happens at trace time — see module doc."""
@@ -278,8 +332,8 @@ def guarded(name: str, kernel_fn: Callable, fallback: Callable):
         )
         from spark_rapids_tpu.runtime.retry import is_device_oom
         if is_device_oom(exc) or is_fatal_device_error(exc):
-            # OOMs belong to the retry framework; a dead device/tunnel
-            # is the health monitor's to recover — demoting the kernel
+            # OOMs belong to the retry framework; a dead device is
+            # the health monitor's to recover — demoting the kernel
             # for either would outlive the recovery (demotions are
             # process-permanent by design, for actual kernel faults)
             raise
@@ -299,6 +353,17 @@ def dispatch(name: str, kernel_fn: Callable, fallback: Callable):
     if not enabled(name):
         return count_fallback(name, fallback)
     return guarded(name, kernel_fn, fallback)
+
+
+def compiler_params():
+    """Mosaic compiler parameters shared by every kernel: the scoped
+    VMEM limit the compiler enforces IS the budget the eligibility
+    checks admit against (``KernelsConfig.vmem_budget``). None in
+    interpret mode, where no compiler runs."""
+    if interpret_mode():
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=config().vmem_budget)
 
 
 def interpret_mode() -> bool:

@@ -26,7 +26,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from spark_rapids_tpu.kernels import KernelIneligible, config, interpret_mode
+from spark_rapids_tpu.kernels import (
+    KernelIneligible,
+    compiler_params,
+    config,
+    interpret_mode,
+)
 from spark_rapids_tpu.runtime.faults import fault_point
 
 
@@ -95,7 +100,7 @@ def gather_compact(datas, valids, keep, pos, new_n, capacity: int):
     shapes = tuple((s.shape, str(s.dtype)) for s in streams)
 
     from spark_rapids_tpu.dispatch import pallas_program
-    key = ("compact", capacity, shapes)
+    key = ("compact", capacity, shapes, config().vmem_budget)
 
     def build():
         def kernel(*refs):
@@ -113,6 +118,7 @@ def gather_compact(datas, valids, keep, pos, new_n, capacity: int):
             kernel,
             out_shape=[jax.ShapeDtypeStruct(s.shape, s.dtype)
                        for s in streams],
+            compiler_params=compiler_params(),
             interpret=interpret_mode())
 
     fn = pallas_program(key, build)

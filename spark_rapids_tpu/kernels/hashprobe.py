@@ -36,7 +36,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from spark_rapids_tpu.kernels import KernelIneligible, config, interpret_mode
+from spark_rapids_tpu.kernels import (
+    KernelIneligible,
+    compiler_params,
+    config,
+    interpret_mode,
+)
 from spark_rapids_tpu.runtime.faults import fault_point
 
 #: per-attempt hash salts (odd multiplicative constants; 8 attempts max)
@@ -113,7 +118,7 @@ def probe_rowids(p_hi, p_lo, valid, table_row, table_hi, table_lo,
 
     from spark_rapids_tpu.dispatch import pallas_program
     key = ("hashprobe", cap, H, blk, attempts, str(p_hi.dtype),
-           str(p_lo.dtype))
+           str(p_lo.dtype), cfg.vmem_budget)
 
     def build():
         def kernel(phi_ref, plo_ref, pvalid_ref, trow_ref, thi_ref,
@@ -140,9 +145,10 @@ def probe_rowids(p_hi, p_lo, valid, table_row, table_hi, table_lo,
             kernel,
             grid=(nb,),
             in_specs=[pl.BlockSpec((blk,), lambda b: (b,))] * 3
-            + [pl.BlockSpec((H,), lambda b: (0,))] * 3,
+            + [pl.BlockSpec((H,), lambda b: (jnp.int32(0),))] * 3,
             out_specs=pl.BlockSpec((blk,), lambda b: (b,)),
             out_shape=jax.ShapeDtypeStruct((cap,), jnp.int32),
+            compiler_params=compiler_params(),
             interpret=interpret_mode())
 
     fn = pallas_program(key, build)

@@ -34,7 +34,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from spark_rapids_tpu.kernels import KernelIneligible, config, interpret_mode
+from spark_rapids_tpu.kernels import (
+    KernelIneligible,
+    compiler_params,
+    config,
+    decline_if_auto_off,
+    interpret_mode,
+)
 from spark_rapids_tpu.runtime.faults import fault_point
 
 
@@ -66,56 +72,84 @@ def fused_minmax(is_min: bool, hi, lo, valid, gid, nseg: int,
     capacity = int(hi.shape[0])
     blk = _pick_block(capacity, nseg, cfg.vmem_budget)
     nb = capacity // blk
+    # Mosaic reduces signed integers only: an unsigned low limb rides
+    # sign-biased (u ^ 2^31 viewed as i32 orders exactly like u)
+    unsigned_lo = lo.dtype == jnp.uint32
+    if unsigned_lo:
+        lo = jax.lax.bitcast_convert_type(lo ^ jnp.uint32(1 << 31),
+                                          jnp.int32)
+        lo_ident = int(lo_ident) - (1 << 31)
     hi_dt, lo_dt = hi.dtype, lo.dtype
 
     from spark_rapids_tpu.dispatch import pallas_program
     key = ("segminmax", bool(is_min), capacity, nseg, blk,
-           str(hi_dt), str(lo_dt))
+           str(hi_dt), str(lo_dt), cfg.vmem_budget)
 
     def build():
         red = jnp.minimum if is_min else jnp.maximum
         axred = jnp.min if is_min else jnp.max
 
+        # Mosaic layout: row streams arrive as lane-dense (1, blk) ROWS
+        # and the per-segment accumulators are (nseg, 1) COLUMNS, so the
+        # (nseg, blk) one-hot tile forms by plain broadcasting — no
+        # in-kernel relayout (a 1-D -> column shape cast does not lower)
         def kernel(hi_ref, lo_ref, valid_ref, gid_ref, mhi_ref, mlo_ref):
             p = pl.program_id(0)
             b = pl.program_id(1)
+            # typed in-trace constants: a bare Python scalar would enter
+            # as a weak 64-bit value under x64, which Mosaic cannot narrow
+            hi_id = jnp.asarray(hi_ident, hi_dt)
+            lo_id = jnp.asarray(lo_ident, lo_dt)
 
             @pl.when((p == 0) & (b == 0))
             def _init():
-                mhi_ref[:] = jnp.full((nseg,), hi_ident, hi_dt)
-                mlo_ref[:] = jnp.full((nseg,), lo_ident, lo_dt)
+                mhi_ref[...] = jnp.full((nseg, 1), hi_id)
+                mlo_ref[...] = jnp.full((nseg, 1), lo_id)
 
-            g = gid_ref[:]
-            onseg = g[:, None] == jax.lax.broadcasted_iota(
-                jnp.int32, (blk, nseg), 1)
+            onseg = gid_ref[...] == jax.lax.broadcasted_iota(
+                jnp.int32, (nseg, blk), 0)
+            valid = valid_ref[...] != 0
 
             @pl.when(p == 0)
             def _hi_pass():
-                contrib = jnp.where(onseg & valid_ref[:][:, None],
-                                    hi_ref[:][:, None],
-                                    jnp.asarray(hi_ident, hi_dt))
-                mhi_ref[:] = red(mhi_ref[:], axred(contrib, axis=0))
+                contrib = jnp.where(onseg & valid, hi_ref[...], hi_id)
+                mhi_ref[...] = red(mhi_ref[...],
+                                   axred(contrib, axis=1, keepdims=True))
 
             @pl.when(p == 1)
             def _lo_pass():
-                win = jnp.take(mhi_ref[:], jnp.clip(g, 0, nseg - 1))
-                cand = valid_ref[:] & (hi_ref[:] == win)
-                contrib = jnp.where(onseg & cand[:, None],
-                                    lo_ref[:][:, None],
-                                    jnp.asarray(lo_ident, lo_dt))
-                mlo_ref[:] = red(mlo_ref[:], axred(contrib, axis=0))
+                # each row's segment winner, selected through the one-hot
+                # tile already built (Mosaic has no 1-D gather)
+                win = axred(jnp.where(onseg, mhi_ref[...], hi_id),
+                            axis=0, keepdims=True)
+                cand = valid & (hi_ref[...] == win)
+                contrib = jnp.where(onseg & cand, lo_ref[...], lo_id)
+                mlo_ref[...] = red(mlo_ref[...],
+                                   axred(contrib, axis=1, keepdims=True))
 
+        # index maps return jnp.int32(0), never a bare 0: under x64 that
+        # is an i64 the Mosaic compiler cannot legalize
         return pl.pallas_call(
             kernel,
             grid=(2, nb),
-            in_specs=[pl.BlockSpec((blk,), lambda p, b: (b,))] * 4,
-            out_specs=[pl.BlockSpec((nseg,), lambda p, b: (0,))] * 2,
-            out_shape=[jax.ShapeDtypeStruct((nseg,), hi_dt),
-                       jax.ShapeDtypeStruct((nseg,), lo_dt)],
+            in_specs=[pl.BlockSpec(
+                (1, blk), lambda p, b: (jnp.int32(0), b))] * 4,
+            out_specs=[pl.BlockSpec(
+                (nseg, 1), lambda p, b: (jnp.int32(0), jnp.int32(0)))] * 2,
+            out_shape=[jax.ShapeDtypeStruct((nseg, 1), hi_dt),
+                       jax.ShapeDtypeStruct((nseg, 1), lo_dt)],
+            compiler_params=compiler_params(),
             interpret=interpret_mode())
 
     fn = pallas_program(key, build)
-    return fn(hi, lo, valid, gid)
+    # validity rides as i32: Mosaic has no packed-bool memory layout
+    mhi, mlo = fn(*(a.reshape(1, capacity) for a in
+                    (hi, lo, valid.astype(jnp.int32), gid)))
+    mhi, mlo = mhi[:, 0], mlo[:, 0]
+    if unsigned_lo:
+        mlo = (jax.lax.bitcast_convert_type(mlo, jnp.uint32)
+               ^ jnp.uint32(1 << 31))
+    return mhi, mlo
 
 
 def onehot_partials(x, gid, nseg: int, nb: int, block: int):
@@ -124,6 +158,7 @@ def onehot_partials(x, gid, nseg: int, nb: int, block: int):
     c), one_hot(gid.reshape(nb, block), nseg), precision='highest')``
     but with the one-hot built in VMEM per block."""
     fault_point("kernels.segreduce")
+    decline_if_auto_off("segreduce.onehot_partials")
     cfg = config()
     c = int(x.shape[1])
     if (block * nseg + block * c + nseg * c) * 4 * 2 > cfg.vmem_budget:
@@ -131,7 +166,7 @@ def onehot_partials(x, gid, nseg: int, nb: int, block: int):
                                "budget")
 
     from spark_rapids_tpu.dispatch import pallas_program
-    key = ("onehotsum", nb, block, nseg, c, str(x.dtype))
+    key = ("onehotsum", nb, block, nseg, c, str(x.dtype), cfg.vmem_budget)
 
     def build():
         def kernel(x_ref, gid_ref, out_ref):
@@ -145,10 +180,13 @@ def onehot_partials(x, gid, nseg: int, nb: int, block: int):
         return pl.pallas_call(
             kernel,
             grid=(nb,),
-            in_specs=[pl.BlockSpec((block, c), lambda b: (b, 0)),
+            in_specs=[pl.BlockSpec((block, c),
+                                   lambda b: (b, jnp.int32(0))),
                       pl.BlockSpec((block,), lambda b: (b,))],
-            out_specs=pl.BlockSpec((1, nseg, c), lambda b: (b, 0, 0)),
+            out_specs=pl.BlockSpec(
+                (1, nseg, c), lambda b: (b, jnp.int32(0), jnp.int32(0))),
             out_shape=jax.ShapeDtypeStruct((nb, nseg, c), x.dtype),
+            compiler_params=compiler_params(),
             interpret=interpret_mode())
 
     fn = pallas_program(key, build)

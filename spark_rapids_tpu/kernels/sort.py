@@ -28,7 +28,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from spark_rapids_tpu.kernels import KernelIneligible, config, interpret_mode
+from spark_rapids_tpu.kernels import (
+    KernelIneligible,
+    compiler_params,
+    config,
+    interpret_mode,
+)
 from spark_rapids_tpu.runtime.faults import fault_point
 
 
@@ -84,6 +89,7 @@ def _build(n: int, dtypes):
     return pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((n,), dt) for dt in dtypes],
+        compiler_params=compiler_params(),
         interpret=interpret_mode())
 
 
@@ -106,6 +112,7 @@ def sort_with_payload(operands: List[jax.Array],
     if 3 * sum(a.dtype.itemsize * n for a in arrs) > config().vmem_budget:
         raise KernelIneligible("sort working set exceeds the VMEM budget")
     from spark_rapids_tpu.dispatch import pallas_program
-    key = ("sort", n, tuple(str(a.dtype) for a in arrs))
+    key = ("sort", n, tuple(str(a.dtype) for a in arrs),
+           config().vmem_budget)
     fn = pallas_program(key, lambda: _build(n, [a.dtype for a in arrs]))
     return list(fn(*arrs))

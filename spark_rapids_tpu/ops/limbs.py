@@ -2,7 +2,13 @@
 
 TPU ALUs are 32-bit: f64 storage IS an (f32, f32) pair and i64 compute
 emulates through 32-bit word sequences, so every hot path in the engine
-represents a 64-bit value as TWO native 32-bit limbs:
+represents a 64-bit value as TWO native 32-bit limbs. What that means
+for a double on the ``tpu`` backend (chip_smoke.py's representation
+probe on a v5e, jax 0.9.0 / libtpu 0.0.34; PERF.md "the number
+format"): about 48 mantissa bits (1+2^-52 and 2^53-1 survive; 1/3 and
+0.1 come back a few 1e-16 off), f32's exponent range (|x| > f32 max is
++-inf; below f32's normal range the value is a denormal high limb or 0),
+-0.0 comes back +0.0, NaN and +-inf are kept. i64 is exact.
 
   f64 -> (hi = f32(x), lo = f32(x - hi)) — EXACT on TPU because the
          storage itself is the pair; hi rounds monotonically, so

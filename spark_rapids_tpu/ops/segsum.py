@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # the limb split/recombine recipes live in ops/limbs.py (single source
 # of truth for kernels/ and the HLO paths); re-exported here because
@@ -278,7 +279,11 @@ def segment_minmax_64(is_min: bool, sd, sv, gid, num_segments: int):
         hi, lo = split_f64_hi_lo(sd)
 
         def fast(_):
-            ident = jnp.float32(jnp.inf if is_min else -jnp.inf)
+            # numpy scalars, not jnp ones: the Pallas kernel closes over
+            # the identities — a captured jax.Array is rejected, and a
+            # weakly-typed Python scalar would enter the kernel as a
+            # 64-bit constant Mosaic cannot narrow
+            ident = np.float32(np.inf if is_min else -np.inf)
             mhi, mlo = _limb_minmax(hi, lo, use, ident, ident)
             return combine_f64(mhi, mlo)
 
@@ -305,8 +310,8 @@ def segment_minmax_64(is_min: bool, sd, sv, gid, num_segments: int):
         return jnp.where(any_nan, jnp.float64(jnp.nan), out)
     hi, lo = split_i64_hi_lo(sd)
     info = jnp.iinfo(jnp.int32)
-    hi_ident = jnp.int32(info.max if is_min else info.min)
-    lo_ident = jnp.uint32(0xFFFFFFFF if is_min else 0)
+    hi_ident = np.int32(info.max if is_min else info.min)
+    lo_ident = np.uint32(0xFFFFFFFF if is_min else 0)
     mhi, mlo = _limb_minmax(hi, lo, sv, hi_ident, lo_ident)
     return combine_i64(mhi, mlo)
 

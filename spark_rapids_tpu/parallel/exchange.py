@@ -34,11 +34,6 @@ from spark_rapids_tpu.shuffle.hashing import (
 )
 
 
-def _shard_map():
-    from spark_rapids_tpu.shims import get_shim
-    return get_shim().shard_map()
-
-
 def _axis_size(mesh, axis) -> int:
     """Device count of ``axis`` — a single axis name or a tuple of them
     (the hierarchical (dcn, ici) mesh exchanges over both)."""
@@ -310,10 +305,9 @@ class MeshExchange:
             if has:
                 in_specs += [P_(), P_()]  # replicated dictionary bytes
         out_specs = [P_(axis)] * (2 * ncols) + [P_(axis)]
-        sm = _shard_map()
-        return tpu_jit(sm(shard_fn, mesh=self.mesh,
-                          in_specs=tuple(in_specs),
-                          out_specs=tuple(out_specs)))
+        return tpu_jit(jax.shard_map(shard_fn, mesh=self.mesh,
+                                     in_specs=tuple(in_specs),
+                                     out_specs=tuple(out_specs)))
 
     def run(self, datas, valids, key_datas, key_valids, live,
             string_bytes: Optional[Dict[int, tuple]] = None):
@@ -443,7 +437,7 @@ def mesh_partial_then_merge(mesh, axis_name: str = "data"):
             return jax.tree.map(lambda x: jax.lax.psum(x, axis_name),
                                 partial_out)
 
-        sm = _shard_map()
-        return tpu_jit(sm(wrapper, mesh=mesh,
-                          in_specs=P_(axis_name), out_specs=P_()))
+        return tpu_jit(jax.shard_map(wrapper, mesh=mesh,
+                                     in_specs=P_(axis_name),
+                                     out_specs=P_()))
     return build

@@ -349,7 +349,7 @@ def eval_window_udf(pdf, fn, arg_names, spec):
     (series in, scalar or aligned series out); the default ORDER BY
     frame (RANGE UNBOUNDED PRECEDING..CURRENT ROW) is a running
     aggregate whose frame ends at the last PEER of each row; bounded
-    rows frames slice per row — the same frame taxonomy the reference
+    rows frames slice per row — the same frame classes the reference
     implements in GpuWindowInPandasExec."""
     import numpy as np
     import pandas as pd

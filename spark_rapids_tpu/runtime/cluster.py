@@ -1253,7 +1253,10 @@ def spawn_executor(address: Tuple[str, int], host_id: str,
         t.start()
         return ExecutorHandle(host_id, mode, thread=t, stop=stop)
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # assigned, not defaulted: executors decode on the host by design,
+    # and an inherited JAX_PLATFORMS would send each one after the chip
+    # the driver process already owns
+    env["JAX_PLATFORMS"] = "cpu"
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")

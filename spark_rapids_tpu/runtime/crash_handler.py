@@ -7,7 +7,7 @@ process with code 20 so Spark reschedules on another node;
 ``DumpUtils.scala`` dumps cudf tables to parquet for debugging.
 
 TPU mapping: fatal XLA/PJRT errors (non-OOM XlaRuntimeError: INTERNAL,
-device halted, tunnel lost) trigger a crash-report capture — device
+device halted, PJRT connection lost) trigger a crash-report capture — device
 memory stats, buffer-catalog state, the failing plan, the exception, and
 a faulthandler-style thread dump — written to the configured dump dir.
 ``FATAL_EXIT_CODE`` and ``exit_on_fatal`` implement the
@@ -40,7 +40,7 @@ EXIT_ON_FATAL = bool_conf(
 def is_fatal_device_error(exc: BaseException) -> bool:
     """Fatal = device/runtime failure that is NOT a recoverable OOM.
     Distinct from the per-op KernelCrashError class the circuit breaker
-    owns: a fatal error means the DEVICE (or its PJRT tunnel) is gone,
+    owns: a fatal error means the DEVICE (or its PJRT client) is gone,
     so recovery is backend reinitialization (runtime/health.py), not
     operator demotion."""
     from spark_rapids_tpu.errors import DeviceLostError

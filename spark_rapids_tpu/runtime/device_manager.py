@@ -23,7 +23,33 @@ from spark_rapids_tpu.conf import (
 )
 from spark_rapids_tpu.lockorder import ordered_lock
 
-_DEFAULT_HBM_BYTES = 16 << 30  # v5e has 16 GiB per chip
+#: what a CPU device "holds": the CPU backend reports no memory limit,
+#: and the tests and the CPU test mesh budget against one v5e chip's
+#: 16 GiB. Never used for an accelerator.
+_CPU_STANDIN_HBM_BYTES = 16 << 30
+
+
+def reported_hbm_bytes(dev) -> int:
+    """The memory limit ``dev`` itself reports. Only the CPU backend,
+    which reports none, gets the stand-in; an accelerator without a
+    ``bytes_limit`` is an error, never a guess."""
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if dev.platform != "cpu":
+        from spark_rapids_tpu.errors import ColumnarProcessingError
+        raise ColumnarProcessingError(
+            f"{dev.platform} device {dev} reports no memory limit "
+            f"(memory_stats() = {stats!r}); refusing to assume one")
+    return _CPU_STANDIN_HBM_BYTES
+
+
+def hbm_budget_bytes(conf: RapidsConf, dev) -> int:
+    """The engine's share of ``dev``'s memory: the reported limit with
+    the pool fraction and the reserve applied."""
+    frac = conf.get_entry(HBM_POOL_FRACTION)
+    reserve = conf.get_entry(HBM_RESERVE_BYTES)
+    return max(int(reported_hbm_bytes(dev) * frac) - reserve, 256 << 20)
 
 
 @dataclass
@@ -78,24 +104,14 @@ class TpuDeviceManager:
             return
         # the backend is being initialized anyway; auto-detected TPU
         # hosts (unset JAX_PLATFORMS) pick up the persistent compile
-        # cache here rather than silently running uncached (ADVICE r5)
+        # cache here rather than silently running uncached
         import spark_rapids_tpu
         spark_rapids_tpu.ensure_compile_cache()
         self.devices = list(jax.devices())
         local = list(jax.local_devices())
         ordinal = self._select_device(local)
         dev = local[ordinal]
-        total = _DEFAULT_HBM_BYTES
-        stats = None
-        try:
-            stats = dev.memory_stats()
-        except Exception:
-            stats = None
-        if stats and "bytes_limit" in stats:
-            total = int(stats["bytes_limit"])
-        frac = self.conf.get_entry(HBM_POOL_FRACTION)
-        reserve = self.conf.get_entry(HBM_RESERVE_BYTES)
-        limit = max(int(total * frac) - reserve, 256 << 20)
+        limit = hbm_budget_bytes(self.conf, dev)
         try:
             nproc = jax.process_count()
             pidx = jax.process_index()

@@ -49,7 +49,7 @@ FAULT_KINDS = (
     "slow",        # slow peer / stall (sleep; exercises timeouts without failing)
     "wedge",       # long stall INSIDE one dispatch (no exception; the cooperative
                    # cancel boundary never runs — watchdog hard-timeout territory)
-    "device_lost", # fatal device/tunnel loss (DeviceLostError; health-monitor
+    "device_lost", # fatal device/PJRT loss (DeviceLostError; health-monitor
                    # recovery: backend reinit + cache invalidation, NOT the breaker)
     "race",        # lost optimistic-concurrency race (DeltaConcurrentModification-
                    # Exception; the transaction's rebase-and-retry loop owns it)
@@ -124,7 +124,7 @@ FAULT_POINTS: Dict[str, tuple] = {
     "device.lost": (
         "spark_rapids_tpu/dispatch.py",
         "before each jitted kernel dispatch; device_lost simulates a "
-        "fatal PJRT/tunnel loss (health-monitor recovery path)"),
+        "fatal PJRT client loss (health-monitor recovery path)"),
     "kernels.sort": (
         "spark_rapids_tpu/kernels/sort.py",
         "at the Pallas multi-column sort's trace-time entry; a crash "

@@ -228,24 +228,26 @@ class MemoryArbiter:
             if key == self._cfg:
                 return
             self._cfg = key
-            self._budget = budget if budget > 0 else self._backend_budget()
+            self._budget = (budget if budget > 0
+                            else self._backend_budget(conf))
             self._chunk_fraction = min(max(fraction, 0.001), 1.0)
 
     @staticmethod
-    def _backend_budget() -> int:
-        """The backend-reported HBM limit (allocFraction applied); the
-        v5e per-chip default when no manager has initialized yet."""
-        try:
-            from spark_rapids_tpu.runtime.device_manager import (
-                TpuDeviceManager,
-                _DEFAULT_HBM_BYTES,
-            )
-            mgr = TpuDeviceManager.current()
-            if mgr is not None and mgr.info is not None:
-                return int(mgr.info.hbm_limit_bytes)
-            return int(_DEFAULT_HBM_BYTES)
-        except Exception:
-            return 16 << 30
+    def _backend_budget(conf=None) -> int:
+        """The backend-reported HBM limit with allocFraction and the
+        reserve applied: the live device manager's when one has
+        initialized, else read from the first local device."""
+        from spark_rapids_tpu.runtime.device_manager import (
+            TpuDeviceManager,
+            hbm_budget_bytes,
+        )
+        mgr = TpuDeviceManager.current()
+        if mgr is not None and mgr.info is not None:
+            return int(mgr.info.hbm_limit_bytes)
+        import jax
+        from spark_rapids_tpu.conf import RapidsConf
+        return hbm_budget_bytes(conf if conf is not None else RapidsConf(),
+                                jax.local_devices()[0])
 
     def budget_bytes(self) -> int:
         with self._lock:

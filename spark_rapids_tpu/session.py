@@ -571,7 +571,7 @@ class TpuSession:
           (the replay re-plans, so the demotion takes effect
           immediately);
         * a FATAL device error (is_fatal_device_error — the device or
-          its tunnel is gone, not one operator) captures a crash report
+          its PJRT client is gone, not one operator) captures a crash report
           and hands recovery to the health monitor (runtime/health.py):
           backend reinit, device-referencing caches invalidated, and
           after deviceLoss.maxReinits consecutive losses the CPU-only
@@ -592,6 +592,12 @@ class TpuSession:
         )
 
         F.FAULTS.arm(str(self.conf.get_entry(TEST_FAULTS) or ""))
+        # a host that relies on JAX auto-detection (no JAX_PLATFORMS)
+        # has no cache decision from import time: take it here, where
+        # the backend is about to be used anyway (a flag read once the
+        # cache is on; two cached lookups on the CPU backend)
+        import spark_rapids_tpu as _pkg
+        _pkg.ensure_compile_cache()
         # telemetry sampler + flight-recorder defaults follow this
         # session's conf (cheap no-op when unchanged, the arm contract)
         from spark_rapids_tpu.obs.telemetry import TELEMETRY

@@ -25,23 +25,24 @@ from spark_rapids_tpu.tools.compare import (  # noqa: F401
 )
 
 
-def require_tpu_backend() -> str:
-    """THE --require-tpu gate shared by bench.py and scale_test.py:
-    resolve the JAX backend (initializes it — call only after any
-    virtual-device/mesh environment setup) and exit 2 with a
-    machine-readable error when it is 'cpu'. Returns the backend name.
-    Exists because BENCH_r06 silently committed CPU-backend numbers: a
-    perf run that meant to hit the TPU must fail loudly, with one
-    error contract, not two hand-synced copies."""
+def require_tpu_backend():
+    """THE require-a-TPU gate shared by chip_smoke.py, bench.py and
+    scale_test.py: resolve the JAX backend (initializes it — call only
+    after any virtual-device/mesh environment setup) and exit 2 with a
+    machine-readable error unless the platform is literally 'tpu' — any
+    other name, not only 'cpu', is a run that meant to hit the chip and
+    did not. Returns (platform, device_kind). One error contract, not
+    hand-synced copies: a CPU-backend number was once committed as a
+    chip reading because nothing failed loudly."""
     import json
     import sys
 
     import jax
-    backend = jax.default_backend()
-    if backend == "cpu":
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
         print(json.dumps({
-            "error": "backend is 'cpu' but --require-tpu was given "
-                     "(no TPU backend resolved)",
-            "backend": backend}))
+            "error": f"backend is {dev.platform!r} but a TPU was "
+                     "required (no 'tpu' platform resolved)",
+            "backend": dev.platform}))
         sys.exit(2)
-    return backend
+    return dev.platform, dev.device_kind

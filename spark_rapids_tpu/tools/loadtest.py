@@ -391,8 +391,8 @@ def run_loadtest(sf: float = 0.05, seed: int = 0, queries=None,
         "mode": "loadtest",
         "scaleFactor": sf,
         "seed": seed,
-        # which backend these numbers measured (the BENCH_r06 lesson:
-        # a CPU-backend artifact must say so in-band, not in prose)
+        # which backend these numbers measured (a CPU-backend artifact
+        # must say so in-band, not in prose)
         "backend": jax.default_backend(),
         "form": "sql" if use_sql else "dsl",
         "concurrency": concurrency,

@@ -180,47 +180,3 @@ def test_wide_table_collect_skips_padded_embed():
         col("c0") > lit(0.99)).collect()
     got, want = q(tpu), q(cpu)
     assert len(got) == len(want)
-
-
-# -- persistent compile cache on auto-detected TPU hosts (ADVICE r5) ---------
-
-def test_compile_cache_defers_to_default_backend(monkeypatch):
-    """An unset JAX_PLATFORMS must NOT mean 'cpu, no cache': the decision
-    defers to jax.default_backend() at runtime init, so auto-detected
-    TPU hosts get the persistent cache. Explicit/effective cpu stays
-    uncached (CPU AOT segfault hazard)."""
-    import jax
-
-    import spark_rapids_tpu as st
-
-    monkeypatch.setattr(st, "_compile_cache_enabled", False)
-
-    # explicit cpu config: never enables, never probes the backend
-    monkeypatch.setattr(st, "_configured_platform", lambda: "cpu")
-    assert st.ensure_compile_cache() is False
-
-    # unset config, auto-detection resolved to cpu: stays uncached
-    monkeypatch.setattr(st, "_configured_platform", lambda: "")
-    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    assert st.ensure_compile_cache() is False
-
-    # unset config, auto-detection resolved to a device backend:
-    # the cache turns on and the dir is host-fingerprint-namespaced
-    cache_root = None
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_CACHE", "/tmp/_sr_tpu_cache_test")
-    seen = {}
-    real_update = jax.config.update
-
-    def spy_update(key, value):
-        seen[key] = value
-        if key == "jax_compilation_cache_dir":
-            return  # don't mutate real config in the test process
-        return real_update(key, value)
-
-    monkeypatch.setattr(jax.config, "update", spy_update)
-    assert st.ensure_compile_cache() is True
-    cache_root = seen.get("jax_compilation_cache_dir")
-    assert cache_root and cache_root.startswith("/tmp/_sr_tpu_cache_test")
-    assert cache_root != "/tmp/_sr_tpu_cache_test"  # fingerprint subdir
-    monkeypatch.setattr(st, "_compile_cache_enabled", False)

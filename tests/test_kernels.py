@@ -8,6 +8,8 @@ interpreter evaluates the same jnp program the kernel traces, so any
 divergence from the HLO path is an algorithmic bug, not a backend
 artifact."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -48,6 +50,19 @@ class _forced:
 
     def __exit__(self, *exc):
         kernels.KERNELS_ENABLED.reset(self.tok)
+
+
+@contextlib.contextmanager
+def _ran_on_pallas():
+    """The forced-on call inside must have traced a Pallas kernel and
+    demoted nothing: guarded() turns a kernel failure into a silent HLO
+    fallback, and comparing HLO with HLO proves no identity."""
+    from spark_rapids_tpu.dispatch import COMPILE_SCOPE
+    before = COMPILE_SCOPE.get("pallasKernels", 0)
+    yield
+    assert kernels.demoted_ops() == {}
+    assert COMPILE_SCOPE.get("pallasKernels", 0) > before, \
+        "the forced-on call took the HLO path"
 
 
 def _edge_i64(n, rng):
@@ -94,7 +109,7 @@ def test_sort_bit_identity_vs_lax_sort():
            + descending_operands(comparable_operands(jnp.asarray(f64))))
     payload = jnp.arange(n, dtype=jnp.int32)
     ref = jax.lax.sort(list(ops) + [payload], num_keys=len(ops))
-    with _forced("sort"):
+    with _forced("sort"), _ran_on_pallas():
         got = lex_sort(ops, payload)
     for r, g in zip(ref, got):
         assert _eq(r, g)
@@ -126,7 +141,7 @@ def test_segment_minmax_bit_identity():
     f64_np[np.asarray(gid) == 3] = np.nan
     for vals in (i64, jnp.asarray(f64_np)):
         for is_min in (True, False):
-            with _forced("segreduce"):
+            with _forced("segreduce"), _ran_on_pallas():
                 got = segment_minmax_64(is_min, vals, sv, gid, nseg)
             with _forced():  # empty set = all HLO
                 ref = segment_minmax_64(is_min, vals, sv, gid, nseg)
@@ -146,7 +161,7 @@ def test_split_sum_onehot_bit_identity():
     cancel[0::2], cancel[1::2] = 1e16, -1e16
     cancel[0] += 1.0
     for cols in (well, [jnp.asarray(cancel)]):
-        with _forced("segreduce"):
+        with _forced("segreduce"), _ran_on_pallas():
             got = batched_segment_sum_f64(cols, gid, nseg, n, True)
         with _forced():
             ref = batched_segment_sum_f64(cols, gid, nseg, n, True)
@@ -169,7 +184,7 @@ def test_compact_bit_identity_dtype_zoo():
     for keep_np in (rng.random(n) > 0.5, np.ones(n, bool),
                     np.zeros(n, bool)):
         keep = jnp.asarray(keep_np)
-        with _forced("compact"):
+        with _forced("compact"), _ran_on_pallas():
             got, n_got = compact_pairs(datas, valids, keep, n)
         with _forced():
             ref, n_ref = compact_pairs(datas, valids, keep, n)
@@ -210,6 +225,9 @@ def test_hashprobe_matches_and_flags_duplicates():
             (jnp.asarray(rdup), jnp.ones(cap_r, bool)),
             jnp.ones(cap_l, bool), jnp.ones(cap_r, bool), H, 4)
         assert bool(fail2)
+    # probe_ranges IS the kernel (no guarded() in between, so a failure
+    # raises instead of falling back); nothing may have demoted either
+    assert kernels.demoted_ops() == {}
 
 
 # ---------------------------------------------------------------------------
