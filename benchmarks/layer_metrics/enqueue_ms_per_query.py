@@ -1,0 +1,13 @@
+"""Dispatch + compile (dispatch.py `tpu_jit`): the median over the window
+of the host seconds a query spent inside its program dispatches — the
+enqueue, which blocks on a compile or a full queue (`phasesS.dispatchS` of
+the event record), in milliseconds."""
+
+import statistics
+
+
+def read(run):
+    values = [q["record"]["phasesS"].get("dispatchS") for q in run["queries"]
+              if "record" in q and q["record"].get("phasesS")]
+    values = [v for v in values if v is not None]
+    return statistics.median(values) * 1e3 if values else None

@@ -405,7 +405,9 @@ class DeviceColumn:
         # np.unique-over-objects; order is code-point order == UTF-8 byte
         # order either way (spark_rapids_tpu/native.py)
         from spark_rapids_tpu.native import encode_sorted_dict
-        got = encode_sorted_dict(np.asarray(vals, dtype=object))
+        from spark_rapids_tpu.obs.spans import span
+        with span("encode", "transfer", rows=len(vals)):
+            got = encode_sorted_dict(np.asarray(vals, dtype=object))
         host._cache["encode"] = got
         return got
 

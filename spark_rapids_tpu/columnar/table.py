@@ -11,7 +11,13 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-from spark_rapids_tpu.dispatch import count_pad_waste, tpu_jit
+from spark_rapids_tpu.dispatch import (
+    count_host_sync,
+    count_pad_waste,
+    host_fetch,
+    phase_span,
+    tpu_jit,
+)
 import jax.numpy as jnp
 import numpy as np
 
@@ -23,6 +29,7 @@ from spark_rapids_tpu.columnar.column import (
     stage_upload,
 )
 from spark_rapids_tpu.errors import ColumnarProcessingError
+from spark_rapids_tpu.obs.spans import span
 
 #: jitted per-(recipe, capacity) H2D assemble kernels (see stage_upload):
 #: one device program rebuilds every column's logical dtype + validity from
@@ -67,7 +74,7 @@ def _get_assemble(recipes: tuple, cap: int):
                 outs.append((data, validity))
             return outs
 
-        fn = tpu_jit(assemble)
+        fn = tpu_jit(assemble, name="assemble")
         _ASSEMBLE_CACHE[key] = fn
     return fn
 
@@ -246,7 +253,7 @@ def _get_pack(kinds: tuple, k: int, cap: int, n_extra: int = 0):
                 parts = [head] + parts
             return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
-        fn = tpu_jit(pack)
+        fn = tpu_jit(pack, name="d2h_pack")
         _PACK_CACHE[key] = fn
     return fn
 
@@ -413,7 +420,7 @@ def concat_device(tables: Sequence["DeviceTable"]) -> "DeviceTable":
                 total = total + n
             return outs, total
 
-        fn = tpu_jit(concat)
+        fn = tpu_jit(concat, name="concat")
         _CONCAT_CACHE[key] = fn
 
     cols_per_table = tuple(
@@ -554,21 +561,26 @@ class PendingHostTable:
 
     def resolve(self) -> HostTable:
         from spark_rapids_tpu.runtime import speculation as spec
-        buf = np.asarray(self._buf)  # blocks: the one d2h round trip
-        extras, datas, valids = _unpack_host(buf, self._kinds, self._k,
-                                             self._n_extra)
-        if self._pend:
-            spec.check_flag_values([s for s, _ in self._pend], extras[1:])
-        t = self._table
-        n = int(extras[0])
-        if t._nrows_host is None:
-            t._nrows_host = n
-        n = min(n, self._k)
-        cols = []
-        for c, data, validity in zip(t.columns, datas, valids):
-            cols.append(c.decode_host(
-                data[:n], np.ascontiguousarray(validity[:n])))
-        return HostTable(t.names, cols)
+        with span("resolve", "fetch"):
+            count_host_sync()
+            with phase_span("fetchWaitS", "wait", "fetch"):
+                buf = np.asarray(self._buf)  # blocks: the one d2h round trip
+            with phase_span("fetchUnpackS", "unpack", "fetch"):
+                extras, datas, valids = _unpack_host(
+                    buf, self._kinds, self._k, self._n_extra)
+                if self._pend:
+                    spec.check_flag_values([s for s, _ in self._pend],
+                                           extras[1:])
+                t = self._table
+                n = int(extras[0])
+                if t._nrows_host is None:
+                    t._nrows_host = n
+                n = min(n, self._k)
+                cols = []
+                for c, data, validity in zip(t.columns, datas, valids):
+                    cols.append(c.decode_host(
+                        data[:n], np.ascontiguousarray(validity[:n])))
+                return HostTable(t.names, cols)
 
 
 class DeviceTable:
@@ -625,7 +637,9 @@ class DeviceTable:
     @property
     def num_rows(self) -> int:
         if self._nrows_host is None:
-            self._nrows_host = int(jax.device_get(self.nrows_dev))
+            # a blocking read of a device scalar: through host_fetch, so
+            # it is counted (hostSyncs), timed (syncWaitS) and ranged
+            self._nrows_host = int(host_fetch(self.nrows_dev))
         return self._nrows_host
 
     @property
@@ -710,21 +724,28 @@ class DeviceTable:
         budget accounting happens in the caller)."""
         split_f64 = jax.default_backend() != "cpu"
         recipes, staged, dicts = [], [], []
-        for c in host.columns:
-            recipe, arrays, dictionary = stage_upload(c, cap, split_f64)
-            recipes.append(recipe)
-            staged.extend(arrays)
-            dicts.append(dictionary)
-        if sharding is None:
-            dev_arrays = tuple(jnp.asarray(a) for a in staged)
-        else:
-            # the shard-landing fault site (the second registered
-            # mesh.shard.put call site — parallel/mesh.shard_put covers
-            # the exchange reshards): one evaluation per sharded batch,
-            # before any per-device transfer starts
-            from spark_rapids_tpu.runtime.faults import fault_point
-            fault_point("mesh.shard.put")
-            dev_arrays = tuple(jax.device_put(a, sharding) for a in staged)
+        # the landing's host side, one range each: srt.transfer.stage
+        # (f64 split, padding to the bucket; a string column's
+        # srt.transfer.encode nests in it), srt.transfer.upload, then
+        # the assemble program's srt.dispatch.assemble
+        with span("stage", "transfer", columns=len(host.columns)):
+            for c in host.columns:
+                recipe, arrays, dictionary = stage_upload(c, cap, split_f64)
+                recipes.append(recipe)
+                staged.extend(arrays)
+                dicts.append(dictionary)
+        with span("upload", "transfer", arrays=len(staged)):
+            if sharding is None:
+                dev_arrays = tuple(jnp.asarray(a) for a in staged)
+            else:
+                # the shard-landing fault site (the second registered
+                # mesh.shard.put call site — parallel/mesh.shard_put
+                # covers the exchange reshards): one evaluation per
+                # sharded batch, before any per-device transfer starts
+                from spark_rapids_tpu.runtime.faults import fault_point
+                fault_point("mesh.shard.put")
+                dev_arrays = tuple(jax.device_put(a, sharding)
+                                   for a in staged)
         fn = _get_assemble(tuple(recipes), cap)
         outs = fn(dev_arrays, jnp.asarray(np.int32(host.num_rows)))
         cols = [
@@ -849,7 +870,7 @@ class DeviceTable:
                 outs, _ = compact_pairs(datas, valids, keep, cap)
                 return outs
 
-            fn = tpu_jit(compact)
+            fn = tpu_jit(compact, name="compact_live")
             _PACK_CACHE[key] = fn
         outs = fn(tuple(c.data for c in self.columns),
                   tuple(c.validity for c in self.columns), self.live)

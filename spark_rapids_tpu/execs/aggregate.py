@@ -492,11 +492,12 @@ class TpuHashAggregateExec(TpuExec):
             if fast:
                 fn = tpu_jit(self._build_fast_kernel(
                     capacity, fast[0], fast[3], filter_preps, key_preps,
-                    val_preps, grouping, agg_specs, filters))
+                    val_preps, grouping, agg_specs, filters),
+                    name="agg_fast")
             else:
                 fn = tpu_jit(self._build_kernel(
                     capacity, filter_preps, key_preps, val_preps,
-                    grouping, agg_specs, filters))
+                    grouping, agg_specs, filters), name="agg_sorted")
             self._traces[tkey] = fn
 
         if fast:
@@ -565,7 +566,8 @@ class TpuHashAggregateExec(TpuExec):
         flag_fn = self._traces.get(flag_key)
         if flag_fn is None:
             flag_fn = tpu_jit(
-                lambda n: n > jnp.asarray(spec_cap, jnp.int32))
+                lambda n: n > jnp.asarray(spec_cap, jnp.int32),
+                name="agg_flag")
             self._traces[flag_key] = flag_fn
         ctx.add_flag(site, flag_fn(out.nrows_dev))
         cols = [c.sliced_rows(spec_cap) for c in out.columns]
@@ -599,29 +601,34 @@ class TpuHashAggregateExec(TpuExec):
         value_exprs = [list(fn.children) for _, fn in agg_specs]
         use_split = self.use_split
 
+        # the named scopes are op metadata: they tell one fusion of
+        # jit_agg_fast from another on the device timeline, and cost
+        # nothing at run time
         def kernel(cols, aux, nrows, sizes, strides, bases, live_in):
-            live = self._eval_live(filters, capacity, cols, aux, nrows,
-                                   filter_preps, live_in)
+            with jax.named_scope("live_mask"):
+                live = self._eval_live(filters, capacity, cols, aux,
+                                       nrows, filter_preps, live_in)
 
-            gid = jnp.zeros(capacity, dtype=jnp.int32)
-            for i, (g, preps, kind) in enumerate(zip(grouping, key_preps, kinds)):
-                ctx = EvalCtx(cols, aux, nrows, capacity, live=live_in)
-                ctx._prep_iter = iter(preps)
-                kv = _walk_eval(g, ctx)
-                if kind == "int":
-                    # domain-coded integer key: value - base. The where
-                    # runs BEFORE the int32 narrowing — invalid/padding
-                    # slots hold arbitrary data, valid ones are inside the
-                    # stats bound by the domain superset contract.
-                    delta = kv.data.astype(jnp.int64) - bases[i]
-                    code = jnp.where(kv.validity, delta,
-                                     (sizes[i] - 1).astype(jnp.int64))
-                    code = code.astype(jnp.int32)
-                else:
-                    code = (kv.data.astype(jnp.int32)
-                            if kind == "bool" else kv.data)
-                    code = jnp.where(kv.validity, code, sizes[i] - 1)
-                gid = gid + code * strides[i]
+            with jax.named_scope("group_ids"):
+                gid = jnp.zeros(capacity, dtype=jnp.int32)
+                for i, (g, preps, kind) in enumerate(zip(grouping, key_preps, kinds)):
+                    ctx = EvalCtx(cols, aux, nrows, capacity, live=live_in)
+                    ctx._prep_iter = iter(preps)
+                    kv = _walk_eval(g, ctx)
+                    if kind == "int":
+                        # domain-coded integer key: value - base. The where
+                        # runs BEFORE the int32 narrowing — invalid/padding
+                        # slots hold arbitrary data, valid ones are inside the
+                        # stats bound by the domain superset contract.
+                        delta = kv.data.astype(jnp.int64) - bases[i]
+                        code = jnp.where(kv.validity, delta,
+                                         (sizes[i] - 1).astype(jnp.int64))
+                        code = code.astype(jnp.int32)
+                    else:
+                        code = (kv.data.astype(jnp.int32)
+                                if kind == "bool" else kv.data)
+                        code = jnp.where(kv.validity, code, sizes[i] - 1)
+                    gid = gid + code * strides[i]
 
             # ---- batched value aggregation ------------------------------
             # All sum-class f64 reductions (Sum/Average/Stddev/Variance)
@@ -629,38 +636,40 @@ class TpuHashAggregateExec(TpuExec):
             # for every spec plus group existence ride one 2-D i32
             # segment_sum. Min/Max/First/Last and i64 sums stay per-spec
             # (_agg_one).
-            vvs = []
-            for ves, per_child in zip(value_exprs, val_preps):
-                vals = []
-                for ve, preps in zip(ves, per_child):
-                    ctx = EvalCtx(cols, aux, nrows, capacity, live=live_in)
-                    ctx._prep_iter = iter(preps)
-                    vals.append(_walk_eval(ve, ctx))
-                vvs.append(vals)
-            svs = [(vv[0].validity & live) if vv else None for vv in vvs]
+            with jax.named_scope("agg_values"):
+                vvs = []
+                for ves, per_child in zip(value_exprs, val_preps):
+                    vals = []
+                    for ve, preps in zip(ves, per_child):
+                        ctx = EvalCtx(cols, aux, nrows, capacity, live=live_in)
+                        ctx._prep_iter = iter(preps)
+                        vals.append(_walk_eval(ve, ctx))
+                    vvs.append(vals)
+                svs = [(vv[0].validity & live) if vv else None for vv in vvs]
 
             # one scatter for live-count + every spec's nonnull count
-            masks = [live] + [sv for sv in svs if sv is not None]
-            mix = {}
-            k = 1
-            for j, sv in enumerate(svs):
-                if sv is not None:
-                    mix[j] = k
-                    k += 1
-            if gpad <= 4096:
-                # one 2-D scatter: reads the input once; the minor-dim
-                # 128-lane padding on the OUTPUT is cheap at small gpad
-                mcnt = jax.ops.segment_sum(
-                    jnp.stack(masks, axis=1).astype(jnp.int32), gid,
-                    num_segments=gpad)
-            else:
-                # large gpad: the padded (gpad, 128-lane) output dwarfs
-                # the input re-reads — per-mask 1-D scatters win
-                mcnt = jnp.stack(
-                    [jax.ops.segment_sum(mk.astype(jnp.int32), gid,
-                                         num_segments=gpad)
-                     for mk in masks], axis=1)
-            nonnulls = {j: mcnt[:, i] for j, i in mix.items()}
+            with jax.named_scope("valid_counts"):
+                masks = [live] + [sv for sv in svs if sv is not None]
+                mix = {}
+                k = 1
+                for j, sv in enumerate(svs):
+                    if sv is not None:
+                        mix[j] = k
+                        k += 1
+                if gpad <= 4096:
+                    # one 2-D scatter: reads the input once; the minor-dim
+                    # 128-lane padding on the OUTPUT is cheap at small gpad
+                    mcnt = jax.ops.segment_sum(
+                        jnp.stack(masks, axis=1).astype(jnp.int32), gid,
+                        num_segments=gpad)
+                else:
+                    # large gpad: the padded (gpad, 128-lane) output dwarfs
+                    # the input re-reads — per-mask 1-D scatters win
+                    mcnt = jnp.stack(
+                        [jax.ops.segment_sum(mk.astype(jnp.int32), gid,
+                                             num_segments=gpad)
+                         for mk in masks], axis=1)
+                nonnulls = {j: mcnt[:, i] for j, i in mix.items()}
 
             exists = mcnt[:, 0] > 0
             if not grouping:
@@ -783,9 +792,10 @@ class TpuHashAggregateExec(TpuExec):
                         fnagg, sd, svs[j], live, gid, gpad, exists,
                         capacity, use_split)
                 pairs.append((data, validity))
-            from spark_rapids_tpu.ops.scatter32 import compact_pairs
-            outs, _ = compact_pairs([d for d, _ in pairs],
-                                    [v for _, v in pairs], exists, gpad)
+            with jax.named_scope("compact_groups"):
+                from spark_rapids_tpu.ops.scatter32 import compact_pairs
+                outs, _ = compact_pairs([d for d, _ in pairs],
+                                        [v for _, v in pairs], exists, gpad)
             return list(outs), ngroups
 
         return kernel
@@ -797,64 +807,67 @@ class TpuHashAggregateExec(TpuExec):
         use_split = self.use_split
 
         def kernel(cols, aux, nrows, live_in):
-            live = self._eval_live(filters, capacity, cols, aux, nrows,
-                                   filter_preps, live_in)
+            with jax.named_scope("live_mask"):
+                live = self._eval_live(filters, capacity, cols, aux,
+                                       nrows, filter_preps, live_in)
 
-            key_vals: List[DevVal] = []
-            for g, preps in zip(grouping, key_preps):
-                ctx = EvalCtx(cols, aux, nrows, capacity, live=live_in)
-                ctx._prep_iter = iter(preps)
-                key_vals.append(_walk_eval(g, ctx))
-            val_vals = []
-            for ves, per_child in zip(value_exprs, val_preps):
-                vals = []
-                for ve, preps in zip(ves, per_child):
+            with jax.named_scope("agg_values"):
+                key_vals: List[DevVal] = []
+                for g, preps in zip(grouping, key_preps):
                     ctx = EvalCtx(cols, aux, nrows, capacity, live=live_in)
                     ctx._prep_iter = iter(preps)
-                    vals.append(_walk_eval(ve, ctx))
-                val_vals.append(vals)
+                    key_vals.append(_walk_eval(g, ctx))
+                val_vals = []
+                for ves, per_child in zip(value_exprs, val_preps):
+                    vals = []
+                    for ve, preps in zip(ves, per_child):
+                        ctx = EvalCtx(cols, aux, nrows, capacity, live=live_in)
+                        ctx._prep_iter = iter(preps)
+                        vals.append(_walk_eval(ve, ctx))
+                    val_vals.append(vals)
 
-            # normalize float keys so grouping matches the CPU oracle
-            norm = []
-            for kv in key_vals:
-                d = kv.data
-                if jnp.issubdtype(d.dtype, jnp.floating):
-                    d = jnp.where(d == 0.0, jnp.zeros_like(d), d)
-                norm.append(DevVal(d, kv.validity))
-            key_vals = norm
-
-            if grouping:
-                operands = [(~live).astype(jnp.int32)]  # dead rows last
+            with jax.named_scope("group_ids"):
+                # normalize float keys so grouping matches the CPU oracle
+                norm = []
                 for kv in key_vals:
-                    operands.extend(_sortable(kv.data, kv.validity))
-                from spark_rapids_tpu.ops.ordering import lex_sort
-                payload = jnp.arange(capacity, dtype=jnp.int32)
-                sorted_all = lex_sort(operands, payload)
-                perm = sorted_all[-1]
-                s_live = live[perm]
-                s_keys = [DevVal(kv.data[perm], kv.validity[perm])
-                          for kv in key_vals]
-                s_vals = [[DevVal(x.data[perm], x.validity[perm])
-                           for x in vv] for vv in val_vals]
+                    d = kv.data
+                    if jnp.issubdtype(d.dtype, jnp.floating):
+                        d = jnp.where(d == 0.0, jnp.zeros_like(d), d)
+                    norm.append(DevVal(d, kv.validity))
+                key_vals = norm
 
-                # group boundaries on the CANONICAL operands (raw float
-                # compares would split NaN groups: NaN != NaN); the sort
-                # already emitted every operand in sorted order — compare
-                # those directly instead of re-gathering by perm
-                first = jnp.arange(capacity) == 0
-                changed = jnp.zeros(capacity, dtype=jnp.bool_)
-                for so in sorted_all[1:-1]:
-                    changed = changed | (so != jnp.roll(so, 1))
-                new_group = (first | changed) & s_live
-                gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-                gid = jnp.where(s_live, gid, capacity - 1)  # park dead rows
-                ngroups = jnp.sum(new_group.astype(jnp.int32))
-            else:
-                s_live = live
-                s_keys = []
-                s_vals = val_vals
-                gid = jnp.zeros(capacity, dtype=jnp.int32)
-                ngroups = jnp.asarray(1, dtype=jnp.int32)
+                if grouping:
+                    operands = [(~live).astype(jnp.int32)]  # dead rows last
+                    for kv in key_vals:
+                        operands.extend(_sortable(kv.data, kv.validity))
+                    from spark_rapids_tpu.ops.ordering import lex_sort
+                    payload = jnp.arange(capacity, dtype=jnp.int32)
+                    sorted_all = lex_sort(operands, payload)
+                    perm = sorted_all[-1]
+                    s_live = live[perm]
+                    s_keys = [DevVal(kv.data[perm], kv.validity[perm])
+                              for kv in key_vals]
+                    s_vals = [[DevVal(x.data[perm], x.validity[perm])
+                               for x in vv] for vv in val_vals]
+
+                    # group boundaries on the CANONICAL operands (raw float
+                    # compares would split NaN groups: NaN != NaN); the sort
+                    # already emitted every operand in sorted order — compare
+                    # those directly instead of re-gathering by perm
+                    first = jnp.arange(capacity) == 0
+                    changed = jnp.zeros(capacity, dtype=jnp.bool_)
+                    for so in sorted_all[1:-1]:
+                        changed = changed | (so != jnp.roll(so, 1))
+                    new_group = (first | changed) & s_live
+                    gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+                    gid = jnp.where(s_live, gid, capacity - 1)  # park dead rows
+                    ngroups = jnp.sum(new_group.astype(jnp.int32))
+                else:
+                    s_live = live
+                    s_keys = []
+                    s_vals = val_vals
+                    gid = jnp.zeros(capacity, dtype=jnp.int32)
+                    ngroups = jnp.asarray(1, dtype=jnp.int32)
 
             group_live = jnp.arange(capacity, dtype=jnp.int32) < ngroups
 

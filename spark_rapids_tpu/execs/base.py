@@ -87,8 +87,8 @@ class HostToDevice(TpuExec):
         return self.cpu_node.output_schema()
 
     def execute(self):
+        from spark_rapids_tpu.obs.spans import span
         from spark_rapids_tpu.runtime.memory import scan_chunks
-        from spark_rapids_tpu.runtime.profiler import op_range
         from spark_rapids_tpu.runtime.retry import retry_block
         for batch in self.cpu_node.execute_cpu():
             # transitions are device landings like scans: batches over
@@ -97,7 +97,7 @@ class HostToDevice(TpuExec):
             # instead of failing the query at the upload
             for ch in scan_chunks(batch):
                 t0 = time.perf_counter()
-                with op_range("HostToDevice", cat="transfer"):
+                with span("HostToDevice", "transfer"):
                     dt = retry_block(
                         lambda c=ch: DeviceTable.from_host(c))
                 self.add_metric("h2dTime", time.perf_counter() - t0)
@@ -139,10 +139,10 @@ class DeviceToHost:
 
     def execute_cpu(self) -> Iterator[HostTable]:
         from spark_rapids_tpu.columnar.table import PendingHostTable
-        from spark_rapids_tpu.runtime.profiler import op_range
+        from spark_rapids_tpu.obs.spans import span
         for dt in self.tpu_exec.execute():
             t0 = time.perf_counter()
-            with op_range("DeviceToHost", cat="transfer"):
+            with span("DeviceToHost", "transfer"):
                 out = dt.to_host_pending() if self._async_fetch \
                     else dt.to_host()
             # incremental so an early-terminating consumer (limit) still

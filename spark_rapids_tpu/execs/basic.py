@@ -310,7 +310,7 @@ class _FilterKernel:
                                             keep, capacity)
                 return outs, new_n, errs
 
-            got = (tpu_jit(run), labels)
+            got = (tpu_jit(run, name="filter"), labels)
             self._traces[tkey] = got
         fn, labels = got
 
@@ -574,6 +574,6 @@ def _compaction_kernel(capacity: int, schema_key):
             from spark_rapids_tpu.ops.scatter32 import compact_pairs
             return compact_pairs(datas, valids, keep, capacity)
 
-        fn = tpu_jit(run)
+        fn = tpu_jit(run, name="compact_rows")
         _COMPACT_KERNELS[key] = fn
     return fn

@@ -209,7 +209,7 @@ class TpuNestedLoopJoinExec(TpuExec):
         fn = traces.get(tkey)
         if fn is None:
             fn = tpu_jit(self._build_tile_kernel(
-                jt, swapped, cap_p, cap_b, preps))
+                jt, swapped, cap_p, cap_b, preps), name="nlj_tile")
             traces[tkey] = fn
 
         lcols = tuple((c.data, c.validity) for c in lt.columns)

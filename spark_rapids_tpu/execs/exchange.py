@@ -268,7 +268,7 @@ class TpuShuffleExchangeExec(TpuExec):
                     outs.append((m, jnp.sum(m.astype(jnp.int32))))
                 return outs
 
-            fn = tpu_jit(masks)
+            fn = tpu_jit(masks, name="split_masks")
             traces[tkey] = fn
         outs = fn(pids, table.nrows_dev, table.live)
         self.add_metric("localSplitParts", nparts)

@@ -105,7 +105,8 @@ class TpuGenerateExec(TpuExec):
                 table.schema_key()[0])
         fn = traces.get(tkey)
         if fn is None:
-            fn = tpu_jit(self._build_kernel(cap, ecap, out_cap, preps))
+            fn = tpu_jit(self._build_kernel(cap, ecap, out_cap, preps),
+                         name="generate")
             traces[tkey] = fn
         out_arrays, nout = fn(gen_cols, cols, aux, table.nrows_dev)
 

@@ -105,7 +105,7 @@ class JoinKernel:
                 tuple(str(k[0].dtype) for k in rkeys))
         fn = self._probe_traces.get(tkey)
         if fn is None:
-            fn = tpu_jit(self._build_probe(cap_l, cap_r))
+            fn = tpu_jit(self._build_probe(cap_l, cap_r), name="join_probe")
             self._probe_traces[tkey] = fn
         return fn(tuple(lkeys), tuple(rkeys), nl_dev, nr_dev, live_l_mask)
 
@@ -176,7 +176,8 @@ class JoinKernel:
         tkey = (kind, out_cap, cap_l, cap_r)
         fn = self._gather_traces.get(tkey)
         if fn is None:
-            fn = tpu_jit(self._build_expand(kind, out_cap, cap_l))
+            fn = tpu_jit(self._build_expand(kind, out_cap, cap_l),
+                         name="join_expand")
             self._gather_traces[tkey] = fn
         return fn(*args)
 
@@ -277,7 +278,7 @@ class _DirectJoinKernel:
         fn = cls._traces.get(key)
         if fn is None:
             fn = tpu_jit(cls._build(jt, H, lt.capacity, rt.capacity,
-                                    masked_out))
+                                    masked_out), name="join_direct")
             cls._traces[key] = fn
         l_cols = tuple((c.data, c.validity) for c in lt.columns)
         r_cols = tuple((c.data, c.validity) for c in rt.columns)
@@ -371,7 +372,7 @@ class _ColumnGather:
                     out.append((d[safe], v[safe] & ~null_mask & out_live))
                 return out
 
-            fn = tpu_jit(gather)
+            fn = tpu_jit(gather, name="join_gather")
             cls._traces[key] = fn
         datas = tuple(c.data for c in table.columns)
         valids = tuple(c.validity for c in table.columns)
@@ -587,7 +588,7 @@ class TpuJoinExec(TpuExec):
                 return jax.ops.segment_sum(
                     live.astype(jnp.int32), jnp.clip(pids, 0, nparts - 1),
                     num_segments=nparts)
-            fn = tpu_jit(counts_fn)
+            fn = tpu_jit(counts_fn, name="join_split_counts")
             self._kernel._aux_traces[key] = fn
         from spark_rapids_tpu.dispatch import host_fetch
         counts = np.asarray(host_fetch(fn(pids, live)))
@@ -730,7 +731,7 @@ class TpuJoinExec(TpuExec):
                         (live_l & (counts == 0)).astype(jnp.int64))
                 return tot > out_cap
 
-            fn = tpu_jit(flag)
+            fn = tpu_jit(flag, name="join_size_flag")
             self._kernel._aux_traces[key] = fn
         return fn(total_d, counts, live_l)
 
@@ -801,7 +802,7 @@ class TpuJoinExec(TpuExec):
             # fall back again
             from spark_rapids_tpu.dispatch import COMPILE_SCOPE
             from spark_rapids_tpu.kernels import KernelIneligible
-            fn = tpu_jit(hashprobe)
+            fn = tpu_jit(hashprobe, name="join_hash_probe")
             try:
                 out = fn(lkeys[0], rkeys[0], lt.nrows_dev, rt.nrows_dev,
                          lt.live)
@@ -912,7 +913,7 @@ class TpuJoinExec(TpuExec):
                 marks = marks.at[ends].add(jnp.where(counts > 0, -1, 0), mode="drop")
                 covered_sorted = jnp.cumsum(marks[:-1]) > 0
                 return jnp.zeros(cap_r, jnp.bool_).at[rs_perm].set(covered_sorted)
-            fn = tpu_jit(rmatch)
+            fn = tpu_jit(rmatch, name="join_right_matched")
             self._kernel._aux_traces[key] = fn
         return fn(lo, counts, rs_perm)
 
@@ -920,7 +921,8 @@ class TpuJoinExec(TpuExec):
         key = ("maskcount", keep.shape[0])
         fn = self._kernel._aux_traces.get(key)
         if fn is None:
-            fn = tpu_jit(lambda k: jnp.sum(k.astype(jnp.int32)))
+            fn = tpu_jit(lambda k: jnp.sum(k.astype(jnp.int32)),
+                         name="join_mask_count")
             self._kernel._aux_traces[key] = fn
         return fn(keep)
 
@@ -938,7 +940,7 @@ class TpuJoinExec(TpuExec):
                 from spark_rapids_tpu.ops.scatter32 import compact_pairs
                 return compact_pairs(datas, valids, keep, cap)
 
-            fn = tpu_jit(compact)
+            fn = tpu_jit(compact, name="join_compact")
             self._kernel._aux_traces[key] = fn
         datas = tuple(c.data for c in table.columns)
         valids = tuple(c.validity for c in table.columns)
@@ -961,7 +963,7 @@ class TpuJoinExec(TpuExec):
                 ri = j % nr64
                 out_live = j < nl_d.astype(jnp.int64) * nr_d.astype(jnp.int64)
                 return li, ri, out_live
-            fn = tpu_jit(cross_maps)
+            fn = tpu_jit(cross_maps, name="join_cross_maps")
             self._kernel._aux_traces[key] = fn
         li, ri, out_live = fn(lt.nrows_dev, rt.nrows_dev)
         zero = jnp.zeros(out_cap, jnp.bool_)

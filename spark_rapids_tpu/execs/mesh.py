@@ -63,7 +63,7 @@ def _table_digest(table: DeviceTable):
             if live is not None:
                 acc = acc + wordsum_u32(live)
             return acc
-        return tpu_jit(digest)
+        return tpu_jit(digest, name="reland_digest")
 
     fn = digest_kernel(key, build)
     return fn(tuple(c.data for c in table.columns),

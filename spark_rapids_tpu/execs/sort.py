@@ -201,7 +201,7 @@ class TpuSortExec(TpuExec):
                 perm = res[-1]
                 return [(d[perm], v[perm]) for d, v in cols]
 
-            fn = tpu_jit(run)
+            fn = tpu_jit(run, name="sort_run")
             self._traces[tkey] = fn
 
         outs = fn(cols, aux, table.nrows_dev, table.live)
@@ -270,7 +270,7 @@ class TpuSortExec(TpuExec):
                     outs.append((d[idx], v[idx] & out_live))
                 return outs, n_out
 
-            fn = tpu_jit(run)
+            fn = tpu_jit(run, name="sort_topk")
             self._traces[tkey] = fn
         outs, n_out = fn(cols, aux, table.nrows_dev, table.live)
         new_cols = [c.with_arrays(d, v)

@@ -557,7 +557,8 @@ class TpuWindowExec(TpuExec):
             for op, vp in specs))
         fn = self._traces.get(tkey)
         if fn is None:
-            fn = tpu_jit(self._build_stream_kernel(capacity, specs))
+            fn = tpu_jit(self._build_stream_kernel(capacity, specs),
+                         name="window_stream")
             self._traces[tkey] = fn
         if state is None:
             state = self._initial_state()
@@ -773,7 +774,8 @@ class TpuWindowExec(TpuExec):
             for pp, op, vp in expr_preps))
         fn = self._traces.get(tkey)
         if fn is None:
-            fn = tpu_jit(self._build_kernel(capacity, expr_preps))
+            fn = tpu_jit(self._build_kernel(capacity, expr_preps),
+                         name="window")
             self._traces[tkey] = fn
         col_outs, win_outs = fn(cols, aux, table.nrows_dev)
 
@@ -1273,7 +1275,8 @@ class TpuWindowGroupLimitExec(TpuExec):
                 tuple(_prep_trace_key(x) for x in op))
         fn = self._traces.get(tkey)
         if fn is None:
-            fn = tpu_jit(self._build_kernel(capacity, pp, op))
+            fn = tpu_jit(self._build_kernel(capacity, pp, op),
+                         name="window_group_limit")
             self._traces[tkey] = fn
         keep, nkeep = fn(cols, aux, table.nrows_dev, table.live)
         self.add_metric("groupLimitBatches", 1)

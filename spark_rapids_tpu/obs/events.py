@@ -126,7 +126,18 @@ EVENT_LOG_DIR = str_conf(
 #: 0 for non-streaming queries and result-cache serves; plus mvEpoch
 #: (the maintained table's Delta version when this query was served
 #: FROM a materialized view; null for every other query).
-EVENT_SCHEMA_VERSION = 11
+#: v12 (tracing PR): + hostSyncs (blocking device->host fetches of the
+#: query: ``dispatch.host_fetch`` calls plus root-result
+#: ``PendingHostTable.resolve``s; 0 for result-cache serves), and
+#: phasesS gains the host seconds taken where the work happens —
+#: parseS (``sql()`` lowering, absent for DataFrame-built queries),
+#: dispatchS (inside ``tpu_jit`` calls: enqueue, compile, a full
+#: queue), syncWaitS (blocked in ``host_fetch``), fetchWaitS /
+#: fetchUnpackS (the root result's blocking d2h and its host unpack)
+#: and semaphoreWaitS (the wait for a device slot). planS / executeS /
+#: collectS are unchanged; the new ones lie inside executeS + collectS
+#: (parseS before the wall) and overlap nothing but them.
+EVENT_SCHEMA_VERSION = 12
 
 
 def plan_tree(executable) -> dict:
@@ -264,7 +275,8 @@ def build_query_record(*, query_index: int, wall_s: float,
                        mv_full_recomputes: int = 0,
                        sink_commits: int = 0,
                        sink_replays: int = 0,
-                       mv_epoch: Optional[int] = None) -> dict:
+                       mv_epoch: Optional[int] = None,
+                       host_syncs: int = 0) -> dict:
     """Assemble one event-log record. Every field is JSON-native; the
     golden schema test normalizes timings and pins the shape.
     ``service`` is the query-service envelope (tenant, pool, queueWaitS,
@@ -293,6 +305,7 @@ def build_query_record(*, query_index: int, wall_s: float,
         "wallS": round(wall_s, 6),
         "phasesS": {k: round(v, 6) for k, v in sorted(phases.items())},
         "dispatches": dispatches,
+        "hostSyncs": int(host_syncs),
         "compileMs": round(float(compile_ms), 3),
         "executableCacheHit": bool(executable_cache_hit),
         "padWasteRows": int(pad_waste_rows),

@@ -1,20 +1,29 @@
-"""Thread-aware host-side span tracer + exec-boundary instrumentation.
+"""The engine's one span primitive + exec-boundary instrumentation.
 
 Reference (SURVEY.md §5): NVTX ranges (``NvtxWithMetrics.scala``) put
 operator ranges on the DEVICE timeline; nothing in the reference shows
 where HOST wall time goes — which is where this engine's queries can
-live (transfers, shuffle IO, serialization, spill). This
-tracer records host spans (enter/exit wall times, thread, parent,
-query/op attribution) and exports Chrome trace-event JSON, so a host
-timeline loads in Perfetto/chrome://tracing NEXT TO the Xprof device
-trace the profiler collects.
+live (transfers, shuffle IO, serialization, spill).
+
+:func:`span` is the ONE function that opens a range, and it writes to
+two sinks. It always enters a ``jax.profiler.TraceAnnotation`` named
+``srt.<cat>.<name>`` (``srt.<name>`` where the name already starts
+with its category: ``srt.query``, ``srt.shuffle.fetch``), so the
+range lies in ``/host:CPU`` of any Xprof trace of the process, on the
+clock of the device planes; a TraceMe costs about a microsecond while
+no profiler session is active. While the thread's query envelope
+collects, the same range is also a :class:`Span` of the
+:class:`SpanTracer`, which the session summarises into the event
+record and exports as Chrome trace-event JSON. Every range opened on a
+thread that executes a query carries ``query=<query index>`` as
+annotation metadata; nesting on the thread gives the parent.
 
 Two layers:
 
 * :class:`SpanTracer` / the process-wide :data:`TRACER` — collection is
   enabled per query by the session (``spark.rapids.trace.enabled``, or
-  implicitly while the event log needs attribution). Disabled cost is
-  one attribute read per site.
+  implicitly while the event log needs attribution). Idle, a range is
+  its annotation and nothing else.
 * :func:`install_observation` — the per-query exec-boundary wrapper
   (the ``install_fault_boundaries`` threading pattern from PR 3): every
   device exec's ``execute``/``execute_masked`` and the ``DeviceToHost``
@@ -31,6 +40,8 @@ import json
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from spark_rapids_tpu.conf import bool_conf, str_conf
 from spark_rapids_tpu.lockorder import ordered_lock
@@ -73,31 +84,27 @@ class Span:
         return (self.t1 - self.t0) if self.t1 is not None else 0.0
 
 
-class _NoopSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _NoopSpan()
-
-
 class _LiveSpan:
-    __slots__ = ("tracer", "span")
+    """A range while its thread's query collects: the annotation plus
+    the tracer's span, entered and left together."""
 
-    def __init__(self, tracer, span):
-        self.tracer = tracer
-        self.span = span
+    __slots__ = ("ann", "name", "cat", "args", "span")
+
+    def __init__(self, ann, name, cat, args):
+        self.ann = ann
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.span = None
 
     def __enter__(self):
+        self.span = TRACER._begin(self.name, self.cat, self.args)
+        self.ann.__enter__()
         return self.span
 
     def __exit__(self, *exc):
-        self.tracer.end(self.span)
+        self.ann.__exit__(*exc)
+        TRACER._end(self.span)
         return False
 
 
@@ -123,6 +130,10 @@ class _QueryCtx:
 #: sentinel bound to a thread's ctx slot while it runs an UNOBSERVED
 #: query — blocks the single-active-context adoption below
 _ADOPT_BLOCKED = object()
+#: sentinel bound from ``end_query`` to ``leave_query``: the envelope's
+#: tail (row-count fetch, record build, event write) runs on this thread
+#: and belongs to no other query's context either
+_DETACHED = object()
 
 
 class SpanTracer:
@@ -147,9 +158,10 @@ class SpanTracer:
     # -- context resolution -------------------------------------------------
     def _ctx(self) -> Optional[_QueryCtx]:
         ctx = getattr(self._tls, "ctx", None)
-        if ctx is _ADOPT_BLOCKED:
-            # this thread runs an UNOBSERVED query concurrently with an
-            # observed one: its spans belong to neither active ctx
+        if ctx is _ADOPT_BLOCKED or ctx is _DETACHED:
+            # this thread runs an UNOBSERVED query (or an observed one's
+            # tail) concurrently with an observed one: its spans belong
+            # to neither active ctx
             return None
         if ctx is not None and not ctx.closed:
             return ctx
@@ -163,20 +175,30 @@ class SpanTracer:
                 return next(iter(self._ctxs.values()))
         return None
 
-    def begin_unobserved_query(self) -> None:
+    def begin_unobserved_query(self, query_id: Optional[int] = None) -> None:
         """Mark this thread as executing a query WITHOUT an observation
         envelope (event log and tracing off for its session): neither
         its own spans nor its helper-pool work may be adopted into some
         other session's concurrently active query context."""
         self._tls.ctx = _ADOPT_BLOCKED
+        self._tls.query = query_id
         with self._lock:
             self._unobserved += 1
 
-    def end_unobserved_query(self) -> None:
-        if getattr(self._tls, "ctx", None) is _ADOPT_BLOCKED:
-            self._tls.ctx = None
+    def leave_query(self) -> None:
+        """This thread is done with its query, the envelope's tail
+        included: ranges it opens from here on carry no ``query`` and
+        may be adopted again. Closes whatever ``begin_query`` /
+        ``begin_unobserved_query`` left open (a failed query never
+        reaches ``end_query``)."""
+        ctx = getattr(self._tls, "ctx", None)
+        if ctx is _ADOPT_BLOCKED:
             with self._lock:
                 self._unobserved -= 1
+        elif ctx is not None and ctx is not _DETACHED:
+            self.end_query()
+        self._tls.ctx = None
+        self._tls.query = None
 
     def _stack(self, ctx: _QueryCtx) -> list:
         return ctx.stacks.setdefault(threading.get_ident(), [])
@@ -212,24 +234,25 @@ class SpanTracer:
             self._ctxs[tid] = ctx
             self.enabled = True
         self._tls.ctx = ctx
+        self._tls.query = query_id
         return ctx
 
     def end_query(self) -> List[Span]:
         """Stop collecting THIS thread's query and return its finished
-        spans."""
+        spans. The thread stays detached (no adoption, ``query`` still
+        set) until ``leave_query``."""
         tid = threading.get_ident()
         with self._lock:
             ctx = self._ctxs.pop(tid, None)
             self.enabled = bool(self._ctxs)
-        self._tls.ctx = None
+        self._tls.ctx = _DETACHED
         if ctx is None:
             return []
         ctx.closed = True
         return [s for s in ctx.spans if s.t1 is not None]
 
-    def begin(self, name: str, cat: str = "op", **args) -> Optional[Span]:
-        if not self.enabled:
-            return None
+    # the tracer's half of a range: only ``span()`` below calls these
+    def _begin(self, name: str, cat: str, args) -> Optional[Span]:
         ctx = self._ctx()
         if ctx is None:
             return None
@@ -250,7 +273,7 @@ class SpanTracer:
         st.append(sp)
         return sp
 
-    def end(self, span: Optional[Span]) -> None:
+    def _end(self, span: Optional[Span]) -> None:
         if span is None or span.t1 is not None:
             return  # idempotent: an error path may re-end a closed span
         span.t1 = time.perf_counter()
@@ -265,12 +288,6 @@ class SpanTracer:
                 st.pop().t1 = span.t1
             if st:
                 st.pop()
-
-    def span(self, name: str, cat: str = "op", **args):
-        """Context manager; zero-allocation no-op when disabled."""
-        if not self.enabled:
-            return _NOOP
-        return _LiveSpan(self, self.begin(name, cat, **args))
 
     # -- cross-host trace propagation ---------------------------------------
     def add_remote_spans(self, source: str, payload, anchor_t0: float,
@@ -322,7 +339,24 @@ TRACER = SpanTracer()
 
 
 def span(name: str, cat: str = "op", **args):
-    return TRACER.span(name, cat, **args)
+    """Open one range (a context manager) on both sinks: the profiler's
+    host timeline as ``srt.<cat>.<name>`` — always — and the tracer's
+    span buffer while this thread's query collects. ``args`` and the
+    thread's query index ride as annotation metadata. With the tracer
+    idle the returned object IS the annotation: nothing else is
+    allocated."""
+    if name == cat or name.startswith(cat + "."):
+        full = "srt." + name   # srt.query; names that carry their cat
+    else:
+        full = f"srt.{cat}.{name}"
+    query = getattr(TRACER._tls, "query", None)
+    if query is None:
+        ann = TraceAnnotation(full, **args)
+    else:
+        ann = TraceAnnotation(full, query=query, **args)
+    if not TRACER.enabled:
+        return ann
+    return _LiveSpan(ann, name, cat, args)
 
 
 # ---------------------------------------------------------------------------
@@ -432,15 +466,13 @@ def _observed(fn, e, name: str, count_output: bool):
                 continue
             e._obs_depth = 1
             t0 = time.perf_counter()
-            sp = TRACER.begin(name, "exec") if TRACER.enabled else None
             stop = False
             try:
-                try:
+                with span(name, "exec"):
                     batch = next(it)
-                except StopIteration:
-                    stop = True
+            except StopIteration:
+                stop = True
             finally:
-                TRACER.end(sp)
                 e._obs_depth = 0
                 e.metrics.add("opTime", time.perf_counter() - t0)
             if stop:

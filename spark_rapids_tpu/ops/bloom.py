@@ -93,7 +93,7 @@ def _build_kernel(num_bits: int, k: int, cap: int):
                 bits = bits.at[tgt].max(True, mode="drop")
             return bits
 
-        fn = tpu_jit(build)
+        fn = tpu_jit(build, name="bloom_build")
         _BUILD_CACHE[key] = fn
     return fn
 

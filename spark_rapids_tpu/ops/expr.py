@@ -542,7 +542,7 @@ class CompiledProject:
                 labels.extend(lbl for lbl, _ in errs)
                 return outs, tuple(f for _, f in errs)
 
-            got = (tpu_jit(traced), labels)
+            got = (tpu_jit(traced, name="project"), labels)
             self._traces[tkey] = got
         return got
 
@@ -619,11 +619,12 @@ _GLOBAL_KERNEL_CACHE: dict = {}
 
 
 def cached_kernel(key: tuple, build):
-    """Return the jitted kernel for ``key``, building (and jitting) it on
-    first use. ``build`` must close only over values captured by the key."""
+    """Return the jitted kernel for ``key``, building it on first use.
+    ``build`` returns the ``tpu_jit`` program (named at its own site) and
+    must close only over values captured by the key."""
     fn = _GLOBAL_KERNEL_CACHE.get(key)
     if fn is None:
-        fn = tpu_jit(build())
+        fn = build()
         _GLOBAL_KERNEL_CACHE[key] = fn
     return fn
 

@@ -115,49 +115,52 @@ def batched_segment_sum_f64(cols, gid, num_segments: int, capacity: int,
         return _batched_unblocked_split(cols, gid, num_segments,
                                         counts=counts)
 
-    his, los, abss = [], [], []
-    for c in cols:
-        hi, lo = split_f64_hi_lo(c)
-        his.append(hi)
-        los.append(lo)
-        abss.append(jnp.abs(hi))
-    x = jnp.stack(his + los + abss, axis=1)  # (capacity, 3m)
+    with jax.named_scope("split_sums"):
+        his, los, abss = [], [], []
+        for c in cols:
+            hi, lo = split_f64_hi_lo(c)
+            his.append(hi)
+            los.append(lo)
+            abss.append(jnp.abs(hi))
+        x = jnp.stack(his + los + abss, axis=1)  # (capacity, 3m)
 
-    if num_segments <= MATMUL_MAX_SEGMENTS:
-        def hlo_parts():
-            oh = jax.nn.one_hot(gid.reshape(nb, block), num_segments,
-                                dtype=jnp.float32)
-            return jnp.einsum('nbc,nbg->ngc', x.reshape(nb, block, 3 * m),
-                              oh, precision='highest')
+    with jax.named_scope("block_partials"):
+        if num_segments <= MATMUL_MAX_SEGMENTS:
+            def hlo_parts():
+                oh = jax.nn.one_hot(gid.reshape(nb, block), num_segments,
+                                    dtype=jnp.float32)
+                return jnp.einsum('nbc,nbg->ngc', x.reshape(nb, block, 3 * m),
+                                  oh, precision='highest')
 
-        def kern_parts():
-            from spark_rapids_tpu.kernels import segreduce as kseg
-            return kseg.onehot_partials(x, gid, num_segments, nb, block)
+            def kern_parts():
+                from spark_rapids_tpu.kernels import segreduce as kseg
+                return kseg.onehot_partials(x, gid, num_segments, nb, block)
 
-        from spark_rapids_tpu import kernels
-        parts = kernels.dispatch("segreduce", kern_parts, hlo_parts)
-    else:
-        blk = jnp.arange(capacity, dtype=jnp.int32) // block
-        ids = blk * num_segments + gid
-        parts = jax.ops.segment_sum(
-            x, ids, num_segments=nb * num_segments
-        ).reshape(nb, num_segments, 3 * m)
-    p64 = parts.astype(jnp.float64).sum(axis=0)  # (num_segments, 3m)
-    shi, slo, mass = p64[:, :m], p64[:, m:2 * m], p64[:, 2 * m:]
-    split_sum = shi + slo
+            from spark_rapids_tpu import kernels
+            parts = kernels.dispatch("segreduce", kern_parts, hlo_parts)
+        else:
+            blk = jnp.arange(capacity, dtype=jnp.int32) // block
+            ids = blk * num_segments + gid
+            parts = jax.ops.segment_sum(
+                x, ids, num_segments=nb * num_segments
+            ).reshape(nb, num_segments, 3 * m)
+    with jax.named_scope("merge"):
+        p64 = parts.astype(jnp.float64).sum(axis=0)  # (num_segments, 3m)
+        shi, slo, mass = p64[:, :m], p64[:, m:2 * m], p64[:, 2 * m:]
+        split_sum = shi + slo
 
-    err_est = mass * ERR_PER_MASS
-    risky = err_est > (jnp.abs(split_sum) * RTOL + ATOL)
-    has_big = jnp.any(mass * 0 != 0) | jnp.any(
-        jnp.max(jnp.abs(x[:, :m]), axis=0) > SPLIT_MAX_ABS)
-    bad = jnp.any(risky) | has_big
+        err_est = mass * ERR_PER_MASS
+        risky = err_est > (jnp.abs(split_sum) * RTOL + ATOL)
+        has_big = jnp.any(mass * 0 != 0) | jnp.any(
+            jnp.max(jnp.abs(x[:, :m]), axis=0) > SPLIT_MAX_ABS)
+        bad = jnp.any(risky) | has_big
 
-    def exact(_):
-        return jax.ops.segment_sum(jnp.stack(cols, axis=1), gid,
-                                   num_segments=num_segments)
+        def exact(_):
+            return jax.ops.segment_sum(jnp.stack(cols, axis=1), gid,
+                                       num_segments=num_segments)
 
-    return jax.lax.cond(bad, exact, lambda _: split_sum,
-                        jnp.zeros((), dtype=jnp.int32))
+        return jax.lax.cond(bad, exact, lambda _: split_sum,
+                            jnp.zeros((), dtype=jnp.int32))
 
 
 def _batched_unblocked_split(cols, gid, num_segments: int, counts=None):
@@ -178,14 +181,15 @@ def _batched_unblocked_split(cols, gid, num_segments: int, counts=None):
     Per-stream 1-D scatters, never a (capacity, 3m) 2-D scatter: the TPU
     lane width is 128 and a 2-D scatter pads the tiny minor dim to it."""
     m = len(cols)
-    his, los = [], []
-    for c in cols:
-        hi, lo = split_f64_hi_lo(c)
-        his.append(hi)
-        los.append(lo)
-    parts = jnp.stack(
-        [jax.ops.segment_sum(st, gid, num_segments=num_segments)
-         for st in his + los], axis=1)
+    with jax.named_scope("split_sums"):
+        his, los = [], []
+        for c in cols:
+            hi, lo = split_f64_hi_lo(c)
+            his.append(hi)
+            los.append(lo)
+        parts = jnp.stack(
+            [jax.ops.segment_sum(st, gid, num_segments=num_segments)
+             for st in his + los], axis=1)
     if counts is None:
         any_nz = jnp.zeros(cols[0].shape, dtype=jnp.bool_)
         for c in cols:
@@ -194,41 +198,42 @@ def _batched_unblocked_split(cols, gid, num_segments: int, counts=None):
                                    num_segments=num_segments)[:, None]
     else:
         cnt2 = counts if counts.ndim == 2 else counts[:, None]
-    p64 = parts.astype(jnp.float64)
-    shi, slo = p64[:, :m], p64[:, m:2 * m]
-    split_sum = shi + slo
+    with jax.named_scope("merge"):
+        p64 = parts.astype(jnp.float64)
+        shi, slo = p64[:, :m], p64[:, m:2 * m]
+        split_sum = shi + slo
 
-    all_nonneg = jnp.ones((), dtype=jnp.bool_)
-    for hi in his:
-        all_nonneg = all_nonneg & jnp.all(hi >= 0)
+        all_nonneg = jnp.ones((), dtype=jnp.bool_)
+        for hi in his:
+            all_nonneg = all_nonneg & jnp.all(hi >= 0)
 
-    def mass_from_hi(_):
-        return shi
+        def mass_from_hi(_):
+            return shi
 
-    def mass_scatter(_):
-        return jnp.stack(
-            [jax.ops.segment_sum(jnp.abs(hi), gid,
-                                 num_segments=num_segments)
-             for hi in his], axis=1).astype(jnp.float64)
+        def mass_scatter(_):
+            return jnp.stack(
+                [jax.ops.segment_sum(jnp.abs(hi), gid,
+                                     num_segments=num_segments)
+                 for hi in his], axis=1).astype(jnp.float64)
 
-    mass = jax.lax.cond(all_nonneg, mass_from_hi, mass_scatter,
-                        jnp.zeros((), dtype=jnp.int32))
+        mass = jax.lax.cond(all_nonneg, mass_from_hi, mass_scatter,
+                            jnp.zeros((), dtype=jnp.int32))
 
-    scale = jnp.sqrt(jnp.maximum(cnt2.astype(jnp.float64) / BLOCK, 1.0))
-    err_est = ERR_PER_MASS * scale * mass
-    risky = err_est > (jnp.abs(split_sum) * RTOL + ATOL)
-    has_big = jnp.zeros((), dtype=jnp.bool_)
-    for c in cols:
-        has_big = has_big | jnp.any(jnp.abs(c) > SPLIT_MAX_ABS)
-    has_nonfinite = ~jnp.all(jnp.isfinite(mass))
-    bad = jnp.any(risky) | has_big | has_nonfinite
+        scale = jnp.sqrt(jnp.maximum(cnt2.astype(jnp.float64) / BLOCK, 1.0))
+        err_est = ERR_PER_MASS * scale * mass
+        risky = err_est > (jnp.abs(split_sum) * RTOL + ATOL)
+        has_big = jnp.zeros((), dtype=jnp.bool_)
+        for c in cols:
+            has_big = has_big | jnp.any(jnp.abs(c) > SPLIT_MAX_ABS)
+        has_nonfinite = ~jnp.all(jnp.isfinite(mass))
+        bad = jnp.any(risky) | has_big | has_nonfinite
 
-    def exact(_):
-        return jax.ops.segment_sum(jnp.stack(cols, axis=1), gid,
-                                   num_segments=num_segments)
+        def exact(_):
+            return jax.ops.segment_sum(jnp.stack(cols, axis=1), gid,
+                                       num_segments=num_segments)
 
-    return jax.lax.cond(bad, exact, lambda _: split_sum,
-                        jnp.zeros((), dtype=jnp.int32))
+        return jax.lax.cond(bad, exact, lambda _: split_sum,
+                            jnp.zeros((), dtype=jnp.int32))
 
 
 def segment_minmax_64(is_min: bool, sd, sv, gid, num_segments: int):
@@ -345,23 +350,25 @@ def segment_sum_f64(v, gid, num_segments: int, capacity: int,
                                             counts=counts)[:, 0]
         return _unblocked_split_segment_sum(v, gid, num_segments)
 
-    hi, lo = split_f64_hi_lo(v)
-    blk = jnp.arange(capacity, dtype=jnp.int32) // block
-    ids = blk * num_segments + gid
-    phi = jax.ops.segment_sum(hi, ids, num_segments=nb * num_segments)
-    plo = jax.ops.segment_sum(lo, ids, num_segments=nb * num_segments)
-    pabs = jax.ops.segment_sum(jnp.abs(hi), ids, num_segments=nb * num_segments)
-    parts = phi.astype(jnp.float64) + plo.astype(jnp.float64)
-    split_sum = parts.reshape(nb, num_segments).sum(axis=0)
-    mass = pabs.reshape(nb, num_segments).sum(axis=0).astype(jnp.float64)
+    with jax.named_scope("block_partials"):
+        hi, lo = split_f64_hi_lo(v)
+        blk = jnp.arange(capacity, dtype=jnp.int32) // block
+        ids = blk * num_segments + gid
+        phi = jax.ops.segment_sum(hi, ids, num_segments=nb * num_segments)
+        plo = jax.ops.segment_sum(lo, ids, num_segments=nb * num_segments)
+        pabs = jax.ops.segment_sum(jnp.abs(hi), ids, num_segments=nb * num_segments)
+    with jax.named_scope("merge"):
+        parts = phi.astype(jnp.float64) + plo.astype(jnp.float64)
+        split_sum = parts.reshape(nb, num_segments).sum(axis=0)
+        mass = pabs.reshape(nb, num_segments).sum(axis=0).astype(jnp.float64)
 
-    err_est = mass * ERR_PER_MASS
-    risky = err_est > (jnp.abs(split_sum) * RTOL + ATOL)
-    has_big = jnp.any(jnp.abs(v) > SPLIT_MAX_ABS)
-    has_nonfinite = ~jnp.all(jnp.isfinite(mass))
-    bad = jnp.any(risky) | has_big | has_nonfinite
+        err_est = mass * ERR_PER_MASS
+        risky = err_est > (jnp.abs(split_sum) * RTOL + ATOL)
+        has_big = jnp.any(jnp.abs(v) > SPLIT_MAX_ABS)
+        has_nonfinite = ~jnp.all(jnp.isfinite(mass))
+        bad = jnp.any(risky) | has_big | has_nonfinite
 
-    def exact(x):
-        return jax.ops.segment_sum(x, gid, num_segments=num_segments)
+        def exact(x):
+            return jax.ops.segment_sum(x, gid, num_segments=num_segments)
 
-    return jax.lax.cond(bad, exact, lambda x: split_sum, v)
+        return jax.lax.cond(bad, exact, lambda x: split_sum, v)

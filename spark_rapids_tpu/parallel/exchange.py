@@ -307,7 +307,8 @@ class MeshExchange:
         out_specs = [P_(axis)] * (2 * ncols) + [P_(axis)]
         return tpu_jit(jax.shard_map(shard_fn, mesh=self.mesh,
                                      in_specs=tuple(in_specs),
-                                     out_specs=tuple(out_specs)))
+                                     out_specs=tuple(out_specs)),
+                       name="ici_exchange")
 
     def run(self, datas, valids, key_datas, key_valids, live,
             string_bytes: Optional[Dict[int, tuple]] = None):
@@ -439,5 +440,6 @@ def mesh_partial_then_merge(mesh, axis_name: str = "data"):
 
         return tpu_jit(jax.shard_map(wrapper, mesh=mesh,
                                      in_specs=P_(axis_name),
-                                     out_specs=P_()))
+                                     out_specs=P_()),
+                       name="mesh_partial_merge")
     return build

@@ -265,11 +265,14 @@ class DataFrame:
 
     def collect_table(self) -> HostTable:
         if self.session is not None:
-            # SQL-origin DataFrames carry their text; hand it to the
-            # session so the query event log records it
+            # SQL-origin DataFrames carry their text and the seconds
+            # sql() took to lower it; hand both to the session so the
+            # query event log records them
             sql_text = getattr(self, "sql_text", None)
             if sql_text is not None:
                 self.session.next_query_sql = sql_text
+                self.session.next_query_parse_s = getattr(
+                    self, "parse_s", None)
             return self.session.execute(self.plan)
         return self.plan.collect_cpu()
 

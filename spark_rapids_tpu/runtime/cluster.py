@@ -1010,7 +1010,7 @@ class ClusterDriver:
         ever covers usable hosts (hostRelands counts each lost host
         whose work was re-assigned)."""
         from spark_rapids_tpu.errors import CorruptFrameError, HostLostError
-        from spark_rapids_tpu.obs.spans import TRACER
+        from spark_rapids_tpu.obs.spans import span
         from spark_rapids_tpu.runtime.faults import fault_point
         from spark_rapids_tpu.shuffle.serializer import unpack_table
 
@@ -1035,12 +1035,9 @@ class ClusterDriver:
             # one driver-side span per dispatched host: the dispatch
             # round trip is attributed wall (executing thread), and the
             # executor's own spans nest under an executor-<host> lane
-            sp = (TRACER.begin("cluster.scan", "cluster", host=host_id,
-                               files=len(sub)) if TRACER.enabled else None)
-            try:
+            with span("cluster.scan", "cluster", host=host_id,
+                      files=len(sub)):
                 frames = self.scan_host(host_id, scan_node, sub)
-            finally:
-                TRACER.end(sp)
             for frame in frames:
                 # THE host shard landing point: corrupt damages the
                 # landed copy and the TPAK CRC catches it — the intact
@@ -1077,7 +1074,7 @@ def _executor_scan(msg: dict, host_id: str):
     relative to scan start, so the driver can merge them into ITS
     query trace on an executor lane. Returns (frames, scan_summary,
     span_payload)."""
-    from spark_rapids_tpu.obs.spans import TRACER
+    from spark_rapids_tpu.obs.spans import TRACER, span
     from spark_rapids_tpu.shuffle.serializer import pack_table
     want_trace = bool(msg.get("trace"))
     node = _build_scan_node(msg)
@@ -1095,30 +1092,28 @@ def _executor_scan(msg: dict, host_id: str):
             TRACER.begin_query(0)
             try:
                 it = node.execute_cpu()
-                i = 0
+                done = object()
                 while True:
-                    t_f0 = time.perf_counter()
-                    try:
-                        table = next(it)
-                    except StopIteration:
+                    # the decode happens inside next()
+                    with span("executor.scan.file", "exec-scan",
+                              index=len(frames)):
+                        table = next(it, done)
+                    if table is done:
                         break
-                    sp = TRACER.begin("executor.scan.file", "exec-scan",
-                                      index=i)
-                    if sp is not None:
-                        sp.t0 = t_f0  # decode happened inside next()
-                    TRACER.end(sp)
-                    sp = TRACER.begin("executor.pack", "exec-scan",
-                                      index=i)
-                    frames.append(pack_table(table))
-                    TRACER.end(sp)
-                    i += 1
+                    with span("executor.pack", "exec-scan",
+                              index=len(frames)):
+                        packed = pack_table(table)
+                    frames.append(packed)
             finally:
                 spans = TRACER.end_query()
+                TRACER.leave_query()
+            # one decode and one pack span per file: the pull that found
+            # the scan exhausted is not a file
             span_payload = [
                 {"name": s.name, "cat": s.cat,
                  "t0": round(s.t0 - t_q0, 6), "dur": round(s.dur, 6),
                  "args": s.args}
-                for s in spans][:256]
+                for s in spans if s.args["index"] < len(frames)][:256]
     scan_summary = {
         "wallS": round(time.perf_counter() - t_q0, 6),
         "files": len(frames),

@@ -94,9 +94,9 @@ class PlacementLayer:
         )
         from spark_rapids_tpu.execs.base import MASKED_ENABLED
         from spark_rapids_tpu.execs.join import DIRECT_TABLE_MULT
+        from spark_rapids_tpu.dispatch import phase_span
         from spark_rapids_tpu.runtime import (
             TpuSemaphore,
-            acquired,
             speculation as spec,
         )
 
@@ -121,8 +121,18 @@ class PlacementLayer:
         tok_k = K.KERNELS_ENABLED.set(K.resolve_enabled(conf))
 
         def drain_once():
-            with acquired(sem):
+            if sem is None:
                 batches = list(executable.execute_cpu())
+            else:
+                # the wait for a device slot is its own range and phase
+                # (semaphoreWaitS): 0 with one client, the queue under
+                # concurrent ones
+                with phase_span("semaphoreWaitS", "semaphore", "wait"):
+                    sem.acquire_if_necessary()
+                try:
+                    batches = list(executable.execute_cpu())
+                finally:
+                    sem.release_if_held()
             return self.resolve_pending(executable, batches)
 
         try:

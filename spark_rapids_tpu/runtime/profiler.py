@@ -8,8 +8,9 @@ windows keyed by job/time ranges (``spark.rapids.profile.*`` confs).
 
 TPU mapping: XLA's profiler (Xprof) plays CUPTI's role —
 ``jax.profiler.start_trace/stop_trace`` writes a TensorBoard/Xprof trace
-directory; ``jax.profiler.TraceAnnotation`` is the NVTX-range analog and
-shows engine operators on the device timeline. Enable windows: every
+directory; ``jax.profiler.TraceAnnotation`` is the NVTX-range analog, and
+``obs.spans.span`` is the one place the engine opens one (``srt.*`` on
+the trace's host timeline). Enable windows: every
 query, or a query-index range (``spark.rapids.profile.queryRanges`` e.g.
 "2-5,8" — RangeConfMatcher semantics)."""
 
@@ -137,39 +138,3 @@ class TpuProfiler:
         finally:
             with self._lock:
                 self._active -= 1
-
-
-def op_range(name: str, cat: str = "op"):
-    """Operator range on BOTH timelines (NvtxWithMetrics analog): always
-    a jax.profiler.TraceAnnotation (device/Xprof timeline, zero-cost
-    when no trace session is active) and, while the host span tracer is
-    collecting, a host span too — so the same range shows up in the
-    Xprof trace and the exported Chrome host timeline."""
-    import jax
-    from spark_rapids_tpu.obs.spans import TRACER
-    ann = jax.profiler.TraceAnnotation(name)
-    if not TRACER.enabled:
-        return ann
-    return _CombinedRange(ann, name, cat)
-
-
-class _CombinedRange:
-    __slots__ = ("ann", "name", "cat", "_span")
-
-    def __init__(self, ann, name, cat):
-        self.ann = ann
-        self.name = name
-        self.cat = cat
-        self._span = None
-
-    def __enter__(self):
-        from spark_rapids_tpu.obs.spans import TRACER
-        self._span = TRACER.begin(self.name, self.cat)
-        self.ann.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        from spark_rapids_tpu.obs.spans import TRACER
-        self.ann.__exit__(*exc)
-        TRACER.end(self._span)
-        return False

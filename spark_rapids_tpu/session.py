@@ -38,10 +38,11 @@ class _TLQueryState:
     envelope. ``last_*`` reads fall back to the session-wide mirror so
     serial callers on another thread still see the most recent query."""
 
-    __slots__ = ("exec_depth", "next_tag", "next_sql", "next_service",
+    __slots__ = ("exec_depth", "next_tag", "next_sql", "next_parse_s",
+                 "next_service",
                  "next_mv_epoch", "stream_deltas", "meta", "phases",
                  "executable",
-                 "dispatches", "fault_replays", "event_record",
+                 "dispatches", "host_syncs", "fault_replays", "event_record",
                  "event_path", "exec_cache_token", "exec_cache_hit",
                  "compile_ms", "pad_waste")
 
@@ -49,6 +50,7 @@ class _TLQueryState:
         self.exec_depth = 0
         self.next_tag = None
         self.next_sql = None
+        self.next_parse_s = None
         self.next_service = None
         self.next_mv_epoch = None
         self.stream_deltas = None
@@ -56,6 +58,7 @@ class _TLQueryState:
         self.phases = None
         self.executable = None
         self.dispatches = None
+        self.host_syncs = None
         self.fault_replays = None
         self.event_record = None
         self.event_path = None
@@ -96,6 +99,9 @@ class TpuSession:
         "next_tag", "query tag the NEXT execute() on this thread records")
     next_query_sql = _tl_only(
         "next_sql", "SQL text the NEXT execute() on this thread records")
+    next_query_parse_s = _tl_only(
+        "next_parse_s", "seconds sql() took to lower the statement the "
+        "NEXT execute() on this thread runs (its record's phasesS.parseS)")
     next_query_service = _tl_only(
         "next_service", "service envelope (tenant/pool/queue-wait/"
         "cache-hit) the NEXT execute() on this thread records")
@@ -180,9 +186,15 @@ class TpuSession:
         through parser -> analyzer -> the existing plan layer; the
         resulting DataFrame flows through overrides/AQE exactly like a
         DSL-built one."""
+        import time as _time
+
+        from spark_rapids_tpu.obs.spans import span
         from spark_rapids_tpu.sql import lower_statement
-        df = lower_statement(self, text)
+        t0 = _time.perf_counter()
+        with span("parse", "phase"):
+            df = lower_statement(self, text)
         df.sql_text = text
+        df.parse_s = _time.perf_counter() - t0
         return df
 
     def table(self, name: str) -> DataFrame:
@@ -302,30 +314,16 @@ class TpuSession:
         multiple threads (the query service's worker pool): in-flight
         state is thread-local and the span tracer scopes each query to
         its executing thread."""
-        import time as _time
-
         from spark_rapids_tpu.obs import events as E
-        from spark_rapids_tpu.obs.spans import (
-            TRACE_DIR,
-            TRACE_ENABLED,
-            TRACER,
-        )
+        from spark_rapids_tpu.obs.spans import TRACE_ENABLED, TRACER, span
 
         q = self._q
-        query_tag, q.next_tag = q.next_tag, None
-        sql_text, q.next_sql = q.next_sql, None
-        service_info, q.next_service = q.next_service, None
-        mv_epoch, q.next_mv_epoch = q.next_mv_epoch, None
-        stream_deltas, q.stream_deltas = (q.stream_deltas or {}), None
-
-        if not q.exec_depth:
-            # fresh per-host scan attribution for this top-level query
-            # (thread-local, like the dispatch counters — nested
-            # executes accumulate into the outer query's table)
-            from spark_rapids_tpu.runtime.cluster import (
-                reset_host_scan_stats,
-            )
-            reset_host_scan_stats()
+        tags = {"query_tag": q.next_tag, "sql_text": q.next_sql,
+                "parse_s": q.next_parse_s, "service": q.next_service,
+                "mv_epoch": q.next_mv_epoch,
+                "stream_deltas": q.stream_deltas or {}}
+        q.next_tag = q.next_sql = q.next_parse_s = q.next_service = None
+        q.next_mv_epoch = q.stream_deltas = None
 
         if q.exec_depth:
             # nested query: no separate envelope, no index
@@ -335,9 +333,14 @@ class TpuSession:
             finally:
                 q.exec_depth -= 1
 
+        # fresh per-host scan attribution for this top-level query
+        # (thread-local, like the dispatch counters — nested executes
+        # accumulate into the outer query's table)
+        from spark_rapids_tpu.runtime.cluster import reset_host_scan_stats
+        reset_host_scan_stats()
+
         ev_enabled = bool(self.conf.get_entry(E.EVENT_LOG_ENABLED))
         tr_enabled = bool(self.conf.get_entry(TRACE_ENABLED))
-        obs_active = ev_enabled or tr_enabled
         # this thread's view while THIS query is in flight: no record
         # yet (readers fall back to the session-wide mirror of the last
         # completed query)
@@ -346,6 +349,38 @@ class TpuSession:
         with self._obs_lock:
             qidx = self._obs_query_seq
             self._obs_query_seq += 1
+        if ev_enabled or tr_enabled:
+            TRACER.begin_query(qidx)
+        else:
+            # no envelope for THIS query, but another session's
+            # observed query may be live on a worker thread: block the
+            # tracer's helper-thread adoption so this query's spans
+            # can't pollute that query's record
+            TRACER.begin_unobserved_query(qidx)
+        try:
+            # srt.query: the whole of the query on this thread, the
+            # observation tail included
+            with span("query", "query"):
+                return self._execute_enveloped(plan, qidx, ev_enabled,
+                                               tr_enabled, tags)
+        finally:
+            TRACER.leave_query()
+
+    def _execute_enveloped(self, plan: P.PlanNode, qidx: int,
+                           ev_enabled: bool, tr_enabled: bool,
+                           tags: dict) -> HostTable:
+        """One top-level query inside its ``srt.query`` range: the
+        recovery-wrapped run, then (event log or tracing on) the
+        observation tail, ``srt.phase.observe``, that resolves deferred
+        row counts, builds the record and writes it."""
+        import threading as _threading
+        import time as _time
+
+        from spark_rapids_tpu.obs import events as E
+        from spark_rapids_tpu.obs.spans import TRACE_DIR, TRACER, span
+
+        q = self._q
+        obs_active = ev_enabled or tr_enabled
         if obs_active:
             from spark_rapids_tpu.obs.metrics import scopes_snapshot
             from spark_rapids_tpu.runtime.faults import FAULTS, RECOVERY
@@ -354,28 +389,17 @@ class TpuSession:
             before_recovery = RECOVERY.snapshot()
             before_fires = FAULTS.counters()
             before_health = HEALTH.snapshot()
-            ctx = TRACER.begin_query(qidx)
-        else:
-            # no envelope for THIS query, but another session's
-            # observed query may be live on a worker thread: block the
-            # tracer's helper-thread adoption so this query's spans
-            # can't pollute that query's record
-            TRACER.begin_unobserved_query()
         q.exec_depth = 1
         t0 = _time.perf_counter()
         try:
             result = self._execute_with_recovery(plan)
         except BaseException:
-            if obs_active:
-                TRACER.end_query()
             # a failed run may have left the checked-out tree partially
             # drained — drop the entry, never hand it to another query
             self._release_exec_cache(drop=True)
             raise
         finally:
             q.exec_depth = 0
-            if not obs_active:
-                TRACER.end_unobserved_query()
             # success OR failure: a WriteFiles plan that failed
             # mid-drain may still have changed on-disk contents, so
             # cached results over its paths are stale either way
@@ -386,134 +410,131 @@ class TpuSession:
         wall_s = _time.perf_counter() - t0
         spans = TRACER.end_query()
 
-        from spark_rapids_tpu.obs.metrics import scopes_snapshot
         from spark_rapids_tpu.obs.spans import (
             finalize_observation,
             summarize_spans,
             write_chrome_trace,
         )
-        from spark_rapids_tpu.runtime.faults import (
-            CIRCUIT_BREAKER,
-            FAULTS,
-            RECOVERY,
-        )
-        from spark_rapids_tpu.runtime.health import HEALTH
-        executable = q.executable
-        if executable is not None:
-            finalize_observation(executable)
-        after_recovery = RECOVERY.snapshot()
-        after_fires = FAULTS.counters()
-        after_health = HEALTH.snapshot()
-        after_scopes = scopes_snapshot()
-        # worker restarts ride the process-wide ``health`` scope (the
-        # service's watchdog respawns workers while queries run), so
-        # the per-record delta attributes restarts to the wall they
-        # happened under (0 on a quiet process)
-        worker_restarts = int(
-            after_scopes.get("health", {}).get("workersRespawned", 0)
-            - before_scopes.get("health", {}).get("workersRespawned", 0))
-
-        # transactional-write accounting: per-record deltas of the
-        # ``write`` scope (io/committer.py) — the committer/Delta
-        # transaction counters are process-wide, so the delta
-        # attributes files/bytes/retries to the query whose wall they
-        # happened under (all 0 for read-only queries)
-        def _wdelta(key: str, scope: str = "write") -> int:
-            return int(after_scopes.get(scope, {}).get(key, 0)
-                       - before_scopes.get(scope, {}).get(key, 0))
-
         from spark_rapids_tpu.parallel.mesh import MESH
-        from spark_rapids_tpu.runtime.cluster import (
-            CLUSTER,
-            host_scan_stats,
-        )
+        from spark_rapids_tpu.runtime.cluster import CLUSTER, host_scan_stats
+        from spark_rapids_tpu.runtime.faults import CIRCUIT_BREAKER
+        stream_deltas = tags["stream_deltas"]
+        phases = dict(q.phases or {})
+        if tags["parse_s"] is not None:
+            phases["parseS"] = tags["parse_s"]
+        with span("observe", "phase"):
+            executable = q.executable
+            if executable is not None:
+                finalize_observation(executable)
+            after_recovery = RECOVERY.snapshot()
+            after_fires = FAULTS.counters()
+            after_health = HEALTH.snapshot()
+            after_scopes = scopes_snapshot()
+            # worker restarts ride the process-wide ``health`` scope (the
+            # service's watchdog respawns workers while queries run), so
+            # the per-record delta attributes restarts to the wall they
+            # happened under (0 on a quiet process)
+            worker_restarts = int(
+                after_scopes.get("health", {}).get("workersRespawned", 0)
+                - before_scopes.get("health", {}).get("workersRespawned", 0))
 
-        record = E.build_query_record(
-            query_index=qidx,
-            wall_s=wall_s,
-            phases=q.phases or {},
-            executable=executable,
-            meta=q.meta,
-            sql_text=sql_text,
-            query_tag=query_tag,
-            dispatches=int(q.dispatches or 0),
-            recovery_delta={k: v - before_recovery.get(k, 0)
-                            for k, v in after_recovery.items()
-                            if v - before_recovery.get(k, 0)},
-            scope_deltas=E.scope_delta(before_scopes, after_scopes),
-            fault_fires={k: v - before_fires.get(k, 0)
-                         for k, v in after_fires.items()
-                         if v - before_fires.get(k, 0)},
-            # exec circuit-breaker demotions + Pallas kernel->HLO
-            # demotions in one map (keys 'pallas:<primitive>'), so the
-            # offline tools see both without a schema change
-            demotions={**CIRCUIT_BREAKER.demoted_ops(),
-                       **_kernel_demotions()},
-            spans_summary=summarize_spans(spans, ctx.owner_tid, wall_s),
-            fault_replays=int(q.fault_replays or 0),
-            service=service_info,
-            compile_ms=float(q.compile_ms or 0.0),
-            executable_cache_hit=bool(q.exec_cache_hit),
-            pad_waste_rows=int(q.pad_waste or 0),
-            health_state=HEALTH.state(),
-            device_reinits=int(after_health["deviceReinits"]
-                               - before_health["deviceReinits"]),
-            worker_restarts=worker_restarts,
-            files_written=_wdelta("filesWritten"),
-            bytes_written=_wdelta("bytesWritten"),
-            commit_retries=_wdelta("commitRetries"),
-            mesh_shape=MESH.shape_str(),
-            ici_bytes=_wdelta("iciBytes", "mesh"),
-            mesh_degradations=_wdelta("meshDegradations", "health"),
-            shard_retries=_wdelta("shardRetries", "mesh"),
-            gather_checks_failed=_wdelta("gatherChecksFailed", "mesh"),
-            host_topology=CLUSTER.topology_str(),
-            hosts_lost=_wdelta("hostsLost", "cluster"),
-            host_relands=_wdelta("hostRelands", "cluster"),
-            dcn_exchanges=_wdelta("dcnExchanges", "cluster"),
-            host_scans=host_scan_stats(),
-            oom_retries=_wdelta("oomRetries", "memory"),
-            split_retries=_wdelta("splitRetries", "memory"),
-            spill_bytes=_wdelta("spillBytes", "memory"),
-            unspills=_wdelta("unspills", "memory"),
-            budget_peak=_mem_budget_peak(),
-            # streaming attribution: scope deltas (work done INSIDE
-            # this window) plus the deltas the streaming subsystem
-            # staged on this thread between envelopes
-            micro_batches=_wdelta("microBatches", "streaming")
-            + stream_deltas.get("microBatches", 0),
-            mv_refreshes=_wdelta("mvRefreshes", "streaming")
-            + stream_deltas.get("mvRefreshes", 0),
-            mv_incremental_refreshes=_wdelta(
-                "mvIncrementalRefreshes", "streaming")
-            + stream_deltas.get("mvIncrementalRefreshes", 0),
-            mv_full_recomputes=_wdelta("mvFullRecomputes", "streaming")
-            + stream_deltas.get("mvFullRecomputes", 0),
-            sink_commits=_wdelta("sinkCommits", "streaming")
-            + stream_deltas.get("sinkCommits", 0),
-            sink_replays=_wdelta("sinkReplays", "streaming")
-            + stream_deltas.get("sinkReplays", 0),
-            mv_epoch=mv_epoch,
-        )
-        self.last_event_record = record
-        # the record has read the tree's metrics — the cached executable
-        # may now be handed to the next query (which resets them)
-        self._release_exec_cache()
-        # emission is best-effort: an unwritable log dir or full disk
-        # must not fail a query that already computed its result
-        try:
-            if ev_enabled:
-                self._write_event_record(record)
-            if tr_enabled:
-                import os
-                trace_dir = str(self.conf.get_entry(TRACE_DIR))
-                os.makedirs(trace_dir, exist_ok=True)
-                write_chrome_trace(
-                    os.path.join(trace_dir, f"query_{qidx}.trace.json"),
-                    spans, query_id=qidx)
-        except OSError as exc:
-            print(f"spark_rapids_tpu: event/trace emission failed "
-                  f"(query {qidx}): {exc}")
+            # transactional-write accounting: per-record deltas of the
+            # ``write`` scope (io/committer.py) — the committer/Delta
+            # transaction counters are process-wide, so the delta
+            # attributes files/bytes/retries to the query whose wall they
+            # happened under (all 0 for read-only queries)
+            def _wdelta(key: str, scope: str = "write") -> int:
+                return int(after_scopes.get(scope, {}).get(key, 0)
+                           - before_scopes.get(scope, {}).get(key, 0))
+
+            record = E.build_query_record(
+                query_index=qidx,
+                wall_s=wall_s,
+                phases=phases,
+                executable=executable,
+                meta=q.meta,
+                sql_text=tags["sql_text"],
+                query_tag=tags["query_tag"],
+                dispatches=int(q.dispatches or 0),
+                host_syncs=int(q.host_syncs or 0),
+                recovery_delta={k: v - before_recovery.get(k, 0)
+                                for k, v in after_recovery.items()
+                                if v - before_recovery.get(k, 0)},
+                scope_deltas=E.scope_delta(before_scopes, after_scopes),
+                fault_fires={k: v - before_fires.get(k, 0)
+                             for k, v in after_fires.items()
+                             if v - before_fires.get(k, 0)},
+                # exec circuit-breaker demotions + Pallas kernel->HLO
+                # demotions in one map (keys 'pallas:<primitive>'), so the
+                # offline tools see both without a schema change
+                demotions={**CIRCUIT_BREAKER.demoted_ops(),
+                           **_kernel_demotions()},
+                spans_summary=summarize_spans(
+                    spans, _threading.get_ident(), wall_s),
+                fault_replays=int(q.fault_replays or 0),
+                service=tags["service"],
+                compile_ms=float(q.compile_ms or 0.0),
+                executable_cache_hit=bool(q.exec_cache_hit),
+                pad_waste_rows=int(q.pad_waste or 0),
+                health_state=HEALTH.state(),
+                device_reinits=int(after_health["deviceReinits"]
+                                   - before_health["deviceReinits"]),
+                worker_restarts=worker_restarts,
+                files_written=_wdelta("filesWritten"),
+                bytes_written=_wdelta("bytesWritten"),
+                commit_retries=_wdelta("commitRetries"),
+                mesh_shape=MESH.shape_str(),
+                ici_bytes=_wdelta("iciBytes", "mesh"),
+                mesh_degradations=_wdelta("meshDegradations", "health"),
+                shard_retries=_wdelta("shardRetries", "mesh"),
+                gather_checks_failed=_wdelta("gatherChecksFailed", "mesh"),
+                host_topology=CLUSTER.topology_str(),
+                hosts_lost=_wdelta("hostsLost", "cluster"),
+                host_relands=_wdelta("hostRelands", "cluster"),
+                dcn_exchanges=_wdelta("dcnExchanges", "cluster"),
+                host_scans=host_scan_stats(),
+                oom_retries=_wdelta("oomRetries", "memory"),
+                split_retries=_wdelta("splitRetries", "memory"),
+                spill_bytes=_wdelta("spillBytes", "memory"),
+                unspills=_wdelta("unspills", "memory"),
+                budget_peak=_mem_budget_peak(),
+                # streaming attribution: scope deltas (work done INSIDE
+                # this window) plus the deltas the streaming subsystem
+                # staged on this thread between envelopes
+                micro_batches=_wdelta("microBatches", "streaming")
+                + stream_deltas.get("microBatches", 0),
+                mv_refreshes=_wdelta("mvRefreshes", "streaming")
+                + stream_deltas.get("mvRefreshes", 0),
+                mv_incremental_refreshes=_wdelta(
+                    "mvIncrementalRefreshes", "streaming")
+                + stream_deltas.get("mvIncrementalRefreshes", 0),
+                mv_full_recomputes=_wdelta("mvFullRecomputes", "streaming")
+                + stream_deltas.get("mvFullRecomputes", 0),
+                sink_commits=_wdelta("sinkCommits", "streaming")
+                + stream_deltas.get("sinkCommits", 0),
+                sink_replays=_wdelta("sinkReplays", "streaming")
+                + stream_deltas.get("sinkReplays", 0),
+                mv_epoch=tags["mv_epoch"],
+            )
+            self.last_event_record = record
+            # the record has read the tree's metrics — the cached executable
+            # may now be handed to the next query (which resets them)
+            self._release_exec_cache()
+            # emission is best-effort: an unwritable log dir or full disk
+            # must not fail a query that already computed its result
+            try:
+                if ev_enabled:
+                    self._write_event_record(record)
+                if tr_enabled:
+                    import os
+                    trace_dir = str(self.conf.get_entry(TRACE_DIR))
+                    os.makedirs(trace_dir, exist_ok=True)
+                    write_chrome_trace(
+                        os.path.join(trace_dir, f"query_{qidx}.trace.json"),
+                        spans, query_id=qidx)
+            except OSError as exc:
+                print(f"spark_rapids_tpu: event/trace emission failed "
+                      f"(query {qidx}): {exc}")
         return result
 
     def _write_event_record(self, record: dict) -> str:
@@ -530,7 +551,9 @@ class TpuSession:
         # the flight recorder's "recent events" context rides the same
         # funnel (slim summary, bounded ring — obs/events.py)
         E.note_recent_record(record)
-        path = self._event_writer.write(record)
+        from spark_rapids_tpu.obs.spans import span
+        with span("write", "eventlog"):
+            path = self._event_writer.write(record)
         self.last_event_path = path
         return path
 
@@ -851,33 +874,11 @@ class TpuSession:
         except Exception:
             pass
 
-    def _execute_attempt(self, plan: P.PlanNode) -> HostTable:
-        import time as _time
-
-        from spark_rapids_tpu.obs.spans import TRACER
-
-        t_phase = _time.perf_counter()
-        plan_span = TRACER.begin("plan", "phase") if TRACER.enabled else None
-        try:
-            return self._plan_and_drain(plan, plan_span, t_phase)
-        except BaseException:
-            # a mid-phase failure (plan verify error, conversion bug)
-            # must not leave the phase span dangling on the stack
-            TRACER.end(plan_span)
-            raise
-
-    def _plan_and_drain(self, plan: P.PlanNode, plan_span,
-                        t_phase: float) -> HostTable:
-        import time as _time
-
-        from spark_rapids_tpu.conf import (
-            RETRY_OOM_MAX_RETRIES,
-            TEST_INJECT_RETRY_OOM,
-        )
-        from spark_rapids_tpu.obs.spans import TRACER
-        from spark_rapids_tpu.runtime import RMM_TPU
-        from spark_rapids_tpu.runtime.retry import MAX_RETRIES_VAR
-
+    def _plan(self, plan: P.PlanNode):
+        """Physical planning of one attempt (``srt.phase.plan``, the
+        record's ``planS``): placement, the executable cache, overrides,
+        plan verification and the per-query boundaries. Returns the
+        converted tree, its overrides meta and the cache token."""
         from spark_rapids_tpu.overrides.input_file import \
             rewrite_input_file_exprs
         plan = rewrite_input_file_exprs(plan)
@@ -985,7 +986,23 @@ class TpuSession:
         from spark_rapids_tpu.service.query import install_cancellation
         install_cancellation(executable)
         self._last_executable = executable
-        TRACER.end(plan_span)
+        return executable, meta, tok
+
+    def _execute_attempt(self, plan: P.PlanNode) -> HostTable:
+        import time as _time
+
+        from spark_rapids_tpu.conf import (
+            RETRY_OOM_MAX_RETRIES,
+            TEST_INJECT_RETRY_OOM,
+        )
+        from spark_rapids_tpu.obs.spans import span
+        from spark_rapids_tpu.runtime import RMM_TPU
+        from spark_rapids_tpu.runtime.retry import MAX_RETRIES_VAR
+
+        q = self._q
+        t_phase = _time.perf_counter()
+        with span("plan", "phase"):
+            executable, meta, tok = self._plan(plan)
         phases = {"planS": _time.perf_counter() - t_phase}
 
         inject = str(self.conf.get_entry(TEST_INJECT_RETRY_OOM) or "")
@@ -1012,17 +1029,18 @@ class TpuSession:
             dispatch_count,
             reset_compile_stats,
             reset_dispatch_count,
+            reset_query_phases,
         )
         reset_dispatch_count()
         if q.exec_depth == 1:
             # top level only: a NESTED execute resetting mid-drain
             # would zero the outer query's trace/pad-waste accounting
+            # and its dispatch/sync/fetch seconds
             reset_compile_stats()
+            reset_query_phases()
         t_phase = _time.perf_counter()
-        exec_span = TRACER.begin("execute", "phase") \
-            if TRACER.enabled else None
         try:
-            with self.profiler.profile_query():
+            with span("execute", "phase"), self.profiler.profile_query():
                 # placement owns the drain: device-residency gating
                 # (semaphore), speculation, async-fetch resolution
                 batches = self.placement.drain(executable)
@@ -1032,34 +1050,37 @@ class TpuSession:
                 executable.metrics["dispatches"] = self.last_dispatches
         finally:
             MAX_RETRIES_VAR.reset(token)
-            TRACER.end(exec_span)
             phases["executeS"] = _time.perf_counter() - t_phase
             self._last_phases = phases
         t_phase = _time.perf_counter()
-        collect_span = TRACER.begin("collect", "phase") \
-            if TRACER.enabled else None
         try:
-            if not batches:
-                from spark_rapids_tpu.plan.nodes import _empty_table
-                out = _empty_table(plan.output_schema())
-            else:
-                out = HostTable.concat(batches)
+            with span("collect", "phase"):
+                if not batches:
+                    from spark_rapids_tpu.plan.nodes import _empty_table
+                    out = _empty_table(plan.output_schema())
+                else:
+                    out = HostTable.concat(batches)
         finally:
-            TRACER.end(collect_span)
             phases["collectS"] = _time.perf_counter() - t_phase
             # compile accounting AFTER collect: the packed d2h kernels
             # jit during it, and their traces belong to this query
             # (top level only — a nested execute rides the outer's
-            # counters, mirroring the reset above)
+            # counters, mirroring the reset above); the seconds the
+            # dispatch layer took where the work happened join the
+            # phases here too
             if q.exec_depth == 1:
                 from spark_rapids_tpu.dispatch import (
                     compile_stats,
                     flush_trace_cache_hits,
+                    host_fetch_count,
+                    phase_seconds,
                 )
                 traces, compile_s, pad = compile_stats()
                 self.last_compile_ms = round(compile_s * 1000.0, 3)
                 self.last_pad_waste_rows = pad
                 flush_trace_cache_hits()
+                phases.update(phase_seconds())
+                q.host_syncs = host_fetch_count()
         # a fully successful run fills its executable-cache slot (the
         # entry stays checked out until the query envelope releases it)
         if tok is not None and not tok.hit:

@@ -79,7 +79,7 @@ class HashPartitioner(Partitioner):
                 # Spark pmod: ((h % n) + n) % n
                 m = h % jnp.int32(n)
                 return jnp.where(m < 0, m + n, m)
-            return run
+            return tpu_jit(run, name="partition_ids")
 
         fn = cached_kernel(tkey, build)
         return fn(tuple(datas), tuple(valids), string_bytes)
@@ -269,7 +269,7 @@ class _SplitKernel:
                 outs = [(d[perm], v[perm]) for d, v in zip(datas, valids)]
                 return outs, counts
 
-            fn = tpu_jit(split)
+            fn = tpu_jit(split, name="partition_split")
             cls._traces[key] = fn
         datas = tuple(c.data for c in table.columns)
         valids = tuple(c.validity for c in table.columns)

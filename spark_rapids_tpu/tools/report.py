@@ -167,6 +167,8 @@ def analyze_query(rec: dict, top_n: int = 10) -> dict:
         "wallS": round(wall, 6),
         "phasesS": rec.get("phasesS") or {},
         "dispatches": rec.get("dispatches", 0),
+        # schema v12 (tracing): blocking device->host fetches
+        "hostSyncs": int(rec.get("hostSyncs", 0)),
         "compileMs": round(float(rec.get("compileMs", 0.0)), 3),
         "executableCacheHit": bool(rec.get("executableCacheHit", False)),
         "padWasteRows": int(rec.get("padWasteRows", 0)),
@@ -512,6 +514,7 @@ def render_profile(report: dict) -> str:
         lines.append(
             f"  {q['query']:16s} wall {_fmt_s(q['wallS'])}  "
             f"coverage {cov:5.1f}%  dispatches {q['dispatches']:4d}  "
+            f"hostSyncs {q['hostSyncs']:3d}  "
             f"shuffle {qb['shuffleS']:.4f}s  transfer "
             f"{qb['transferS']:.4f}s")
         for e in q["topOpsBySelfTime"][:3]:

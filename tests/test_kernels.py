@@ -371,7 +371,7 @@ def test_execute_time_failure_demotes_captured_primitives():
 
     with _forced("sort", "compact"):
         with pytest.raises(KernelCrashError, match="demoted"):
-            tpu_jit(body)(jnp.arange(8))
+            tpu_jit(body, name="test_body")(jnp.arange(8))
     assert "pallas:sort" in kernels.demoted_ops()
     assert "pallas:compact" not in kernels.demoted_ops()
 

@@ -166,13 +166,22 @@ def test_chrome_trace_export_schema(tmp_path):
 
 
 def test_tracer_disabled_is_default_and_cheap():
+    import jax
     from spark_rapids_tpu.obs.spans import TRACER, span
     assert TRACER.enabled is False
-    with span("nothing", cat="op"):
-        pass  # no-op context manager when disabled
+    # no profiler session, no envelope: the range IS the annotation —
+    # nothing else is allocated, nothing is recorded
+    rng = span("nothing", cat="op")
+    assert type(rng) is jax.profiler.TraceAnnotation
+    with rng:
+        pass
+    assert TRACER._spans == []
     s = TpuSession()
     _agg_df(s).collect_table()
     assert TRACER.enabled is False
+    assert type(span("after", cat="op")) is jax.profiler.TraceAnnotation
+    # the thread left its query: later ranges carry no query index
+    assert getattr(TRACER._tls, "query", None) is None
 
 
 def test_span_union_seconds():
@@ -182,6 +191,140 @@ def test_span_union_seconds():
     assert union_seconds([(0, 5), (1, 2)]) == pytest.approx(5.0)
 
 
+def _sql_session(tmp_path, **conf):
+    s = TpuSession({"spark.rapids.sql.eventLog.enabled": "true",
+                    "spark.rapids.sql.eventLog.dir": str(tmp_path), **conf})
+    s.create_dataframe(_table_data(400), num_batches=2) \
+        .create_or_replace_temp_view("t")
+    return s
+
+
+_SQL = "select k, sum(v) as sv, count(*) as c from t where v > 10 group by k"
+
+#: the ranges of one query, inside its srt.query (parse runs in sql(),
+#: before execute() opens the query)
+_QUERY_RANGES = ("srt.phase.plan", "srt.phase.execute", "srt.phase.collect",
+                 "srt.phase.observe", "srt.fetch.resolve", "srt.fetch.wait",
+                 "srt.fetch.unpack", "srt.wait.semaphore",
+                 "srt.eventlog.write")
+
+
+def test_srt_spans_lie_on_the_profilers_host_timeline(tmp_path):
+    """One primitive, two sinks: with a jax.profiler session running,
+    the engine's ranges are events of /host:CPU in the .xplane.pb — the
+    clock the device planes share — named srt.<cat>.<name>, each inside
+    the srt.query of its thread and carrying the query index."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    s = _sql_session(tmp_path)
+    s.sql(_SQL).collect_table()  # warm: keep compiles out of the trace
+    trace_dir = str(tmp_path / "xprof")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        s.sql(_SQL).collect_table()
+    finally:
+        jax.profiler.stop_trace()
+    qidx = s.last_event_record["queryIndex"]
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"]
+    assert host, "no /host:CPU plane in the trace"
+    by_line = {}
+    for line in host[0].lines:
+        events = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                   dict(e.stats)) for e in line.events
+                  if e.name.startswith("srt.")]
+        if events:
+            by_line[line.name] = events
+    assert len(by_line) == 1, f"srt ranges on lines {sorted(by_line)}"
+    (events,) = by_line.values()
+    names = [e[0] for e in events]
+    queries = [e for e in events if e[0] == "srt.query"]
+    assert len(queries) == 1
+    _, q0, q1, qstats = queries[0]
+    assert qstats.get("query") == qidx
+    for want in _QUERY_RANGES:
+        assert want in names, f"{want} missing from {sorted(set(names))}"
+    assert any(n.startswith("srt.dispatch.") for n in names)
+    assert any(n.startswith("srt.exec.") for n in names)
+    assert "srt.dispatch.kernel" not in names
+    parse = [e for e in events if e[0] == "srt.phase.parse"]
+    assert len(parse) == 1 and parse[0][2] <= q0, \
+        "sql() lowers the statement before execute() opens the query"
+    for name, t0, t1, stats in events:
+        if name == "srt.phase.parse":
+            continue
+        assert q0 <= t0 and t1 <= q1, f"{name} outside its srt.query"
+        assert stats.get("query") == qidx, (name, stats)
+
+
+def _tpu_jit_sites():
+    """(file:line, name) of every tpu_jit(...) call in the package, the
+    name None where it is not a string literal."""
+    import ast
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "spark_rapids_tpu")
+    sites = []
+    for dirpath, _dirs, files in os.walk(root):
+        for fname in sorted(files):
+            if not fname.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, fname)
+            for node in ast.walk(ast.parse(open(path).read())):
+                if isinstance(node, ast.Call) and getattr(
+                        node.func, "id", getattr(node.func, "attr", "")
+                        ) == "tpu_jit":
+                    lit_ = [k.value.value for k in node.keywords
+                            if k.arg == "name"
+                            and isinstance(k.value, ast.Constant)]
+                    sites.append((
+                        f"{os.path.relpath(path, root)}:{node.lineno}",
+                        lit_[0] if lit_ else None))
+    return sorted(sites)
+
+
+_TPU_JIT_SITES = _tpu_jit_sites()
+
+
+def test_every_tpu_jit_site_is_found():
+    assert len(_TPU_JIT_SITES) >= 35
+
+
+@pytest.mark.parametrize("site,name", _TPU_JIT_SITES,
+                         ids=[s for s, _ in _TPU_JIT_SITES])
+def test_tpu_jit_site_names_its_program(site, name):
+    """The name is the XLA module (jit_<name>), the srt.dispatch.<name>
+    range and the op of the dispatch fault points: a short, stable,
+    unique literal — never the old catch-all `kernel`."""
+    import re
+    assert isinstance(name, str), f"{site}: name= is not a string literal"
+    assert re.fullmatch(r"[a-z0-9_]+", name), (site, name)
+    assert name != "kernel"
+    assert [n for _, n in _TPU_JIT_SITES].count(name) == 1, \
+        f"{site}: {name!r} names more than one program"
+
+
+def test_tpu_jit_names_the_xla_module():
+    import jax.numpy as jnp
+    from spark_rapids_tpu.dispatch import tpu_jit
+
+    def kernel(x):
+        return x + 1
+
+    fn = tpu_jit(kernel, name="plus_one")
+    assert fn.__wrapped__.lower(jnp.arange(4)).as_text().startswith(
+        "module @jit_plus_one")
+    assert kernel.__name__ == "kernel"  # wrapped, not renamed in place
+    assert int(fn(jnp.arange(4))[0]) == 1
+
+
 # ---------------------------------------------------------------------------
 # event log
 # ---------------------------------------------------------------------------
@@ -189,7 +332,8 @@ def test_span_union_seconds():
 
 #: budgetPeak is the memory arbiter's PROCESS-WIDE peak — earlier tests
 #: in the same process move it, so the golden pins presence, not value
-_VOLATILE_INT_KEYS = {"dispatches", "spanCount", "tid", "budgetPeak"}
+_VOLATILE_INT_KEYS = {"dispatches", "hostSyncs", "spanCount", "tid",
+                      "budgetPeak"}
 
 #: scopes whose per-query delta depends on PROCESS WARMTH, not the
 #: query (the compile scope reports kernelTraces on a cold process and
@@ -231,7 +375,9 @@ def test_event_log_written_and_valid(tmp_path):
     lines = open(s.last_event_path).read().strip().splitlines()
     assert len(lines) == 1
     rec = json.loads(lines[0])
-    # schema v11: the streaming PR added the streaming-scope deltas
+    # schema v12: the tracing PR added hostSyncs and the dispatch /
+    # sync / fetch / semaphore seconds under phasesS (tested below);
+    # v11: the streaming PR added the streaming-scope deltas
     # (microBatches / mvRefreshes / mvIncrementalRefreshes /
     # mvFullRecomputes / sinkCommits / sinkReplays — all 0 on a
     # stream-free process) and mvEpoch (null unless the record serves
@@ -240,7 +386,7 @@ def test_event_log_written_and_valid(tmp_path):
     # fault-domain fields, v6's mesh-native fields, v5's
     # transactional-write fields and v4's survivability fields — see
     # obs/events.py
-    assert rec["schema"] == 11
+    assert rec["schema"] == 12
     assert rec["healthState"] == "HEALTHY"
     assert rec["quarantined"] is False
     assert rec["deviceReinits"] == 0 and rec["workerRestarts"] == 0
@@ -341,7 +487,13 @@ def test_event_log_golden_schema(tmp_path):
     strategy, and the exactly-once sink's commits and deduped replays;
     all 0 on a stream-free process and zeroed on result-cache serves;
     mvEpoch — the Delta version a served materialized view reflects,
-    null for everything that is not an MV serve)."""
+    null for everything that is not an MV serve);
+    v12 = tracing fields (hostSyncs — blocking device->host fetches:
+    host_fetch calls plus root-result resolves, normalized in the
+    golden like dispatches; phasesS gains dispatchS / syncWaitS /
+    fetchWaitS / fetchUnpackS / semaphoreWaitS — host seconds taken
+    where the work happens, inside executeS + collectS — and parseS
+    for queries that came through sql())."""
     s = _run_eventlog_query(tmp_path)
     got = _normalize(s.last_event_record)
     golden_path = os.path.join(os.path.dirname(__file__),
@@ -350,6 +502,106 @@ def test_event_log_golden_schema(tmp_path):
     assert got == golden, (
         "event-log record drifted from the golden schema; new normalized "
         "record:\n" + json.dumps(got, indent=1, sort_keys=True))
+
+
+_NEW_PHASES = ("parseS", "dispatchS", "syncWaitS", "fetchWaitS",
+               "fetchUnpackS", "semaphoreWaitS")
+
+
+def _check_phases(rec):
+    ph = rec["phasesS"]
+    for key in _NEW_PHASES + ("planS", "executeS", "collectS"):
+        assert key in ph and ph[key] >= 0, (key, ph)
+    # taken where the work happens, inside execute + collect, and
+    # disjoint from one another (rounded to the microsecond each)
+    inside = sum(ph[k] for k in ("dispatchS", "syncWaitS", "fetchWaitS",
+                                 "fetchUnpackS"))
+    assert inside <= ph["executeS"] + ph["collectS"] + 1e-5, ph
+    assert ph["planS"] + ph["executeS"] + ph["collectS"] \
+        <= rec["wallS"] + 1e-5
+    assert ph["dispatchS"] > 0 and rec["dispatches"] >= 1
+    # the root DeviceToHost's resolve is a host sync
+    assert rec["hostSyncs"] >= 1
+
+
+def test_record_holds_the_phases_taken_inside_the_program(tmp_path):
+    s = _sql_session(tmp_path)
+    s.sql(_SQL).collect_table()
+    s.sql(_SQL).collect_table()
+    rec = s.last_event_record
+    _check_phases(rec)
+    on_disk = [json.loads(line) for line in open(s.last_event_path)]
+    assert on_disk[-1]["phasesS"] == rec["phasesS"]
+    assert on_disk[-1]["hostSyncs"] == rec["hostSyncs"]
+    # a DataFrame-built query was never parsed: no parseS, the rest stay
+    _agg_df(s).collect_table()
+    built = s.last_event_record["phasesS"]
+    assert "parseS" not in built
+    assert set(_NEW_PHASES[1:]) <= set(built)
+    # and tools report carries the new count through
+    from spark_rapids_tpu.tools import build_profile, load_events
+    report = build_profile(load_events(str(tmp_path)))
+    assert all(q["hostSyncs"] >= 1 for q in report["queries"])
+
+
+def test_phase_accumulators_are_per_thread(tmp_path):
+    """The _ThreadCounter contract: a query's dispatch / sync / fetch
+    seconds and host-sync count accumulate on the thread that executes
+    it, so two queries on two threads never share them."""
+    import threading
+    import time
+
+    from spark_rapids_tpu import dispatch
+
+    # the accumulators themselves
+    dispatch.reset_query_phases()
+    gate = threading.Barrier(2, timeout=60)
+    seen = {}
+
+    def other():
+        dispatch.reset_query_phases()
+        with dispatch.phase_span("syncWaitS", "host_fetch", "sync"):
+            time.sleep(0.01)
+        dispatch.count_host_sync()
+        gate.wait()
+        seen["other"] = (dispatch.phase_seconds(),
+                         dispatch.host_fetch_count())
+
+    t = threading.Thread(target=other)
+    t.start()
+    gate.wait()
+    t.join(timeout=60)
+    assert not t.is_alive()
+    assert seen["other"][0]["syncWaitS"] >= 0.01
+    assert seen["other"][1] == 1
+    assert dispatch.phase_seconds() == dict.fromkeys(dispatch.PHASE_KEYS,
+                                                     0.0)
+    assert dispatch.host_fetch_count() == 0
+
+    # and two whole queries in flight at once
+    s = _sql_session(tmp_path)
+    for _ in range(3):  # the first runs size the plan's intermediates
+        s.sql(_SQL).collect_table()
+    serial = s.last_event_record
+    start = threading.Barrier(2, timeout=60)
+    recs = {}
+
+    def run(i):
+        start.wait()
+        for _ in range(3):
+            s.sql(_SQL).collect_table()
+        recs[i] = s.last_event_record  # this thread's own
+
+    threads = [threading.Thread(target=run, args=(i,)) for i in range(2)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=120)
+    assert sorted(recs) == [0, 1]
+    for rec in recs.values():
+        _check_phases(rec)
+        assert rec["hostSyncs"] == serial["hostSyncs"]
+        assert rec["dispatches"] == serial["dispatches"]
 
 
 def test_event_log_disabled_writes_nothing(tmp_path):
