@@ -196,9 +196,10 @@ SEGSUM_MAX_PARTIALS = int_conf(
 
 SEGSUM_MATMUL_MAX_SEGMENTS = int_conf(
     "spark.rapids.tpu.segsum.matmulMaxSegments", 32,
-    "One-hot MXU matmul partials run for segment counts up to this "
-    "(the materialized one-hot costs capacity*segments*4 bytes of HBM "
-    "traffic).")
+    "One-hot MXU matmul partials run for segment counts up to this: "
+    "the split-f64 sums' f32 partials and the fast aggregate's "
+    "per-group row counts (the materialized one-hot costs "
+    "capacity*segments*4 bytes of HBM traffic).")
 
 SPLIT_SUM_MAX_ABS = float_conf(
     "spark.rapids.tpu.sum.splitMaxAbs", 1e34,

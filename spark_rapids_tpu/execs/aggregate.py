@@ -12,7 +12,9 @@ null. Aggregation is then direct ``segment_*`` reductions with
 ``num_segments = padded domain product`` (small!), group compaction is a
 cumsum scatter, and the live group count stays on device — no sort, no
 host sync, no capacity-sized outputs. f64 sums run through the exact-
-decomposition blocked f32 path (ops/segsum.py).
+decomposition blocked f32 path, and the per-group row counts through a
+one-hot contraction as well, up to segsum.MATMUL_MAX_SEGMENTS groups
+(ops/segsum.py).
 
 SORT-SEGMENT PATH (general keys): lexicographic multi-operand ``lax.sort``
 over (live, key-validity, key-data...) with a row-index payload; segment
@@ -42,6 +44,7 @@ from spark_rapids_tpu.errors import ColumnarProcessingError
 from spark_rapids_tpu.execs.base import TpuExec
 from spark_rapids_tpu.ops import aggregates as agg
 from spark_rapids_tpu.ops.expr import (
+    BoundReference,
     DevVal,
     EvalCtx,
     Expression,
@@ -51,7 +54,11 @@ from spark_rapids_tpu.ops.expr import (
     _walk_eval,
     _walk_prep,
 )
-from spark_rapids_tpu.ops.segsum import batched_segment_sum_f64, segment_sum_f64
+from spark_rapids_tpu.ops.segsum import (
+    batched_segment_sum_f64,
+    segment_counts,
+    segment_sum_f64,
+)
 
 DEVICE_SUPPORTED_AGGS = (agg.Sum, agg.Min, agg.Max, agg.Count, agg.Average,
                          agg.First, agg.Last, agg.StddevPop, agg.StddevSamp,
@@ -268,7 +275,7 @@ class TpuHashAggregateExec(TpuExec):
         """
         from types import SimpleNamespace
         from spark_rapids_tpu.ops.cast import Cast
-        from spark_rapids_tpu.ops.expr import BoundReference, Literal, col, lit
+        from spark_rapids_tpu.ops.expr import Literal, col, lit
         from spark_rapids_tpu.ops.math import Sqrt
 
         pschema = [(n, g.data_type)
@@ -448,7 +455,8 @@ class TpuHashAggregateExec(TpuExec):
         for i in range(len(sizes) - 2, -1, -1):
             strides[i] = strides[i + 1] * sizes[i + 1]
         # tight power-of-two segment count (NOT the 128-row table bucket):
-        # one-hot einsum traffic scales with it, and a q1-style 12-slot
+        # one-hot einsum traffic scales with it (the sums' and, up to
+        # segsum.MATMUL_MAX_SEGMENTS, the counts'), and a q1-style 12-slot
         # domain must pad to 16, not 128
         gpad = max(8, 1 << (max(total - 1, 1)).bit_length())
         return tuple(kinds), sizes, strides, gpad, bases
@@ -502,6 +510,8 @@ class TpuHashAggregateExec(TpuExec):
 
         if fast:
             _, sizes, strides, gpad, bases = fast
+            if _ss.takes_contraction(gpad, capacity):
+                self.add_metric("countsByContraction", 1)
             out_arrays, ngroups = fn(
                 cols, aux, table.nrows_dev,
                 device_const(np.asarray(sizes, dtype=np.int32)),
@@ -632,10 +642,11 @@ class TpuHashAggregateExec(TpuExec):
 
             # ---- batched value aggregation ------------------------------
             # All sum-class f64 reductions (Sum/Average/Stddev/Variance)
-            # ride ONE batched device pass (ops/segsum.py); validity counts
-            # for every spec plus group existence ride one 2-D i32
-            # segment_sum. Min/Max/First/Last and i64 sums stay per-spec
-            # (_agg_one).
+            # ride ONE batched device pass (ops/segsum.py); group existence
+            # and every spec's validity count ride one more
+            # (segsum.segment_counts: a one-hot contraction too at small
+            # gpad, a scatter above). Min/Max/First/Last and i64 sums stay
+            # per-spec (_agg_one).
             with jax.named_scope("agg_values"):
                 vvs = []
                 for ves, per_child in zip(value_exprs, val_preps):
@@ -647,28 +658,29 @@ class TpuHashAggregateExec(TpuExec):
                     vvs.append(vals)
                 svs = [(vv[0].validity & live) if vv else None for vv in vvs]
 
-            # one scatter for live-count + every spec's nonnull count
+            # one pass for the live count + every DISTINCT nonnull mask:
+            # specs over one input column (sum(x), avg(x), count(x)) share
+            # ``validity & live``, so the mask is counted once and its
+            # column of mcnt fans back out through mix. Only bare column
+            # references are matched: key() is no identity of a computed
+            # child (it leaves string literals' values and rand's draws
+            # out: pivot's when(p = 'x', v) and when(p = 'y', v) share one)
             with jax.named_scope("valid_counts"):
-                masks = [live] + [sv for sv in svs if sv is not None]
+                masks = [live]
                 mix = {}
-                k = 1
+                seen = {}
                 for j, sv in enumerate(svs):
-                    if sv is not None:
-                        mix[j] = k
-                        k += 1
-                if gpad <= 4096:
-                    # one 2-D scatter: reads the input once; the minor-dim
-                    # 128-lane padding on the OUTPUT is cheap at small gpad
-                    mcnt = jax.ops.segment_sum(
-                        jnp.stack(masks, axis=1).astype(jnp.int32), gid,
-                        num_segments=gpad)
-                else:
-                    # large gpad: the padded (gpad, 128-lane) output dwarfs
-                    # the input re-reads — per-mask 1-D scatters win
-                    mcnt = jnp.stack(
-                        [jax.ops.segment_sum(mk.astype(jnp.int32), gid,
-                                             num_segments=gpad)
-                         for mk in masks], axis=1)
+                    if sv is None:
+                        continue
+                    child = value_exprs[j][0]
+                    ckey = (("col", child.ordinal)
+                            if isinstance(child, BoundReference)
+                            else ("spec", j))
+                    if ckey not in seen:
+                        seen[ckey] = len(masks)
+                        masks.append(sv)
+                    mix[j] = seen[ckey]
+                mcnt = segment_counts(masks, gid, gpad, capacity)
                 nonnulls = {j: mcnt[:, i] for j, i in mix.items()}
 
             exists = mcnt[:, 0] > 0

@@ -22,6 +22,13 @@ f32 block partial) and catastrophic cancellation (mass >> |sum|). On
 well-conditioned data (TPC-style positive measures) the observed error is
 ~1e-9 relative (tests/test_agg_fastpath.py).
 
+Per-group ROW COUNTS ride a one-hot contraction too (``segment_counts``,
+below) whenever the sums do: 0/1 masks against the 0/1 one-hot are exact
+in any MXU operand type, so they cost one int8 pass into int32, not
+'highest''s six, and no scatter is left on the small-segment path. One
+predicate (``takes_contraction``) decides "small segment count, whole
+blocks -> contraction" for both.
+
 This is the same class of trade the reference makes for float aggregation:
 GPU float sums differ from CPU Spark in ULPs by reduction order and are
 gated by ``spark.rapids.sql.variableFloatAgg.enabled``
@@ -87,6 +94,67 @@ def resolve_split_mode(conf) -> bool:
 MATMUL_MAX_SEGMENTS = 32
 
 
+def _blocking(capacity: int):
+    """(rows per block, whole blocks in ``capacity``): the blocked
+    reductions apply when their product is ``capacity``."""
+    block = min(BLOCK, capacity)
+    return block, max(capacity // block, 1)
+
+
+def takes_contraction(num_segments: int, capacity: int) -> bool:
+    """True where a segmented reduction runs as the blocked one-hot
+    contraction on the MXU instead of a scatter: few segments (the
+    one-hot is ``capacity * num_segments`` entries) over whole blocks."""
+    block, nb = _blocking(capacity)
+    return (num_segments <= MATMUL_MAX_SEGMENTS and nb * block == capacity
+            and nb * num_segments <= MAX_PARTIALS)
+
+
+def segment_counts(masks, gid, num_segments: int, capacity: int):
+    """Rows per segment under each of several boolean masks, in one pass.
+
+    ``masks``: list of (capacity,) bool arrays; ``gid`` int32 in
+    [0, num_segments). Returns (num_segments, len(masks)) int32, column i
+    the per-segment count of ``masks[i]``'s true rows: the integers
+    ``numpy.bincount(gid, weights=masks[i])`` gives.
+
+    Where ``takes_contraction`` holds (the sums' own condition: one
+    predicate decides), the counts are the contraction of the masks
+    against the one-hot of ``gid`` over the rows: no scatter, which the
+    TPU serialises on duplicate indices. They are EXACT, and need none of
+    the f32 sums' 'highest' (six MXU passes): mask and one-hot entries
+    are 0 or 1, so every product is exact in any operand type the MXU
+    has, and int8 operands accumulate in int32, where a count of at most
+    ``capacity`` < 2^31 rows cannot round. So the counts need no blocks
+    either (the sums' blocks bound f32 accumulation error; bf16 or
+    one-pass f32 operands would need them, a partial being exact in an
+    f32 accumulator only up to 2^24): on a v5e the one unblocked int8 dot
+    ran 8% under the blocked forms inside Q1's program and kept its time
+    when they moved with XLA's layout choices (PERF.md, PR 26). Rows stay
+    on the lane axis ((k, capacity), never (capacity, k): a minor
+    dimension of k pads to the 128-lane tile).
+
+    Elsewhere: one 2-D scatter up to 4096 segments (the lane padding of
+    its OUTPUT is cheap there), per-mask 1-D scatters above (the padded
+    (num_segments, 128-lane) output would dwarf the input re-reads)."""
+    k = len(masks)
+    if k == 0:
+        return jnp.zeros((num_segments, 0), dtype=jnp.int32)
+    if takes_contraction(num_segments, capacity):
+        return jnp.einsum(
+            'kc,cg->gk', jnp.stack(masks, axis=0).astype(jnp.int8),
+            jax.nn.one_hot(gid, num_segments, dtype=jnp.int8),
+            preferred_element_type=jnp.int32)
+    if num_segments <= 4096:
+        return jax.ops.segment_sum(
+            jnp.stack(masks, axis=1).astype(jnp.int32), gid,
+            num_segments=num_segments)
+    return jnp.stack(
+        [jax.ops.segment_sum(mk.astype(jnp.int32), gid,
+                             num_segments=num_segments)
+         for mk in masks], axis=1)
+
+
 def batched_segment_sum_f64(cols, gid, num_segments: int, capacity: int,
                             use_split: bool, counts=None):
     """Segmented sums of several f64 columns in ONE device pass.
@@ -102,8 +170,7 @@ def batched_segment_sum_f64(cols, gid, num_segments: int, capacity: int,
     m = len(cols)
     if m == 0:
         return jnp.zeros((num_segments, 0), dtype=jnp.float64)
-    block = min(BLOCK, capacity)
-    nb = max(capacity // block, 1)
+    block, nb = _blocking(capacity)
     if not use_split or cols[0].dtype != jnp.float64 or nb * block != capacity:
         return jax.ops.segment_sum(jnp.stack(cols, axis=1), gid,
                                    num_segments=num_segments)
@@ -125,7 +192,7 @@ def batched_segment_sum_f64(cols, gid, num_segments: int, capacity: int,
         x = jnp.stack(his + los + abss, axis=1)  # (capacity, 3m)
 
     with jax.named_scope("block_partials"):
-        if num_segments <= MATMUL_MAX_SEGMENTS:
+        if takes_contraction(num_segments, capacity):
             def hlo_parts():
                 oh = jax.nn.one_hot(gid.reshape(nb, block), num_segments,
                                     dtype=jnp.float32)

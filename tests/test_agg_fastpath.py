@@ -85,6 +85,17 @@ def test_fast_path_with_fused_filter_project(session, cpu_session):
     assert_tpu_and_cpu_are_equal(build, session, cpu_session)
 
 
+def _exec_tree(e):
+    """Every exec of a converted plan, transitions' links included."""
+    yield e
+    for c in getattr(e, "children", ()):
+        yield from _exec_tree(c)
+    for attr in ("source", "tpu_exec", "cpu_node"):
+        nxt = getattr(e, attr, None)
+        if nxt is not None:
+            yield from _exec_tree(nxt)
+
+
 def test_fusion_peels_project_and_filter(session):
     """The converted exec tree should contain no Project/Filter above the
     scan once fusion inlines them into the aggregate."""
@@ -98,21 +109,10 @@ def test_fusion_peels_project_and_filter(session):
           .group_by("s").agg(F.sum(col("d1")).alias("sd")))
     executable, _ = apply_overrides(df.plan, session.conf)
 
-    aggs, others = [], []
-
-    def walk(e):
-        if isinstance(e, TpuHashAggregateExec):
-            aggs.append(e)
-        if isinstance(e, (TpuFilterExec, TpuProjectExec)):
-            others.append(e)
-        for c in getattr(e, "children", ()):
-            walk(c)
-        for attr in ("source", "tpu_exec", "cpu_node"):
-            nxt = getattr(e, attr, None)
-            if nxt is not None:
-                walk(nxt)
-
-    walk(executable)
+    execs = list(_exec_tree(executable))
+    aggs = [e for e in execs if isinstance(e, TpuHashAggregateExec)]
+    others = [e for e in execs
+              if isinstance(e, (TpuFilterExec, TpuProjectExec))]
     assert len(aggs) == 1
     assert aggs[0].filters, "filter should be fused into the aggregate"
     assert not others, f"unfused execs remain: {others}"
@@ -220,3 +220,330 @@ def test_ungrouped_agg_fast_path_empty_input(session):
              F.avg("v").alias("a"), F.max("v").alias("m")))
     rows = df.collect()
     assert rows == [(0, None, None, None)]
+
+
+# ---------------------------------------------------------------------------
+# per-group counts by contraction (ops/segsum.segment_counts; the
+# valid_counts section of the fast kernel)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("capacity,num_segments,k,contraction", [
+    (4096, 16, 3, True),      # four blocks
+    (4096, 8, 1, True),       # a single mask
+    (4096, 32, 6, True),      # the widest one-hot it takes
+    (8192, 16, 2, True),      # one group holds all rows: partials = BLOCK
+    (512, 16, 3, True),       # one block under BLOCK rows
+    (1536, 16, 2, False),     # ragged capacity: the 2-D scatter
+    (4096, 64, 3, False),     # past MATMUL_MAX_SEGMENTS: the 2-D scatter
+    (8192, 5000, 2, False),   # past 4096 segments: per-mask scatters
+], ids=["g16", "g8-k1", "g32-k6", "one-group-full-blocks", "sub-block",
+        "ragged", "g64-scatter", "g5000-per-mask"])
+def test_segment_counts_unit(request, capacity, num_segments, k, contraction):
+    """segment_counts against numpy.bincount with weights: exact int32
+    on the contraction and on both scatter forms."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import segsum
+
+    rng = np.random.default_rng(capacity + num_segments + k)
+    one_group = "one-group" in request.node.name
+    if one_group:
+        gid = np.full(capacity, 3, dtype=np.int32)
+        masks = [np.ones(capacity, dtype=np.bool_) for _ in range(k)]
+    else:
+        gid = rng.integers(0, num_segments, capacity).astype(np.int32)
+        masks = [rng.random(capacity) < p
+                 for p in np.linspace(0.2, 1.0, k)]
+    got = jax.jit(lambda ms, g: segsum.segment_counts(
+        list(ms), g, num_segments, capacity))(
+            tuple(jnp.asarray(m) for m in masks), jnp.asarray(gid))
+    want = np.stack([np.bincount(gid, weights=m, minlength=num_segments)
+                     for m in masks], axis=1)
+    assert got.dtype == jnp.int32 and got.shape == (num_segments, k)
+    np.testing.assert_array_equal(np.asarray(got), want.astype(np.int32))
+    assert segsum.takes_contraction(num_segments, capacity) == contraction
+    if one_group:
+        assert int(got[3, 0]) == capacity
+
+
+@pytest.mark.parametrize("num_segments,scatters", [(16, False), (64, True)])
+def test_segment_counts_lowering(num_segments, scatters):
+    """At a small segment count the counts lower with no scatter (the
+    helper alone: the sums' exact fallback inside lax.cond keeps one)."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.segsum import segment_counts
+
+    cap, k = 4096, 3
+    text = jax.jit(lambda ms, g: segment_counts(
+        list(ms), g, num_segments, cap)).lower(
+            tuple(jax.ShapeDtypeStruct((cap,), jnp.bool_) for _ in range(k)),
+            jax.ShapeDtypeStruct((cap,), jnp.int32)).compile().as_text()
+    assert ("scatter" in text) == scatters
+    assert ("dot(" in text or "convolution(" in text) != scatters
+
+
+def _counts_table(n, groups, seed, null_keys=True):
+    """k: `groups` distinct strings (+ NULLs); x double and y long with
+    NULLs at different rows; w for a filter."""
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.columnar import HostColumn, HostTable
+    rng = np.random.default_rng(seed)
+    k = np.array([f"g{i:02d}" for i in range(groups)],
+                 dtype=object)[rng.integers(0, groups, n)]
+    kvalid = (rng.random(n) > 0.1) if null_keys else np.ones(n, np.bool_)
+    k[~kvalid] = None
+    return HostTable(["k", "x", "y", "w"], [
+        HostColumn(T.STRING, k, kvalid),
+        HostColumn(T.DOUBLE, rng.random(n) * 100, rng.random(n) > 0.3),
+        HostColumn(T.LONG, rng.integers(-9, 9, n).astype(np.int64),
+                   rng.random(n) > 0.1),
+        HostColumn(T.LONG, rng.integers(0, 100, n).astype(np.int64))])
+
+
+#: three specs over x (one mask), two over y (a second), count(*) (live)
+COUNT_AGGS = [
+    F.count().alias("n"), F.count(col("x")).alias("nx"),
+    F.sum(col("x")).alias("sx"), F.avg(col("x")).alias("ax"),
+    F.count(col("y")).alias("ny"), F.sum(col("y")).alias("sy"),
+]
+
+
+def _logged(event_dir, **conf):
+    """Event log on: the exec metrics come from the query's record."""
+    from spark_rapids_tpu.session import TpuSession
+    return TpuSession({**conf,
+                       "spark.rapids.sql.eventLog.enabled": "true",
+                       "spark.rapids.sql.eventLog.dir": str(event_dir)})
+
+
+@pytest.fixture(scope="module")
+def logged_session(tmp_path_factory):
+    return _logged(tmp_path_factory.mktemp("agg_events"))
+
+
+def _metric_total(session, key):
+    """Sum of an exec metric over the last query's executed plan."""
+    def walk(node):
+        m = node.get("metrics", {}).get(key)
+        return (m["value"] if m else 0) + sum(
+            walk(c) for c in node.get("children", ()))
+    return walk(session.last_event_record["plan"])
+
+
+def _counts_query(s, table, num_batches=1, filtered=False):
+    from spark_rapids_tpu.plan import from_host_table
+    df = from_host_table(table, s, num_batches)
+    if filtered:
+        df = df.filter(col("w") < lit(40))
+    return df.group_by("k").agg(*COUNT_AGGS)
+
+
+@pytest.mark.parametrize("groups,contraction", [
+    (5, True),    # 5 + null slot = 6 -> gpad 8
+    (12, True),   # 13 -> gpad 16
+    (25, True),   # 26 -> gpad 32
+    (40, False),  # 41 -> gpad 64: the scatter
+], ids=["gpad8", "gpad16", "gpad32", "gpad64"])
+@pytest.mark.parametrize("filtered", [False, True], ids=["all", "filtered"])
+def test_fast_counts_match_oracle(logged_session, cpu_session, groups,
+                                  contraction, filtered):
+    """NULLs in the counted values and in the keys, with and without a
+    fused filter that drops rows; contraction up to gpad 32, scatter at 64."""
+    table = _counts_table(3000, groups, seed=groups)
+    assert_tpu_and_cpu_are_equal(
+        lambda s: _counts_query(s, table, filtered=filtered),
+        logged_session, cpu_session, approximate_float=True)
+    assert _metric_total(logged_session, "countsByContraction") == int(
+        contraction)
+
+
+@pytest.mark.parametrize("buckets,contraction", [
+    ("pow2", True),    # 1200 rows -> capacity 2048: two whole blocks
+    ("1536", False),   # capacity 1536: not whole blocks, the scatter
+], ids=["whole-blocks", "ragged"])
+def test_fast_counts_capacity_blocks(tmp_path, cpu_session, buckets,
+                                     contraction):
+    sess = _logged(tmp_path, **{"spark.rapids.sql.shapeBuckets": buckets})
+    table = _counts_table(1200, 12, seed=2)
+    try:
+        assert_tpu_and_cpu_are_equal(
+            lambda s: _counts_query(s, table), sess, cpu_session,
+            approximate_float=True)
+        assert _metric_total(sess, "countsByContraction") == int(contraction)
+    finally:
+        # the bucket policy is process-wide: put the default back
+        from spark_rapids_tpu.columnar.column import set_bucket_policy
+        set_bucket_policy("pow2")
+
+
+def test_fast_counts_one_group_fills_blocks(logged_session, cpu_session):
+    """Every row of four blocks in one group: each block partial is 1024."""
+    n = 4096
+    data = {"k": np.array(["only"] * n, dtype=object),
+            "v": np.arange(n, dtype=np.int64)}
+
+    def build(s):
+        return s.create_dataframe(data).group_by("k").agg(
+            F.count().alias("n"), F.count(col("v")).alias("nv"))
+    assert build(logged_session).collect() == [("only", n, n)]
+    assert _metric_total(logged_session, "countsByContraction") == 1
+    assert build(cpu_session).collect() == [("only", n, n)]
+
+
+def _fast_agg_exec(session, df):
+    from spark_rapids_tpu.execs.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.overrides import apply_overrides
+    executable, _ = apply_overrides(df.plan, session.conf)
+    found = [e for e in _exec_tree(executable)
+             if isinstance(e, TpuHashAggregateExec)]
+    assert len(found) == 1
+    return found[0]
+
+
+def test_fast_counts_live_mask(session, cpu_session):
+    """A deferred-compaction batch (rows live at their own slots) counts
+    the same as the CPU oracle over the rows the mask keeps."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar import DeviceTable
+    from spark_rapids_tpu.plan import from_host_table
+
+    table = _counts_table(3000, 12, seed=9)
+    keep = np.random.default_rng(4).random(3000) < 0.6
+    aggx = _fast_agg_exec(
+        session, from_host_table(table, session).group_by("k")
+        .agg(*COUNT_AGGS))
+    batch = next(iter(aggx.children[0].execute_masked()))
+    live = np.zeros(batch.capacity, dtype=np.bool_)
+    live[:3000] = keep
+    masked = DeviceTable(batch.names, batch.columns, int(keep.sum()),
+                         batch.capacity, live=jnp.asarray(live))
+    got = aggx._aggregate(masked, aggx.grouping, aggx.agg_specs,
+                          aggx.grouping_names, aggx.filters).to_host()
+    assert aggx.metrics.get("countsByContraction") == 1
+
+    from spark_rapids_tpu.columnar import HostColumn, HostTable
+    kept = HostTable(table.names, [
+        HostColumn(c.dtype, c.data[keep], c.validity[keep])
+        for c in table.columns])
+    want = from_host_table(kept, cpu_session).group_by("k").agg(
+        *COUNT_AGGS).collect_table()
+    g, w = got.to_pydict(), want.to_pydict()
+    order_g = sorted(range(got.num_rows), key=lambda i: repr(g["k"][i]))
+    order_w = sorted(range(want.num_rows), key=lambda i: repr(w["k"][i]))
+    for name in ("k", "n", "nx", "ny", "sy"):
+        assert [g[name][i] for i in order_g] == \
+            [w[name][i] for i in order_w], name
+
+
+def test_fast_counts_dedup_fans_out(logged_session, cpu_session, monkeypatch):
+    """Specs over one column share a mask; specs over different columns
+    do not: five counted specs over three columns make four masks (with
+    live), and every spec still reads its own column's count."""
+    from spark_rapids_tpu.execs import aggregate as A
+    from spark_rapids_tpu.plan import from_host_table
+
+    seen = []
+    real = A.segment_counts
+
+    def spy(masks, gid, num_segments, capacity):
+        seen.append(len(masks))
+        return real(masks, gid, num_segments, capacity)
+    monkeypatch.setattr(A, "segment_counts", spy)
+
+    t = _counts_table(2000, 5, seed=21)
+    # fresh column names: a fresh trace, so the spy sees the kernel built
+    t = type(t)(["dk", "dx", "dy", "dz"], t.columns)
+
+    def build(s):
+        return from_host_table(t, s).group_by("dk").agg(
+            F.count(col("dx")).alias("nx"), F.avg(col("dx")).alias("ax"),
+            F.count(col("dy")).alias("ny"), F.count(col("dz")).alias("nz"),
+            F.sum(col("dy")).alias("sy"), F.count().alias("n"))
+    assert_tpu_and_cpu_are_equal(build, logged_session, cpu_session,
+                                 approximate_float=True)
+    assert seen == [4], seen
+
+
+def test_fast_counts_rand_child_is_not_shared(session, cpu_session):
+    """One rand-bearing expression under two specs draws twice (each spec
+    preps its own stream), so its two counts differ: computed children
+    with equal key() must not share a mask (string literals are the
+    other such case, test_expr_tail's pivot)."""
+    data = {"k": np.array(["a", "b"] * 500, dtype=object),
+            "x": np.arange(1000, dtype=np.int64)}
+
+    def build(s):
+        e = F.when(F.rand(3) < lit(0.5), col("x")).otherwise(lit(None))
+        return s.create_dataframe(data).group_by("k").agg(
+            F.count(e).alias("c1"), F.sum(e).alias("s1"),
+            F.count(e).alias("c2"))
+    assert_tpu_and_cpu_are_equal(build, session, cpu_session)
+    assert any(r[1] != r[3] for r in build(session).collect())
+
+
+@pytest.mark.parametrize("n", [50, 5000], ids=["one-block", "five-blocks"])
+def test_ungrouped_counts_empty_input(logged_session, n):
+    """An ungrouped aggregate (gpad 8) whose filter keeps no row: one
+    row, counts 0."""
+    df = (logged_session.create_dataframe(
+        {"v": np.arange(n, dtype=np.int64)})
+        .filter(col("v") > lit(10**9))
+        .agg(F.count().alias("n"), F.count("v").alias("c"),
+             F.sum("v").alias("s"), F.avg("v").alias("a")))
+    assert df.collect() == [(0, 0, None, None)]
+    assert _metric_total(logged_session, "countsByContraction") == 1
+
+
+def test_counts_by_contraction_per_aggregate_call(tmp_path, cpu_session,
+                                                  monkeypatch):
+    """A multi-batch group-by bumps countsByContraction once per
+    _aggregate call (the partials and the merge), and the lowered fast
+    kernel keeps the valid_counts scope around the counting."""
+    import jax
+    from spark_rapids_tpu.execs import aggregate as A
+
+    calls = []
+    real_aggregate = A.TpuHashAggregateExec._aggregate
+
+    def counting(self, *a, **k):
+        calls.append(1)
+        return real_aggregate(self, *a, **k)
+    monkeypatch.setattr(A.TpuHashAggregateExec, "_aggregate", counting)
+
+    lowered = []
+    real_jit = A.tpu_jit
+
+    def jit_spy(fn, *, name, **kw):
+        jf = real_jit(fn, name=name, **kw)
+        if name != "agg_fast":
+            return jf
+
+        def call(*args):
+            if not lowered:
+                lowered.append(jax.jit(fn).lower(*args).as_text(
+                    debug_info=True))
+            return jf(*args)
+        return call
+    monkeypatch.setattr(A, "tpu_jit", jit_spy)
+
+    # a batch target under one batch: the coalesce passes batches through
+    logged_session = _logged(
+        tmp_path, **{"spark.rapids.sql.batchSizeBytes": "1024"})
+    t = _counts_table(4000, 12, seed=33)
+    t = type(t)(["mk", "mx", "my", "mw"], t.columns)  # a fresh trace
+
+    def build(s):
+        from spark_rapids_tpu.plan import from_host_table
+        return from_host_table(t, s, 4).group_by("mk").agg(
+            F.count().alias("n"), F.count(col("mx")).alias("nx"),
+            F.avg(col("mx")).alias("ax"), F.sum(col("my")).alias("sy"))
+    assert_tpu_and_cpu_are_equal(build, logged_session, cpu_session,
+                                 approximate_float=True)
+    batches = _metric_total(logged_session, "partialAggBatches")
+    assert batches == 4
+    assert len(calls) == batches + 1
+    assert _metric_total(logged_session, "countsByContraction") == len(calls)
+    assert lowered and "valid_counts" in lowered[0]
+    for scope in ("live_mask", "group_ids", "agg_values", "compact_groups"):
+        assert scope in lowered[0], scope
