@@ -325,19 +325,116 @@ def _multi_device(a) -> bool:
     return isinstance(a, jax.Array) and len(a.sharding.device_set) > 1
 
 
-#: jitted concat kernels keyed by (schema kinds, input caps, out cap)
+#: jitted concat kernels keyed by (program, schema kinds, input caps, out
+#: cap, which inputs are masked)
 _CONCAT_CACHE: Dict[tuple, object] = {}
 
 
-def concat_device(tables: Sequence["DeviceTable"]) -> "DeviceTable":
+def _same_dictionary(d0, d) -> bool:
+    """Do two dictionaries hold the same strings in the same order (so
+    that their codes agree)? Identity first, then lengths, then contents:
+    one linear pass of ``==`` over the entries, where the union it saves
+    sorts them all (n log n string compares), so no size is excluded."""
+    if d is d0:
+        return True
+    if d is None or d0 is None or len(d) != len(d0):
+        return False
+    return bool(np.array_equal(d, d0))
+
+
+def _build_concat(ncols: int, out_cap: int):
+    """The concat program's body for ``ncols`` columns into ``out_cap``
+    rows (module level, so that it can be lowered at any shapes)."""
+    def concat(cols_per_table, remap_per_table, nrows_list, lives):
+        from spark_rapids_tpu.ops.scatter32 import scatter_pair
+        outs = []
+        for ci in range(ncols):
+            od = ov = None
+            offset = jnp.asarray(0, dtype=jnp.int32)
+            for ti in range(len(cols_per_table)):
+                data, valid = cols_per_table[ti][ci]
+                rm = remap_per_table[ti][ci]
+                if rm is not None:
+                    data = rm[jnp.clip(data, 0, rm.shape[0] - 1)]
+                n = nrows_list[ti]
+                if lives[ti] is None:
+                    # the copy: the input whole, the next input's head
+                    # landing on its dead tail. Only the tail's validity
+                    # is cleared: a dead slot's data is never read (as
+                    # in a masked table), and clearing it too would be
+                    # one more pass over every 64-bit half
+                    valid = valid & (
+                        jnp.arange(data.shape[0], dtype=jnp.int32) < n)
+                    if od is None:
+                        pad = out_cap - data.shape[0]
+                        od = jnp.pad(
+                            data, [(0, pad)] + [(0, 0)] * (data.ndim - 1))
+                        ov = jnp.pad(valid, (0, pad))
+                    else:
+                        od = jax.lax.dynamic_update_slice(
+                            od, data,
+                            (offset,) + (jnp.int32(0),) * (data.ndim - 1))
+                        ov = jax.lax.dynamic_update_slice(
+                            ov, valid, (offset,))
+                else:
+                    # masked input: its deferred compaction fuses into
+                    # this scatter (slot -> rank among live rows). It
+                    # takes everything from the offset on: what lies
+                    # there may be a copied input's dead tail, whose
+                    # data is whatever its producer left (a literal, a
+                    # projection over padding), so nothing is added in
+                    lv = lives[ti]
+                    pos = jnp.cumsum(lv.astype(jnp.int32)) - 1
+                    tgt = jnp.where(lv, pos + offset, out_cap)
+                    pd, pv = scatter_pair(out_cap, tgt, data, valid)
+                    if od is None:
+                        od, ov = pd, pv
+                    else:
+                        past = jnp.arange(out_cap, dtype=jnp.int32) >= offset
+                        od = jnp.where(
+                            past.reshape((out_cap,) + (1,) * (od.ndim - 1)),
+                            pd, od)
+                        ov = jnp.where(past, pv, ov)
+                offset = offset + n
+            outs.append((od, ov))
+        total = jnp.asarray(0, dtype=jnp.int32)
+        for n in nrows_list:
+            total = total + n
+        return outs, total
+
+    return concat
+
+
+def concat_device(tables: Sequence["DeviceTable"], *, coalesce: bool = False,
+                  stats: Optional[dict] = None) -> "DeviceTable":
     """Concatenate device tables ON DEVICE (no host round trip).
 
-    Row counts stay device scalars: each table's rows scatter at the
-    running dynamic offset (sum of predecessors' nrows_dev), so no host
-    sync happens. String columns are remapped into the union dictionary
-    first (host work is O(dict size), device work one gather per column).
-    Output capacity is the bucket of the capacity sum — a static upper
-    bound that avoids syncing the live counts."""
+    Row counts stay device scalars, so no host sync happens. An input
+    without a ``live`` mask is COPIED: each column is written whole at
+    the running device offset (the sum of its predecessors' nrows_dev)
+    by a ``dynamic_update_slice`` in input order, so the next input's
+    head overwrites its dead tail and the last tail lies beyond the total
+    (its validity cleared; dead data is never read). The offset plus an input's capacity never passes
+    the output's (an offset is at most the capacities before it), so no
+    start index is ever clamped. A MASKED input keeps its deferred
+    compaction fused in: its live rows scatter to their ranks past the
+    offset, and the scatter's result REPLACES the output from the offset
+    on (a copied predecessor's dead tail may hold anything: a literal
+    fills its whole capacity). Output capacity is the bucket of the
+    capacity sum — a static upper bound that avoids syncing the live
+    counts.
+
+    String columns whose inputs carry the same dictionary — the same
+    object, or equal contents — keep the first input's dictionary and
+    their codes (a sorted one stays flagged sorted); unequal ones are
+    remapped into the union dictionary (host work O(dict log dict) —
+    ``stats['dictUnions']`` counts those columns — and one device gather
+    per input).
+
+    ``coalesce`` only names the program: ``jit_coalesce`` for the
+    coalesce exec's flush, ``jit_concat`` for everything else (the
+    streaming merge's partials, sorts, joins), so a device trace tells
+    the two apart."""
     if not tables:
         raise ColumnarProcessingError("concat of zero tables")
     if len(tables) == 1:
@@ -357,16 +454,19 @@ def concat_device(tables: Sequence["DeviceTable"]) -> "DeviceTable":
         if not isinstance(col0.dtype, T.StringType):
             out_dicts.append(None)
             continue
-        if all(t.columns[ci].dictionary is col0.dictionary
-               for t in tables):
-            # identical dictionary OBJECT on every input (masked splits of
-            # one table, re-coalesced scan batches): codes already agree —
-            # skip the O(dict log dict) union entirely (a 1M-entry object
-            # dict costs ~seconds to re-sort). The shared dictionary may
-            # be UNSORTED (concat_ws outputs); record its real flag
-            out_sorted[ci] = col0.dict_sorted
+        if all(_same_dictionary(col0.dictionary, t.columns[ci].dictionary)
+               for t in tables[1:]):
+            # one dictionary on every input (masked splits of one table,
+            # re-coalesced scan batches, a low-cardinality column encoded
+            # batch by batch): codes already agree — skip the union (a
+            # 1M-entry object dict costs ~seconds to re-sort). The shared
+            # dictionary may be UNSORTED (concat_ws outputs): sortedness
+            # is a property of the contents, so any input's flag proves it
+            out_sorted[ci] = any(t.columns[ci].dict_sorted for t in tables)
             out_dicts.append(col0.dictionary)
             continue
+        if stats is not None:
+            stats["dictUnions"] = stats.get("dictUnions", 0) + 1
         dicts = [(t.columns[ci].dictionary if t.columns[ci].dictionary
                   is not None else np.array([], dtype=object))
                  for t in tables]
@@ -381,46 +481,12 @@ def concat_device(tables: Sequence["DeviceTable"]) -> "DeviceTable":
     kinds = tuple((str(c.dtype), c.dictionary is not None)
                   for c in tables[0].columns)
     masked = tuple(t.live is not None for t in tables)
-    key = (kinds, caps, out_cap, masked)
+    key = (coalesce, kinds, caps, out_cap, masked)
     fn = _CONCAT_CACHE.get(key)
     if fn is None:
-        def concat(cols_per_table, remap_per_table, nrows_list, lives):
-            from spark_rapids_tpu.ops.scatter32 import scatter_pair
-            outs = []
-            for ci in range(ncols):
-                od = None
-                ov = jnp.zeros(out_cap, dtype=jnp.bool_)
-                offset = jnp.asarray(0, dtype=jnp.int32)
-                for ti in range(len(cols_per_table)):
-                    data, valid = cols_per_table[ti][ci]
-                    rm = remap_per_table[ti][ci]
-                    if rm is not None:
-                        data = rm[jnp.clip(data, 0, rm.shape[0] - 1)]
-                    if od is None:
-                        od = jnp.zeros((out_cap,) + data.shape[1:],
-                                       dtype=data.dtype)
-                    n = nrows_list[ti]
-                    if lives[ti] is not None:
-                        # masked input: its deferred compaction fuses into
-                        # this scatter (slot -> rank among live rows)
-                        lv = lives[ti]
-                        pos = jnp.cumsum(lv.astype(jnp.int32)) - 1
-                        tgt = jnp.where(lv, pos + offset, out_cap)
-                    else:
-                        idx = jnp.arange(data.shape[0], dtype=jnp.int32)
-                        tgt = jnp.where(idx < n, idx + offset, out_cap)
-                    pd, pv = scatter_pair(out_cap, tgt, data, valid)
-                    od = od + pd if jnp.issubdtype(od.dtype, jnp.number) \
-                        else od | pd
-                    ov = ov | pv
-                    offset = offset + n
-                outs.append((od, ov))
-            total = jnp.asarray(0, dtype=jnp.int32)
-            for n in nrows_list:
-                total = total + n
-            return outs, total
-
-        fn = tpu_jit(concat, name="concat")
+        concat = _build_concat(ncols, out_cap)
+        fn = (tpu_jit(concat, name="coalesce") if coalesce
+              else tpu_jit(concat, name="concat"))
         _CONCAT_CACHE[key] = fn
 
     cols_per_table = tuple(
@@ -662,6 +728,21 @@ class DeviceTable:
 
     def device_nbytes(self) -> int:
         return sum(c.device_nbytes() for c in self.columns)
+
+    def select_columns(self, ordinals: Sequence[int]) -> "DeviceTable":
+        """This table with only the columns at ``ordinals``, in that
+        order: the same device arrays under a narrower table (no data
+        moves), its row count (the host's copy too), mask, sharding and
+        shared-view group kept."""
+        nrows = (self._nrows_host if self._nrows_host is not None
+                 else self.nrows_dev)
+        out = DeviceTable([self.names[i] for i in ordinals],
+                          [self.columns[i] for i in ordinals],
+                          nrows, self.capacity, live=self.live,
+                          shard_spec=self.shard_spec)
+        if is_shared_view(self):
+            mark_shared_view(out, view_group(self))
+        return out
 
     @staticmethod
     def from_host(host: HostTable, capacity: Optional[int] = None,

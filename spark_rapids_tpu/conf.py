@@ -820,6 +820,7 @@ def generate_docs() -> str:
         "pull, `srt.dispatch.<program>` per device program (the same "
         "name as its XLA module `jit_<program>`), `srt.sync.host_fetch`, "
         "`srt.fetch.resolve|wait|unpack`, `srt.wait.semaphore`, "
+        "`srt.coalesce.flush` (a coalesce exec's multi-batch copy), "
         "`srt.transfer.encode|stage|upload|HostToDevice|DeviceToHost`, "
         "`srt.eventlog.write`, `srt.shuffle.*`, `srt.spill.*`, "
         "`srt.cluster.scan`. They appear on the host timeline "
@@ -832,7 +833,8 @@ def generate_docs() -> str:
         "while a query's envelope collects. The event record's "
         "`phasesS` also holds the host seconds taken where the work "
         "happens (`parseS`, `dispatchS`, `syncWaitS`, `fetchWaitS`, "
-        "`fetchUnpackS`, `semaphoreWaitS`) and `hostSyncs` counts the "
+        "`fetchUnpackS`, `semaphoreWaitS`, `coalesceS`) and `hostSyncs` "
+        "counts the "
         # (the removed switches' names are split across literals so that
         # a grep of the package for them finds no code)
         "blocking device-to-host fetches. The `SRT_PROFILE_"

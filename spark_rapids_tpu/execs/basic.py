@@ -4,7 +4,7 @@ GpuExpandExec.scala — SURVEY.md §2.3)."""
 
 from __future__ import annotations
 
-from typing import Iterator, List, Sequence
+from typing import Iterator, List, Optional, Sequence
 
 import jax
 from spark_rapids_tpu.dispatch import tpu_jit
@@ -423,31 +423,62 @@ class TpuExpandExec(TpuExec):
 
 
 class TpuCoalesceExec(TpuExec):
-    """Concatenate child batches up to a target size — or into ONE batch
+    """Copy child batches together up to a target size — or into ONE batch
     when ``require_single`` (reference: GpuCoalesceBatches with
     TargetSize/RequireSingleBatch goals).
 
-    Multi-batch flushes concat ON DEVICE (columnar/table.concat_device:
-    no host round trip; string dictionaries union with O(dict) host
-    work; masked inputs fuse their deferred compaction into the concat
-    scatter). Two passthroughs: a lone buffered batch, and — under
-    TargetSize only — capacity-sharing masked VIEWS from a local shuffle
-    split (columnar/table.is_shared_view), which stream un-coalesced
-    because concatenating views of one table only multiplies capacity."""
+    What it does, in order:
+
+    - ``columns`` (ordinals of the child's schema, or None for all): only
+      these are buffered, copied and yielded — a consumer that has peeled
+      its input chain (the aggregate, overrides/rules.py) names what its
+      expressions read, so a wide cached table's unread columns (and
+      their string dictionaries) never reach the copy. Selecting columns
+      of a batch moves no data.
+    - the flush rule, under TargetSize: a batch joins the pending ones
+      unless the output they would then make — the capacity bucket of the
+      capacity sum (what ``concat_device`` allocates) at their row width —
+      would pass the target; then the pending ones flush first. So the
+      goal is honoured in the bytes the output takes, padding included,
+      and a run of equal batches stops at a bucket boundary (four 2^21-row
+      batches make exactly 2^23 rows; a fifth would pay for 2^24). Pending
+      bytes at or over the target flush at once; a lone batch over the
+      target passes through.
+    - a multi-batch flush is ONE device program, ``jit_coalesce``
+      (columnar/table.concat_device): unmasked inputs are copied at their
+      running device offsets, masked inputs fuse their deferred
+      compaction into a scatter, row counts stay on the device (no host
+      round trip); string columns whose dictionaries are equal keep
+      them, unequal ones union on the host (``dictUnions``). The flush is
+      the range ``srt.coalesce.flush`` and the query's
+      ``phasesS.coalesceS``. Nothing is kept from query to query: the
+      copy is redone per execution (PERF.md section 6, PR 27, has the
+      readings behind that).
+
+    Two passthroughs: a lone buffered batch, and — under TargetSize only —
+    capacity-sharing masked VIEWS from a local shuffle split
+    (columnar/table.is_shared_view), which stream un-coalesced because
+    concatenating views of one table only multiplies capacity."""
 
     def __init__(self, child: TpuExec, target_bytes: int = 1 << 30,
-                 require_single: bool = False):
+                 require_single: bool = False,
+                 columns: Optional[Sequence[int]] = None):
         super().__init__()
         self.children = (child,)
         self.target_bytes = target_bytes
         self.require_single = require_single
+        self.columns = None if columns is None else tuple(columns)
 
     def output_schema(self):
-        return self.children[0].output_schema()
+        schema = self.children[0].output_schema()
+        if self.columns is None:
+            return schema
+        return [schema[i] for i in self.columns]
 
     produces_masked = True
 
     def execute_masked(self):
+        from spark_rapids_tpu.columnar.table import is_shared_view
         from spark_rapids_tpu.runtime.memory import MEMORY
         from spark_rapids_tpu.runtime.spill import BufferCatalog, SpillableBatch
 
@@ -462,10 +493,11 @@ class TpuCoalesceExec(TpuExec):
         if not self.require_single:
             target = min(target, MEMORY.scan_chunk_bytes())
         pending: List[SpillableBatch] = []
-        pending_bytes = 0
+        pending_bytes = pending_rows = 0
         try:
             for batch in self.children[0].execute_masked():
-                from spark_rapids_tpu.columnar.table import is_shared_view
+                if self.columns is not None:
+                    batch = batch.select_columns(self.columns)
                 if is_shared_view(batch) and not self.require_single:
                     # capacity-sharing views (a local split's per-partition
                     # masks over ONE table): concatenation would only
@@ -474,17 +506,27 @@ class TpuCoalesceExec(TpuExec):
                     # (independent filter outputs) still coalesce.
                     if pending:
                         yield self._flush(pending)
-                        pending, pending_bytes = [], 0
+                        pending, pending_bytes, pending_rows = [], 0, 0
                     self.add_metric("maskedPassthrough", 1)
                     yield batch
                     continue
-                pending_bytes += batch.device_nbytes()
+                nbytes = batch.device_nbytes()
+                rows = pending_rows + batch.capacity
+                # device_nbytes counts capacity rows, so bytes / rows is
+                # the row width and the output takes bucket * width
+                if (pending and not self.require_single
+                        and bucket_for(rows) * (pending_bytes + nbytes)
+                        > target * rows):
+                    yield self._flush(pending)
+                    pending, pending_bytes, rows = [], 0, batch.capacity
+                pending_bytes += nbytes
+                pending_rows = rows
                 # buffered batches are spillable while more input streams in
                 # (reference: coalesce inputs are SpillableColumnarBatches)
                 pending.append(SpillableBatch(batch, catalog))
                 if not self.require_single and pending_bytes >= target:
                     yield self._flush(pending)
-                    pending, pending_bytes = [], 0
+                    pending, pending_bytes, pending_rows = [], 0, 0
             if pending:
                 yield self._flush(pending)
                 pending = []
@@ -496,6 +538,7 @@ class TpuCoalesceExec(TpuExec):
 
     def _flush(self, batches) -> DeviceTable:
         from spark_rapids_tpu.columnar.table import concat_device
+        from spark_rapids_tpu.dispatch import phase_span
         from spark_rapids_tpu.runtime.retry import retry_block
         if len(batches) == 1:
             sb = batches[0]
@@ -503,18 +546,25 @@ class TpuCoalesceExec(TpuExec):
             sb.release()
             return out
         self.add_metric("concatBatches", len(batches))
+        stats = {"dictUnions": 0}
         try:
-            # device-side concat: no host round trip; string dictionaries
-            # union-remap with O(dict) host work
-            return retry_block(
-                lambda: concat_device([b.get() for b in batches]))
+            with phase_span("coalesceS", "flush", "coalesce"):
+                out = retry_block(lambda: concat_device(
+                    [b.get() for b in batches], coalesce=True, stats=stats))
         finally:
             for b in batches:
                 b.release()
+        if "coalescedColumns" not in self.metrics:
+            # the width of a flush, not a sum over flushes
+            self.add_metric("coalescedColumns", len(out.columns))
+        self.add_metric("coalescedBytes", out.device_nbytes())
+        self.add_metric("dictUnions", stats["dictUnions"])
+        return out
 
     def describe(self):
         goal = "RequireSingleBatch" if self.require_single else f"TargetSize({self.target_bytes})"
-        return f"TpuCoalesce[{goal}]"
+        cols = "" if self.columns is None else f", columns={list(self.columns)}"
+        return f"TpuCoalesce[{goal}{cols}]"
 
 
 class TpuSampleExec(TpuExec):

@@ -249,6 +249,17 @@ def _check_schema(path, node, diags):
     children = [c for _, c in _edges(node)]
     if type(node).__name__ in _PASS_THROUGH and children:
         cs = _schema_of(children[0])
+        # a coalesce may carry a selection of its child's columns
+        columns = getattr(node, "columns", None)
+        if isinstance(cs, list) and columns is not None:
+            if all(0 <= i < len(cs) for i in columns):
+                cs = [cs[i] for i in columns]
+            else:
+                diags.append(make(
+                    "PV-SCHEMA", path,
+                    f"carried columns {list(columns)} outside the child's "
+                    f"{len(cs)} columns"))
+                cs = None
         if isinstance(cs, list) and schema != cs:
             diags.append(make(
                 "PV-SCHEMA", path,

@@ -137,7 +137,11 @@ EVENT_LOG_DIR = str_conf(
 #: and semaphoreWaitS (the wait for a device slot). planS / executeS /
 #: collectS are unchanged; the new ones lie inside executeS + collectS
 #: (parseS before the wall) and overlap nothing but them.
-EVENT_SCHEMA_VERSION = 12
+#: v13 (coalesce PR): phasesS gains coalesceS — host seconds inside the
+#: coalesce exec's multi-batch flushes (range ``srt.coalesce.flush``:
+#: dictionary checks, the ``jit_coalesce`` dispatch, which dispatchS
+#: counts too); 0.0 for a query whose coalesces only passed batches on.
+EVENT_SCHEMA_VERSION = 13
 
 
 def plan_tree(executable) -> dict:

@@ -189,27 +189,26 @@ def batched_segment_sum_f64(cols, gid, num_segments: int, capacity: int,
             his.append(hi)
             los.append(lo)
             abss.append(jnp.abs(hi))
-        x = jnp.stack(his + los + abss, axis=1)  # (capacity, 3m)
+        streams = his + los + abss  # 3m f32 streams of (capacity,)
 
     with jax.named_scope("block_partials"):
         if takes_contraction(num_segments, capacity):
-            def hlo_parts():
-                oh = jax.nn.one_hot(gid.reshape(nb, block), num_segments,
-                                    dtype=jnp.float32)
-                return jnp.einsum('nbc,nbg->ngc', x.reshape(nb, block, 3 * m),
-                                  oh, precision='highest')
-
             def kern_parts():
                 from spark_rapids_tpu.kernels import segreduce as kseg
-                return kseg.onehot_partials(x, gid, num_segments, nb, block)
+                return kseg.onehot_partials(jnp.stack(streams, axis=1), gid,
+                                            num_segments, nb, block)
 
             from spark_rapids_tpu import kernels
-            parts = kernels.dispatch("segreduce", kern_parts, hlo_parts)
+            parts = kernels.dispatch(
+                "segreduce", kern_parts,
+                lambda: _onehot_block_partials(streams, gid, num_segments,
+                                               nb, block))
         else:
             blk = jnp.arange(capacity, dtype=jnp.int32) // block
             ids = blk * num_segments + gid
             parts = jax.ops.segment_sum(
-                x, ids, num_segments=nb * num_segments
+                jnp.stack(streams, axis=1), ids,
+                num_segments=nb * num_segments
             ).reshape(nb, num_segments, 3 * m)
     with jax.named_scope("merge"):
         p64 = parts.astype(jnp.float64).sum(axis=0)  # (num_segments, 3m)
@@ -219,15 +218,36 @@ def batched_segment_sum_f64(cols, gid, num_segments: int, capacity: int,
         err_est = mass * ERR_PER_MASS
         risky = err_est > (jnp.abs(split_sum) * RTOL + ATOL)
         has_big = jnp.any(mass * 0 != 0) | jnp.any(
-            jnp.max(jnp.abs(x[:, :m]), axis=0) > SPLIT_MAX_ABS)
+            jnp.stack([jnp.max(a) for a in abss]) > SPLIT_MAX_ABS)
         bad = jnp.any(risky) | has_big
 
         def exact(_):
-            return jax.ops.segment_sum(jnp.stack(cols, axis=1), gid,
-                                       num_segments=num_segments)
+            # per-column 1-D scatters: a stacked (capacity, m) f64 operand
+            # pads m to the 128-lane tile, 1 KiB a row, and XLA sizes the
+            # program for this branch whether or not it ever runs (at
+            # 2^24 rows it was two 8 GiB allocations, "Used 17.13G of
+            # 15.75G hbm": the aggregate did not compile there)
+            return jnp.stack(
+                [jax.ops.segment_sum(c, gid, num_segments=num_segments)
+                 for c in cols], axis=1)
 
         return jax.lax.cond(bad, exact, lambda _: split_sum,
                             jnp.zeros((), dtype=jnp.int32))
+
+
+def _onehot_block_partials(streams, gid, num_segments: int, nb: int,
+                           block: int):
+    """Per-(block, segment) f32 partial sums of each stream, shape
+    (nb, num_segments, len(streams)): the one-hot contraction over a
+    block's rows at 'highest' (f32-faithful) precision. How the operands
+    are written does not matter (XLA lays them out itself); what does is
+    that nothing around it materialises a (capacity, k) array, 512 B a
+    row once the minor dimension pads to the 128-lane tile."""
+    k = len(streams)
+    x = jnp.stack(streams, axis=0).reshape(k, nb, block)
+    oh = jax.nn.one_hot(gid.reshape(nb, block), num_segments,
+                        dtype=jnp.float32)
+    return jnp.einsum('knb,nbg->ngk', x, oh, precision='highest')
 
 
 def _batched_unblocked_split(cols, gid, num_segments: int, counts=None):

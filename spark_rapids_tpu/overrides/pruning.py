@@ -214,3 +214,22 @@ def prune_plan(root: P.PlanNode) -> P.PlanNode:
     except Exception:
         # pruning is an optimization — never fail a query over it
         return root
+
+
+def narrow_to_references(width: int, exprs: Sequence[Expression],
+                         preds: Sequence[Expression]):
+    """What a consumer that peeled its input chain (execs/fuse.py) reads
+    of the base exec's ``width`` columns: returns (kept ordinals in
+    schema order, ``exprs`` and ``preds`` rebound to positions in that
+    list), or (None, exprs, preds) when every column is read. A consumer
+    that reads none (``count(*)`` alone) keeps column 0: a batch needs
+    one to carry its rows."""
+    refs: set = set()
+    for e in list(exprs) + list(preds):
+        _collect_refs(e, refs)
+    kept = sorted(o for o in refs if o < width) or [0]
+    if len(kept) >= width:
+        return None, list(exprs), list(preds)
+    mapping = {o: i for i, o in enumerate(kept)}
+    return (kept, [_remap(e, mapping) for e in exprs],
+            [_remap(p, mapping) for p in preds])

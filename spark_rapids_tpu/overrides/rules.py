@@ -496,15 +496,22 @@ def _convert_aggregate(node: P.Aggregate, children, conf):
     from spark_rapids_tpu.conf import (AGG_FUSE_INPUT, AGG_MAX_DICT_GROUPS,
                                        AGG_MAX_KEY_DOMAIN_GROUPS)
     from spark_rapids_tpu.execs.fuse import peel_input_chain
+    from spark_rapids_tpu.overrides.pruning import narrow_to_references
     from spark_rapids_tpu.ops.segsum import resolve_split_mode
 
     child = children[0]
     grouping = list(node.grouping)
     agg_specs = list(node.agg_specs)
     filters = []
+    columns = None
     if conf.get_entry(AGG_FUSE_INPUT):
         exprs = grouping + [fn for _, fn in agg_specs]
         child, exprs, filters = peel_input_chain(child, exprs)
+        # the chain is gone, and with it the keep-Project that pruning put
+        # over the leaf: the coalesce below carries only the columns the
+        # peeled expressions read, and they are rebound to those
+        columns, exprs, filters = narrow_to_references(
+            len(child.output_schema()), exprs, filters)
         grouping = exprs[:len(grouping)]
         agg_specs = [(n, fn) for (n, _), fn in
                      zip(agg_specs, exprs[len(grouping):])]
@@ -513,9 +520,11 @@ def _convert_aggregate(node: P.Aggregate, children, conf):
     # percentile have no merge decomposition yet -> one coalesced batch.
     from spark_rapids_tpu.execs.aggregate import SORT_ONLY_AGGS
     if any(isinstance(fn, SORT_ONLY_AGGS) for _, fn in agg_specs):
-        coalesced = TpuCoalesceExec(child, require_single=True)
+        coalesced = TpuCoalesceExec(child, require_single=True,
+                                    columns=columns)
     else:
-        coalesced = TpuCoalesceExec(child, target_bytes=conf.batch_size_bytes)
+        coalesced = TpuCoalesceExec(child, target_bytes=conf.batch_size_bytes,
+                                    columns=columns)
     return TpuHashAggregateExec(coalesced, grouping, agg_specs,
                                 node.grouping_names,
                                 filters=filters,
@@ -699,6 +708,8 @@ def _maybe_install_dpp(jt: str, probe_exec, build_exec, probe_keys,
         scan_exec = None
         while True:
             if isinstance(cur, (TpuCoalesceExec, TpuFilterExec)):
+                if getattr(cur, "columns", None) is not None:
+                    ordinal = cur.columns[ordinal]
                 cur = cur.children[0]
             elif isinstance(cur, TpuProjectExec):
                 pe = cur.exprs[ordinal]

@@ -1,0 +1,135 @@
+"""The default-conf Q1 path compiled at its real shapes for a DESCRIBED
+v5e chip (the chip's own compiler, no chip attached): the coalesce's copy
+of eight 2^21-row batches and the aggregate at the 2^24 rows it builds.
+What the compiler refuses here (a program that does not fit the chip's
+memory) the chip would refuse too; nothing runs, so nothing here is a
+time. All such compiles live in this one file: the topology is described
+inside a fixture, by the one worker that is given the file."""
+
+import os
+
+import numpy as np
+import pytest
+
+GB = 1e9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(one_chip, shape, dtype):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile(fn, *args):
+    import jax
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _q1_aggregate():
+    """Q1's aggregate exec over a small 7-column lineitem and the batch
+    its coalesce yields."""
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.columnar import HostColumn, HostTable
+    from spark_rapids_tpu.execs.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.overrides import apply_overrides
+    from spark_rapids_tpu.session import TpuSession
+    n = 3000
+    rng = np.random.default_rng(7)
+    cols = {
+        "l_quantity": (T.DOUBLE, np.floor(rng.random(n) * 50 + 1)),
+        "l_extendedprice": (T.DOUBLE, np.round(rng.random(n) * 1e5, 2)),
+        "l_discount": (T.DOUBLE, np.round(rng.random(n) * 0.1, 2)),
+        "l_tax": (T.DOUBLE, np.round(rng.random(n) * 0.08, 2)),
+        "l_returnflag": (T.STRING, np.array(["A", "N", "R"], object)[
+            rng.integers(0, 3, n)]),
+        "l_linestatus": (T.STRING, np.array(["F", "O"], object)[
+            rng.integers(0, 2, n)]),
+        "l_shipdate": (T.DATE, rng.integers(8036, 10561, n).astype(np.int32)),
+    }
+    host = HostTable(list(cols), [HostColumn(t, v) for t, v in cols.values()])
+    session = TpuSession({"spark.rapids.tpu.sum.splitF64": "true"})
+    session.create_dataframe(host).create_or_replace_temp_view("lineitem")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    text = open(os.path.join(root, "benchmarks", "queries", "q1.sql")) \
+        .read().replace("[DELTA]", "90")
+    executable, _ = apply_overrides(session.sql(text).plan, session.conf)
+    found = []
+
+    def walk(e):
+        if isinstance(e, TpuHashAggregateExec):
+            found.append(e)
+        for c in getattr(e, "children", ()):
+            walk(c)
+        if getattr(e, "tpu_exec", None) is not None:
+            walk(e.tpu_exec)
+    walk(executable)
+    exec_ = found[0]
+    return exec_, next(iter(exec_.children[0].execute_masked()))
+
+
+def test_q1_aggregate_fits_the_chip_at_the_coalesced_capacity(one_chip):
+    """`agg_fast` at 2^24 rows asked for 17.13 GB of a 15.75 GB chip
+    before the split sums kept rows on the lane axis (PERF.md, PR 27)."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.dispatch import prep_aux
+    from spark_rapids_tpu.ops.expr import DevVal
+    cap = 1 << 24
+    exec_, batch = _q1_aggregate()
+    assert exec_.use_split
+    pctx, fpre, kpre, vpre = exec_._prep_all(
+        batch, exec_.grouping, exec_.agg_specs, exec_.filters)
+    kinds, sizes, strides, gpad, bases = exec_._fast_layout(
+        exec_.grouping, kpre, cap)
+    assert gpad == 16
+    kernel = exec_._build_fast_kernel(cap, kinds, gpad, fpre, kpre, vpre,
+                                      exec_.grouping, exec_.agg_specs,
+                                      exec_.filters)
+    cols = tuple(DevVal(_shape(one_chip, (cap,), c.data.dtype),
+                        _shape(one_chip, (cap,), jnp.bool_))
+                 for c in batch.columns)
+    aux = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
+                       prep_aux(pctx))
+    compiled = _compile(
+        kernel, cols, aux, _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (len(sizes),), jnp.int32),
+        _shape(one_chip, (len(strides),), jnp.int32),
+        _shape(one_chip, (len(bases),), jnp.int64), None)
+    memory = compiled.memory_analysis()
+    # beside a 3.6 GB table and two 0.86 GB coalesced batches
+    assert memory.temp_size_in_bytes < 5 * GB, memory.temp_size_in_bytes
+    assert memory.argument_size_in_bytes < 1 * GB
+
+
+def test_coalesce_copies_eight_batches_without_a_scatter(one_chip):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.table import _build_concat
+    k, cap = 8, 1 << 21
+    dtypes = [jnp.float64] * 4 + [jnp.int32] * 3
+    cols = tuple(tuple((_shape(one_chip, (cap,), dt),
+                        _shape(one_chip, (cap,), jnp.bool_))
+                       for dt in dtypes) for _ in range(k))
+    none = tuple(tuple(None for _ in dtypes) for _ in range(k))
+    rows = tuple(_shape(one_chip, (), jnp.int32) for _ in range(k))
+    compiled = _compile(_build_concat(len(dtypes), k * cap), cols, none,
+                        rows, tuple(None for _ in range(k)))
+    text = compiled.as_text()
+    assert " scatter(" not in text
+    assert "dynamic-update-slice" in text
+    memory = compiled.memory_analysis()
+    # 4 DOUBLE and 3 int32 columns with a validity byte each, and the total
+    assert memory.output_size_in_bytes >= k * cap * (4 * 9 + 3 * 5)
+    assert memory.output_size_in_bytes < k * cap * (4 * 9 + 3 * 5) + 4096
+    assert memory.temp_size_in_bytes < 0.1 * GB

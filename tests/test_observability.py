@@ -294,7 +294,7 @@ _TPU_JIT_SITES = _tpu_jit_sites()
 
 
 def test_every_tpu_jit_site_is_found():
-    assert len(_TPU_JIT_SITES) >= 35
+    assert len(_TPU_JIT_SITES) >= 36
 
 
 @pytest.mark.parametrize("site,name", _TPU_JIT_SITES,
@@ -375,8 +375,10 @@ def test_event_log_written_and_valid(tmp_path):
     lines = open(s.last_event_path).read().strip().splitlines()
     assert len(lines) == 1
     rec = json.loads(lines[0])
-    # schema v12: the tracing PR added hostSyncs and the dispatch /
-    # sync / fetch / semaphore seconds under phasesS (tested below);
+    # schema v13: phasesS gains coalesceS (the coalesce exec's
+    # multi-batch flushes); v12: the tracing PR added hostSyncs and the
+    # dispatch / sync / fetch / semaphore seconds under phasesS (tested
+    # below);
     # v11: the streaming PR added the streaming-scope deltas
     # (microBatches / mvRefreshes / mvIncrementalRefreshes /
     # mvFullRecomputes / sinkCommits / sinkReplays — all 0 on a
@@ -386,7 +388,7 @@ def test_event_log_written_and_valid(tmp_path):
     # fault-domain fields, v6's mesh-native fields, v5's
     # transactional-write fields and v4's survivability fields — see
     # obs/events.py
-    assert rec["schema"] == 12
+    assert rec["schema"] == 13
     assert rec["healthState"] == "HEALTHY"
     assert rec["quarantined"] is False
     assert rec["deviceReinits"] == 0 and rec["workerRestarts"] == 0
@@ -493,7 +495,11 @@ def test_event_log_golden_schema(tmp_path):
     golden like dispatches; phasesS gains dispatchS / syncWaitS /
     fetchWaitS / fetchUnpackS / semaphoreWaitS — host seconds taken
     where the work happens, inside executeS + collectS — and parseS
-    for queries that came through sql())."""
+    for queries that came through sql());
+    v13 = phasesS gains coalesceS (host seconds inside the coalesce
+    exec's multi-batch flushes, the range srt.coalesce.flush; its
+    jit_coalesce dispatch counts in dispatchS too; 0.0 where every
+    coalesce passed its batches on)."""
     s = _run_eventlog_query(tmp_path)
     got = _normalize(s.last_event_record)
     golden_path = os.path.join(os.path.dirname(__file__),
@@ -505,7 +511,7 @@ def test_event_log_golden_schema(tmp_path):
 
 
 _NEW_PHASES = ("parseS", "dispatchS", "syncWaitS", "fetchWaitS",
-               "fetchUnpackS", "semaphoreWaitS")
+               "fetchUnpackS", "semaphoreWaitS", "coalesceS")
 
 
 def _check_phases(rec):
