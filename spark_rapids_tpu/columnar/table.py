@@ -709,6 +709,12 @@ class DeviceTable:
         return self._nrows_host
 
     @property
+    def num_rows_known(self) -> bool:
+        """True when ``num_rows`` costs no device read (the count came
+        from the host, or a read has already fetched it)."""
+        return self._nrows_host is not None
+
+    @property
     def num_columns(self) -> int:
         return len(self.columns)
 
@@ -1012,11 +1018,15 @@ class DeviceTable:
 
     def shrink(self) -> "DeviceTable":
         """Re-bucket to the smallest capacity holding the live rows. Syncs
-        the row count (host round-trip) — worth it after cardinality-
-        collapsing ops (aggregate output of a few groups must not drag the
-        input's multi-million-row bucket through downstream sorts/uploads)."""
+        the row count (host round-trip) only when a smaller bucket exists:
+        a table at or under the smallest bucket is returned as it is, its
+        count unread. Worth the sync after cardinality-collapsing ops
+        (aggregate output of a few groups must not drag the input's
+        multi-million-row bucket through downstream sorts/uploads)."""
         if self.live is not None:
             return self.compacted().shrink()
+        if bucket_for(1) >= self.capacity:
+            return self
         n = self.num_rows
         k = bucket_for(max(n, 1))
         if k >= self.capacity:

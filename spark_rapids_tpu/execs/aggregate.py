@@ -25,9 +25,11 @@ into the kernel (execs/fuse.py) — predicates become weight masks evaluated
 in the same XLA program, so a filter+project+aggregate pipeline is ONE
 device dispatch with no intermediate materialization.
 
-Multi-batch inputs STREAM (GpuMergeAggregateIterator analog): one batch in
-HBM at a time aggregates to a spillable partial, and a merge aggregation +
-finalize projection combines the partials (see _merge_plan)."""
+Multi-batch inputs STREAM (GpuMergeAggregateIterator analog): each batch
+aggregates to a spillable partial as it arrives, the host running ahead of
+the device by no more input than the memory arbiter's budget has room for
+(_bound_run_ahead), and a merge aggregation + finalize projection combines
+the partials (see _merge_plan)."""
 
 from __future__ import annotations
 
@@ -189,6 +191,7 @@ class TpuHashAggregateExec(TpuExec):
         return out
 
     def execute(self):
+        from collections import deque
         from itertools import chain
         from spark_rapids_tpu.runtime.retry import retry_block
         from spark_rapids_tpu.runtime.spill import BufferCatalog, SpillableBatch
@@ -197,6 +200,10 @@ class TpuHashAggregateExec(TpuExec):
         # aggregation is partition-structure-blind: a repartition's
         # same-split views mask-union back into one batch (no data moves)
         it = merge_split_views(self.children[0].execute_masked())
+        # the node's record carries both counts of the streaming loop,
+        # 0 where it read no partial's count and never waited
+        self.add_metric("partialCountReads", 0)
+        self.add_metric("runAheadWaits", 0)
         first = next(it, None)
         if first is None:
             return
@@ -210,31 +217,63 @@ class TpuHashAggregateExec(TpuExec):
 
         # STREAMING multi-batch path (GpuMergeAggregateIterator analog,
         # GpuAggregateExec.scala:718-950): each input batch aggregates
-        # immediately to a per-batch PARTIAL table (bounded HBM — only one
-        # input batch is resident at a time), partials are spillable, and
-        # one merge aggregation re-groups the concatenated partials with
-        # merge semantics (sum-of-sums, min-of-mins, Chan-style moment
-        # combination), followed by a finalize projection (avg = s/n, ...).
+        # immediately to a per-batch PARTIAL table, partials are
+        # spillable, and one merge aggregation re-groups the concatenated
+        # partials with merge semantics (sum-of-sums, min-of-mins,
+        # Chan-style moment combination), followed by a finalize
+        # projection (avg = s/n, ...).
         plan = self._merge_plan()
         catalog = BufferCatalog.get()
         partials = []
+        #: capacities of the partials kept with their count on the device
+        kept_capacity = 0
+        #: (count, input bytes) of the partials enqueued and not yet
+        #: known complete, oldest first (_bound_run_ahead)
+        ahead = deque()
         try:
             for batch in chain([first, second], it):
                 pt = retry_block(lambda b=batch: self._aggregate(
                     b, self.grouping, plan.partial_specs,
                     self.grouping_names, self.filters))
-                # SHRINK each partial to its live-group bucket before
-                # it buffers: a partial carries its input's full
-                # capacity for a handful of group rows, and the merge
-                # concat below buckets the SUM of partial capacities —
-                # unshrunk, a chunked scan's N partials concat into an
-                # N-fold over-capacity table, which is exactly the
-                # over-budget resident the out-of-core contract
-                # forbids. Pays one row-count sync per partial (the
-                # merge is a sync point anyway; shrink's docstring
-                # case: after cardinality-collapsing ops).
-                partials.append(SpillableBatch(pt.shrink(), catalog))
+                # A partial's row count stays a device scalar
+                # (concat_device and the merge take it as one): reading
+                # it stalls the host until the batch's kernel has run,
+                # and nothing further is enqueued meanwhile. It is read
+                # only where the read can free something. THE CAPACITY
+                # RULE: the merge concat below buckets the SUM of
+                # partial capacities, so a chunked scan's N
+                # full-capacity partials would concat into an N-fold
+                # over-capacity table, exactly the over-budget resident
+                # the out-of-core contract forbids. A partial is kept
+                # as it is only while it is smaller than its input (the
+                # fast path's domain-sized output, not the sorted
+                # path's input-sized one), under EMBED_NROWS_CAP rows
+                # (where the sorted path's speculative capacities
+                # start: such a partial's batch costs far more than a
+                # sync, and so would a sorted merge over it), and the
+                # kept capacities together stay within one input
+                # batch's, so the merge costs at most one more batch.
+                # Any other partial is shrunk to its live-group bucket
+                # at the price of one row-count sync (shrink() reads
+                # nothing where no smaller bucket exists).
+                if not pt.num_rows_known:
+                    if (pt.capacity < batch.capacity
+                            and pt.capacity < DeviceTable.EMBED_NROWS_CAP
+                            and kept_capacity + pt.capacity
+                            <= batch.capacity):
+                        kept_capacity += pt.capacity
+                    else:
+                        pt = pt.shrink()
+                if pt.num_rows_known:
+                    # read here, or by _aggregate's own shrink (the
+                    # sorted path): the device is done up to this one
+                    self.add_metric("partialCountReads", 1)
+                    ahead.clear()
+                else:
+                    ahead.append((pt.nrows_dev, batch.device_nbytes()))
+                partials.append(SpillableBatch(pt, catalog))
                 self.add_metric("partialAggBatches", 1)
+                self._bound_run_ahead(ahead)
 
             from spark_rapids_tpu.columnar.table import concat_device
 
@@ -254,6 +293,27 @@ class TpuHashAggregateExec(TpuExec):
         out_cols = compile_project(bound, mt)
         out_names = self.grouping_names + [n for n, _ in self.agg_specs]
         yield DeviceTable(out_names, out_cols, mt.nrows_dev, mt.capacity)
+
+    def _bound_run_ahead(self, ahead) -> None:
+        """Back-pressure for the streaming loop, called before it pulls
+        the next batch. The per-partial row-count sync used to be the
+        only one: without it the host can enqueue the whole stream, and
+        an input the pull BUILT (a coalesce flush, a scan chunk's
+        upload, an upstream exec's output) stays in HBM until its
+        partial's kernel has run, while the arbiter releases a table
+        when its Python reference dies, i.e. at enqueue. ``ahead``
+        holds those inputs' bytes; while they would not fit the
+        arbiter's budget beside what it accounts, wait for the oldest
+        partial (its count: a counted, timed, ranged host_fetch) and
+        drop what that confirms."""
+        from spark_rapids_tpu.dispatch import host_fetch
+        from spark_rapids_tpu.runtime.memory import MEMORY
+        while ahead and (MEMORY.occupancy()
+                         + sum(nbytes for _, nbytes in ahead)
+                         > MEMORY.budget_bytes()):
+            count, _ = ahead.popleft()
+            host_fetch(count)
+            self.add_metric("runAheadWaits", 1)
 
     # -- streaming merge plan ----------------------------------------------
     def _merge_plan(self):
@@ -546,7 +606,8 @@ class TpuHashAggregateExec(TpuExec):
         out = DeviceTable(names, out_cols, ngroups, out_capacity)
         if fast:
             # outputs are already domain-sized; the group count stays a
-            # device scalar (no host sync on the hot path)
+            # device scalar: no host sync here, and the streaming loop
+            # reads it only under its capacity rule (execute)
             return out
         from spark_rapids_tpu.columnar import bucket_for
         from spark_rapids_tpu.runtime import speculation as spec

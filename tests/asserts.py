@@ -98,3 +98,13 @@ def assert_falls_back(build_df, session, node_name: str):
     assert found, f"no node {node_name} in plan"
     assert any(not m.can_run_on_tpu for m in found), \
         f"{node_name} unexpectedly supported on TPU"
+
+
+def plan_metric_total(session, key):
+    """Sum of an exec metric over the plan tree of the session's last
+    event record (the event log must be on)."""
+    def walk(node):
+        m = node.get("metrics", {}).get(key)
+        return (m["value"] if m else 0) + sum(
+            walk(c) for c in node.get("children", ()))
+    return walk(session.last_event_record["plan"])

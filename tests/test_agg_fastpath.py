@@ -9,6 +9,7 @@ from spark_rapids_tpu import functions as F
 from spark_rapids_tpu.ops.expr import col, lit
 
 from tests.asserts import assert_tpu_and_cpu_are_equal
+from tests.asserts import plan_metric_total as _metric_total
 from tests.data_gen import (
     BooleanGen, DoubleGen, IntGen, LongGen, StringGen, gen_table,
 )
@@ -322,15 +323,6 @@ def logged_session(tmp_path_factory):
     return _logged(tmp_path_factory.mktemp("agg_events"))
 
 
-def _metric_total(session, key):
-    """Sum of an exec metric over the last query's executed plan."""
-    def walk(node):
-        m = node.get("metrics", {}).get(key)
-        return (m["value"] if m else 0) + sum(
-            walk(c) for c in node.get("children", ()))
-    return walk(session.last_event_record["plan"])
-
-
 def _counts_query(s, table, num_batches=1, filtered=False):
     from spark_rapids_tpu.plan import from_host_table
     df = from_host_table(table, s, num_batches)
@@ -547,3 +539,247 @@ def test_counts_by_contraction_per_aggregate_call(tmp_path, cpu_session,
     assert lowered and "valid_counts" in lowered[0]
     for scope in ("live_mask", "group_ids", "agg_values", "compact_groups"):
         assert scope in lowered[0], scope
+
+
+# ---------------------------------------------------------------------------
+# the streaming loop: a partial's row count stays on the device unless
+# reading it can shrink something (DeviceTable.shrink, the capacity rule in
+# TpuHashAggregateExec.execute); tests/test_memory.py holds the run-ahead
+# bound
+# ---------------------------------------------------------------------------
+
+def _unknown_count_table(capacity, n, masked=False):
+    """One LONG column 0..capacity-1 whose first n rows are live and
+    whose count is a device scalar the host has not read."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.columnar import DeviceColumn, DeviceTable
+    live = jnp.arange(capacity) < n
+    col_ = DeviceColumn(T.LONG, jnp.arange(capacity, dtype=jnp.int64),
+                        jnp.ones(capacity, dtype=jnp.bool_))
+    return DeviceTable(["v"], [col_], jnp.asarray(n, jnp.int32), capacity,
+                       live=live if masked else None)
+
+
+@pytest.mark.parametrize("capacity,masked,reads", [
+    (16, False, False),    # a fast-path partial: under the smallest bucket
+    (128, False, False),   # the smallest bucket itself
+    (128, True, False),    # compacted first, still nothing to shrink to
+    (256, False, True),    # one bucket above: the count decides
+    (4096, False, True),
+    (4096, True, True),
+], ids=["cap16", "cap128", "cap128-masked", "cap256", "cap4096",
+        "cap4096-masked"])
+def test_shrink_reads_the_count_only_for_a_smaller_bucket(capacity, masked,
+                                                          reads):
+    from spark_rapids_tpu import dispatch
+    t = _unknown_count_table(capacity, 5, masked)
+    before = dispatch.host_fetch_count()
+    out = t.shrink()
+    assert dispatch.host_fetch_count() - before == int(reads)
+    assert out.num_rows_known == reads
+    if reads:
+        assert out.capacity == 128 and out.num_rows == 5
+    else:
+        assert out.capacity == capacity
+        assert (out is t) != masked   # a masked table comes back compacted
+    assert out.live is None
+    assert out.to_host().to_pydict() == {"v": [0, 1, 2, 3, 4]}
+
+
+def _per_batch_shrink(monkeypatch):
+    """Test-only: every _aggregate output's count is read and the table
+    shrunk before the loop sees it, as the loop did for each partial
+    before the capacity rule."""
+    from spark_rapids_tpu.execs import aggregate as A
+    real = A.TpuHashAggregateExec._aggregate
+
+    def forced(self, *a, **k):
+        out = real(self, *a, **k)
+        out.num_rows
+        return out.shrink()
+    monkeypatch.setattr(A.TpuHashAggregateExec, "_aggregate", forced)
+
+
+def _stream_table(n, groups, seed, tag):
+    """_counts_table under column names of its own (a fresh trace)."""
+    t = _counts_table(n, groups, seed)
+    return type(t)([f"{tag}{c}" for c in "kxyw"], t.columns)
+
+
+def _stream_query(s, table, tag, batches, shape):
+    from spark_rapids_tpu.plan import from_host_table
+    k, x, y, w = (col(f"{tag}{c}") for c in "kxyw")
+    aggs = [F.count().alias("n"), F.count(x).alias("nx"),
+            F.sum(x).alias("sx"), F.avg(x).alias("ax"),
+            F.sum(y).alias("sy"), F.min(y).alias("my")]
+    if shape == "masked":
+        # filters under a union are not fused into the aggregate: its
+        # batches arrive with a deferred-compaction live mask
+        half = batches // 2
+        df = (from_host_table(table, s, half).filter(w < lit(40))
+              .union(from_host_table(table, s, batches - half)
+                     .filter(w >= lit(70))))
+    else:
+        df = from_host_table(table, s, batches)
+        if shape == "filtered":
+            df = df.filter(w < lit(40))
+    return df.group_by(f"{tag}k").agg(*aggs)
+
+
+@pytest.mark.parametrize("batches", [4, 16])
+@pytest.mark.parametrize("groups,shape", [
+    (5, "all"), (12, "all"), (25, "all"), (40, "all"),
+    (5, "filtered"), (12, "filtered"), (25, "filtered"), (40, "filtered"),
+    (12, "masked"), (40, "masked"),
+], ids=["gpad8", "gpad16", "gpad32", "gpad64", "gpad8-filtered",
+        "gpad16-filtered", "gpad32-filtered", "gpad64-filtered",
+        "gpad16-masked", "gpad64-masked"])
+def test_streaming_fast_path_reads_no_partial_count(
+        tmp_path, cpu_session, monkeypatch, batches, groups, shape):
+    """NULL keys, NULL values, a fused filter, masked batches: one host
+    sync a query (the root result), every partial kept with its count on
+    the device, answers equal to the CPU's and bit-equal to the same
+    query with the per-batch count read and shrink forced."""
+    from spark_rapids_tpu.execs import aggregate as A
+    masks = []
+    real = A.TpuHashAggregateExec._aggregate
+
+    def spy(self, table, *a, **k):
+        masks.append(table.live is not None)
+        return real(self, table, *a, **k)
+    monkeypatch.setattr(A.TpuHashAggregateExec, "_aggregate", spy)
+
+    sess = _logged(tmp_path, **{"spark.rapids.sql.batchSizeBytes": "1024"})
+    tag = f"s{batches}g{groups}{shape[0]}"
+    table = _stream_table(1000 * batches, groups, seed=groups + batches, tag=tag)
+
+    def build(s):
+        return _stream_query(s, table, tag, batches, shape)
+    assert_tpu_and_cpu_are_equal(build, sess, cpu_session,
+                                 approximate_float=True)
+    rec = sess.last_event_record
+    assert rec["hostSyncs"] == 1
+    assert _metric_total(sess, "partialAggBatches") == batches
+    assert _metric_total(sess, "partialCountReads") == 0
+    assert _metric_total(sess, "runAheadWaits") == 0
+    # the partials' inputs, then the merge's unmasked concat
+    assert masks[:batches] == [shape == "masked"] * batches
+    got = sorted(build(sess).collect(), key=repr)
+
+    _per_batch_shrink(monkeypatch)
+    forced = sorted(build(sess).collect(), key=repr)
+    assert sess.last_event_record["hostSyncs"] > batches
+    assert _metric_total(sess, "partialCountReads") == batches
+    assert got == forced
+
+
+@pytest.mark.parametrize("rows,batches", [
+    (1000, 4), (1000, 6),
+    (140000, 2),   # 2^18-row inputs: partials at the speculative 2^16
+], ids=["4", "6", "speculative-quarter"])
+def test_streaming_sorted_path_shrinks_every_partial(
+        tmp_path, cpu_session, monkeypatch, rows, batches):
+    """A sorted-path partial has its input's capacity (or, past
+    EMBED_NROWS_CAP, a speculative quarter of it): the capacity rule
+    keeps none, each is shrunk to its live bucket at one count read, and
+    the merge concat is as small as before."""
+    from spark_rapids_tpu.columnar import bucket_for
+    from spark_rapids_tpu.columnar import table as TB
+    concats = []
+    real = TB.concat_device
+
+    def spy(tables, **kw):
+        out = real(tables, **kw)
+        concats.append(([(t.capacity, t.num_rows) for t in tables],
+                        out.capacity))
+        return out
+    monkeypatch.setattr(TB, "concat_device", spy)
+
+    sess = _logged(tmp_path, **{"spark.rapids.sql.batchSizeBytes": "1024",
+                                "spark.rapids.tpu.agg.maxDictGroups": "0"})
+    tag = f"o{rows}x{batches}"
+    table = _stream_table(rows * batches, 12, seed=batches, tag=tag)
+
+    def build(s):
+        return _stream_query(s, table, tag, batches, "all")
+    assert_tpu_and_cpu_are_equal(build, sess, cpu_session,
+                                 approximate_float=True)
+    assert _metric_total(sess, "partialAggBatches") == batches
+    assert _metric_total(sess, "partialCountReads") == batches
+    assert _metric_total(sess, "countsByContraction") == 0
+    assert sess.last_event_record["hostSyncs"] == batches + 1
+    (parts, out_capacity), = concats
+    assert len(parts) == batches
+    assert all(cap == bucket_for(max(n, 1)) for cap, n in parts), parts
+    assert out_capacity == bucket_for(sum(cap for cap, _ in parts))
+
+
+@pytest.mark.parametrize("rows,groups,batches,reads", [
+    (1000, 200, 4, 0),   # 4 x 256 slots = one input batch's 1024: all kept
+    (1000, 200, 5, 1),   # the fifth would pass it: shrunk, one count read
+    (1000, 200, 7, 3),
+    (200, 2000, 4, 4),   # 256 slots against a 256-row input: none is kept
+], ids=["at-the-boundary", "one-over", "three-over", "not-under-its-input"])
+def test_streaming_capacity_rule_boundary(tmp_path, cpu_session, rows,
+                                          groups, batches, reads):
+    """Partials of 256 slots (129 to 255 keys + the null slot): kept
+    while their capacities together stay within one input batch's, and
+    only while a partial is smaller than its input."""
+    sess = _logged(tmp_path, **{"spark.rapids.sql.batchSizeBytes": "1024"})
+    tag = f"b{rows}x{batches}"
+    table = _stream_table(rows * batches, groups, seed=rows + batches,
+                          tag=tag)
+    exec_ = _fast_agg_exec(sess, _stream_query(sess, table, tag, batches,
+                                               "all"))
+    batch = next(iter(exec_.children[0].execute_masked()))
+    assert exec_._fast_layout(
+        exec_.grouping, exec_._prep_all(batch, exec_.grouping,
+                                        exec_.agg_specs, exec_.filters)[2],
+        batch.capacity)[3] == 256
+
+    assert_tpu_and_cpu_are_equal(
+        lambda s: _stream_query(s, table, tag, batches, "all"),
+        sess, cpu_session, approximate_float=True)
+    assert _metric_total(sess, "partialAggBatches") == batches
+    assert _metric_total(sess, "partialCountReads") == reads
+    assert sess.last_event_record["hostSyncs"] == 1 + reads
+
+
+@pytest.mark.parametrize("k", [1, 3, 4], ids=["first", "third", "last"])
+def test_streaming_injected_oom_replays_the_partial(
+        tmp_path, cpu_session, monkeypatch, k):
+    """An OOM injected into the k-th partial's retry block is replayed
+    there (one retry, no count read by the loop) and the answer does not
+    change."""
+    from spark_rapids_tpu.execs import aggregate as A
+    from spark_rapids_tpu.runtime.retry import RMM_TPU
+    calls = []
+    real = A.TpuHashAggregateExec._aggregate
+
+    def failing(self, *a, **kw):
+        calls.append(1)
+        if len(calls) == k:
+            RMM_TPU.force_retry_oom(1)
+            RMM_TPU.maybe_inject()
+        return real(self, *a, **kw)
+
+    sess = _logged(tmp_path, **{"spark.rapids.sql.batchSizeBytes": "1024"})
+    table = _stream_table(4000, 12, seed=k, tag=f"f{k}")
+
+    def build(s):
+        return _stream_query(s, table, f"f{k}", 4, "all")
+    want = sorted(build(sess).collect(), key=repr)
+    monkeypatch.setattr(A.TpuHashAggregateExec, "_aggregate", failing)
+    retries = RMM_TPU.retry_count
+    got = sorted(build(sess).collect(), key=repr)
+    assert RMM_TPU.retry_count == retries + 1
+    assert len(calls) == 4 + 1 + 1   # the partials, the replay, the merge
+    rec = sess.last_event_record
+    # the replay's spill pass reads what it demotes: the k - 1 partials
+    # buffered so far, and nothing else but the root result
+    assert rec["oomRetries"] == 1 and rec["hostSyncs"] <= k
+    assert _metric_total(sess, "partialCountReads") == 0
+    assert got == want
+    assert_tpu_and_cpu_are_equal(build, sess, cpu_session,
+                                 approximate_float=True)

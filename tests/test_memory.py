@@ -35,6 +35,8 @@ from spark_rapids_tpu.runtime.spill import BufferCatalog, SpillableDeviceTable
 from spark_rapids_tpu.columnar import DeviceTable, HostTable
 from spark_rapids_tpu.session import TpuSession
 
+from tests.asserts import plan_metric_total
+
 pytestmark = pytest.mark.chaos
 
 
@@ -344,6 +346,56 @@ def test_split_and_retry_under_budget():
     assert moved.get("splitRetries", 0) >= 1, moved
     merged = HostTable.concat(outs)
     assert merged.to_pydict() == host.to_pydict()
+
+
+# ---------------------------------------------------------------------------
+# the streaming aggregate's run-ahead bound
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("nb", [1, 6], ids=["one-batch-chunked", "six"])
+def test_streaming_agg_run_ahead_bounded_by_budget(tmp_path, monkeypatch, nb):
+    """With no row-count read per partial, the arbiter's budget is the
+    streaming aggregate's back-pressure: under a 160KB budget the loop
+    waits for its oldest partial before it pulls another chunk, the
+    answer is bitwise the same-shape baseline's, and the ledger's peak
+    is no higher than with a count read after every partial (+ one
+    input chunk). At the default budget it never waits."""
+    from spark_rapids_tpu.execs.aggregate import TpuHashAggregateExec
+    data = _data(20000, seed=nb)
+    log = {"spark.rapids.sql.eventLog.enabled": "true",
+           "spark.rapids.sql.eventLog.dir": str(tmp_path)}
+
+    budgeted = TpuSession(_budget_conf(log))
+    got = _agg(budgeted, data, nb=nb)
+    batches = plan_metric_total(budgeted, "partialAggBatches")
+    assert batches > nb  # the scan chunked
+    waits = plan_metric_total(budgeted, "runAheadWaits")
+    assert waits > 0
+    assert plan_metric_total(budgeted, "partialCountReads") == 0
+    assert budgeted.last_event_record["hostSyncs"] == 1 + waits
+    peak = MEMORY.peak_bytes()
+
+    MEMORY.reset()
+    plain = TpuSession(log)
+    with forced_chunking(_SHARE):
+        want = _agg(plain, data, nb=nb)
+    assert got == want  # bitwise
+    assert plan_metric_total(plain, "partialAggBatches") == batches
+    assert plan_metric_total(plain, "runAheadWaits") == 0
+    assert plain.last_event_record["hostSyncs"] == 1
+
+    # the loop as it was: every partial's count read before the next pull
+    real = TpuHashAggregateExec._aggregate
+
+    def synced(self, *a, **k):
+        out = real(self, *a, **k)
+        out.num_rows
+        return out
+    monkeypatch.setattr(TpuHashAggregateExec, "_aggregate", synced)
+    MEMORY.reset()
+    assert _agg(TpuSession(_budget_conf(log)), data, nb=nb) == want
+    assert peak <= MEMORY.peak_bytes() + _SHARE
 
 
 # ---------------------------------------------------------------------------

@@ -499,7 +499,12 @@ def test_event_log_golden_schema(tmp_path):
     v13 = phasesS gains coalesceS (host seconds inside the coalesce
     exec's multi-batch flushes, the range srt.coalesce.flush; its
     jit_coalesce dispatch counts in dispatchS too; 0.0 where every
-    coalesce passed its batches on)."""
+    coalesce passed its batches on).
+    Exec metrics in the plan tree are no schema fields (no bump): every
+    TpuHashAggregateExec node carries partialCountReads (partials whose
+    row count the streaming loop read to shrink them) and runAheadWaits
+    (times its run-ahead bound waited), both 0 for the golden's
+    single-batch aggregate."""
     s = _run_eventlog_query(tmp_path)
     got = _normalize(s.last_event_record)
     golden_path = os.path.join(os.path.dirname(__file__),
