@@ -22,7 +22,7 @@ phase when four chips are attached. It exits non-zero, printing no
 result line, as soon as any child fails; nothing here catches a phase
 failure and carries on. On success stdout holds two lines: first
 {"report": {...}} (row counts, HBM, phases, cold and warm seconds, cache
-hits, kernels, the f64 probe, the mesh child — smoke readings,
+hits, the f64 probe, the mesh child — smoke readings,
 information, not a benchmark), and LAST the result line, which holds
 exactly {"ok": true, "device": {"platform", "kind", "count"}}.
 """
@@ -105,7 +105,6 @@ def parent(rows):
         "persistent_cache": {"dir": a["persistent_cache"]["dir"],
                              "A": a["persistent_cache"],
                              "B": b["persistent_cache"]},
-        "kernels": a["kernels"],
         "demotions": a["demotions"],
         "f64_on_device": a["f64_on_device"],
         "native_available": a["native_available"],
@@ -143,7 +142,6 @@ class CacheCounter:
 
 def check_record(rec, label):
     """What every query's event record must say on the smoke path."""
-    from spark_rapids_tpu import kernels
     from spark_rapids_tpu.runtime.health import HEALTH
     assert rec is not None, f"{label}: no event record (event log off?)"
     assert rec["fallbacks"] == [], \
@@ -154,7 +152,6 @@ def check_record(rec, label):
     assert rec["healthState"] == "HEALTHY", f"{label}: {rec['healthState']}"
     assert rec["recovery"] == {}, f"{label}: recovery {rec['recovery']}"
     assert rec["oomRetries"] == 0, f"{label}: {rec['oomRetries']} OOM retries"
-    assert not kernels.interpret_mode(), f"{label}: Pallas is interpreting"
     assert HEALTH.cpu_only_reason() is None, \
         f"{label}: CPU-only latch: {HEALTH.cpu_only_reason()}"
 
@@ -337,10 +334,9 @@ def child_single(rows, out_path):
     device, hbm = phase_device()
     phases = ["device"]
 
-    from spark_rapids_tpu import kernels
-    from spark_rapids_tpu.dispatch import COMPILE_SCOPE
     from spark_rapids_tpu.models import tpch
     from spark_rapids_tpu.native import native_available
+    from spark_rapids_tpu.runtime.faults import CIRCUIT_BREAKER
     from spark_rapids_tpu.service import QueryService
 
     session = make_session()
@@ -419,17 +415,10 @@ def child_single(rows, out_path):
         service.shutdown()
     phases.append("served")
 
-    resolved = kernels.resolve_enabled(session.conf)
-    compiled = dict(COMPILE_SCOPE)
     result = {
         "device": device, "hbm": hbm, "data": data, "phases": phases,
         "seconds": seconds, "persistent_cache": cache.report(),
-        "kernels": {"auto_on": sorted(resolved.enabled),
-                    "auto_off": sorted(kernels.TPU_AUTO_OFF),
-                    "interpret": kernels.interpret_mode(),
-                    "pallas_programs_traced": compiled.get("pallasKernels", 0),
-                    "hlo_fallbacks_traced": compiled.get("hloFallbacks", 0)},
-        "demotions": kernels.demoted_ops(),
+        "demotions": CIRCUIT_BREAKER.demoted_ops(),
         "f64_on_device": f64_report,
         "native_available": bool(native_available()),
     }

@@ -3,7 +3,7 @@
 A from-scratch framework with the capabilities of the RAPIDS Accelerator for
 Apache Spark (reference: /root/reference, NVIDIA spark-rapids): a plan-rewrite
 engine that converts SQL physical plans into columnar operators executing on
-TPUs via JAX/XLA (Pallas for custom kernels), with per-operator CPU fallback,
+TPUs via JAX/XLA, with per-operator CPU fallback,
 bit-for-bit Spark-compatible semantics, an HBM buffer catalog with host/disk
 spill and OOM split-and-retry, TPU-aware shuffle (host path + ICI collectives),
 and accelerated Parquet/ORC/CSV/JSON/Avro IO.

@@ -945,9 +945,7 @@ class DeviceTable:
         per column word this representation exists to defer."""
         if self.live is None:
             return self
-        from spark_rapids_tpu import kernels
-        key = ("tablecompact", self.capacity, self.schema_key()[0],
-               kernels.trace_token())
+        key = ("tablecompact", self.capacity, self.schema_key()[0])
         fn = _PACK_CACHE.get(key)
         if fn is None:
             cap = self.capacity

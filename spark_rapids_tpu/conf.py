@@ -499,70 +499,6 @@ SPLIT_F64_SUM = str_conf(
     "force the mode. The same trade the reference gates with "
     "variableFloatAgg.enabled.")
 
-KERNELS_SORT_ENABLED = str_conf(
-    "spark.rapids.tpu.kernels.sort.enabled", "auto",
-    "Pallas multi-column sort kernel (kernels/sort.py): a bitonic "
-    "network over packed two-limb key operands + payload permutation "
-    "in ONE fused device program, replacing the multi-operand "
-    "lexicographic lax.sort. 'auto' enables a primitive on the TPU "
-    "backend unless kernels.TPU_AUTO_OFF stands it down there (a "
-    "program the Mosaic compiler refuses, or that does not match HLO "
-    "bit for bit on the chip — as of the v5e bring-up that is sort, "
-    "compact, hashprobe and segreduce's one-hot partials) and never "
-    "on CPU (Pallas runs in interpret mode there — correct but slow); "
-    "'true'/'false' force. Bit-identity with the HLO path is pinned; "
-    "ineligible shapes (non-power-of-two capacity, VMEM budget) fall "
-    "back per call, and a kernel crash demotes the primitive to HLO "
-    "for the process (reason in explain()/event log).")
-
-KERNELS_SEGREDUCE_ENABLED = str_conf(
-    "spark.rapids.tpu.kernels.segreduce.enabled", "auto",
-    "Pallas segmented-reduction kernels (kernels/segreduce.py): fused "
-    "two-limb 64-bit segment min/max (hi-limb reduce + lo-limb "
-    "tiebreak in one two-pass program instead of 4+ scatter/gather "
-    "passes) and the blocked one-hot split-sum partials built in VMEM "
-    "instead of materializing the one-hot in HBM. 'auto'/'true'/"
-    "'false' as for kernels.sort.enabled.")
-
-KERNELS_HASHPROBE_ENABLED = str_conf(
-    "spark.rapids.tpu.kernels.hashprobe.enabled", "auto",
-    "Pallas hash-probe join kernel (kernels/hashprobe.py): a bounded-"
-    "attempt open-addressing table over two-limb keys replaces the "
-    "dense-code prefix chain (two full sorts) for single-integer-key "
-    "joins with unique build keys; duplicate/overflowing builds set a "
-    "device flag and the sort-based probe replays (speculation "
-    "machinery). 'auto'/'true'/'false' as for kernels.sort.enabled.")
-
-KERNELS_COMPACT_ENABLED = str_conf(
-    "spark.rapids.tpu.kernels.compact.enabled", "auto",
-    "Pallas row-compaction kernel (kernels/compact.py): one i32 "
-    "gather-map scatter + ONE fused kernel gathering every column's "
-    "32-bit limb streams, replacing 2-3 scatter passes per 64-bit "
-    "column in every filter/join-output/split compaction. "
-    "'auto'/'true'/'false' as for kernels.sort.enabled.")
-
-KERNELS_VMEM_BUDGET = int_conf(
-    "spark.rapids.tpu.kernels.vmemBudgetBytes", 64 << 20,
-    "Per-call VMEM working-set bound for the Pallas kernels: a "
-    "primitive whose resident operands would exceed this falls back "
-    "to the HLO path for that call (counted as an hloFallback in the "
-    "compile metric scope). The same number is handed to the Mosaic "
-    "compiler as its scoped-VMEM limit (its own default is 16 MiB), "
-    "held to the device's VMEM capacity (128 MiB on a v5e).")
-
-KERNELS_SEGREDUCE_MAX_SEGMENTS = int_conf(
-    "spark.rapids.tpu.kernels.segreduce.maxSegments", 8192,
-    "Segment-count bound for the Pallas segmented min/max kernel (the "
-    "per-block accumulator is segment-sized in VMEM); wider segment "
-    "spaces keep the native-32-bit HLO scatter path.")
-
-KERNELS_HASHPROBE_ATTEMPTS = int_conf(
-    "spark.rapids.tpu.kernels.hashprobe.attempts", 4,
-    "Rehash attempts for the Pallas hash-probe table: build rows that "
-    "cannot place within this many alternative slots (or duplicate "
-    "build keys) set the failure flag and the join replays on the "
-    "sort-based probe.")
-
 AGG_MAX_DICT_GROUPS = int_conf(
     "spark.rapids.tpu.agg.maxDictGroups", 1 << 16,
     "Max key-domain product for the no-sort dictionary-code aggregation "
@@ -765,8 +701,9 @@ def generate_docs() -> str:
         "`CREATE TEMP VIEW v USING fmt OPTIONS (path '...')` resolve "
         "through `session.catalog`; views capture the PLAN, live for the "
         "session, and drop via `DROP VIEW [IF EXISTS]`. The supported "
-        "grammar table lives in README.md; `bench.py --sql` and "
-        "`scale_test.py --sql` run the TPC-H corpus from SQL text.",
+        "grammar table lives in README.md; `benchmarks/run.py` runs "
+        "its cells' TPC-H statements, and `scale_test.py --sql` the "
+        "TPC-H corpus, from SQL text.",
         "",
         "## Static analysis (`python -m spark_rapids_tpu.lint`)",
         "",
@@ -805,8 +742,8 @@ def generate_docs() -> str:
         "dispatch) and exports a Chrome trace-event JSON per query "
         "under `spark.rapids.trace.dir` — load it in Perfetto next to "
         "the Xprof device trace `spark.rapids.profile.enabled` "
-        "collects. `bench.py` and `scale_test.py` write event logs by "
-        "default; `python -m spark_rapids_tpu.tools profile <log>` "
+        "collects. `benchmarks/run.py` and `scale_test.py` write event "
+        "logs by default; `python -m spark_rapids_tpu.tools profile <log>` "
         "builds the offline report (top operators by self time, "
         "compute/transfer/shuffle/spill breakdown, per-exchange skew, "
         "fallback inventory, >=95% span-attribution contract) and "

@@ -231,14 +231,6 @@ register_metric("kernelCompileTime", "timing", "ESSENTIAL",
 register_metric("padWasteRows", "count", "MODERATE",
                 "dead tail rows uploaded to pad batches up to their "
                 "capacity bucket (the price of the bounded kernel set)")
-register_metric("pallasKernels", "count", "MODERATE",
-                "primitive dispatch sites that resolved to a Pallas "
-                "kernel at trace time (kernels/); warm dispatches "
-                "replay the traced choice without re-counting")
-register_metric("hloFallbacks", "count", "MODERATE",
-                "primitive dispatch sites that took the HLO path at "
-                "trace time — disabled by conf, shape outside the "
-                "kernel's envelope, or a demoted primitive")
 
 #: the process-wide `compile` scope: serving-latency observability for
 #: shape bucketing + the executable cache (which adds its own counters)
@@ -291,40 +283,6 @@ def reset_compile_stats() -> None:
     _TRACES.n = 0
     _COMPILE_S.v = 0.0
     _PAD_WASTE.n = 0
-
-
-# -- Pallas program interning ------------------------------------------------
-
-#: built pallas_call callables keyed by their static shape signature —
-#: the kernels/ layer's analog of the shared_traces jit pools: a
-#: primitive's program is constructed once per shape and every trace
-#: that embeds it (across queries and sessions) reuses the object
-_PALLAS_CACHE: Dict[tuple, object] = {}
-
-
-def pallas_program(key: tuple, builder):
-    """Process-wide interning of built Pallas programs. ``key`` must
-    capture every static parameter of the program (shape, dtypes,
-    grid/block choices); ``builder`` constructs it on first use."""
-    with _LOCK:
-        got = _PALLAS_CACHE.get(key)
-    if got is None:
-        built = builder()
-        with _LOCK:
-            # build-race loser adopts the winner's interned program —
-            # returning its own duplicate would pay a second compile
-            got = _PALLAS_CACHE.setdefault(key, built)
-    return got
-
-
-def clear_pallas_programs() -> int:
-    """Drop interned Pallas programs (device-loss recovery rides along
-    with ops/expr.clear_kernel_caches: a program object is cheap to
-    rebuild and must not outlive a reinitialized backend)."""
-    with _LOCK:
-        n = len(_PALLAS_CACHE)
-        _PALLAS_CACHE.clear()
-    return n
 
 
 # -- dispatch accounting ----------------------------------------------------
@@ -406,64 +364,7 @@ def tpu_jit(fn, *, name: str, **kwargs):
         with phase_span("dispatchS", name, "dispatch"):
             before = jf._cache_size()
             t0 = time.perf_counter()
-            # Pallas primitives embedded while TRACING this call record
-            # themselves in the capture frame (kernels._note_used). A
-            # kernel that traces fine but dies at backend compile /
-            # first execution (Mosaic lowering happens HERE, inside
-            # jf(...), not at trace time) raises outside the kernels
-            # layer's guarded() wrapper — the frame tells us which
-            # primitives to demote so the session's replay re-traces on
-            # the HLO path instead of the exec circuit breaker dropping
-            # the whole operator to CPU.
-            from spark_rapids_tpu import kernels as _kernels
-            frame = _kernels.begin_trace_capture()
-            try:
-                res = jf(*args, **kw)
-            except Exception as exc:
-                from spark_rapids_tpu.errors import (
-                    ColumnarProcessingError,
-                    KernelCrashError,
-                )
-                from spark_rapids_tpu.runtime.crash_handler import (
-                    is_fatal_device_error,
-                )
-                from spark_rapids_tpu.runtime.retry import is_device_oom
-                if (not frame or is_device_oom(exc)
-                        or is_fatal_device_error(exc)
-                        or isinstance(exc, _kernels.KernelIneligible)):
-                    # OOMs belong to the retry framework, fatal errors
-                    # to the health monitor, and KernelIneligible is a
-                    # structured fallback signal for the dispatch site
-                    # (the join memoizes it) — none are kernel crashes
-                    raise
-                if isinstance(exc, KernelCrashError):
-                    # already replayable (e.g. an injected crash that
-                    # crossed this frame): demote what was embedded,
-                    # keep the type
-                    for kname in sorted(frame):
-                        _kernels.demote(kname, exc)
-                    raise
-                if isinstance(exc, ColumnarProcessingError):
-                    # engine-typed trace failure (expression/plan bug
-                    # that happens to share a trace with a kernel):
-                    # not the kernel's fault — surface it untouched
-                    raise
-                # everything else (XlaRuntimeError, Mosaic lowering
-                # NotImplementedError, raw jnp errors) demotes the
-                # embedded primitives. Deliberately CONSERVATIVE: an
-                # unrelated raw trace bug sharing the program costs the
-                # kernels their fast path process-wide and surfaces the
-                # real error on the replayed HLO trace — the priced-in
-                # alternative (trying to classify compiler errors by
-                # message) silently misses real lowering failures.
-                for kname in sorted(frame):
-                    _kernels.demote(kname, exc)
-                raise KernelCrashError(
-                    f"pallas-embedding program {name} failed at "
-                    f"compile/execute; demoted "
-                    f"{sorted(frame)} to HLO: {exc}") from exc
-            finally:
-                _kernels.end_trace_capture(frame)
+            res = jf(*args, **kw)
             after = jf._cache_size()
             grew = after > before
             if grew:

@@ -545,12 +545,11 @@ class TpuHashAggregateExec(TpuExec):
              tuple(fn.key() for _, fn in agg_specs),
              tuple(f.key() for f in filters),
              table.schema_key()[0]))
-        from spark_rapids_tpu import kernels
         from spark_rapids_tpu.ops import segsum as _ss
         mode_key = ("fast", fast[0], fast[3]) if fast else ("sorted",)
         has_mask = table.live is not None
         tkey = (capacity, self.use_split, _ss.trace_key(),
-                kernels.trace_token(), mode_key, has_mask,
+                mode_key, has_mask,
                 tuple(_prep_trace_key(p) for p in filter_preps),
                 tuple(_prep_trace_key(p) for p in key_preps),
                 tuple(tuple(_prep_trace_key(p) for p in per_child)
@@ -751,9 +750,8 @@ class TpuHashAggregateExec(TpuExec):
                 exists = jnp.arange(gpad, dtype=jnp.int32) == 0
             ngroups = jnp.sum(exists.astype(jnp.int32))
 
-            # every output column compacts slot -> dense rank through ONE
-            # shared call (the Pallas compact kernel fuses the whole
-            # column set into one gather pass when enabled)
+            # every output column compacts slot -> dense rank through
+            # ONE shared call
             pairs = []
             slot_ix = jnp.arange(gpad, dtype=jnp.int32)
             for i, kind in enumerate(kinds):

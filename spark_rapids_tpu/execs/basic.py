@@ -278,11 +278,10 @@ class _FilterKernel:
         has_mask = table.live is not None
         ansi = ANSI_MODE.get()
 
-        from spark_rapids_tpu import kernels
         self._traces = shared_traces(
             ("filter", self.condition.key(), table.schema_key()[0]))
         tkey = (capacity, emit_mask, has_mask, ansi,
-                kernels.trace_token(), _prep_trace_key(preps))
+                _prep_trace_key(preps))
         got = self._traces.get(tkey)
         if got is None:
             cond = self.condition
@@ -616,8 +615,7 @@ _COMPACT_KERNELS = {}
 
 
 def _compaction_kernel(capacity: int, schema_key):
-    from spark_rapids_tpu import kernels
-    key = (capacity, schema_key, kernels.trace_token())
+    key = (capacity, schema_key)
     fn = _COMPACT_KERNELS.get(key)
     if fn is None:
         def run(datas, valids, keep):

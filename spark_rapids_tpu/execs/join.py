@@ -98,9 +98,7 @@ class JoinKernel:
     # -- phase A: shared code space + probe ranges --------------------------
     def probe(self, lkeys: List[DevVal], rkeys, nl_dev, nr_dev,
               cap_l: int, cap_r: int, live_l_mask=None):
-        from spark_rapids_tpu import kernels
         tkey = (cap_l, cap_r, live_l_mask is not None,
-                kernels.trace_token(),
                 tuple(str(k[0].dtype) for k in lkeys),
                 tuple(str(k[0].dtype) for k in rkeys))
         fn = self._probe_traces.get(tkey)
@@ -270,9 +268,8 @@ class _DirectJoinKernel:
         output stays IN PLACE (live rows marked by the returned mask — no
         compaction scatter at all, columnar/table.py DeviceTable.live);
         otherwise inner/semi/anti compact as before."""
-        from spark_rapids_tpu import kernels
         key = (jt, H, lt.capacity, rt.capacity, masked_out,
-               lt.live is not None, kernels.trace_token(),
+               lt.live is not None,
                lt.schema_key()[0], rt.schema_key()[0],
                str(lkey[0].dtype), str(rkey[0].dtype))
         fn = cls._traces.get(key)
@@ -653,12 +650,9 @@ class TpuJoinExec(TpuExec):
         if direct is not None:
             return direct, None
 
-        probe_out = self._try_hashprobe(lt, rt, lkeys, rkeys)
-        if probe_out is None:
-            probe_out = self._kernel.probe(
-                lkeys, rkeys, lt.nrows_dev, rt.nrows_dev,
-                lt.capacity, rt.capacity, lt.live)
-        (lo, counts, total_d, matched_l, rs_perm, live_l, live_r) = probe_out
+        (lo, counts, total_d, matched_l, rs_perm, live_l, live_r) = \
+            self._kernel.probe(lkeys, rkeys, lt.nrows_dev, rt.nrows_dev,
+                               lt.capacity, rt.capacity, lt.live)
 
         r_matched = None
         if full_outer:
@@ -734,105 +728,6 @@ class TpuJoinExec(TpuExec):
             fn = tpu_jit(flag, name="join_size_flag")
             self._kernel._aux_traces[key] = fn
         return fn(total_d, counts, live_l)
-
-    def _try_hashprobe(self, lt, rt, lkeys, rkeys):
-        """Pallas hash-probe (kernels/hashprobe.py): for single
-        integer-key joins, one bounded-attempt hash table replaces the
-        dense-rank sort chain. Outputs are probe()-compatible ranges
-        (counts in {0,1}, identity perm) so every downstream consumer —
-        expand, outer nulls, the full-outer match bitmap — runs
-        unchanged. Unique-build-key speculation: the device ``fail``
-        flag (duplicate keys or table overflow) rides the collect's
-        packed fetch; a miss blocklists this site and replays on the
-        sort-based probe — the _DirectJoinKernel protocol. Returns None
-        when the shape doesn't qualify."""
-        from spark_rapids_tpu import kernels
-        if len(lkeys) != 1:
-            return None
-        if not (getattr(lkeys[0][0], "ndim", 1) == 1
-                and getattr(rkeys[0][0], "ndim", 1) == 1):
-            # decimal128 keys are (rows, 2) limb MATRICES — the scalar
-            # two-limb split does not apply; sorted probe handles them
-            return None
-        if not (jnp.issubdtype(lkeys[0][0].dtype, jnp.integer)
-                and jnp.issubdtype(rkeys[0][0].dtype, jnp.integer)):
-            return None
-        if not kernels.enabled("hashprobe"):
-            # qualifying shape, primitive disabled/demoted: counted
-            # ONCE per exec per query (this runs per probe BATCH; the
-            # other routers count once per trace — a per-batch count
-            # would swamp the fallback ratio)
-            if not getattr(self, "_hashprobe_off_counted", False):
-                self._hashprobe_off_counted = True
-                return kernels.count_fallback("hashprobe", lambda: None)
-            return None
-        from spark_rapids_tpu.runtime import speculation as spec
-        site = self._site_key + ":hashprobe"
-        ctx = spec.allowed(site)
-        if ctx is None:
-            return None
-        H = 1 << max(2 * rt.capacity - 1, 1).bit_length()
-        attempts = kernels.config().attempts
-        tkey = ("hashprobe", H, lt.capacity, rt.capacity,
-                lt.live is not None, attempts, kernels.trace_token(),
-                str(lkeys[0][0].dtype), str(rkeys[0][0].dtype))
-        fn = self._kernel._probe_traces.get(tkey, "absent")
-        if fn is None:
-            return None  # memoized ineligible shape: sorted path
-        if fn == "absent":
-            cap_l, cap_r = lt.capacity, rt.capacity
-
-            def hashprobe(lk, rk, nl, nr, live_l_mask):
-                from spark_rapids_tpu.kernels import hashprobe as khash
-                if live_l_mask is not None:
-                    live_l = live_l_mask
-                else:
-                    live_l = jnp.arange(cap_l, dtype=jnp.int32) < nl
-                live_r = jnp.arange(cap_r, dtype=jnp.int32) < nr
-                lo, counts, total, matched, rs_perm, fail = \
-                    khash.probe_ranges(lk, rk, live_l, live_r, H,
-                                       attempts)
-                return (lo, counts, total, matched, rs_perm,
-                        live_l, live_r, fail)
-
-            # resolution is counted ONCE per trace key (trace-time
-            # semantics, like the other primitives' routers) and an
-            # ineligible shape is MEMOIZED — without the sentinel every
-            # probe batch would re-trace probe_ranges just to raise and
-            # fall back again
-            from spark_rapids_tpu.dispatch import COMPILE_SCOPE
-            from spark_rapids_tpu.kernels import KernelIneligible
-            fn = tpu_jit(hashprobe, name="join_hash_probe")
-            try:
-                out = fn(lkeys[0], rkeys[0], lt.nrows_dev, rt.nrows_dev,
-                         lt.live)
-            except KernelIneligible:
-                COMPILE_SCOPE.add("hloFallbacks", 1)
-                self._kernel._probe_traces[tkey] = None
-                return None
-            except Exception as exc:
-                from spark_rapids_tpu.runtime.crash_handler import (
-                    is_fatal_device_error,
-                )
-                from spark_rapids_tpu.runtime.retry import is_device_oom
-                if is_device_oom(exc) or is_fatal_device_error(exc):
-                    # OOMs belong to the retry framework; a dead
-                    # device is the health monitor's to recover
-                    # — neither is the kernel's fault (the tpu_jit
-                    # capture handler makes the same exemptions)
-                    raise
-                # idempotent when tpu_jit's capture frame already did it
-                kernels.demote("hashprobe", exc)
-                COMPILE_SCOPE.add("hloFallbacks", 1)
-                return None
-            COMPILE_SCOPE.add("pallasKernels", 1)
-            self._kernel._probe_traces[tkey] = fn
-        else:
-            out = fn(lkeys[0], rkeys[0], lt.nrows_dev, rt.nrows_dev,
-                     lt.live)
-        ctx.add_flag(site, out[-1])
-        self.add_metric("hashProbeBatches", 1)
-        return out[:-1]
 
     def _try_direct(self, jt, lt, rt, lkeys, rkeys, swapped, full_outer):
         """Dense-domain direct-address fast path (see _DirectJoinKernel).
@@ -929,9 +824,7 @@ class TpuJoinExec(TpuExec):
     def _compact(self, table: DeviceTable, keep) -> DeviceTable:
         """Semi/anti: compact kept rows (static capacity, like the filter
         kernel's scatter-to-cumsum compaction)."""
-        from spark_rapids_tpu import kernels
-        key = ("compact", table.capacity, table.schema_key()[0],
-               kernels.trace_token())
+        key = ("compact", table.capacity, table.schema_key()[0])
         fn = self._kernel._aux_traces.get(key)
         if fn is None:
             cap = table.capacity

@@ -173,9 +173,8 @@ class TpuSortExec(TpuExec):
         aux = prep_aux(pctx)
         capacity = table.capacity
 
-        from spark_rapids_tpu import kernels
         has_mask = table.live is not None
-        tkey = (capacity, has_mask, kernels.trace_token(),
+        tkey = (capacity, has_mask,
                 tuple(_prep_trace_key(p) for p in key_preps))
         fn = self._traces.get(tkey)
         if fn is None:
@@ -236,9 +235,8 @@ class TpuSortExec(TpuExec):
         from spark_rapids_tpu.dispatch import prep_aux
         cols = tuple(DevVal(c.data, c.validity) for c in table.columns)
         aux = prep_aux(pctx)
-        from spark_rapids_tpu import kernels
         has_mask = table.live is not None
-        tkey = (capacity, has_mask, k, kernels.trace_token(),
+        tkey = (capacity, has_mask, k,
                 tuple(_prep_trace_key(p) for p in key_preps))
         fn = self._traces.get(tkey)
         if fn is None:

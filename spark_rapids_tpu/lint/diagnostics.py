@@ -97,12 +97,6 @@ RULES: Dict[str, str] = {
                     "shard-dispatch placement layer outside a "
                     "sanctioned gather point (device shards must stay "
                     "resident between exchanges)",
-    "RL-KERNEL-HOST": "numpy import/materialization or host sync "
-                      "(jax.device_get / host_fetch / "
-                      ".block_until_ready) inside the Pallas kernel "
-                      "layer (kernels/) outside the sanctioned "
-                      "allowlist — kernels are pure device code "
-                      "traced into other programs",
     "RL-OBS-PASSIVE": "the passive telemetry module (obs/telemetry.py) "
                       "touches the device (jax/jnp/host syncs/"
                       "finalize_observation), drives query execution, "

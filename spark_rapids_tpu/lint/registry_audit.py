@@ -342,10 +342,9 @@ def _audit_conf_referenced(diags: List[Diagnostic], root: str) -> None:
         for n in names:
             if n.endswith(".py"):
                 sources.append(os.path.join(dirpath, n))
-    for extra in ("bench.py", "scale_test.py"):
-        p = os.path.join(root, extra)
-        if os.path.exists(p):
-            sources.append(p)
+    p = os.path.join(root, "scale_test.py")
+    if os.path.exists(p):
+        sources.append(p)
     text = "\n".join(open(p, encoding="utf-8").read() for p in sources)
 
     #: key -> ConfEntry variable names bound in any engine module

@@ -16,10 +16,9 @@ are unaffected by the package split.
 
 Rules (RL-*): RL-HOST-SYNC, RL-JNP-SCOPE, RL-CONF-KEY,
 RL-NONDETERMINISM, RL-DEAD-LAMBDA, RL-FAULT-POINT, RL-THREAD-SHARED,
-RL-MESH-HOST, RL-WRITE-COMMIT, RL-KERNEL-HOST, RL-OBS-PASSIVE,
-RL-MEM-ACCOUNT, RL-MV-EPOCH, and the concurrency contract
-(RL-LOCK-DECL, RL-LOCK-ORDER, RL-LOCK-EFFECT — see
-``lint/concurrency.py``).
+RL-MESH-HOST, RL-WRITE-COMMIT, RL-OBS-PASSIVE, RL-MEM-ACCOUNT,
+RL-MV-EPOCH, and the concurrency contract (RL-LOCK-DECL,
+RL-LOCK-ORDER, RL-LOCK-EFFECT — see ``lint/concurrency.py``).
 """
 
 from __future__ import annotations
@@ -40,10 +39,9 @@ from spark_rapids_tpu.lint.rules.conf_keys import (  # noqa: F401
 from spark_rapids_tpu.lint.rules.determinism import (  # noqa: F401
     _SEEDED_RANDOM_OK, _check_dead_lambdas, _check_nondeterminism)
 from spark_rapids_tpu.lint.rules.device_residency import (  # noqa: F401
-    _DEVICE_DIRS, _DEVICE_FILES, _KERNEL_HOST_ALLOWLIST,
-    _MEM_ACCOUNT_ALLOWLIST, _MESH_HOST_ALLOWLIST, _check_host_sync,
-    _check_jnp_scope, _check_kernel_host, _check_mem_account,
-    _check_mesh_host)
+    _DEVICE_DIRS, _DEVICE_FILES, _MEM_ACCOUNT_ALLOWLIST,
+    _MESH_HOST_ALLOWLIST, _check_host_sync, _check_jnp_scope,
+    _check_mem_account, _check_mesh_host)
 from spark_rapids_tpu.lint.rules.fault_points import (  # noqa: F401
     _check_fault_registry, _check_fault_sites, _is_fault_point_call)
 from spark_rapids_tpu.lint.rules.io_write import (  # noqa: F401

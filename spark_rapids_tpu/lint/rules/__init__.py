@@ -84,9 +84,6 @@ REGISTRY: Tuple[LintRule, ...] = (
     LintRule(("RL-MESH-HOST",),
              lambda ctx, rel, tree, diags:
              device_residency._check_mesh_host(rel, tree, diags)),
-    LintRule(("RL-KERNEL-HOST",),
-             lambda ctx, rel, tree, diags:
-             device_residency._check_kernel_host(rel, tree, diags)),
     LintRule(("RL-OBS-PASSIVE",),
              lambda ctx, rel, tree, diags:
              obs_passive._check_obs_passive(rel, tree, diags)),

@@ -29,10 +29,9 @@ def _iter_source_files(root: str):
         for f in sorted(filenames):
             if f.endswith(".py"):
                 yield os.path.join(dirpath, f)
-    for f in ("bench.py", "scale_test.py"):
-        p = os.path.join(root, f)
-        if os.path.exists(p):
-            yield p
+    p = os.path.join(root, "scale_test.py")
+    if os.path.exists(p):
+        yield p
 
 
 def _rel(root: str, path: str) -> str:
@@ -52,10 +51,8 @@ def _attr_chain(node: ast.AST) -> str:
 
 
 def _host_sync_call(chain: str) -> bool:
-    """THE host-synchronization call set shared by the device-residency
-    rules (RL-MESH-HOST and RL-KERNEL-HOST walk different scopes but
-    must agree on what a host sync IS — a spelling added to one and not
-    the other would silently diverge)."""
+    """THE host-synchronization call set of the device-residency
+    rules: what a host sync IS, spelled once."""
     return ((chain.endswith("device_get") and chain.startswith(
                 ("jax.", "jax")))
             or chain == "host_fetch" or chain.endswith(".host_fetch")

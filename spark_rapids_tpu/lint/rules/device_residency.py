@@ -10,10 +10,6 @@ sanctioned sites.
   BETWEEN exchanges: inside ``parallel/`` and the shard-dispatch
   placement layer, host materialization may appear only at sanctioned
   gather points (``_MESH_HOST_ALLOWLIST``, each entry justified).
-* RL-KERNEL-HOST — the Pallas kernel layer (``kernels/``) is pure
-  device code that executes INSIDE other traces: any numpy
-  materialization or host synchronization there would stall the trace
-  or smuggle device data to the host mid-kernel.
 * RL-MEM-ACCOUNT — device landings in execs//ops/ must route through
   arbiter-accounted paths (``DeviceTable.from_host``); a raw
   ``jax.device_put`` lands bytes the MemoryArbiter never sees.
@@ -32,7 +28,7 @@ from spark_rapids_tpu.lint.rules.common import (_attr_chain,
 #: directories (under spark_rapids_tpu/) whose modules are device layers
 #: and may import jax.numpy
 _DEVICE_DIRS = ("execs", "ops", "columnar", "parallel", "runtime",
-                "shuffle", "shims", "models", "kernels")
+                "shuffle", "shims", "models")
 #: top-level device-layer files
 _DEVICE_FILES = ("dispatch.py", "udf.py")
 
@@ -85,7 +81,7 @@ def _check_jnp_scope(rel: str, tree: ast.AST, diags: List[Diagnostic]):
     parts = rel.split("/")
     allowed = False
     if parts[0] != "spark_rapids_tpu":
-        allowed = False  # bench.py / scale_test.py are host drivers
+        allowed = False  # scale_test.py is a host driver
     elif len(parts) == 2:
         allowed = parts[1] in _DEVICE_FILES
     else:
@@ -177,56 +173,6 @@ def _check_mesh_host(rel: str, tree: ast.AST, diags: List[Diagnostic]):
         elif isinstance(node, ast.Attribute) \
                 and node.attr == "addressable_shards":
             flag(node, ".addressable_shards read", func)
-        for child in ast.iter_child_nodes(node):
-            walk(child, func)
-
-    walk(tree, None)
-
-
-#: sanctioned host-side operations inside kernels/:
-#: "<rel>:<qualified function>" -> justification. The hook for new
-#: exceptions — add an entry HERE with a reason, never a bare
-#: suppression.
-_KERNEL_HOST_ALLOWLIST = {}
-
-
-def _check_kernel_host(rel: str, tree: ast.AST, diags: List[Diagnostic]):
-    """RL-KERNEL-HOST: kernels/ modules run inside other traces — no
-    numpy at all (materialization happens the moment an np.* call sees
-    a device array) and no host syncs. The static guard for 'a Pallas
-    primitive never stalls the program that embeds it'."""
-    if not rel.startswith("spark_rapids_tpu/kernels/"):
-        return
-
-    def flag(node, what: str, func: Optional[str]):
-        if f"{rel}:{func}" in _KERNEL_HOST_ALLOWLIST:
-            return
-        diags.append(make(
-            "RL-KERNEL-HOST", f"{rel}:{node.lineno}",
-            f"{what} in the Pallas kernel layer"
-            + (f" (function {func!r})" if func else " (module level)")
-            + " — kernels/ is pure device code traced into other "
-            "programs; keep host work at the dispatch sites or "
-            "allowlist the function in _KERNEL_HOST_ALLOWLIST with a "
-            "justification"))
-
-    def walk(node, func: Optional[str]):
-        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
-                             ast.ClassDef)):
-            func = f"{func}.{node.name}" if func else node.name
-        if isinstance(node, (ast.Import, ast.ImportFrom)):
-            mod = getattr(node, "module", None)
-            names = [a.name for a in node.names]
-            if mod == "numpy" or "numpy" in names \
-                    or any(n.startswith("numpy.") for n in names) \
-                    or (mod or "").startswith("numpy."):
-                flag(node, "numpy import", func)
-        elif isinstance(node, ast.Call):
-            chain = _attr_chain(node.func)
-            if chain.startswith(("np.", "numpy.")):
-                flag(node, f"{chain}()", func)
-            elif _host_sync_call(chain):
-                flag(node, f"{chain}()", func)
         for child in ast.iter_child_nodes(node):
             walk(child, func)
 

@@ -1,6 +1,6 @@
 """TPC-H-style flagship pipeline (q1: scan -> filter -> project -> group-by
 aggregate) — the reference's headline workload shape (pricing summary
-report). Used by bench.py and __graft_entry__.py.
+report). Used by __graft_entry__.py.
 
 Two forms:
 * ``q1_dataframe``  — through the full engine (plan -> overrides -> execs);
@@ -77,8 +77,8 @@ def q1_dataframe(session, table: HostTable, num_batches: int = 1):
     )
 
 
-#: q1 as SQL text (bench.py --sql): lowers onto the same plan shape as
-#: q1_dataframe (Sort over Aggregate over Project over Filter)
+#: q1 as SQL text: lowers onto the same plan shape as q1_dataframe
+#: (Sort over Aggregate over Project over Filter)
 Q1_SQL = """
 SELECT l_returnflag, l_linestatus,
        SUM(l_quantity) AS sum_qty,
@@ -158,9 +158,9 @@ def q1_kernel_example_args(num_rows: int = 1 << 16, seed: int = 0):
 
 
 def q1_pandas(table: HostTable):
-    """CPU baseline via pandas (the "Spark CPU" proxy for bench.py).
-    Built from the raw internal arrays (dates stay int days) so the baseline
-    measures compute, not python-object conversion."""
+    """CPU baseline via pandas (the "Spark CPU" proxy). Built from the
+    raw internal arrays (dates stay int days) so the baseline measures
+    compute, not python-object conversion."""
     import pandas as pd
     df = pd.DataFrame({n: c.data for n, c in zip(table.names, table.columns)})
     df = df[df.l_shipdate <= Q1_CUTOFF_DAYS].copy()
@@ -242,8 +242,8 @@ def q3_dataframe(session, cust, orders, lineitem, segment: str = "BUILDING"):
             .limit(10))
 
 
-#: q3 as SQL text (bench.py --sql); nested selects mirror the
-#: filter/with_column/join chain of q3_dataframe
+#: q3 as SQL text; nested selects mirror the filter/with_column/join
+#: chain of q3_dataframe
 Q3_SQL = """
 SELECT l_orderkey, SUM(volume) AS revenue, COUNT(*) AS n FROM (
     SELECT l_orderkey, o_orderdate,

@@ -20,8 +20,8 @@ analyzer — SURVEY.md §5):
 * :mod:`spark_rapids_tpu.obs.telemetry` — the BETWEEN-queries layer:
   a passive background telemetry ring (per-scope metric deltas +
   topology at a conf-driven interval) and the flight recorder that
-  dumps bounded incident bundles on every ladder action, quarantine
-  strike, and kernel demotion (`tools incident` renders them).
+  dumps bounded incident bundles on every ladder action and
+  quarantine strike (`tools incident` renders them).
 """
 
 from spark_rapids_tpu.obs.metrics import (  # noqa: F401

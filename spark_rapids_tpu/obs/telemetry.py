@@ -19,8 +19,8 @@ This module is the between-queries half of observability:
   snapshot surfaces every subsystem already exposes, each of which
   bounds its own lock hold to a dict copy).
 * **Flight recorder** (:func:`record_incident`) — any degradation-
-  ladder action (mesh / host / whole-backend), quarantine strike, or
-  Pallas kernel demotion dumps one bounded INCIDENT BUNDLE (JSON) to
+  ladder action (mesh / host / whole-backend) or quarantine strike
+  dumps one bounded INCIDENT BUNDLE (JSON) to
   ``spark.rapids.obs.flightRecorder.dir``: the trigger (kind, ladder
   action, error, the fault point parsed from an injected error),
   ladder + fault-point state, health/mesh/cluster topology, the
@@ -73,9 +73,9 @@ FLIGHT_RECORDER_ENABLED = bool_conf(
     "spark.rapids.obs.flightRecorder.enabled", True,
     "Dump a bounded incident bundle (trigger, ladder + fault-point "
     "state, topology, telemetry tail, recent event summaries, live "
-    "query table) on every degradation-ladder action, quarantine "
-    "strike, and kernel demotion — the black box `python -m "
-    "spark_rapids_tpu.tools incident` renders. Best-effort: recording "
+    "query table) on every degradation-ladder action and quarantine "
+    "strike — the black box `python -m spark_rapids_tpu.tools "
+    "incident` renders. Best-effort: recording "
     "can never fail or slow the recovery it documents.")
 
 FLIGHT_RECORDER_DIR = str_conf(
@@ -273,8 +273,8 @@ def register_service(service) -> None:
         _SERVICES.add(service)
 
 
-#: process defaults for conf-less trigger sites (quarantine strikes,
-#: kernel demotions), refreshed by TELEMETRY.configure
+#: process defaults for conf-less trigger sites (quarantine strikes),
+#: refreshed by TELEMETRY.configure
 _FR_LOCK = ordered_lock("obs.flightrec")
 _FR_STATE = {
     "enabled": bool(FLIGHT_RECORDER_ENABLED.default),
@@ -292,7 +292,7 @@ _FAULT_POINT_RE = re.compile(r"\bat ([a-z][a-z0-9_]*(?:\.[a-z0-9_]+)+)")
 #: "one bundle per ladder action" by (seq, faultDomain) instead of
 #: timestamp windows, so the attribution must be total: anything not
 #: claimed by a hardware/memory/stream prefix belongs to the service
-#: plane (backend ladder, quarantine, kernel demotion).
+#: plane (backend ladder, quarantine).
 _FAULT_DOMAIN_PREFIXES = (
     ("host.", "host"),
     ("mesh.", "mesh"),
@@ -419,10 +419,7 @@ def record_incident(kind: str, action: str, reason: str,
             "cluster": CLUSTER.health_snapshot(),
             "memory": _memory_snapshot(),
             "quarantine": QUARANTINE.snapshot(),
-            # exec circuit-breaker + Pallas kernel demotions in one
-            # map, the event record's convention (keys 'pallas:<name>')
-            "demotions": {**CIRCUIT_BREAKER.demoted_ops(),
-                          **_kernel_demotions()},
+            "demotions": CIRCUIT_BREAKER.demoted_ops(),
             "recovery": RECOVERY.snapshot(),
             "faultFires": FAULTS.counters(),
             "scopes": scopes_snapshot(),
@@ -472,11 +469,6 @@ def record_incident_async(kind: str, action: str, reason: str,
 def _recent_event_summaries() -> List[dict]:
     from spark_rapids_tpu.obs.events import recent_records
     return recent_records()
-
-
-def _kernel_demotions() -> Dict[str, str]:
-    from spark_rapids_tpu import kernels
-    return kernels.demoted_ops()
 
 
 def _memory_snapshot() -> dict:

@@ -19,11 +19,10 @@ format"): about 48 mantissa bits (1+2^-52 and 2^53-1 survive; 1/3 and
 
 Before this module the split/recombine recipes were hand-rolled in
 three places (ops/scatter32.py, ops/segsum.py, segment_minmax_64) and
-had started to drift; now kernels/ (the Pallas layer), the HLO scatter/
-sort/segment paths, and the d2h pack all import the one definition
-here. The numpy staging variant (host-side upload split) remains in
-columnar/column.py stage_upload — it runs on host buffers before any
-device array exists.
+had started to drift; now the scatter/sort/segment paths and the d2h
+pack all import the one definition here. The numpy staging variant
+(host-side upload split) remains in columnar/column.py stage_upload —
+it runs on host buffers before any device array exists.
 """
 
 from __future__ import annotations

@@ -94,28 +94,12 @@ def descending_operands(ops: List[jax.Array]) -> List[jax.Array]:
 
 
 def lex_sort(operands: List[jax.Array], payload: jax.Array) -> List[jax.Array]:
-    """THE engine-wide lexicographic sort dispatch point:
-    ``jax.lax.sort(operands + [payload], num_keys=len(operands))`` with
-    the Pallas multi-column sort kernel substituted when the ``sort``
-    primitive is enabled and the shape qualifies (kernels/sort.py).
-
-    ``payload`` must be a UNIQUE i32 row-index iota (every call site
-    passes ``jnp.arange(capacity)``): lax.sort is stable, and the
-    bitonic kernel recovers exactly the stable order by using the
-    payload as the final tiebreak key — so the two paths are
-    bit-identical. Callers whose jitted kernels embed this choice must
-    fold ``kernels.trace_token()`` into their trace cache keys."""
-    from spark_rapids_tpu import kernels
-
-    def hlo():
-        return jax.lax.sort(list(operands) + [payload],
-                            num_keys=len(operands))
-
-    def kern():
-        from spark_rapids_tpu.kernels import sort as ksort
-        return ksort.sort_with_payload(list(operands), payload)
-
-    return kernels.dispatch("sort", kern, hlo)
+    """THE engine-wide lexicographic sort point: a stable sort of the
+    rows by the operand tuple, the payload carried along. Every call
+    site passes ``jnp.arange(capacity)`` as ``payload``, so the last
+    output is the stable sorting permutation."""
+    return jax.lax.sort(list(operands) + [payload],
+                        num_keys=len(operands))
 
 
 def operands_equal_adjacent(ops: List[jax.Array]) -> jax.Array:

@@ -57,27 +57,11 @@ def scatter_pair(out_len: int, tgt, data, validity, mode: str = "drop"):
 
 
 def compact_pairs(datas, valids, keep, capacity: int):
-    """THE row-compaction dispatch point: compact every column's
-    (data, validity) to the kept-row prefix. Returns ([(data,
-    validity)...], new_n). The HLO path is the classic per-column
-    scatter_pair loop; with the ``compact`` Pallas kernel enabled the
-    whole table compacts through ONE i32 gather-map scatter plus one
-    fused gather kernel (kernels/compact.py) — bit-identical. Callers
-    whose jitted kernels embed this choice fold
-    ``kernels.trace_token()`` into their trace cache keys."""
-    from spark_rapids_tpu import kernels
+    """THE row-compaction point: compact every column's (data,
+    validity) to the kept-row prefix, one scatter_pair a column.
+    Returns ([(data, validity)...], new_n)."""
     keep_i = keep.astype(jnp.int32)
     new_n = jnp.sum(keep_i)
-    pos = jnp.cumsum(keep_i) - 1
-
-    def hlo():
-        tgt = jnp.where(keep, pos, capacity)
-        return [scatter_pair(capacity, tgt, d, v)
-                for d, v in zip(datas, valids)]
-
-    def kern():
-        from spark_rapids_tpu.kernels import compact as kcompact
-        return kcompact.gather_compact(list(datas), list(valids), keep,
-                                       pos, new_n, capacity)
-
-    return kernels.dispatch("compact", kern, hlo), new_n
+    tgt = jnp.where(keep, jnp.cumsum(keep_i) - 1, capacity)
+    return [scatter_pair(capacity, tgt, d, v)
+            for d, v in zip(datas, valids)], new_n

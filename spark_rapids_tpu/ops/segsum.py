@@ -42,9 +42,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# the limb split/recombine recipes live in ops/limbs.py (single source
-# of truth for kernels/ and the HLO paths); re-exported here because
-# half the engine historically imported them from this module
+# the limb split/recombine recipes live in ops/limbs.py (their single
+# source of truth); re-exported here because half the engine
+# historically imported them from this module
 from spark_rapids_tpu.ops.limbs import (  # noqa: F401
     combine_f64,
     combine_i64,
@@ -193,16 +193,8 @@ def batched_segment_sum_f64(cols, gid, num_segments: int, capacity: int,
 
     with jax.named_scope("block_partials"):
         if takes_contraction(num_segments, capacity):
-            def kern_parts():
-                from spark_rapids_tpu.kernels import segreduce as kseg
-                return kseg.onehot_partials(jnp.stack(streams, axis=1), gid,
-                                            num_segments, nb, block)
-
-            from spark_rapids_tpu import kernels
-            parts = kernels.dispatch(
-                "segreduce", kern_parts,
-                lambda: _onehot_block_partials(streams, gid, num_segments,
-                                               nb, block))
+            parts = _onehot_block_partials(streams, gid, num_segments,
+                                           nb, block)
         else:
             blk = jnp.arange(capacity, dtype=jnp.int32) // block
             ids = blk * num_segments + gid
@@ -345,25 +337,14 @@ def segment_minmax_64(is_min: bool, sd, sv, gid, num_segments: int):
     red = jax.ops.segment_min if is_min else jax.ops.segment_max
 
     def _limb_minmax(hi, lo, use, hi_ident, lo_ident):
-        """(per-segment hi winner, lo tiebreak) — the Pallas fused
-        two-pass kernel when enabled, else the two HLO segment
-        reductions; bit-identical either way (min/max reductions are
-        exactly associative)."""
-        def hlo():
-            mhi = red(jnp.where(use, hi, hi_ident), gid,
-                      num_segments=num_segments)
-            cand = use & (hi == mhi[gid])
-            mlo = red(jnp.where(cand, lo, lo_ident), gid,
-                      num_segments=num_segments)
-            return mhi, mlo
-
-        def kern():
-            from spark_rapids_tpu.kernels import segreduce as kseg
-            return kseg.fused_minmax(is_min, hi, lo, use, gid,
-                                     num_segments, hi_ident, lo_ident)
-
-        from spark_rapids_tpu import kernels
-        return kernels.dispatch("segreduce", kern, hlo)
+        """(per-segment hi winner, lo tiebreak among the rows that
+        hold it): two native 32-bit segment reductions."""
+        mhi = red(jnp.where(use, hi, hi_ident), gid,
+                  num_segments=num_segments)
+        cand = use & (hi == mhi[gid])
+        mlo = red(jnp.where(cand, lo, lo_ident), gid,
+                  num_segments=num_segments)
+        return mhi, mlo
 
     if sd.dtype == jnp.float64:
         isnan = jnp.isnan(sd) & sv
@@ -371,10 +352,6 @@ def segment_minmax_64(is_min: bool, sd, sv, gid, num_segments: int):
         hi, lo = split_f64_hi_lo(sd)
 
         def fast(_):
-            # numpy scalars, not jnp ones: the Pallas kernel closes over
-            # the identities — a captured jax.Array is rejected, and a
-            # weakly-typed Python scalar would enter the kernel as a
-            # 64-bit constant Mosaic cannot narrow
             ident = np.float32(np.inf if is_min else -np.inf)
             mhi, mlo = _limb_minmax(hi, lo, use, ident, ident)
             return combine_f64(mhi, mlo)

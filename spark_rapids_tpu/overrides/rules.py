@@ -1021,12 +1021,6 @@ class PlanMeta:
                         f"{snap['shape']}-device surviving mesh "
                         f"(excluded device ids "
                         f"{snap['excludedDeviceIds']}): {degraded}")
-            # Pallas kernel demotions (ROOT note, advisory — the op
-            # still runs on device, on its HLO path): surfaced in
-            # explain() exactly like the ICI/mesh demotion reasons
-            from spark_rapids_tpu import kernels as _K
-            for _reason in sorted(_K.demoted_ops().values()):
-                self.notes.append(_reason)
         demoted = CIRCUIT_BREAKER.demotion_reason(type(self.node).__name__)
         if rule is None:
             self.reasons.append(f"exec {self.node.name} is not supported on TPU")

@@ -409,12 +409,6 @@ def fingerprint(plan, conf, *, strip_literals: bool = False,
     # re-landed on survivors) must not serve the full-strength topology
     from spark_rapids_tpu.runtime.cluster import CLUSTER
     h.update(CLUSTER.identity_token().encode())
-    # Pallas kernel demotions are runtime state the conf cannot see
-    # (the kernels.* conf keys fold in above): a cached tree traced
-    # with a kernel embedded must never serve a query after that
-    # primitive demoted to HLO, and vice versa
-    from spark_rapids_tpu import kernels
-    h.update(kernels.demotion_token().encode())
     return h.hexdigest()
 
 

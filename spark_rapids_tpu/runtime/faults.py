@@ -125,23 +125,6 @@ FAULT_POINTS: Dict[str, tuple] = {
         "spark_rapids_tpu/dispatch.py",
         "before each jitted kernel dispatch; device_lost simulates a "
         "fatal PJRT client loss (health-monitor recovery path)"),
-    "kernels.sort": (
-        "spark_rapids_tpu/kernels/sort.py",
-        "at the Pallas multi-column sort's trace-time entry; a crash "
-        "here demotes the 'sort' primitive to the HLO lax.sort path"),
-    "kernels.segreduce": (
-        "spark_rapids_tpu/kernels/segreduce.py",
-        "at the Pallas segmented-reduction entries (fused two-limb "
-        "min/max, one-hot split-sum partials); a crash demotes "
-        "'segreduce' to the HLO scatter/einsum paths"),
-    "kernels.hashprobe": (
-        "spark_rapids_tpu/kernels/hashprobe.py",
-        "at the Pallas hash-probe entry; a crash demotes 'hashprobe' "
-        "to the sort-based dense-rank probe"),
-    "kernels.compact": (
-        "spark_rapids_tpu/kernels/compact.py",
-        "at the Pallas row-compaction entry; a crash demotes "
-        "'compact' to the per-column scatter_pair path"),
     "dispatch.wedge": (
         "spark_rapids_tpu/dispatch.py",
         "before each jitted kernel dispatch; wedge stalls INSIDE the "

@@ -536,16 +536,12 @@ class DeviceHealthMonitor:
         cached scan images ARE device arrays. Today (pre-PR) these
         would all be served stale after a reinit."""
         from spark_rapids_tpu.columnar.table import evict_device_caches
-        from spark_rapids_tpu.dispatch import (
-            clear_device_constants,
-            clear_pallas_programs,
-        )
+        from spark_rapids_tpu.dispatch import clear_device_constants
         from spark_rapids_tpu.ops.expr import clear_kernel_caches
         from spark_rapids_tpu.parallel.exchange import clear_mesh_caches
         from spark_rapids_tpu.plan.executable_cache import EXEC_CACHE
         EXEC_CACHE.invalidate_all()
         clear_kernel_caches()
-        clear_pallas_programs()
         clear_device_constants()
         evict_device_caches()
         # mesh-exchange caches key on device IDS, which survive a reinit

@@ -108,17 +108,12 @@ class PlacementLayer:
             sem = TpuSemaphore.initialize(conf.concurrent_tpu_tasks)
 
         self.apply_tuning_confs()
-        from spark_rapids_tpu import kernels as K
         from spark_rapids_tpu.conf import ANSI_ENABLED
         from spark_rapids_tpu.dispatch import ANSI_MODE
         tok_m = MASKED_ENABLED.set(bool(conf.get_entry(MASKED_BATCHES)))
         tok_d = DIRECT_TABLE_MULT.set(
             conf.get_entry(JOIN_DIRECT_TABLE_MULT))
         tok_a = ANSI_MODE.set(bool(conf.get_entry(ANSI_ENABLED)))
-        # Pallas kernel enablement rides a contextvar like the masked/
-        # direct-join knobs: ops and execs hold no conf handle, and the
-        # resolved set folds into their trace keys (kernels.trace_token)
-        tok_k = K.KERNELS_ENABLED.set(K.resolve_enabled(conf))
 
         def drain_once():
             if sem is None:
@@ -161,7 +156,6 @@ class PlacementLayer:
             MASKED_ENABLED.reset(tok_m)
             DIRECT_TABLE_MULT.reset(tok_d)
             ANSI_MODE.reset(tok_a)
-            K.KERNELS_ENABLED.reset(tok_k)
 
     def resolve_pending(self, executable, batches) -> List:
         """Complete enqueued async downloads — the device semaphore is

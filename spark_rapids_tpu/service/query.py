@@ -228,12 +228,9 @@ class QueryHandle:
         self.scope.cancel()
         svc = self._service
         if svc is not None and svc._remove_queued(self):
-            done = self._transition(
-                QueryState.CANCELLED,
+            return svc._terminal(
+                self, QueryState.CANCELLED, "cancelled",
                 error=QueryCancelledError("cancelled while queued"))
-            if done:
-                svc._count_event("cancelled")
-            return done
         with self._lock:
             return self._state not in QueryState.TERMINAL
 

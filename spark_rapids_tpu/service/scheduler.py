@@ -616,18 +616,15 @@ class QueryService:
                 if h.scope.cancelled.is_set():
                     dq.remove(h)
                     self._queued_per_pool[pool] -= 1
-                    if h._transition(QueryState.CANCELLED,
-                                     error=QueryCancelledError(
-                                         "cancelled while queued")):
-                        self.counters["cancelled"] += 1
+                    self._terminal(h, QueryState.CANCELLED, "cancelled",
+                                   error=QueryCancelledError(
+                                       "cancelled while queued"))
                 elif h.scope.expired():
                     dq.remove(h)
                     self._queued_per_pool[pool] -= 1
-                    if h._transition(QueryState.TIMED_OUT,
-                                     error=QueryTimeoutError(
-                                         "deadline expired while "
-                                         "queued")):
-                        self.counters["timed_out"] += 1
+                    self._terminal(h, QueryState.TIMED_OUT, "timed_out",
+                                   error=QueryTimeoutError(
+                                       "deadline expired while queued"))
             self._drop_if_empty_locked((pool, _tenant))
 
     def _memory_gate_open_locked(self) -> bool:
@@ -672,16 +669,21 @@ class QueryService:
         self._drop_if_empty_locked((pool, tenant))
         return handle
 
-    def _count_event(self, name: str, n: int = 1) -> None:
-        """All lifecycle counter bumps funnel here: counters are read
-        under the condition lock (stats, retry-after), so every writer
-        must hold it too or concurrent workers lose increments. A
-        completed query also pays down the DEGRADED latch — the
-        service proved it can finish work again."""
+    def _terminal(self, handle: QueryHandle, state: str, counter: str,
+                  *, error=None, result=None) -> bool:
+        """A terminal transition and its lifecycle counter as ONE step
+        under the scheduler lock: the transition wakes the handle's
+        waiters, and a stats() one of them takes next must already
+        count it (counters are read under this lock too). A completed
+        query also pays down the DEGRADED latch — the service proved it
+        can finish work again. False when the transition lost a race."""
         with self._cond:
-            self.counters[name] += n
-            if name == "finished" and self._degraded_pending > 0:
+            if not handle._transition(state, error=error, result=result):
+                return False
+            self.counters[counter] += 1
+            if counter == "finished" and self._degraded_pending > 0:
                 self._degraded_pending -= 1
+        return True
 
     def _charge_locked(self, handle: QueryHandle, elapsed_s: float):
         w_t = self.tenant_weights.get(handle.tenant, 1.0)
@@ -807,8 +809,8 @@ class QueryService:
                         f"spent after {handle.requeues} requeues")
             self._cond.notify_all()
         if fail_with is not None:
-            if handle._transition(QueryState.FAILED, error=fail_with):
-                self._count_event("failed")
+            self._terminal(handle, QueryState.FAILED, "failed",
+                           error=fail_with)
 
     def _on_device_lost(self, handle: QueryHandle,
                         exc: DeviceLostError) -> None:
@@ -842,8 +844,7 @@ class QueryService:
                     f"{len(QUARANTINE.history(handle.template_fp))} "
                     "time(s)",
                     strikes=QUARANTINE.history(handle.template_fp))
-        if handle._transition(QueryState.FAILED, error=fail_with):
-            self._count_event("failed")
+        self._terminal(handle, QueryState.FAILED, "failed", error=fail_with)
 
     def _worker_loop(self, w: "_Worker"):
         while True:
@@ -929,9 +930,8 @@ class QueryService:
                 handle.cache_hit = True
                 self._emit_cache_hit_record(
                     handle, cached, time.monotonic() - t0)
-                if handle._transition(QueryState.FINISHED,
-                                      result=cached.table):
-                    self._count_event("finished")
+                if self._terminal(handle, QueryState.FINISHED, "finished",
+                                  result=cached.table):
                     self._note_finished(handle)
                 return
             with cancel_scope(handle.scope):
@@ -952,23 +952,22 @@ class QueryService:
             if self.result_cache is not None:
                 self.result_cache.put(fp, table, handle.event_record,
                                       epochs=epochs)
-            if handle._transition(QueryState.FINISHED, result=table):
-                self._count_event("finished")
+            if self._terminal(handle, QueryState.FINISHED, "finished",
+                              result=table):
                 self._note_finished(handle)
         except QueryCancelledError as exc:
-            if handle._transition(QueryState.CANCELLED, error=exc):
-                self._count_event("cancelled")
+            self._terminal(handle, QueryState.CANCELLED, "cancelled",
+                           error=exc)
         except QueryTimeoutError as exc:
-            if handle._transition(QueryState.TIMED_OUT, error=exc):
-                self._count_event("timed_out")
+            self._terminal(handle, QueryState.TIMED_OUT, "timed_out",
+                           error=exc)
         except DeviceLostError as exc:
             # retryable by contract: the backend already recovered
             # (runtime/health.py) — requeue against it, or fail typed
             # once the replay/quarantine budget is spent
             self._on_device_lost(handle, exc)
         except BaseException as exc:
-            if handle._transition(QueryState.FAILED, error=exc):
-                self._count_event("failed")
+            self._terminal(handle, QueryState.FAILED, "failed", error=exc)
         finally:
             with self._cond:
                 self._charge_locked(handle, time.monotonic() - t0)
@@ -1062,10 +1061,9 @@ class QueryService:
                 while dq:
                     h = dq.popleft()
                     self._queued_per_pool[pool] -= 1
-                    if h._transition(QueryState.CANCELLED,
-                                     error=QueryCancelledError(
-                                         "service shut down")):
-                        self.counters["cancelled"] += 1
+                    self._terminal(h, QueryState.CANCELLED, "cancelled",
+                                   error=QueryCancelledError(
+                                       "service shut down"))
             self._cond.notify_all()
             workers = list(self._workers)
         if wait:

@@ -15,16 +15,10 @@ from spark_rapids_tpu.plan import DataFrame, from_host_table
 from spark_rapids_tpu.plan import nodes as P
 
 
-def _kernel_demotions() -> Dict[str, str]:
-    """Pallas primitive->HLO demotions for the event record (lazy
-    import: the session module must stay importable standalone)."""
-    from spark_rapids_tpu import kernels
-    return kernels.demoted_ops()
-
-
 def _mem_budget_peak() -> int:
     """The memory arbiter's peak accounted device bytes (event-log
-    schema v10 budgetPeak field; lazy import like _kernel_demotions)."""
+    schema v10 budgetPeak field; lazy import: the session module must
+    stay importable standalone)."""
     from spark_rapids_tpu.runtime.memory import MEMORY
     return int(MEMORY.peak_bytes())
 
@@ -464,11 +458,7 @@ class TpuSession:
                 fault_fires={k: v - before_fires.get(k, 0)
                              for k, v in after_fires.items()
                              if v - before_fires.get(k, 0)},
-                # exec circuit-breaker demotions + Pallas kernel->HLO
-                # demotions in one map (keys 'pallas:<primitive>'), so the
-                # offline tools see both without a schema change
-                demotions={**CIRCUIT_BREAKER.demoted_ops(),
-                           **_kernel_demotions()},
+                demotions=CIRCUIT_BREAKER.demoted_ops(),
                 spans_summary=summarize_spans(
                     spans, _threading.get_ident(), wall_s),
                 fault_replays=int(q.fault_replays or 0),

@@ -26,8 +26,8 @@ from spark_rapids_tpu.tools.compare import (  # noqa: F401
 
 
 def require_tpu_backend():
-    """THE require-a-TPU gate shared by chip_smoke.py, bench.py and
-    scale_test.py: resolve the JAX backend (initializes it — call only
+    """THE require-a-TPU gate shared by chip_smoke.py and scale_test.py:
+    resolve the JAX backend (initializes it — call only
     after any virtual-device/mesh environment setup) and exit 2 with a
     machine-readable error unless the platform is literally 'tpu' — any
     other name, not only 'cpu', is a run that meant to hit the chip and

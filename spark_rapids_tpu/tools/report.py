@@ -259,22 +259,12 @@ def build_profile(records: Iterable[dict], top_n: int = 10,
     low_coverage = [q["query"] for q in queries
                     if q["attribution"]["coverage"] < coverage_floor]
     cold = [q["query"] for q in queries if q["compileMs"] > 0]
-    def _compile_scope(q, key):
-        return int((q["scopes"].get("compile") or {}).get(key, 0))
-
     compile_summary = {
         "totalCompileMs": round(sum(q["compileMs"] for q in queries), 3),
         "coldQueries": cold,
         "executableCacheHits": sum(
             1 for q in queries if q["executableCacheHit"]),
         "padWasteRows": sum(q["padWasteRows"] for q in queries),
-        # which path each primitive resolved to at trace time
-        # (kernels/): a demoted Pallas kernel is visible offline as
-        # hloFallbacks > 0 plus a 'pallas:<name>' demotion entry
-        "pallasKernels": sum(
-            _compile_scope(q, "pallasKernels") for q in queries),
-        "hloFallbacks": sum(
-            _compile_scope(q, "hloFallbacks") for q in queries),
     }
     # mesh-native execution (schema v6): which queries ran on the mesh,
     # how much payload rode ICI collectives, the worst per-shard skew
@@ -422,12 +412,6 @@ def render_profile(report: dict) -> str:
         f"{len(c['coldQueries'])} cold queries | executable-cache hits "
         f"{c['executableCacheHits']}/{report['queryCount']} | pad waste "
         f"{c['padWasteRows']} rows")
-    if c.get("pallasKernels") or c.get("hloFallbacks"):
-        lines.append(
-            f"Pallas kernels: {c['pallasKernels']} primitive sites on "
-            f"the kernel path | {c['hloFallbacks']} HLO fallbacks "
-            "(disabled / ineligible shape / demoted — demotions show "
-            "per query below)")
     me = report["mesh"]
     if me["meshQueries"]:
         lines.append(

@@ -1,5 +1,5 @@
 """Bring-up contracts (ISSUE 21): a compile cache the driver can place,
-no quiet CPU on the chip path, kernels that stand down in the open.
+no quiet CPU on the chip path.
 
 Everything here runs on the CPU backend; what only a chip can show is
 chip_smoke.py's job."""
@@ -15,7 +15,6 @@ import pytest
 import jax
 
 import spark_rapids_tpu as st
-from spark_rapids_tpu import kernels
 from spark_rapids_tpu.conf import RapidsConf
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -103,7 +102,7 @@ def test_chip_smoke_last_line_is_exactly_ok_and_device(monkeypatch, capsys):
     import chip_smoke as cs
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     child = {"device": device, "data": {}, "hbm": {}, "phases": ["device"],
-             "seconds": {}, "child_wall_s": 1.0, "kernels": {},
+             "seconds": {}, "child_wall_s": 1.0,
              "demotions": {}, "f64_on_device": {}, "native_available": True,
              "persistent_cache": {"dir": "d", "hits": 1, "misses": 0}}
     monkeypatch.setattr(cs, "run_child", lambda *a: dict(child))
@@ -200,78 +199,3 @@ def test_device_manager_discovery_and_selection():
         {"spark.rapids.tpu.deviceOrdinal": 4096}))
     with pytest.raises(ColumnarProcessingError):
         bad.initialize()
-
-
-# -- kernels that compile, or stand down in the open -------------------------
-
-@pytest.fixture
-def on_tpu(monkeypatch):
-    """resolve_enabled's view of a v5e: 'tpu' backend, 128 MiB of VMEM."""
-    from jax.experimental.pallas import tpu as pltpu
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(
-        pltpu, "get_tpu_info",
-        lambda: type("Info", (), {"vmem_capacity_bytes": 128 << 20}))
-
-
-def test_auto_on_the_tpu_backend_follows_the_table(on_tpu):
-    cfg = kernels.resolve_enabled(RapidsConf())
-    assert cfg.enabled == {"segreduce"}
-    assert cfg.declined == {"segreduce.onehot_partials"}
-    for name, words in kernels.TPU_AUTO_OFF.items():
-        assert name.split(".")[0] in kernels.PRIMITIVES and words
-    # an explicit =true still forces a primitive (and all its programs) on
-    forced = kernels.resolve_enabled(RapidsConf({
-        "spark.rapids.tpu.kernels.sort.enabled": "true",
-        "spark.rapids.tpu.kernels.segreduce.enabled": "true"}))
-    assert forced.enabled == {"sort", "segreduce"} and not forced.declined
-
-
-def test_auto_is_off_on_the_cpu_backend():
-    assert not kernels.resolve_enabled(RapidsConf()).enabled
-
-
-def test_vmem_budget_is_held_to_what_the_device_has(on_tpu):
-    key = "spark.rapids.tpu.kernels.vmemBudgetBytes"
-    assert kernels.resolve_enabled(
-        RapidsConf({key: 1 << 30})).vmem_budget == 128 << 20
-    assert kernels.resolve_enabled(
-        RapidsConf({key: 32 << 20})).vmem_budget == 32 << 20
-
-
-def test_compiler_gets_the_budget_the_eligibility_checks_use(monkeypatch):
-    assert kernels.compiler_params() is None  # interpret mode: no compiler
-    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
-    tok = kernels.KERNELS_ENABLED.set(
-        kernels.KernelsConfig(vmem_budget=48 << 20))
-    try:
-        assert kernels.compiler_params().vmem_limit_bytes == 48 << 20
-    finally:
-        kernels.KERNELS_ENABLED.reset(tok)
-
-
-def test_declined_program_takes_hlo_without_a_demotion():
-    import jax.numpy as jnp
-    import numpy as np
-
-    from spark_rapids_tpu.dispatch import COMPILE_SCOPE
-    from spark_rapids_tpu.ops.segsum import batched_segment_sum_f64
-    kernels.reset()
-    rng = np.random.default_rng(0)
-    n, nseg = 1024, 4
-    gid = jnp.asarray(rng.integers(0, nseg, n), jnp.int32)
-    cols = [jnp.asarray(rng.random(n))]
-    ref = batched_segment_sum_f64(cols, gid, nseg, n, True)
-    tok = kernels.KERNELS_ENABLED.set(kernels.KernelsConfig(
-        enabled=("segreduce",), declined=("segreduce.onehot_partials",)))
-    try:
-        before = dict(COMPILE_SCOPE)
-        got = batched_segment_sum_f64(cols, gid, nseg, n, True)
-    finally:
-        kernels.KERNELS_ENABLED.reset(tok)
-    assert np.array_equal(np.asarray(got), np.asarray(ref))
-    assert COMPILE_SCOPE.get("pallasKernels", 0) == \
-        before.get("pallasKernels", 0)
-    assert COMPILE_SCOPE.get("hloFallbacks", 0) > \
-        before.get("hloFallbacks", 0)
-    assert kernels.demoted_ops() == {}

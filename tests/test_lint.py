@@ -678,51 +678,6 @@ def test_rl_mesh_host():
     assert len(_find(placed, "RL-MESH-HOST")) == 1
 
 
-def test_rl_kernel_host():
-    """RL-KERNEL-HOST: numpy or host syncs inside kernels/ — the
-    static guard for 'a Pallas primitive never stalls the program
-    that embeds it' (ISSUE 11 satellite)."""
-    from spark_rapids_tpu.lint.repo_lint import _check_kernel_host
-    src = (
-        "import jax\n"
-        "import numpy as np\n"                      # numpy import
-        "from spark_rapids_tpu.dispatch import host_fetch\n"
-        "def bad(x):\n"
-        "    a = np.asarray(x)\n"                   # np materialization
-        "    b = jax.device_get(x)\n"               # raw device fetch
-        "    c = host_fetch(x)\n"                   # sanctioned-elsewhere
-        "    return x.block_until_ready()\n"        # device sync
-    )
-    diags = _run_rl(_check_kernel_host,
-                    "spark_rapids_tpu/kernels/foo.py", src)
-    hits = _find(diags, "RL-KERNEL-HOST")
-    assert len(hits) == 5, [str(d) for d in hits]
-    msgs = " ".join(d.message for d in hits)
-    assert "numpy import" in msgs and "np.asarray" in msgs
-    # jnp and pallas are the kernel layer's whole point — clean
-    ok = ("import jax\nimport jax.numpy as jnp\n"
-          "from jax.experimental import pallas as pl\n"
-          "def k(r):\n    r[:] = jnp.cumsum(r[:])\n")
-    assert _run_rl(_check_kernel_host,
-                   "spark_rapids_tpu/kernels/foo.py", ok) == []
-    # outside kernels/ the rule does not apply (other rules own those)
-    assert _run_rl(_check_kernel_host,
-                   "spark_rapids_tpu/ops/foo.py", src) == []
-    # the allowlist hook keys on rel:qualified-function
-    from spark_rapids_tpu.lint import repo_lint as RL
-    RL._KERNEL_HOST_ALLOWLIST["spark_rapids_tpu/kernels/foo.py:ok_fn"] = \
-        "negative-test probe"
-    try:
-        allowed = _run_rl(
-            _check_kernel_host, "spark_rapids_tpu/kernels/foo.py",
-            "from spark_rapids_tpu.dispatch import host_fetch\n"
-            "def ok_fn(x):\n    return host_fetch(x)\n")
-        assert allowed == []
-    finally:
-        del RL._KERNEL_HOST_ALLOWLIST[
-            "spark_rapids_tpu/kernels/foo.py:ok_fn"]
-
-
 def test_rl_fault_point():
     from spark_rapids_tpu.lint.repo_lint import (
         _check_fault_registry,
@@ -1236,14 +1191,12 @@ def test_lock_witness_chaos_service_scenario():
 
 
 def test_cli_json_smoke(tmp_path):
-    """Satellite: the --json contract in a real subprocess, within the
-    5s budget — a clean all-skip run exits 0, and a tiny synthetic tree
-    with an undeclared lock exits 1 with machine-readable diagnostics."""
+    """Satellite: the --json contract in a real subprocess — a clean
+    all-skip run exits 0, and a tiny synthetic tree with an undeclared
+    lock exits 1 with machine-readable diagnostics."""
     import json
     import subprocess
     import sys
-    import time
-    t0 = time.monotonic()
     clean = subprocess.run(
         [sys.executable, "-m", "spark_rapids_tpu.lint", "--json",
          "--skip-repo", "--skip-registry", "--skip-plans",
@@ -1269,7 +1222,6 @@ def test_cli_json_smoke(tmp_path):
         and d["path"] == "spark_rapids_tpu/runtime/bad.py:2"
         and d["severity"] == "error"
         for d in out["diagnostics"])
-    assert time.monotonic() - t0 < 5.0, "CLI smoke blew the 5s budget"
 
 
 def test_every_rule_has_a_negative_test():

@@ -40,11 +40,7 @@ from tests.asserts import plan_metric_total
 pytestmark = pytest.mark.chaos
 
 
-@pytest.fixture(autouse=True)
-def _clean_process_state():
-    """Every test leaves the process-wide fault/health/arbiter state
-    the way it found it (the file rides tier-1 between other suites)."""
-    yield
+def _reset_process_state():
     from spark_rapids_tpu.runtime.retry import RMM_TPU
     FAULTS.disarm()
     CIRCUIT_BREAKER.reset()
@@ -55,6 +51,18 @@ def _clean_process_state():
     # later suites must get the default catalog back, not a tier
     # pointed at a removed directory
     BufferCatalog.reset()
+
+
+@pytest.fixture(autouse=True)
+def _clean_process_state():
+    """Every test starts from, and leaves, clean process-wide
+    fault/health/arbiter state (the file rides tier-1 between other
+    suites: tables an earlier suite's abandoned workers still hold
+    stay in the arbiter's ledger, and a 160KB budget is then spent
+    before the test's first landing)."""
+    _reset_process_state()
+    yield
+    _reset_process_state()
 
 
 def _mem_scope():

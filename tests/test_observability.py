@@ -294,7 +294,7 @@ _TPU_JIT_SITES = _tpu_jit_sites()
 
 
 def test_every_tpu_jit_site_is_found():
-    assert len(_TPU_JIT_SITES) >= 36
+    assert len(_TPU_JIT_SITES) >= 35
 
 
 @pytest.mark.parametrize("site,name", _TPU_JIT_SITES,
