@@ -29,7 +29,13 @@ Multi-batch inputs STREAM (GpuMergeAggregateIterator analog): each batch
 aggregates to a spillable partial as it arrives, the host running ahead of
 the device by no more input than the memory arbiter's budget has room for
 (_bound_run_ahead), and a merge aggregation + finalize projection combines
-the partials (see _merge_plan)."""
+the partials (see _merge_plan).
+
+A batch of several AGG_SLICE-row slices (what a coalesce under the default
+batchSizeBytes builds) is such a stream INSIDE one program: the fast
+kernel compiled at one slice runs in a loop over the batch's rows and the
+slices' partial tables merge like any other partials (_slices_of,
+_over_slices)."""
 
 from __future__ import annotations
 
@@ -70,6 +76,14 @@ DEVICE_SUPPORTED_AGGS = (agg.Sum, agg.Min, agg.Max, agg.Count, agg.Average,
 #: aggregates needing the SORT-SEGMENT path (contiguous groups / per-group
 #: value order) and a single coalesced input (no streaming merge decomposition)
 SORT_ONLY_AGGS = (agg.CollectList, agg.CollectSet, agg.Percentile)
+
+#: rows of one slice of the fast aggregate. The kernel's elementwise
+#: fusions cost 2 to 3 times as much a row at 2^24 rows as at 2^21, where
+#: an f32[rows] intermediate still stays near the cores between fusions
+#: (PERF.md, PR 30), so a larger batch is walked in slices of this many
+#: rows by the kernel compiled at this capacity. Not an option: what is
+#: observed is the batch's capacity.
+AGG_SLICE = 1 << 21
 
 
 _M32 = 0xFFFFFFFF
@@ -156,6 +170,52 @@ def _dec128_minmax_segments(is_min, sd, sv, gid, nseg, has_any):
     return (data, has_any)
 
 
+def _over_slices(kernel, slices: int, rows: int, gpad: int):
+    """A fast kernel built at ``rows`` rows, run over a batch of
+    ``slices * rows`` rows inside one program: a scan over the slices'
+    first rows, each step the kernel itself over that slice of every
+    column, with the slice's own row count and its part of the live
+    mask. Returns what the kernel returns, at ``slices * gpad`` rows:
+    every slice's partial groups compacted to one prefix, and their
+    count."""
+    from spark_rapids_tpu.ops.scatter32 import compact_pairs
+
+    def sliced(cols, aux, nrows, sizes, strides, bases, live_in):
+        def step(_, start):
+            # read in place: scanning over the columns reshaped to
+            # [slices, rows] copies them first (the chip tiles a 2-D
+            # array otherwise) and ran 3 x slower (PERF.md, PR 30)
+            c, live = jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(a, start, rows),
+                (cols, live_in))
+            return None, kernel(c, aux, jnp.clip(nrows - start, 0, rows),
+                                sizes, strides, bases, live)
+
+        _, (outs, ngroups) = jax.lax.scan(
+            step, None, jnp.arange(slices, dtype=jnp.int32) * rows)
+        # a slice's groups are a prefix of its gpad rows
+        exists = (jnp.arange(gpad, dtype=jnp.int32)
+                  < ngroups[:, None]).reshape(-1)
+        with jax.named_scope("compact_slices"):
+            outs, total = compact_pairs(
+                [d.reshape((slices * gpad,) + d.shape[2:]) for d, _ in outs],
+                [v.reshape(-1) for _, v in outs], exists, slices * gpad)
+        return list(outs), total
+
+    return sliced
+
+
+def _preps(exprs, pctx: PrepCtx) -> List[List[NodePrep]]:
+    """The host prep pass of each expression: one NodePrep list apiece,
+    in the order given (aux slots are handed out in that order)."""
+    out = []
+    for e in exprs:
+        preps: List[NodePrep] = []
+        _walk_prep(e, pctx, preps)
+        out.append(preps)
+    return out
+
+
 def _sortable(data, validity):
     """Transform (data, validity) into sort operands grouping nulls
     together: (invalid_first_flag, *native-width key operands). The
@@ -204,11 +264,15 @@ class TpuHashAggregateExec(TpuExec):
         # 0 where it read no partial's count and never waited
         self.add_metric("partialCountReads", 0)
         self.add_metric("runAheadWaits", 0)
+        # and both counts of the sliced aggregate: batches walked in
+        # slices inside one program, and the slices they held
+        self.add_metric("slicedAggBatches", 0)
+        self.add_metric("aggSlices", 0)
         first = next(it, None)
         if first is None:
             return
         second = next(it, None)
-        if second is None:
+        if second is None and self._slices_of(first) == 1:
             # single batch: aggregate directly (spill-and-replay on OOM)
             yield retry_block(lambda: self._aggregate(
                 first, self.grouping, self.agg_specs, self.grouping_names,
@@ -221,7 +285,8 @@ class TpuHashAggregateExec(TpuExec):
         # spillable, and one merge aggregation re-groups the concatenated
         # partials with merge semantics (sum-of-sums, min-of-mins,
         # Chan-style moment combination), followed by a finalize
-        # projection (avg = s/n, ...).
+        # projection (avg = s/n, ...). A single batch of several slices
+        # is the same stream: its slices' partials merge below.
         plan = self._merge_plan()
         catalog = BufferCatalog.get()
         partials = []
@@ -231,10 +296,15 @@ class TpuHashAggregateExec(TpuExec):
         #: known complete, oldest first (_bound_run_ahead)
         ahead = deque()
         try:
-            for batch in chain([first, second], it):
-                pt = retry_block(lambda b=batch: self._aggregate(
+            head = [first] if second is None else [first, second]
+            for batch in chain(head, it):
+                slices = self._slices_of(batch)
+                if slices > 1:
+                    self.add_metric("slicedAggBatches", 1)
+                    self.add_metric("aggSlices", slices)
+                pt = retry_block(lambda b=batch, n=slices: self._aggregate(
                     b, self.grouping, plan.partial_specs,
-                    self.grouping_names, self.filters))
+                    self.grouping_names, self.filters, slices=n))
                 # A partial's row count stays a device scalar
                 # (concat_device and the merge take it as one): reading
                 # it stalls the host until the batch's kernel has run,
@@ -432,26 +502,11 @@ class TpuHashAggregateExec(TpuExec):
     # -- core ---------------------------------------------------------------
     def _prep_all(self, table: DeviceTable, grouping, agg_specs, filters):
         pctx = PrepCtx(table)
-        filter_preps: List[List[NodePrep]] = []
-        for f in filters:
-            preps: List[NodePrep] = []
-            _walk_prep(f, pctx, preps)
-            filter_preps.append(preps)
-        key_preps: List[List[NodePrep]] = []
-        for g in grouping:
-            preps = []
-            _walk_prep(g, pctx, preps)
-            key_preps.append(preps)
+        filter_preps = _preps(filters, pctx)
+        key_preps = _preps(grouping, pctx)
         # per spec: one prep list PER CHILD expression (Count() has none,
         # most aggs have one, MergeMoments has three)
-        val_preps: List[List[List[NodePrep]]] = []
-        for _, fn in agg_specs:
-            per_child = []
-            for c in fn.children:
-                preps = []
-                _walk_prep(c, pctx, preps)
-                per_child.append(preps)
-            val_preps.append(per_child)
+        val_preps = [_preps(fn.children, pctx) for _, fn in agg_specs]
         return pctx, filter_preps, key_preps, val_preps
 
     def _fast_layout(self, grouping, key_preps, capacity) -> Optional[tuple]:
@@ -521,8 +576,37 @@ class TpuHashAggregateExec(TpuExec):
         gpad = max(8, 1 << (max(total - 1, 1)).bit_length())
         return tuple(kinds), sizes, strides, gpad, bases
 
+    def _slices_of(self, table: DeviceTable) -> int:
+        """How many AGG_SLICE-row slices the fast kernel walks ``table``
+        in inside one program; 1 = the whole-capacity body. More than
+        one where the capacity is a whole multiple of the slice, every
+        column is one row-shaped array (its rows can be sliced), no
+        expression depends on a row's position in the batch, and the
+        fast layout applies with the small segment count whose one-hot
+        contractions are the body that was measured (a larger domain's
+        partial per slice would outgrow what slicing saves). Every
+        aggregate the fast layout admits has a merge decomposition
+        (_merge_plan), which is what combines the slices."""
+        from spark_rapids_tpu.ops import segsum as _ss
+        from spark_rapids_tpu.ops.expr import has_position_dependent
+        slices, rest = divmod(table.capacity, AGG_SLICE)
+        if slices < 2 or rest or any(c.is_nested for c in table.columns):
+            return 1
+        exprs = (self.grouping + self.filters
+                 + [c for _, fn in self.agg_specs for c in fn.children])
+        if any(has_position_dependent(e) for e in exprs):
+            return 1
+        fast = self._fast_layout(
+            self.grouping, _preps(self.grouping, PrepCtx(table)), AGG_SLICE)
+        if fast is None or not _ss.takes_contraction(fast[3], AGG_SLICE):
+            return 1
+        return slices
+
     def _aggregate(self, table: DeviceTable, grouping, agg_specs,
-                   grouping_names, filters) -> DeviceTable:
+                   grouping_names, filters, slices: int = 1) -> DeviceTable:
+        """One aggregation of ``table``. ``slices`` > 1 (_slices_of; the
+        caller merges what comes back): the fast kernel runs once a
+        slice and the output holds every slice's partial groups."""
         if table.live is not None:
             from spark_rapids_tpu.ops.expr import has_position_dependent
             exprs = (list(grouping) + list(filters)
@@ -535,8 +619,10 @@ class TpuHashAggregateExec(TpuExec):
         cols = tuple(DevVal(c.data, c.validity) for c in table.columns)
         aux = prep_aux(pctx)
         capacity = table.capacity
+        #: rows the kernel body is built at: the batch, or one slice
+        body_rows = capacity // slices
 
-        fast = self._fast_layout(grouping, key_preps, capacity)
+        fast = self._fast_layout(grouping, key_preps, body_rows)
 
         from spark_rapids_tpu.ops.expr import shared_traces
         self._traces = shared_traces(
@@ -547,6 +633,8 @@ class TpuHashAggregateExec(TpuExec):
              table.schema_key()[0]))
         from spark_rapids_tpu.ops import segsum as _ss
         mode_key = ("fast", fast[0], fast[3]) if fast else ("sorted",)
+        if slices > 1:
+            mode_key = ("fast_sliced", fast[0], fast[3], slices)
         has_mask = table.live is not None
         tkey = (capacity, self.use_split, _ss.trace_key(),
                 mode_key, has_mask,
@@ -557,10 +645,15 @@ class TpuHashAggregateExec(TpuExec):
         fn = self._traces.get(tkey)
         if fn is None:
             if fast:
-                fn = tpu_jit(self._build_fast_kernel(
-                    capacity, fast[0], fast[3], filter_preps, key_preps,
-                    val_preps, grouping, agg_specs, filters),
-                    name="agg_fast")
+                kernel = self._build_fast_kernel(
+                    body_rows, fast[0], fast[3], filter_preps, key_preps,
+                    val_preps, grouping, agg_specs, filters)
+                if slices > 1:
+                    fn = tpu_jit(_over_slices(kernel, slices, body_rows,
+                                              fast[3]),
+                                 name="agg_fast_sliced")
+                else:
+                    fn = tpu_jit(kernel, name="agg_fast")
             else:
                 fn = tpu_jit(self._build_kernel(
                     capacity, filter_preps, key_preps, val_preps,
@@ -569,7 +662,7 @@ class TpuHashAggregateExec(TpuExec):
 
         if fast:
             _, sizes, strides, gpad, bases = fast
-            if _ss.takes_contraction(gpad, capacity):
+            if _ss.takes_contraction(gpad, body_rows):
                 self.add_metric("countsByContraction", 1)
             out_arrays, ngroups = fn(
                 cols, aux, table.nrows_dev,
@@ -577,7 +670,7 @@ class TpuHashAggregateExec(TpuExec):
                 device_const(np.asarray(strides, dtype=np.int32)),
                 device_const(np.asarray(bases, dtype=np.int64)),
                 table.live)
-            out_capacity = gpad
+            out_capacity = slices * gpad
         else:
             out_arrays, ngroups = fn(cols, aux, table.nrows_dev, table.live)
             out_capacity = capacity

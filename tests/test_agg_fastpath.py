@@ -783,3 +783,171 @@ def test_streaming_injected_oom_replays_the_partial(
     assert got == want
     assert_tpu_and_cpu_are_equal(build, sess, cpu_session,
                                  approximate_float=True)
+
+
+# ---------------------------------------------------------------------------
+# a batch of several slices is aggregated slice by slice inside one
+# program (AGG_SLICE patched small: 8 slices under a 16,384-row batch)
+# ---------------------------------------------------------------------------
+
+SLICED_AGGS = [
+    F.count().alias("n"), F.count(col("x")).alias("nx"),
+    F.sum(col("x")).alias("sx"), F.avg(col("x")).alias("ax"),
+    F.min(col("x")).alias("mnx"), F.max(col("y")).alias("mxy"),
+    F.sum(col("y")).alias("sy"), F.variance(col("x")).alias("vx"),
+]
+
+
+def _rows_close(got, want):
+    """Two HostTables hold the same rows in any order: floats to 1e-6
+    relative (sums and moments merged in another order), the rest equal."""
+    got = sorted(zip(*got.to_pydict().values()), key=repr)
+    want = sorted(zip(*want.to_pydict().values()), key=repr)
+    assert len(got) == len(want), (got, want)
+    for g, w in zip(got, want):
+        for gv, wv in zip(g, w):
+            if isinstance(gv, float) and isinstance(wv, float):
+                assert abs(gv - wv) <= 1e-6 * max(1.0, abs(wv)), (g, w)
+            else:
+                assert gv == wv, (g, w)
+
+
+def _feed(aggx, batches):
+    """`aggx` over the given device batches in place of its child."""
+    from spark_rapids_tpu.execs.base import TpuExec
+
+    class Batches(TpuExec):
+        def execute(self):
+            return iter(batches)
+    aggx.children = (Batches(),)
+    return [t.to_host() for t in aggx.execute()]
+
+
+@pytest.mark.parametrize("grouped", [True, False],
+                         ids=["grouped", "ungrouped"])
+@pytest.mark.parametrize("masked", [False, True], ids=["prefix", "masked"])
+@pytest.mark.parametrize("nrows", [1500, 4096, 15000, 0], ids=[
+    "first-slice-only", "on-a-boundary", "partial-last-slice", "empty"])
+def test_sliced_aggregate_matches_oracle(session, cpu_session, monkeypatch,
+                                         nrows, masked, grouped):
+    """NULL keys and values, a fused filter, sum, count, avg, min, max
+    and a variance: one program a batch, its slices' partials merged by
+    the streaming path's plan, equal to the CPU over the live rows."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar import DeviceTable, HostColumn, HostTable
+    from spark_rapids_tpu.execs import aggregate as A
+    from spark_rapids_tpu.plan import from_host_table
+    monkeypatch.setattr(A, "AGG_SLICE", 2048)
+    table = _counts_table(15000, 12, seed=21)
+
+    def build(s, t):
+        df = from_host_table(t, s).filter(col("w") < lit(80))
+        return (df.group_by("k") if grouped else df).agg(*SLICED_AGGS)
+    aggx = _fast_agg_exec(session, build(session, table))
+    assert aggx.filters
+    batch = next(iter(aggx.children[0].execute_masked()))
+    assert batch.capacity == 16384
+    keep = np.arange(15000) < nrows
+    live = None
+    if masked:
+        keep &= np.random.default_rng(5).random(15000) < 0.6
+        live = np.zeros(batch.capacity, dtype=np.bool_)
+        live[:15000] = keep
+        live = jnp.asarray(live)
+    cut = DeviceTable(batch.names, batch.columns, int(keep.sum()),
+                      batch.capacity, live=live)
+    assert aggx._slices_of(cut) == 8
+    got, = _feed(aggx, [cut])
+    assert aggx.metrics.get("slicedAggBatches") == 1
+    assert aggx.metrics.get("aggSlices") == 8
+    assert aggx.metrics.get("partialCountReads") == 0
+
+    kept = HostTable(table.names, [
+        HostColumn(c.dtype, c.data[keep], c.validity[keep])
+        for c in table.columns])
+    want = build(cpu_session, kept).collect_table()
+    if not grouped:
+        assert got.num_rows == 1    # also from an empty input
+    _rows_close(got, want)
+
+
+@pytest.mark.parametrize("case", ["position-dependent", "no-multiple",
+                                  "wide-domain", "sorted"])
+def test_unsliceable_batch_takes_the_whole_capacity_body(
+        tmp_path, cpu_session, monkeypatch, case):
+    """What cannot be sliced is decided from the expressions and the
+    shape: a position-dependent child, a capacity that is no whole
+    multiple of the slice, a domain above the contraction's (its
+    partials a slice would outgrow the saving), the sorted path."""
+    from spark_rapids_tpu.execs import aggregate as A
+    from spark_rapids_tpu.plan import from_host_table
+    monkeypatch.setattr(A, "AGG_SLICE",
+                        6144 if case == "no-multiple" else 2048)
+    sess = _logged(tmp_path)
+    table = _counts_table(9000, 40 if case == "wide-domain" else 12,
+                          seed=8, null_keys=case != "sorted")
+
+    def build(s):
+        x = col("x")
+        if case == "position-dependent":
+            x = x + F.rand(11)
+        key = col("y") * lit(3) if case == "sorted" else col("k")
+        return from_host_table(table, s).group_by(key.alias("g")).agg(
+            F.count().alias("n"), F.sum(x).alias("sx"),
+            F.avg(col("x")).alias("ax"))
+    assert_tpu_and_cpu_are_equal(build, sess, cpu_session,
+                                 approximate_float=True)
+    assert _metric_total(sess, "slicedAggBatches") == 0
+    assert _metric_total(sess, "aggSlices") == 0
+    assert _metric_total(sess, "partialAggBatches") == 0
+
+
+@pytest.mark.parametrize("slice_rows,mode,program,batches,slices", [
+    (16384, ("fast", ("str",), 16), "agg_fast", 0, 0),
+    (2048, ("fast_sliced", ("str",), 16, 8), "agg_fast_sliced", 1, 8),
+], ids=["one-slice", "eight-slices"])
+def test_one_slice_is_todays_program(tmp_path, cpu_session, monkeypatch,
+                                     slice_rows, mode, program, batches,
+                                     slices):
+    """A batch of exactly one slice builds the kernel it always built,
+    at its capacity, under the name and the trace key it always had;
+    a batch of several builds the sliced program under a key of its own."""
+    from spark_rapids_tpu.execs import aggregate as A
+    from spark_rapids_tpu.ops import segsum as S
+    from spark_rapids_tpu.plan import from_host_table
+    monkeypatch.setattr(A, "AGG_SLICE", slice_rows)
+    built, traces = [], []
+    real_jit = A.tpu_jit
+
+    def jit_spy(fn, *, name, **kw):
+        built.append(name)
+        return real_jit(fn, name=name, **kw)
+    monkeypatch.setattr(A, "tpu_jit", jit_spy)
+    real_build = A.TpuHashAggregateExec._build_fast_kernel
+
+    def build_spy(self, capacity, *a, **k):
+        traces.append((self._traces, self.use_split))
+        built.append(capacity)
+        return real_build(self, capacity, *a, **k)
+    monkeypatch.setattr(A.TpuHashAggregateExec, "_build_fast_kernel",
+                        build_spy)
+
+    sess = _logged(tmp_path)
+    t = _counts_table(9000, 12, seed=17)
+
+    def build(s):
+        # a literal of the case's own: traces no earlier test has built
+        return (from_host_table(t, s).filter(col("w") < lit(900 + slices))
+                .group_by("k").agg(F.count().alias("n"),
+                                   F.sum(col("x")).alias("sx")))
+    assert_tpu_and_cpu_are_equal(build, sess, cpu_session,
+                                 approximate_float=True)
+    assert _metric_total(sess, "slicedAggBatches") == batches
+    assert _metric_total(sess, "aggSlices") == slices
+    # the batch's kernel first: at the body's rows, under the program's name
+    assert built[:2] == [slice_rows, program]
+    assert ("agg_fast_sliced" in built) == bool(slices)
+    (batch_traces, use_split), *_ = traces
+    # (capacity, use_split, segsum's tuning, mode, masked, the preps...)
+    assert [k[:5] for k in batch_traces] == [
+        (16384, use_split, S.trace_key(), mode, False)]

@@ -79,38 +79,67 @@ def _q1_aggregate():
     return exec_, next(iter(exec_.children[0].execute_masked()))
 
 
-def test_q1_aggregate_fits_the_chip_at_the_coalesced_capacity(one_chip):
-    """`agg_fast` at 2^24 rows asked for 17.13 GB of a 15.75 GB chip
-    before the split sums kept rows on the lane axis (PERF.md, PR 27)."""
+def _compile_q1_aggregate(one_chip, cap, slices=1):
+    """Q1's fast kernel over a `cap`-row batch, compiled for the chip:
+    the whole-capacity body, or the body of `cap // slices` rows in a
+    loop over the slices (the streaming path's partial specs)."""
     import jax
     import jax.numpy as jnp
     from spark_rapids_tpu.dispatch import prep_aux
+    from spark_rapids_tpu.execs.aggregate import _over_slices
     from spark_rapids_tpu.ops.expr import DevVal
-    cap = 1 << 24
     exec_, batch = _q1_aggregate()
     assert exec_.use_split
+    specs = exec_._merge_plan().partial_specs if slices > 1 \
+        else exec_.agg_specs
+    rows = cap // slices
     pctx, fpre, kpre, vpre = exec_._prep_all(
-        batch, exec_.grouping, exec_.agg_specs, exec_.filters)
+        batch, exec_.grouping, specs, exec_.filters)
     kinds, sizes, strides, gpad, bases = exec_._fast_layout(
-        exec_.grouping, kpre, cap)
+        exec_.grouping, kpre, rows)
     assert gpad == 16
-    kernel = exec_._build_fast_kernel(cap, kinds, gpad, fpre, kpre, vpre,
-                                      exec_.grouping, exec_.agg_specs,
-                                      exec_.filters)
+    kernel = exec_._build_fast_kernel(rows, kinds, gpad, fpre, kpre, vpre,
+                                      exec_.grouping, specs, exec_.filters)
+    if slices > 1:
+        kernel = _over_slices(kernel, slices, rows, gpad)
     cols = tuple(DevVal(_shape(one_chip, (cap,), c.data.dtype),
                         _shape(one_chip, (cap,), jnp.bool_))
                  for c in batch.columns)
     aux = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
                        prep_aux(pctx))
-    compiled = _compile(
+    return _compile(
         kernel, cols, aux, _shape(one_chip, (), jnp.int32),
         _shape(one_chip, (len(sizes),), jnp.int32),
         _shape(one_chip, (len(strides),), jnp.int32),
         _shape(one_chip, (len(bases),), jnp.int64), None)
-    memory = compiled.memory_analysis()
+
+
+def test_q1_aggregate_fits_the_chip_at_the_coalesced_capacity(one_chip):
+    """`agg_fast` at 2^24 rows asked for 17.13 GB of a 15.75 GB chip
+    before the split sums kept rows on the lane axis (PERF.md, PR 27)."""
+    memory = _compile_q1_aggregate(one_chip, 1 << 24).memory_analysis()
     # beside a 3.6 GB table and two 0.86 GB coalesced batches
     assert memory.temp_size_in_bytes < 5 * GB, memory.temp_size_in_bytes
     assert memory.argument_size_in_bytes < 1 * GB
+
+
+def test_q1_aggregate_walks_the_coalesced_batch_in_slices(one_chip):
+    """`agg_fast_sliced` at 2^24 rows is ONE program holding a loop over
+    eight 2^21-row slices. Its temporaries are one slice's (0.32 GB) and
+    the f32 halves of the four DOUBLE columns, which the compiler splits
+    at the program's entry, before the loop (0.54 GB): 0.84 GB where the
+    whole-capacity body takes 3.02 GB (PERF.md, PR 27 and PR 30)."""
+    from spark_rapids_tpu.execs.aggregate import AGG_SLICE
+    assert AGG_SLICE == 1 << 21
+    compiled = _compile_q1_aggregate(one_chip, 1 << 24, slices=8)
+    text = compiled.as_text()
+    assert text.count("ENTRY ") == 1
+    assert " while(" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.2 * GB, memory.temp_size_in_bytes
+    assert memory.argument_size_in_bytes < 1 * GB
+    # every slice's 16 partial groups, 15 columns: a few KB
+    assert memory.output_size_in_bytes < 64 * 1024
 
 
 def test_coalesce_copies_eight_batches_without_a_scatter(one_chip):

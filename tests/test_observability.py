@@ -294,7 +294,9 @@ _TPU_JIT_SITES = _tpu_jit_sites()
 
 
 def test_every_tpu_jit_site_is_found():
-    assert len(_TPU_JIT_SITES) >= 35
+    assert len(_TPU_JIT_SITES) >= 36
+    # the sliced aggregate is a program of its own on the device timeline
+    assert {"agg_fast", "agg_fast_sliced"} <= {n for _, n in _TPU_JIT_SITES}
 
 
 @pytest.mark.parametrize("site,name", _TPU_JIT_SITES,
@@ -503,8 +505,9 @@ def test_event_log_golden_schema(tmp_path):
     Exec metrics in the plan tree are no schema fields (no bump): every
     TpuHashAggregateExec node carries partialCountReads (partials whose
     row count the streaming loop read to shrink them) and runAheadWaits
-    (times its run-ahead bound waited), both 0 for the golden's
-    single-batch aggregate."""
+    (times its run-ahead bound waited), slicedAggBatches (batches the
+    fast kernel walked in slices inside one program) and aggSlices (the
+    slices they held), all 0 for the golden's single-batch aggregate."""
     s = _run_eventlog_query(tmp_path)
     got = _normalize(s.last_event_record)
     golden_path = os.path.join(os.path.dirname(__file__),
@@ -513,6 +516,34 @@ def test_event_log_golden_schema(tmp_path):
     assert got == golden, (
         "event-log record drifted from the golden schema; new normalized "
         "record:\n" + json.dumps(got, indent=1, sort_keys=True))
+
+
+@pytest.mark.parametrize("slice_rows,batches,slices", [
+    (1024, 3, 6), (2048, 0, 0)], ids=["two-slices-a-batch", "one-slice"])
+def test_record_counts_the_sliced_aggregates(tmp_path, monkeypatch,
+                                             slice_rows, batches, slices):
+    """A streamed aggregate over three 2,048-row batches: with a slice of
+    1,024 rows each batch is one `agg_fast_sliced` dispatch over two
+    slices and the record's plan tree says so; with a slice of the
+    batch's own capacity nothing is sliced and both counts read 0."""
+    from spark_rapids_tpu.execs import aggregate as A
+    from spark_rapids_tpu.plan import from_host_table
+    from tests.asserts import plan_metric_total
+    from tests.data_gen import DoubleGen, StringGen, gen_table
+    monkeypatch.setattr(A, "AGG_SLICE", slice_rows)
+    s = TpuSession({"spark.rapids.sql.eventLog.enabled": "true",
+                    "spark.rapids.sql.eventLog.dir": str(tmp_path),
+                    "spark.rapids.sql.batchSizeBytes": "1024"})
+    table = gen_table({"k": StringGen(cardinality=5),
+                       "d": DoubleGen(nullable=False)}, 4500, 3)
+    from_host_table(table, s, 3).group_by("k").agg(
+        F.count().alias("n"), F.min("d").alias("m")).collect_table()
+    rec = s.last_event_record
+    assert plan_metric_total(s, "partialAggBatches") == 3
+    assert plan_metric_total(s, "slicedAggBatches") == batches
+    assert plan_metric_total(s, "aggSlices") == slices
+    on_disk = [json.loads(line) for line in open(s.last_event_path)]
+    assert on_disk[-1]["plan"] == rec["plan"]
 
 
 _NEW_PHASES = ("parseS", "dispatchS", "syncWaitS", "fetchWaitS",
