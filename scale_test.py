@@ -33,7 +33,9 @@ resume exactly-once from its checkpoint.
 runs MESH-NATIVE under a seeded mesh-fault schedule firing every
 ``mesh.*`` point — shard-put crashes, checksummed-fetch corruption,
 partial device losses walking the degradation ladder down to a mesh
-shrink — asserting bit-identity to fault-free single-chip, bounded
+shrink — asserting the mesh contract against fault-free single-chip
+(mesh_contract_differ: bit-identity, but for the DOUBLE columns of a
+query whose aggregate ran on the resident shards), bounded
 recovery counters, and the mesh back at full strength at the end
 (MULTICHIP_r07.json). Unsupported flag combinations fail fast
 (validate_flags) instead of silently ignoring a mode."""
@@ -604,9 +606,13 @@ def _record_lock_witness(report: dict, failures: list) -> None:
             f"lock witness observed {n} rank inversion(s) during the run")
 
 
-def tables_differ(a, b):
+def tables_differ(a, b, double_limit=None):
     """Bit-identity check between two HostTables; returns None when
-    identical, else a description of the first divergence."""
+    identical, else a description of the first divergence. With
+    ``double_limit`` (mesh_contract_differ) the FLOAT columns' valid
+    cells may differ by that share of the larger magnitude; everything
+    else stays bitwise: names, row count and order, types, validity,
+    every other column."""
     import numpy as np
     if list(a.names) != list(b.names):
         return f"column names differ: {a.names} vs {b.names}"
@@ -625,6 +631,16 @@ def tables_differ(a, b):
                 if va[i] and da[i] != db[i]:
                     return (f"column {name} row {i}: "
                             f"{da[i]!r} != {db[i]!r}")
+        elif double_limit is not None and da.dtype.kind == "f":
+            xa, xb = da[va].astype(np.float64), db[vb].astype(np.float64)
+            with np.errstate(invalid="ignore"):
+                off = ~((xa == xb) | (np.isnan(xa) & np.isnan(xb))
+                        | (np.abs(xa - xb) <= double_limit
+                           * np.maximum(np.abs(xa), np.abs(xb))))
+            if off.any():
+                i = int(np.flatnonzero(off)[0])
+                return (f"column {name}: valid value {i} differs beyond "
+                        f"{double_limit:g}: {xa[i]!r} vs {xb[i]!r}")
         else:
             # bit identity over VALID rows only: raw bytes so NaN
             # payloads and signed zeros count (float equality would mask
@@ -1641,6 +1657,29 @@ def mesh_chaos_fault_spec(seed: int) -> str:
     ])
 
 
+#: what a DOUBLE sum merged from a mesh's shard partials may differ by
+#: from one chip's (a share of the larger magnitude): the limit the
+#: benchmark holds the same sums to against its float64 reference
+#: (benchmarks/limits/, PERF.md section 2)
+MESH_DOUBLE_LIMIT = 2e-7
+
+#: mesh-scope counters of the aggregate on the resident shards
+_MESH_AGG_COUNTERS = ("meshAggBatches", "meshAggShards")
+
+
+def mesh_contract_differ(expected, got, shard_aggregated: bool):
+    """The mesh contract (execs/mesh.py) between one chip's answer and
+    the mesh's; None when it holds. A query none of whose aggregates ran
+    on the resident shards (``meshAggBatches`` did not move) is
+    bit-identical. One that did keeps integers, counts, decimals,
+    strings, dates, min/max, validity, the number of rows and their
+    order bit-identical, and its DOUBLE columns (the sums merged from
+    shard partials, and what the plan computes from them) within
+    MESH_DOUBLE_LIMIT."""
+    return tables_differ(expected, got,
+                         MESH_DOUBLE_LIMIT if shard_aggregated else None)
+
+
 #: whole-run recovery-work ceilings for the mesh chaos closure (a
 #: runaway retry loop must fail the run, not grind through it)
 MESH_CHAOS_BOUNDS = {"query_replays": 30, "shardRetries": 40,
@@ -1650,8 +1689,10 @@ MESH_CHAOS_BOUNDS = {"query_replays": 30, "shardRetries": 40,
 def run_mesh_chaos(sf: float, seed: int, ndev: int, queries=None,
                    use_sql: bool = False, shape: str = ""):
     """``--mesh N --chaos``: q1-q22 MESH-NATIVE under the seeded
-    mesh-fault schedule, asserting every query bit-identical to the
-    fault-free single-chip baseline, every ``mesh.*`` fault point fired
+    mesh-fault schedule, asserting every query within the mesh contract
+    of the fault-free single-chip baseline (mesh_contract_differ:
+    bit-identical, but for the DOUBLE columns of a query whose
+    aggregate ran on the resident shards), every ``mesh.*`` fault point fired
     at least once, recovery counters within MESH_CHAOS_BOUNDS, and the
     mesh back at full strength at the end (a degraded end state is
     tolerated only EXPLAINED — shrink reason + excluded devices in the
@@ -1733,7 +1774,13 @@ def run_mesh_chaos(sf: float, seed: int, ndev: int, queries=None,
         got = mesh_queries[name]().collect_table()
         wall = time.perf_counter() - t0
         after_m, after_h = _scopes()
-        diff = tables_differ(expected_tables[name], got)
+        # (an attempt that aggregated on the shards and was then
+        # replayed on one device counts too: the limit contains
+        # bit-identity)
+        shard_aggregated = (after_m.get("meshAggBatches", 0)
+                            > before_m.get("meshAggBatches", 0))
+        diff = mesh_contract_differ(expected_tables[name], got,
+                                    shard_aggregated)
         recollected = False
         if diff is not None and (CIRCUIT_BREAKER.demoted_ops()
                                  or HEALTH.state() != "HEALTHY"):
@@ -1744,15 +1791,17 @@ def run_mesh_chaos(sf: float, seed: int, ndev: int, queries=None,
             # suspended() keeps the seeded schedule from resetting)
             with FAULTS.suspended():
                 redo = chip_queries[name]().collect_table()
-            diff = tables_differ(redo, got)
+            diff = mesh_contract_differ(redo, got, shard_aggregated)
             recollected = True
         entry = {
             "chaos_s": round(wall, 4),
-            "identical": diff is None,
+            "within_contract": diff is None,
+            "shard_aggregated": shard_aggregated,
             "mesh": {k: int(after_m.get(k, 0) - before_m.get(k, 0))
                      for k in ("shardsDispatched", "iciExchanges",
                                "hostShuffleFallbacks", "shardRetries",
                                "gatherChecksFailed", "meshRelandRows")
+                     + _MESH_AGG_COUNTERS
                      if after_m.get(k, 0) != before_m.get(k, 0)},
             "ladder": {k: int(after_h.get(k, 0) - before_h.get(k, 0))
                        for k in ("meshDeviceLost", "meshDegradations",
@@ -1808,15 +1857,19 @@ def run_mesh_chaos(sf: float, seed: int, ndev: int, queries=None,
         probe = wanted[0]
         with FAULTS.suspended():
             redo = chip_queries[probe]().collect_table()
+        before_m, _ = _scopes()
         got = mesh_queries[probe]().collect_table()
+        probe_diff = mesh_contract_differ(
+            redo, got, _scopes()[0].get("meshAggBatches", 0)
+            > before_m.get("meshAggBatches", 0))
         restored = MESH.health_snapshot()
         report["restore_probe"] = {
             "query": probe,
-            "identical": tables_differ(redo, got) is None,
+            "within_contract": probe_diff is None,
             "mesh": restored,
         }
-        if tables_differ(redo, got) is not None:
-            failures.append(f"restore probe {probe} diverged")
+        if probe_diff is not None:
+            failures.append(f"restore probe {probe} diverged: {probe_diff}")
         if restored["excludedDeviceIds"]:
             failures.append(
                 "mesh did not return to full strength after restore: "
@@ -1863,7 +1916,7 @@ def run_mesh_chaos(sf: float, seed: int, ndev: int, queries=None,
 
 
 # ---------------------------------------------------------------------------
-# Mesh mode: the corpus executed mesh-native, bit-identical to single-chip
+# Mesh mode: the corpus executed mesh-native, held to the mesh contract
 # ---------------------------------------------------------------------------
 
 
@@ -1885,11 +1938,15 @@ def run_mesh(sf: float, seed: int, ndev: int, queries=None,
              use_sql: bool = False, shape: str = ""):
     """Mesh-native corpus run: q1-q22 single-chip for the baseline, the
     SAME corpus with ``spark.rapids.mesh.enabled`` over an ndev-device
-    mesh, asserting BIT-IDENTITY per query and reporting per-exchange
+    mesh, asserting THE MESH CONTRACT per query (mesh_contract_differ:
+    bit-identity, but for the DOUBLE columns of a query whose aggregate
+    ran on the resident shards, which hold MESH_DOUBLE_LIMIT and give
+    the same bits on a second mesh run) and reporting per-exchange
     ICI accounting (collective count, payload bytes, host-shuffle
-    fallbacks with reasons, re-land rows) from the mesh metric scope
-    and the per-exchange metrics. Raises AssertionError on any
-    divergence — this is the MULTICHIP_r06 acceptance harness."""
+    fallbacks with reasons, re-land rows, shard-aggregated batches)
+    from the mesh metric scope and the per-exchange metrics. Raises
+    AssertionError on any divergence: this is the corpus-wide check of
+    the contract that tests/test_mesh.py pins on a slice."""
     _ensure_host_mesh(ndev)
     from spark_rapids_tpu.datagen import scale_test_specs
     from spark_rapids_tpu.obs.events import collect_exchanges
@@ -1926,8 +1983,14 @@ def run_mesh(sf: float, seed: int, ndev: int, queries=None,
                  for k in ("shardsDispatched", "iciExchanges", "iciBytes",
                            "hostShuffleFallbacks", "meshHostUploads",
                            "meshRelandRows", "meshDictInterns",
-                           "meshGatherRows")}
-        diff = tables_differ(expected, got)
+                           "meshGatherRows") + _MESH_AGG_COUNTERS}
+        shard_aggregated = delta["meshAggBatches"] > 0
+        diff = mesh_contract_differ(expected, got, shard_aggregated)
+        if diff is None and shard_aggregated:
+            # a sum merged in (batch, shard, slice) order: the same
+            # mesh gives the same bits again
+            again = tables_differ(got, mesh_queries[name]().collect_table())
+            diff = again and f"a second mesh run gave other bits: {again}"
         exchanges = []
         for e in collect_exchanges(mesh._last_executable):
             exchanges.append({k: e[k] for k in
@@ -1936,7 +1999,10 @@ def run_mesh(sf: float, seed: int, ndev: int, queries=None,
                                "mapOutputBytesMax", "mapOutputBytesMedian",
                                "skewedPartitions")
                               if k in e})
-        entry = {"identical": diff is None, "mesh_wall_s": round(wall, 4),
+        entry = {"identical": tables_differ(expected, got) is None,
+                 "within_contract": diff is None,
+                 "shard_aggregated": shard_aggregated,
+                 "mesh_wall_s": round(wall, 4),
                  "mesh": delta, "exchanges": exchanges}
         if diff is not None:
             failures.append(f"{name}: {diff}")
@@ -1945,14 +2011,21 @@ def run_mesh(sf: float, seed: int, ndev: int, queries=None,
     report["totals"] = {
         k: sum(q["mesh"][k] for q in report["queries"].values())
         for k in ("iciExchanges", "iciBytes", "hostShuffleFallbacks",
-                  "meshHostUploads", "shardsDispatched")}
+                  "meshHostUploads", "shardsDispatched")
+        + _MESH_AGG_COUNTERS}
+    report["double_limit"] = MESH_DOUBLE_LIMIT
+    report["shard_aggregated"] = [
+        n for n, q in report["queries"].items() if q["shard_aggregated"]]
+    report["bits_changed"] = [
+        n for n, q in report["queries"].items() if not q["identical"]]
     report["ok"] = not failures
     report["failures"] = failures
     if failures:
         # the report IS the diagnostic (per-query identical flags, mesh
         # deltas, exchange accounting) — carry it on the error so the
         # CLI can still write --out before exiting non-zero
-        err = AssertionError("mesh run diverged from single-chip:\n"
+        err = AssertionError("mesh run broke the mesh contract against "
+                             "single-chip:\n"
                              + "\n".join(failures))
         err.report = report
         raise err
@@ -3787,9 +3860,10 @@ def main():
     ap.add_argument("--mesh", type=int, default=0, metavar="N",
                     help="run the corpus MESH-NATIVE over an N-device "
                          "mesh (always virtual host-platform devices "
-                         "on the CPU), asserting "
-                         "bit-identity vs single-chip plus per-exchange "
-                         "ICI accounting (the MULTICHIP_r06 harness); "
+                         "on the CPU), asserting the mesh contract "
+                         "vs single-chip (bit-identity, DOUBLE sums of "
+                         "shard-aggregated queries within 2e-7) plus "
+                         "per-exchange ICI accounting; "
                          "with --chaos, the corpus runs under the "
                          "seeded MESH-fault schedule instead (the "
                          "MULTICHIP_r07 closure)")

@@ -325,6 +325,22 @@ def _multi_device(a) -> bool:
     return isinstance(a, jax.Array) and len(a.sharding.device_set) > 1
 
 
+def replica_on_first_device(arrays):
+    """The copy on the engine's first device of arrays a mesh program
+    returned REPLICATED (a pytree): every chip holds the whole value, so
+    taking that chip's own moves nothing. Where the mesh does not hold
+    the first device (a degraded mesh) the first replica is copied
+    there, device to device: the place ``unsharded()`` re-lands on, so
+    what follows runs where it runs on one chip."""
+    leaves, tree = jax.tree.flatten(arrays)
+    parts = [a.addressable_data(0) for a in leaves]
+    home = jax.devices()[0]
+    # one program's results lie on one set of devices: ask the first
+    if parts and parts[0].devices() != {home}:
+        parts = jax.device_put(parts, home)
+    return jax.tree.unflatten(tree, parts)
+
+
 #: jitted concat kernels keyed by (program, schema kinds, input caps, out
 #: cap, which inputs are masked)
 _CONCAT_CACHE: Dict[tuple, object] = {}
@@ -373,7 +389,8 @@ def _build_concat(ncols: int, out_cap: int):
                     else:
                         od = jax.lax.dynamic_update_slice(
                             od, data,
-                            (offset,) + (jnp.int32(0),) * (data.ndim - 1))
+                            (offset,) + (jnp.zeros_like(offset),)
+                            * (data.ndim - 1))
                         ov = jax.lax.dynamic_update_slice(
                             ov, valid, (offset,))
                 else:

@@ -758,6 +758,8 @@ def generate_docs() -> str:
         "name as its XLA module `jit_<program>`), `srt.sync.host_fetch`, "
         "`srt.fetch.resolve|wait|unpack`, `srt.wait.semaphore`, "
         "`srt.coalesce.flush` (a coalesce exec's multi-batch copy), "
+        "`srt.mesh.reland` (the gather of a mesh-sharded batch to one "
+        "device), "
         "`srt.transfer.encode|stage|upload|HostToDevice|DeviceToHost`, "
         "`srt.eventlog.write`, `srt.shuffle.*`, `srt.spill.*`, "
         "`srt.cluster.scan`. They appear on the host timeline "
@@ -770,7 +772,8 @@ def generate_docs() -> str:
         "while a query's envelope collects. The event record's "
         "`phasesS` also holds the host seconds taken where the work "
         "happens (`parseS`, `dispatchS`, `syncWaitS`, `fetchWaitS`, "
-        "`fetchUnpackS`, `semaphoreWaitS`, `coalesceS`) and `hostSyncs` "
+        "`fetchUnpackS`, `semaphoreWaitS`, `coalesceS`, `relandS`) and "
+        "`hostSyncs` "
         "counts the "
         # (the removed switches' names are split across literals so that
         # a grep of the package for them finds no code)

@@ -66,20 +66,29 @@ def _content_key(arr: np.ndarray) -> tuple:
     return (str(arr.dtype), arr.shape, hashlib.sha1(b).digest())
 
 
-def device_const(arr) -> jax.Array:
+def _put(arr: np.ndarray, sharding) -> jax.Array:
+    return jnp.asarray(arr) if sharding is None \
+        else jax.device_put(arr, sharding)
+
+
+def device_const(arr, sharding=None) -> jax.Array:
     """Device copy of a host constant array, interned by content. Safe to
     call inside a jit trace (the cached concrete array is captured as a
-    trace constant — uploaded once at compile, never per call)."""
+    trace constant — uploaded once at compile, never per call). With
+    ``sharding`` (a mesh program's small operand: replicated over its
+    mesh) the copy lies as that says and is interned under content AND
+    sharding: handed a one-device array, a program over sharded operands
+    copies it to every chip on every call."""
     if isinstance(arr, jax.Array):
-        return arr
+        return arr if sharding is None else jax.device_put(arr, sharding)
     arr = np.asarray(arr)
-    key = _content_key(arr)
+    key = _content_key(arr) + (sharding,)
     with _LOCK:
         d = _CONST_CACHE.get(key)
         if d is not None:
             _CONST_CACHE.move_to_end(key)
     if d is None:
-        d = jnp.asarray(arr)
+        d = _put(arr, sharding)
         with _LOCK:
             while len(_CONST_CACHE) >= _CONST_CACHE_CAP:
                 _CONST_CACHE.popitem(last=False)
@@ -87,17 +96,18 @@ def device_const(arr) -> jax.Array:
     return d
 
 
-def device_scalar(value, dtype=np.int32) -> jax.Array:
+def device_scalar(value, dtype=np.int32, sharding=None) -> jax.Array:
     """Interned 0-d device scalar (the DeviceTable row-count pattern:
-    ``jnp.asarray(np.int32(n))`` per table was a ~0.15s upload EACH)."""
+    ``jnp.asarray(np.int32(n))`` per table was a ~0.15s upload EACH);
+    ``sharding`` as in device_const."""
     dt = np.dtype(dtype)
-    key = (dt.str, value)
+    key = (dt.str, value, sharding)
     with _LOCK:
         d = _SCALAR_CACHE.get(key)
         if d is not None:
             _SCALAR_CACHE.move_to_end(key)
     if d is None:
-        d = jnp.asarray(np.asarray(value, dtype=dt))
+        d = _put(np.asarray(value, dtype=dt), sharding)
         with _LOCK:
             while len(_SCALAR_CACHE) >= _CONST_CACHE_CAP:
                 _SCALAR_CACHE.popitem(last=False)
@@ -105,12 +115,13 @@ def device_scalar(value, dtype=np.int32) -> jax.Array:
     return d
 
 
-def prep_aux(pctx) -> tuple:
+def prep_aux(pctx, sharding=None) -> tuple:
     """Upload a PrepCtx's aux arrays: content-interned for deterministic
     slots, plain per-call upload for nondeterministic ones (rand streams —
-    interning those would pin every batch's values on device forever)."""
+    interning those would pin every batch's values on device forever).
+    ``sharding`` as in device_const."""
     intern = getattr(pctx, "aux_intern", None) or [True] * len(pctx.aux_arrays)
-    return tuple(device_const(a) if keep else jnp.asarray(a)
+    return tuple(device_const(a, sharding) if keep else _put(a, sharding)
                  for a, keep in zip(pctx.aux_arrays, intern))
 
 
@@ -142,7 +153,7 @@ _HOST_FETCHES = _ThreadCounter()
 #: the per-query host-clock phases taken where the work happens (keys of
 #: the event record's ``phasesS`` beside planS / executeS / collectS)
 PHASE_KEYS = ("dispatchS", "syncWaitS", "fetchWaitS", "fetchUnpackS",
-              "semaphoreWaitS", "coalesceS")
+              "semaphoreWaitS", "coalesceS", "relandS")
 
 
 class _PhaseSeconds(threading.local):

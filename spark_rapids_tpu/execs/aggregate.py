@@ -35,7 +35,14 @@ A batch of several AGG_SLICE-row slices (what a coalesce under the default
 batchSizeBytes builds) is such a stream INSIDE one program: the fast
 kernel compiled at one slice runs in a loop over the batch's rows and the
 slices' partial tables merge like any other partials (_slices_of,
-_over_slices)."""
+_over_slices).
+
+A batch that lies row-sharded over the device mesh (a mesh-native scan's)
+is the same stream ACROSS chips: a row shard is a slice that lives on
+another chip. The fast kernel runs under ``shard_map`` on each chip's own
+rows, and only the shards' partial groups cross between chips
+(_shards_of, _over_shards). A batch this does not admit is re-landed on
+one chip first (execs/mesh.py)."""
 
 from __future__ import annotations
 
@@ -205,6 +212,43 @@ def _over_slices(kernel, slices: int, rows: int, gpad: int):
     return sliced
 
 
+def _over_shards(kernel, mesh, row_axes, shards: int, rows: int, gpad: int):
+    """A fast kernel built at ``rows`` rows (or ``_over_slices`` of one:
+    ``gpad`` is then the slices' total), run on each of ``shards`` row
+    shards where it lies: under ``shard_map`` over the mesh's row axes
+    every chip runs the kernel over its own rows of every column, with
+    the shard's own row count (the batch's, less the rows of the shards
+    before it) and its part of the live mask. Only the partial groups
+    cross chips: an ``all_gather`` of ``gpad`` rows a shard, in shard
+    order, compacted to one prefix as ``_over_slices`` compacts its
+    slices. Returns what the kernel returns, at ``shards * gpad`` rows,
+    the same on every chip."""
+    from jax.sharding import PartitionSpec as P
+    from spark_rapids_tpu.ops.scatter32 import compact_pairs
+    by_row, same = P(row_axes), P()
+
+    def on_shard(cols, aux, nrows, sizes, strides, bases, live_in):
+        first = jax.lax.axis_index(row_axes) * rows
+        outs, ngroups = kernel(cols, aux, jnp.clip(nrows - first, 0, rows),
+                               sizes, strides, bases, live_in)
+        with jax.named_scope("exchange_partials"):
+            outs, ngroups = jax.lax.all_gather((list(outs), ngroups),
+                                               row_axes)
+        # a shard's groups are a prefix of its gpad rows
+        exists = (jnp.arange(gpad, dtype=jnp.int32)
+                  < ngroups[:, None]).reshape(-1)
+        with jax.named_scope("compact_shards"):
+            outs, total = compact_pairs(
+                [d.reshape((shards * gpad,) + d.shape[2:]) for d, _ in outs],
+                [v.reshape(-1) for _, v in outs], exists, shards * gpad)
+        return list(outs), total
+
+    return jax.shard_map(
+        on_shard, mesh=mesh,
+        in_specs=(by_row, same, same, same, same, same, by_row),
+        out_specs=same, check_vma=False)
+
+
 def _preps(exprs, pctx: PrepCtx) -> List[List[NodePrep]]:
     """The host prep pass of each expression: one NodePrep list apiece,
     in the order given (aux slots are handed out in that order)."""
@@ -257,6 +301,7 @@ class TpuHashAggregateExec(TpuExec):
         from spark_rapids_tpu.runtime.spill import BufferCatalog, SpillableBatch
 
         from spark_rapids_tpu.columnar.table import merge_split_views
+        from spark_rapids_tpu.parallel.mesh import MESH_SCOPE
         # aggregation is partition-structure-blind: a repartition's
         # same-split views mask-union back into one batch (no data moves)
         it = merge_split_views(self.children[0].execute_masked())
@@ -268,15 +313,22 @@ class TpuHashAggregateExec(TpuExec):
         # slices inside one program, and the slices they held
         self.add_metric("slicedAggBatches", 0)
         self.add_metric("aggSlices", 0)
+        # and of the aggregate on a mesh's resident shards: batches
+        # aggregated where they lay, and the shards they held
+        self.add_metric("meshAggBatches", 0)
+        self.add_metric("meshAggShards", 0)
         first = next(it, None)
         if first is None:
             return
         second = next(it, None)
-        if second is None and self._slices_of(first) == 1:
-            # single batch: aggregate directly (spill-and-replay on OOM)
+        # every batch is placed once: on its shards, or on one chip
+        head = self._placed(first)
+        if second is None and head[1:3] == (1, 1):
+            # single batch in one body: aggregate directly
+            # (spill-and-replay on OOM)
             yield retry_block(lambda: self._aggregate(
-                first, self.grouping, self.agg_specs, self.grouping_names,
-                self.filters))
+                head[0], self.grouping, self.agg_specs,
+                self.grouping_names, self.filters))
             return
 
         # STREAMING multi-batch path (GpuMergeAggregateIterator analog,
@@ -296,15 +348,23 @@ class TpuHashAggregateExec(TpuExec):
         #: known complete, oldest first (_bound_run_ahead)
         ahead = deque()
         try:
-            head = [first] if second is None else [first, second]
-            for batch in chain(head, it):
-                slices = self._slices_of(batch)
+            rest = it if second is None else chain([second], it)
+            for batch, shards, slices, fast in chain(
+                    [head], map(self._placed, rest)):
+                if shards > 1:
+                    self.add_metric("meshAggBatches", 1)
+                    self.add_metric("meshAggShards", shards)
+                    MESH_SCOPE.add("meshAggBatches", 1)
+                    MESH_SCOPE.add("meshAggShards", shards)
                 if slices > 1:
                     self.add_metric("slicedAggBatches", 1)
-                    self.add_metric("aggSlices", slices)
-                pt = retry_block(lambda b=batch, n=slices: self._aggregate(
-                    b, self.grouping, plan.partial_specs,
-                    self.grouping_names, self.filters, slices=n))
+                    self.add_metric("aggSlices", shards * slices)
+                pt = retry_block(
+                    lambda b=batch, n=slices, m=shards, f=fast:
+                    self._aggregate(
+                        b, self.grouping, plan.partial_specs,
+                        self.grouping_names, self.filters, slices=n,
+                        shards=m, fast=f))
                 # A partial's row count stays a device scalar
                 # (concat_device and the merge take it as one): reading
                 # it stalls the host until the batch's kernel has run,
@@ -576,9 +636,11 @@ class TpuHashAggregateExec(TpuExec):
         gpad = max(8, 1 << (max(total - 1, 1)).bit_length())
         return tuple(kinds), sizes, strides, gpad, bases
 
-    def _slices_of(self, table: DeviceTable) -> int:
-        """How many AGG_SLICE-row slices the fast kernel walks ``table``
-        in inside one program; 1 = the whole-capacity body. More than
+    def _slices_of(self, table: DeviceTable) -> tuple:
+        """(how many AGG_SLICE-row slices the fast kernel walks ``table``
+        in inside one program, the fast layout at a slice's rows);
+        (1, None) = the whole-capacity body, whose layout _aggregate
+        works out. More than
         one where the capacity is a whole multiple of the slice, every
         column is one row-shaped array (its rows can be sliced), no
         expression depends on a row's position in the batch, and the
@@ -587,26 +649,82 @@ class TpuHashAggregateExec(TpuExec):
         partial per slice would outgrow what slicing saves). Every
         aggregate the fast layout admits has a merge decomposition
         (_merge_plan), which is what combines the slices."""
+        slices, rest = divmod(table.capacity, AGG_SLICE)
+        if slices >= 2 and not rest:
+            fast = self._part_layout(table, AGG_SLICE)
+            if fast is not None:
+                return slices, fast
+        return 1, None
+
+    def _part_layout(self, table: DeviceTable, rows: int) -> Optional[tuple]:
+        """The fast layout at ``rows`` rows where ``table`` may be
+        aggregated in parts of that many rows (slices in one program,
+        shards on their chips) whose partial groups merge: _slices_of's
+        conditions but the capacity's. None where it may not."""
         from spark_rapids_tpu.ops import segsum as _ss
         from spark_rapids_tpu.ops.expr import has_position_dependent
-        slices, rest = divmod(table.capacity, AGG_SLICE)
-        if slices < 2 or rest or any(c.is_nested for c in table.columns):
-            return 1
+        if any(c.is_nested for c in table.columns):
+            return None
         exprs = (self.grouping + self.filters
                  + [c for _, fn in self.agg_specs for c in fn.children])
         if any(has_position_dependent(e) for e in exprs):
-            return 1
+            return None
         fast = self._fast_layout(
-            self.grouping, _preps(self.grouping, PrepCtx(table)), AGG_SLICE)
-        if fast is None or not _ss.takes_contraction(fast[3], AGG_SLICE):
-            return 1
-        return slices
+            self.grouping, _preps(self.grouping, PrepCtx(table)), rows)
+        if fast is None or not _ss.takes_contraction(fast[3], rows):
+            return None
+        return fast
+
+    def _shards_of(self, table: DeviceTable) -> tuple:
+        """(row shards, slices a shard, the fast layout at the body's
+        rows) the fast kernel aggregates ``table`` in where it lies, or
+        (0, 0, None): the batch is then re-landed on one chip first
+        (execs/mesh.py). Admitted is a
+        batch physically sharded over its mesh's row axes, under
+        _slices_of's own conditions at the shard's rows: a shard of at
+        most one AGG_SLICE is one body, a larger one a whole number of
+        slices (_over_slices on each chip). Nothing here is an option:
+        what decides is where the batch lies and what the expressions
+        are."""
+        spec = table.shard_spec
+        if spec is None or not table.columns \
+                or not table.physically_sharded():
+            return 0, 0, None
+        shards = int(spec.mesh.devices.size)
+        rows, rest = divmod(table.capacity, shards)
+        if shards < 2 or rest:
+            return 0, 0, None
+        slices = 1
+        if rows > AGG_SLICE:
+            slices, rest = divmod(rows, AGG_SLICE)
+            if rest:
+                return 0, 0, None
+        fast = self._part_layout(table, rows // slices)
+        if fast is None:
+            return 0, 0, None
+        return shards, slices, fast
+
+    def _placed(self, batch: DeviceTable) -> tuple:
+        """(``batch`` where the fast kernel takes it, its row shards,
+        the slices a shard, the parts' fast layout): on its shards where
+        _shards_of admits it, else gathered to one chip
+        (execs/mesh.reland), where _slices_of counts."""
+        from spark_rapids_tpu.execs.mesh import reland
+        shards, slices, fast = self._shards_of(batch)
+        if not shards:
+            batch, shards = reland(self, batch), 1
+            slices, fast = self._slices_of(batch)
+        return batch, shards, slices, fast
 
     def _aggregate(self, table: DeviceTable, grouping, agg_specs,
-                   grouping_names, filters, slices: int = 1) -> DeviceTable:
-        """One aggregation of ``table``. ``slices`` > 1 (_slices_of; the
-        caller merges what comes back): the fast kernel runs once a
-        slice and the output holds every slice's partial groups."""
+                   grouping_names, filters, slices: int = 1,
+                   shards: int = 1, fast=None) -> DeviceTable:
+        """One aggregation of ``table``. ``slices`` > 1 (_slices_of) or
+        ``shards`` > 1 (_shards_of, ``slices`` then counts a shard's;
+        the caller merges what comes back): the fast kernel runs once a
+        slice of every shard and the output holds every part's partial
+        groups, on one device. ``fast`` is the parts' layout where
+        _placed found one already."""
         if table.live is not None:
             from spark_rapids_tpu.ops.expr import has_position_dependent
             exprs = (list(grouping) + list(filters)
@@ -615,14 +733,28 @@ class TpuHashAggregateExec(TpuExec):
                 table = table.compacted()  # slot ids must match prefix form
         pctx, filter_preps, key_preps, val_preps = self._prep_all(
             table, grouping, agg_specs, filters)
-        from spark_rapids_tpu.dispatch import device_const, prep_aux
+        from spark_rapids_tpu.dispatch import (
+            device_const,
+            device_scalar,
+            prep_aux,
+        )
         cols = tuple(DevVal(c.data, c.validity) for c in table.columns)
-        aux = prep_aux(pctx)
+        #: where the small operands lie: a program over sharded columns
+        #: takes them replicated over the batch's mesh (interned so, once
+        #: per constant: dispatch.device_const)
+        everywhere = None
+        if shards > 1:
+            from jax.sharding import NamedSharding, PartitionSpec
+            mesh = table.shard_spec.mesh
+            everywhere = NamedSharding(mesh, PartitionSpec())
+        aux = prep_aux(pctx, everywhere)
         capacity = table.capacity
         #: rows the kernel body is built at: the batch, or one slice
-        body_rows = capacity // slices
+        #: (of one shard)
+        body_rows = capacity // (slices * shards)
 
-        fast = self._fast_layout(grouping, key_preps, body_rows)
+        if fast is None:
+            fast = self._fast_layout(grouping, key_preps, body_rows)
 
         from spark_rapids_tpu.ops.expr import shared_traces
         self._traces = shared_traces(
@@ -635,6 +767,10 @@ class TpuHashAggregateExec(TpuExec):
         mode_key = ("fast", fast[0], fast[3]) if fast else ("sorted",)
         if slices > 1:
             mode_key = ("fast_sliced", fast[0], fast[3], slices)
+        if shards > 1:
+            from spark_rapids_tpu.parallel.mesh import mesh_token
+            mode_key = ("fast_mesh", fast[0], fast[3], slices, shards,
+                        mesh_token(mesh))
         has_mask = table.live is not None
         tkey = (capacity, self.use_split, _ss.trace_key(),
                 mode_key, has_mask,
@@ -649,9 +785,14 @@ class TpuHashAggregateExec(TpuExec):
                     body_rows, fast[0], fast[3], filter_preps, key_preps,
                     val_preps, grouping, agg_specs, filters)
                 if slices > 1:
-                    fn = tpu_jit(_over_slices(kernel, slices, body_rows,
-                                              fast[3]),
-                                 name="agg_fast_sliced")
+                    kernel = _over_slices(kernel, slices, body_rows, fast[3])
+                if shards > 1:
+                    fn = tpu_jit(_over_shards(
+                        kernel, mesh, table.shard_spec.spec[0], shards,
+                        slices * body_rows, slices * fast[3]),
+                        name="agg_fast_mesh")
+                elif slices > 1:
+                    fn = tpu_jit(kernel, name="agg_fast_sliced")
                 else:
                     fn = tpu_jit(kernel, name="agg_fast")
             else:
@@ -664,13 +805,28 @@ class TpuHashAggregateExec(TpuExec):
             _, sizes, strides, gpad, bases = fast
             if _ss.takes_contraction(gpad, body_rows):
                 self.add_metric("countsByContraction", 1)
+            nrows = table.nrows_dev
+            if shards > 1:
+                # a count the host knows is interned replicated; one
+                # only the device knows is copied to every chip
+                nrows = device_scalar(table.num_rows, sharding=everywhere) \
+                    if table.num_rows_known \
+                    else device_const(nrows, everywhere)
             out_arrays, ngroups = fn(
-                cols, aux, table.nrows_dev,
-                device_const(np.asarray(sizes, dtype=np.int32)),
-                device_const(np.asarray(strides, dtype=np.int32)),
-                device_const(np.asarray(bases, dtype=np.int64)),
+                cols, aux, nrows,
+                device_const(np.asarray(sizes, dtype=np.int32), everywhere),
+                device_const(np.asarray(strides, dtype=np.int32), everywhere),
+                device_const(np.asarray(bases, dtype=np.int64), everywhere),
                 table.live)
-            out_capacity = slices * gpad
+            out_capacity = shards * slices * gpad
+            if shards > 1:
+                # every chip holds the gathered partials: what follows
+                # takes the first device's, where it runs on one chip
+                from spark_rapids_tpu.columnar.table import (
+                    replica_on_first_device,
+                )
+                out_arrays, ngroups = replica_on_first_device(
+                    (out_arrays, ngroups))
         else:
             out_arrays, ngroups = fn(cols, aux, table.nrows_dev, table.live)
             out_capacity = capacity

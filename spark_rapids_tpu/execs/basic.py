@@ -37,8 +37,11 @@ def _scan_sharding(exec_node: TpuExec):
     (``_mesh_scan_gen``), and an unstamped or stale-stamped scan lands
     single-device — a tree converted with the mesh off carries no
     boundaries, so feeding it physically sharded batches would let
-    GSPMD repartition a wide float kernel and break bit-identity when
-    a concurrent session flips the process mesh mid-query. The token
+    GSPMD repartition a sort, a join or a sorted-path aggregate as it
+    likes when a concurrent session flips the process mesh mid-query,
+    outside the contract execs/mesh.py states (exact types bit-identical
+    to one chip; DOUBLE sums merged in (batch, shard, slice) order,
+    judged against a float64 reference). The token
     keys cached device images to the mesh GENERATION, so a
     reconfiguration invalidates every cached placement. Read atomically
     (MeshRuntime.scan_placement) so a concurrent reconfiguration cannot
@@ -454,6 +457,10 @@ class TpuCoalesceExec(TpuExec):
       copy is redone per execution (PERF.md section 6, PR 27, has the
       readings behind that).
 
+    On a mesh a lone buffered batch is handed on where it lies, sharded
+    or not; batches that are concatenated are gathered to one device first
+    (execs/mesh.py ``reland``): the copy builds one batch on one device.
+
     Two passthroughs: a lone buffered batch, and — under TargetSize only —
     capacity-sharing masked VIEWS from a local shuffle split
     (columnar/table.is_shared_view), which stream un-coalesced because
@@ -546,10 +553,15 @@ class TpuCoalesceExec(TpuExec):
             return out
         self.add_metric("concatBatches", len(batches))
         stats = {"dictUnions": 0}
+        # the copy builds ONE batch on one device: batches that lie
+        # sharded over a mesh are gathered first (a lone batch, above,
+        # is handed on where it lies)
+        from spark_rapids_tpu.execs.mesh import reland
         try:
             with phase_span("coalesceS", "flush", "coalesce"):
                 out = retry_block(lambda: concat_device(
-                    [b.get() for b in batches], coalesce=True, stats=stats))
+                    [reland(self, b.get()) for b in batches],
+                    coalesce=True, stats=stats))
         finally:
             for b in batches:
                 b.release()

@@ -1,19 +1,35 @@
 """Mesh re-land boundary: where sharded residency ends inside a plan.
 
 Mesh-native execution (parallel/mesh.py) lands scan shards per-device
-and lets the narrow pipeline — filter/project/masked ops, and the ICI
-shuffle exchange — run on the resident shards (GSPMD partitions those
-kernels; they are elementwise or pure data movement, so their results
-are bitwise independent of the layout). Wide kernels are NOT layout-
-independent: a float reduction partitioned over 8 shards accumulates in
-a different order than the single-chip kernel, and the contract for
-this engine is BIT-IDENTITY with single-chip results (scale_test
---mesh, MULTICHIP_r06). So every wide consumer (aggregate, sort, join,
-window, ...) takes its input through a :class:`TpuMeshRelandExec`
-boundary inserted at conversion time: one device-side gather (ICI on a
-real pod — the host is never touched, pinned by RL-MESH-HOST and the
-meshHostUploads counter) that re-lands the shards into the single-
-device layout the wide kernel compiles against.
+and lets a consumer run on the resident shards where that keeps THE
+CONTRACT: against the same plan on one chip, integers, counts,
+decimals, strings, dates, min/max, the number of rows and their order
+are bit-identical; a DOUBLE sum is merged from the partial sums of
+(batch, shard, slice) in that fixed order, so one mesh shape and one
+batch cut give the same bits run after run, and it is judged as every
+DOUBLE sum of this engine is, against a float64 reference under a
+stated limit (the single-chip answer already depends on how the rows
+are cut into batches and slices). tests/test_mesh_aggregate.py pins
+both halves on the CPU test mesh, and the benchmark's cell `q1-mesh4`
+holds the four-chip host to them on every run.
+
+Three kinds of consumer take sharded input. The narrow pipeline
+(filter / project / masked ops) and the ICI shuffle exchange are
+elementwise or pure data movement: GSPMD partitions them and their
+results are bitwise independent of the layout. The hash aggregate runs
+its fast kernel on each chip's own rows and exchanges only the shards'
+partial groups (execs/aggregate.py ``_shards_of`` says which batches:
+a fast layout with a small group domain, no position-dependent
+expression, no nested column); a batch it refuses it re-lands itself,
+through :func:`reland`. The coalesce hands a sharded batch on where it
+concatenates nothing and re-lands what it concatenates. Every other
+wide consumer (sort, join, window, ...) is NOT layout-independent and
+has no merge decomposition here, so it takes its input through a
+:class:`TpuMeshRelandExec` boundary inserted at conversion time: one
+device-side gather (ICI on a real pod — the host is never touched,
+pinned by RL-MESH-HOST and the meshHostUploads counter) that re-lands
+the shards into the single-device layout the wide kernel compiles
+against.
 
 Post-exchange inputs are already per-device (the all-to-all emits each
 partition on its owner device), so the boundary is a no-op there — the
@@ -107,22 +123,34 @@ class TpuMeshRelandExec(TpuExec):
 
     def execute(self):
         for b in self.children[0].execute():
-            yield self._reland(b)
+            yield reland(self, b)
 
     def execute_masked(self):
         for b in self.children[0].execute_masked():
-            yield self._reland(b)
+            yield reland(self, b)
 
-    def _reland(self, table: DeviceTable) -> DeviceTable:
-        # count only PHYSICAL gathers: unsharded() also returns a new
-        # object when it merely drops a shard_spec descriptor from
-        # single-device buffers (1-device mesh) — no data moved there
+    def describe(self):
+        return "MeshReland"
+
+
+def reland(node: TpuExec, table: DeviceTable) -> DeviceTable:
+    """``table`` in the single-device layout: the verified gather of a
+    physically sharded table, its rows and re-gathers counted on
+    ``node``. THE re-land of every consumer: the boundary's own
+    batches, the aggregate's batches that ``_shards_of`` refuses, the
+    coalesce's batches that it concatenates. The gather is the range
+    ``srt.mesh.reland`` and the query's ``phasesS.relandS``."""
+    # only PHYSICAL gathers count: unsharded() also returns a new
+    # object when it merely drops a shard_spec descriptor from
+    # single-device buffers (1-device mesh) — no data moved there
+    if not (table.columns and table.physically_sharded()):
+        return table if table.shard_spec is None else table.unsharded()
+    from spark_rapids_tpu.dispatch import phase_span
+    with phase_span("relandS", "reland", "mesh"):
         from spark_rapids_tpu.runtime.faults import fault_point
-        if not (table.physically_sharded() and table.columns):
-            return table.unsharded()
         from spark_rapids_tpu.parallel import mesh as PM
         from spark_rapids_tpu.parallel.mesh import MESH_SCOPE, mesh_gather
-        self.add_metric("meshRelandRows", table.capacity)
+        node.add_metric("meshRelandRows", table.capacity)
         MESH_SCOPE.add("meshRelandRows", table.capacity)
         # crash / device_lost / slow fire here, BEFORE the gather (the
         # ladder's mesh.gather injection site); corrupt is consumed by
@@ -156,7 +184,7 @@ class TpuMeshRelandExec(TpuExec):
             if int(pair[0]) == int(pair[1]):
                 return out
             MESH_SCOPE.add("gatherChecksFailed", 1)
-            self.add_metric("gatherChecksFailed", 1)
+            node.add_metric("gatherChecksFailed", 1)
             if retries >= PM.MAX_SHARD_RETRIES:
                 raise MeshGatherError(
                     f"mesh re-land gather failed its row-count/checksum "
@@ -164,22 +192,26 @@ class TpuMeshRelandExec(TpuExec):
                     f"{int(pair[0])} vs landed {int(pair[1])})")
             retries += 1
             MESH_SCOPE.add("shardRetries", 1)
-            self.add_metric("shardRetries", 1)
-
-    def describe(self):
-        return "MeshReland"
+            node.add_metric("shardRetries", 1)
 
 
 #: consumers that accept physically sharded input: elementwise /
 #: data-movement execs whose results are bitwise layout-independent
 #: (GSPMD partitions them across the resident shards), the ICI
-#: exchange (it re-shards explicitly via shard_put), and the re-land
-#: boundary itself. Everything else sees the single-device layout.
+#: exchange (it re-shards explicitly via shard_put), the hash aggregate
+#: and the coalesce (each re-lands itself what it cannot take sharded:
+#: reland), and the re-land boundary itself. Everything else sees the
+#: single-device layout.
 def _shard_safe_consumers() -> tuple:
-    from spark_rapids_tpu.execs.basic import TpuFilterExec, TpuProjectExec
+    from spark_rapids_tpu.execs.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.execs.basic import (
+        TpuCoalesceExec,
+        TpuFilterExec,
+        TpuProjectExec,
+    )
     from spark_rapids_tpu.execs.exchange import TpuShuffleExchangeExec
     return (TpuFilterExec, TpuProjectExec, TpuShuffleExchangeExec,
-            TpuMeshRelandExec)
+            TpuHashAggregateExec, TpuCoalesceExec, TpuMeshRelandExec)
 
 
 def insert_mesh_relands(executable):

@@ -185,7 +185,7 @@ def _check_mesh_host(rel: str, tree: ast.AST, diags: List[Diagnostic]):
 #: suppression. Table-sized landings are NEVER eligible: they belong
 #: on the arbiter-accounted DeviceTable.from_host path.
 _MEM_ACCOUNT_ALLOWLIST = {
-    "spark_rapids_tpu/execs/mesh.py:TpuMeshRelandExec._reland":
+    "spark_rapids_tpu/execs/mesh.py:reland":
         "re-lands a 4-element uint32 DIGEST scalar (gather-integrity "
         "checksum, ~16 bytes) onto device 0 — validation overhead, "
         "not a table landing; budget accounting at this size would be "

@@ -141,7 +141,13 @@ EVENT_LOG_DIR = str_conf(
 #: coalesce exec's multi-batch flushes (range ``srt.coalesce.flush``:
 #: dictionary checks, the ``jit_coalesce`` dispatch, which dispatchS
 #: counts too); 0.0 for a query whose coalesces only passed batches on.
-EVENT_SCHEMA_VERSION = 13
+#: v14 (mesh aggregate PR): phasesS gains relandS — host seconds inside
+#: mesh re-lands (range ``srt.mesh.reland``: the gather's enqueue, its
+#: two digest programs and the fetch that compares them, which
+#: dispatchS and syncWaitS count too); 0.0 for a query that gathered no
+#: sharded batch to one device (no mesh, or every consumer ran on the
+#: resident shards).
+EVENT_SCHEMA_VERSION = 14
 
 
 def plan_tree(executable) -> dict:

@@ -85,7 +85,10 @@ class If(Expression):
         if prep.aux_slots:
             ad = dev_remap_codes(ctx, prep.aux_slots[0], ad)
             bd = dev_remap_codes(ctx, prep.aux_slots[1], bd)
-        return DevVal(jnp.where(take_a, ad, bd), jnp.where(take_a, a.validity, b.validity))
+        # a decimal128's data is a (rows, 2) limb matrix: the row's choice
+        # covers both limbs (the streaming decimal sum's If(.., NULL, sum))
+        rows = take_a.reshape(take_a.shape + (1,) * (ad.ndim - take_a.ndim))
+        return DevVal(jnp.where(rows, ad, bd), jnp.where(take_a, a.validity, b.validity))
 
 
 class CaseWhen(Expression):

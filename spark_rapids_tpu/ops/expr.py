@@ -402,6 +402,10 @@ class Literal(Expression):
         cap = ctx.capacity
         if isinstance(self._dtype, T.StringType):
             data = jnp.zeros(cap, dtype=jnp.int32)
+        elif self.value is None and T.is_dec128(self._dtype):
+            # a typed NULL in the two-limb layout of its column kind (the
+            # streaming decimal sum's overflow arm: If(.., NULL, sum))
+            data = jnp.zeros((cap, 2), dtype=jnp.int64)
         else:
             fill = self.value if self.value is not None else 0
             data = jnp.full(cap, fill, dtype=self._dtype.np_dtype)

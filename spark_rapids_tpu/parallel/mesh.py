@@ -149,6 +149,14 @@ register_metric("gatherChecksFailed", "count", "ESSENTIAL",
                 "fetch) — each one is a corrupted shard CAUGHT instead "
                 "of served")
 
+register_metric("meshAggBatches", "count", "MODERATE",
+                "batches a hash aggregate ran on their resident row "
+                "shards (one agg_fast_mesh program each: every chip "
+                "aggregates its own rows, only partial groups cross)")
+register_metric("meshAggShards", "count", "MODERATE",
+                "row shards those batches held (meshAggBatches x the "
+                "mesh's devices): the partial tables exchanged")
+
 MESH_SCOPE = metric_scope("mesh")
 
 #: runtime tunables pushed by PlacementLayer.apply_tuning_confs (execs
@@ -470,9 +478,7 @@ class MeshRuntime:
         with self._lock:
             if not self._enabled or self._mesh is None:
                 return "mesh:off"
-            ids = ",".join(str(d.id) for d in self._mesh.devices.flat)
-            return (f"mesh:{'x'.join(map(str, self._dims))}/"
-                    f"{'+'.join(self._axes)}/{ids}")
+            return mesh_token(self._mesh)
 
     # -- sharding ------------------------------------------------------------
     def row_sharding(self):
@@ -529,6 +535,15 @@ class MeshRuntime:
                 flat = list(mesh.devices.flat)[:nparts]
                 return Mesh(np.array(flat), ("data",)), "data"
         return Mesh(np.array(jax.devices()[:nparts]), ("data",)), "data"
+
+def mesh_token(mesh) -> str:
+    """The identity of one jax Mesh as a string: dims, axes, device
+    ids. What MeshRuntime.identity_token reports of the current mesh,
+    and what a program compiled against a batch's own mesh keys on."""
+    ids = ",".join(str(d.id) for d in mesh.devices.flat)
+    return (f"mesh:{'x'.join(map(str, mesh.devices.shape))}/"
+            f"{'+'.join(mesh.axis_names)}/{ids}")
+
 
 #: THE process-wide mesh runtime (device topology is process state, like
 #: the device manager that owns it)

@@ -856,7 +856,7 @@ def test_sliced_aggregate_matches_oracle(session, cpu_session, monkeypatch,
         live = jnp.asarray(live)
     cut = DeviceTable(batch.names, batch.columns, int(keep.sum()),
                       batch.capacity, live=live)
-    assert aggx._slices_of(cut) == 8
+    assert aggx._slices_of(cut)[0] == 8
     got, = _feed(aggx, [cut])
     assert aggx.metrics.get("slicedAggBatches") == 1
     assert aggx.metrics.get("aggSlices") == 8

@@ -27,6 +27,19 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def mesh4(one_chip):
+    """The four chips of the described v5e host as the engine's 2x2
+    mesh: (mesh, row sharding, replicated sharding)."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dcn", "ici"))
+    return (mesh, NamedSharding(mesh, P(("dcn", "ici"))),
+            NamedSharding(mesh, P()))
+
+
 def _shape(one_chip, shape, dtype):
     import jax
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -79,20 +92,25 @@ def _q1_aggregate():
     return exec_, next(iter(exec_.children[0].execute_masked()))
 
 
-def _compile_q1_aggregate(one_chip, cap, slices=1):
+def _compile_q1_aggregate(place, cap, slices=1, mesh=None):
     """Q1's fast kernel over a `cap`-row batch, compiled for the chip:
     the whole-capacity body, or the body of `cap // slices` rows in a
-    loop over the slices (the streaming path's partial specs)."""
+    loop over the slices (the streaming path's partial specs). With
+    `mesh` (`place` is then its (row sharding, replicated sharding)) the
+    body runs on each chip's `cap // chips` rows and the shards' partial
+    groups are exchanged: `agg_fast_mesh`."""
     import jax
     import jax.numpy as jnp
     from spark_rapids_tpu.dispatch import prep_aux
-    from spark_rapids_tpu.execs.aggregate import _over_slices
+    from spark_rapids_tpu.execs.aggregate import _over_shards, _over_slices
     from spark_rapids_tpu.ops.expr import DevVal
     exec_, batch = _q1_aggregate()
     assert exec_.use_split
-    specs = exec_._merge_plan().partial_specs if slices > 1 \
+    shards = 1 if mesh is None else mesh.devices.size
+    by_row, same = place if mesh is not None else (place, place)
+    specs = exec_._merge_plan().partial_specs if slices * shards > 1 \
         else exec_.agg_specs
-    rows = cap // slices
+    rows = cap // (slices * shards)
     pctx, fpre, kpre, vpre = exec_._prep_all(
         batch, exec_.grouping, specs, exec_.filters)
     kinds, sizes, strides, gpad, bases = exec_._fast_layout(
@@ -102,16 +120,19 @@ def _compile_q1_aggregate(one_chip, cap, slices=1):
                                       exec_.grouping, specs, exec_.filters)
     if slices > 1:
         kernel = _over_slices(kernel, slices, rows, gpad)
-    cols = tuple(DevVal(_shape(one_chip, (cap,), c.data.dtype),
-                        _shape(one_chip, (cap,), jnp.bool_))
+    if mesh is not None:
+        kernel = _over_shards(kernel, mesh, by_row.spec[0], shards,
+                              slices * rows, slices * gpad)
+    cols = tuple(DevVal(_shape(by_row, (cap,), c.data.dtype),
+                        _shape(by_row, (cap,), jnp.bool_))
                  for c in batch.columns)
-    aux = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
+    aux = jax.tree.map(lambda a: _shape(same, a.shape, a.dtype),
                        prep_aux(pctx))
     return _compile(
-        kernel, cols, aux, _shape(one_chip, (), jnp.int32),
-        _shape(one_chip, (len(sizes),), jnp.int32),
-        _shape(one_chip, (len(strides),), jnp.int32),
-        _shape(one_chip, (len(bases),), jnp.int64), None)
+        kernel, cols, aux, _shape(same, (), jnp.int32),
+        _shape(same, (len(sizes),), jnp.int32),
+        _shape(same, (len(strides),), jnp.int32),
+        _shape(same, (len(bases),), jnp.int64), None)
 
 
 def test_q1_aggregate_fits_the_chip_at_the_coalesced_capacity(one_chip):
@@ -162,3 +183,28 @@ def test_coalesce_copies_eight_batches_without_a_scatter(one_chip):
     assert memory.output_size_in_bytes >= k * cap * (4 * 9 + 3 * 5)
     assert memory.output_size_in_bytes < k * cap * (4 * 9 + 3 * 5) + 4096
     assert memory.temp_size_in_bytes < 0.1 * GB
+
+
+def test_q1_aggregate_runs_on_the_shards_of_a_four_chip_mesh(mesh4):
+    """`agg_fast_mesh` over a 2^23-row batch row-sharded on the 2x2 mesh
+    is ONE program: each chip takes the 2^21-row body over its own rows
+    (the single-chip body's temporaries, no row crosses a chip) and the
+    only collectives are the exchange of the shards' 16 partial groups:
+    `all_gather` in the program, which the chip's compiler turns into a
+    few combined all-reduces over zero-padded copies (exact: x + 0)."""
+    import re
+    mesh, by_row, same = mesh4
+    compiled = _compile_q1_aggregate((by_row, same), 1 << 23, mesh=mesh)
+    text = compiled.as_text()
+    assert text.count("ENTRY ") == 1
+    collectives = set(re.findall(
+        r" (all-gather|all-reduce|all-to-all|collective-permute|"
+        r"reduce-scatter)(?:-start)?\(", text))
+    assert collectives and collectives <= {"all-gather", "all-reduce"}, \
+        collectives
+    memory = compiled.memory_analysis()
+    # per chip: a quarter of the batch's 7 columns, one body's temporaries
+    assert memory.argument_size_in_bytes < 0.25 * GB
+    assert memory.temp_size_in_bytes < 0.5 * GB, memory.temp_size_in_bytes
+    # four shards' 16 partial groups, 15 columns: a few KB
+    assert memory.output_size_in_bytes < 64 * 1024

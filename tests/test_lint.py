@@ -843,13 +843,11 @@ def test_rl_mem_account():
     # the allowlist hook keys on rel:qualified-function — the mesh
     # re-land's digest-scalar put stays sanctioned with justification
     from spark_rapids_tpu.lint.repo_lint import _MEM_ACCOUNT_ALLOWLIST
-    key = ("spark_rapids_tpu/execs/mesh.py:"
-           "TpuMeshRelandExec._reland")
+    key = "spark_rapids_tpu/execs/mesh.py:reland"
     assert key in _MEM_ACCOUNT_ALLOWLIST
     allow = ("import jax\n"
-             "class TpuMeshRelandExec:\n"
-             "    def _reland(self, t):\n"
-             "        return jax.device_put(t, None)\n")
+             "def reland(node, t):\n"
+             "    return jax.device_put(t, None)\n")
     assert _run_rl(_check_mem_account,
                    "spark_rapids_tpu/execs/mesh.py", allow) == []
 

@@ -67,6 +67,19 @@ def _agg(s):
             .agg(F.sum("x").alias("sx"), F.count("v").alias("c")))
 
 
+def _gathered(s):
+    """An aggregate the mesh does NOT run on the resident shards (a
+    computed key: the sorted path), so its sharded input is re-landed
+    through the verified gather: the ``mesh.gather`` fault site. (_agg's
+    dictionary-keyed fast path aggregates where the shards lie and
+    gathers nothing: execs/aggregate.py ``_shards_of``.)"""
+    from spark_rapids_tpu import functions as F
+    from spark_rapids_tpu.ops.expr import col, lit
+    return (s.create_dataframe(_data())
+            .group_by((col("v") % lit(7)).alias("g"))
+            .agg(F.sum("x").alias("sx"), F.count("v").alias("c")))
+
+
 def _exchange(s):
     """A string-keyed 8-way repartition (the q7 shape): lowers to the
     ICI all-to-all on the 8-device mesh."""
@@ -123,12 +136,12 @@ def test_ici_exchange_corrupt_refetches_counts():
 
 def test_gather_checksum_trip_relands_from_source():
     from spark_rapids_tpu.session import TpuSession
-    expected = _agg(TpuSession()).collect_table()
+    expected = _gathered(TpuSession()).collect_table()
     s = TpuSession({"spark.rapids.mesh.enabled": "true",
                     "spark.rapids.test.faults":
                         "mesh.gather:corrupt:1:13"})
     before = _mesh_scope()
-    got = _agg(s).collect_table()
+    got = _gathered(s).collect_table()
     d = _delta(before, _mesh_scope())
     assert _identical(expected, got) is None
     assert d.get("gatherChecksFailed", 0) >= 1, d
@@ -151,7 +164,7 @@ def test_gather_check_exhaustion_raises_typed():
                     "spark.rapids.test.faults":
                         "mesh.gather:corrupt:99:14"})
     with pytest.raises(MeshGatherError):
-        _agg(s).collect_table()
+        _gathered(s).collect_table()
 
 
 def test_partial_device_loss_walks_ladder_to_shrink(tmp_path):
@@ -164,7 +177,7 @@ def test_partial_device_loss_walks_ladder_to_shrink(tmp_path):
     from spark_rapids_tpu.parallel.mesh import MESH
     from spark_rapids_tpu.runtime.health import HEALTH
     from spark_rapids_tpu.session import TpuSession
-    expected = _agg(TpuSession()).collect_table()
+    expected = _gathered(TpuSession()).collect_table()
     s = TpuSession({"spark.rapids.mesh.enabled": "true",
                     "spark.rapids.sql.eventLog.enabled": "true",
                     "spark.rapids.sql.eventLog.dir": str(tmp_path),
@@ -172,12 +185,12 @@ def test_partial_device_loss_walks_ladder_to_shrink(tmp_path):
                         "mesh.gather:device_lost:3:15"})
     # run 1: loss -> retry -> loss -> single-device re-land (converges
     # suppressed; the suppressed success does NOT reset the ladder)
-    got = _agg(s).collect_table()
+    got = _gathered(s).collect_table()
     assert _identical(expected, got) is None
     assert HEALTH.mesh_snapshot()["meshDegradations"] >= 1
     assert MESH.health_snapshot()["excludedDeviceIds"] == []
     # run 2: the third loss walks the ladder to the SHRINK rung
-    got = _agg(s).collect_table()
+    got = _gathered(s).collect_table()
     assert _identical(expected, got) is None
     snap = MESH.health_snapshot()
     assert snap["excludedDeviceIds"], snap
@@ -189,10 +202,10 @@ def test_partial_device_loss_walks_ladder_to_shrink(tmp_path):
     # the shrink is visible in the event log (meshShape of the landed
     # run) and in explain()
     assert s.last_event_record["meshShape"] == "7"
-    explain = s.explain(_agg(s).plan)
+    explain = s.explain(_gathered(s).plan)
     assert "mesh degraded" in explain and "7-device" in explain
     # ...and keeps serving bit-identically on the smaller mesh
-    got = _agg(s).collect_table()
+    got = _gathered(s).collect_table()
     assert _identical(expected, got) is None
     # quarantine strikes recorded against the template that kept
     # killing mesh execution (below the quarantine threshold here)
@@ -212,18 +225,18 @@ def test_ladder_exhaustion_latches_cpu_only():
                     "spark.rapids.service.deviceLoss.maxReinits": "1",
                     "spark.rapids.test.faults":
                         "mesh.gather:device_lost:6:16"})
-    got1 = _agg(s).collect_table()  # retry -> single-device, converges
+    got1 = _gathered(s).collect_table()  # retry -> single-device, converges
     assert HEALTH.state() == "HEALTHY"
-    got2 = _agg(s).collect_table()  # third loss: no shrink budget ->
+    got2 = _gathered(s).collect_table()  # third loss: no shrink budget ->
     assert HEALTH.state() == "CPU_ONLY"  # reinit budget 1 -> latch
     # the latched process serves the SAME results through the CPU path
     # (baseline re-collected post-latch, like the chaos harness does:
     # the latch is process-wide, so the fresh session is latched too)
-    expected = _agg(TpuSession()).collect_table()
+    expected = _gathered(TpuSession()).collect_table()
     assert _identical(expected, got2) is None
-    assert sorted(got1.to_pydict()["k"]) == sorted(
-        expected.to_pydict()["k"])
-    explain = s.explain(_agg(s).plan)
+    assert sorted(got1.to_pydict()["g"]) == sorted(
+        expected.to_pydict()["g"])
+    explain = s.explain(_gathered(s).plan)
     assert "CPU-only mode latched" in explain
 
 

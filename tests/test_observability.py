@@ -294,9 +294,11 @@ _TPU_JIT_SITES = _tpu_jit_sites()
 
 
 def test_every_tpu_jit_site_is_found():
-    assert len(_TPU_JIT_SITES) >= 36
-    # the sliced aggregate is a program of its own on the device timeline
-    assert {"agg_fast", "agg_fast_sliced"} <= {n for _, n in _TPU_JIT_SITES}
+    assert len(_TPU_JIT_SITES) >= 37
+    # the sliced aggregate and the aggregate on a mesh's shards are
+    # programs of their own on the device timeline
+    assert {"agg_fast", "agg_fast_sliced", "agg_fast_mesh"} <= {
+        n for _, n in _TPU_JIT_SITES}
 
 
 @pytest.mark.parametrize("site,name", _TPU_JIT_SITES,
@@ -377,8 +379,8 @@ def test_event_log_written_and_valid(tmp_path):
     lines = open(s.last_event_path).read().strip().splitlines()
     assert len(lines) == 1
     rec = json.loads(lines[0])
-    # schema v13: phasesS gains coalesceS (the coalesce exec's
-    # multi-batch flushes); v12: the tracing PR added hostSyncs and the
+    # schema v14: phasesS gains relandS (mesh re-lands); v13: coalesceS
+    # (the coalesce exec's multi-batch flushes); v12: the tracing PR added hostSyncs and the
     # dispatch / sync / fetch / semaphore seconds under phasesS (tested
     # below);
     # v11: the streaming PR added the streaming-scope deltas
@@ -390,7 +392,7 @@ def test_event_log_written_and_valid(tmp_path):
     # fault-domain fields, v6's mesh-native fields, v5's
     # transactional-write fields and v4's survivability fields — see
     # obs/events.py
-    assert rec["schema"] == 13
+    assert rec["schema"] == 14
     assert rec["healthState"] == "HEALTHY"
     assert rec["quarantined"] is False
     assert rec["deviceReinits"] == 0 and rec["workerRestarts"] == 0
@@ -501,13 +503,18 @@ def test_event_log_golden_schema(tmp_path):
     v13 = phasesS gains coalesceS (host seconds inside the coalesce
     exec's multi-batch flushes, the range srt.coalesce.flush; its
     jit_coalesce dispatch counts in dispatchS too; 0.0 where every
-    coalesce passed its batches on).
+    coalesce passed its batches on);
+    v14 = phasesS gains relandS (host seconds inside mesh re-lands, the
+    range srt.mesh.reland; 0.0 where no sharded batch was gathered to
+    one device).
     Exec metrics in the plan tree are no schema fields (no bump): every
     TpuHashAggregateExec node carries partialCountReads (partials whose
     row count the streaming loop read to shrink them) and runAheadWaits
     (times its run-ahead bound waited), slicedAggBatches (batches the
     fast kernel walked in slices inside one program) and aggSlices (the
-    slices they held), all 0 for the golden's single-batch aggregate."""
+    slices they held), meshAggBatches (batches aggregated on a mesh's
+    resident shards) and meshAggShards (the shards they held), all 0 for
+    the golden's single-batch aggregate."""
     s = _run_eventlog_query(tmp_path)
     got = _normalize(s.last_event_record)
     golden_path = os.path.join(os.path.dirname(__file__),
@@ -547,7 +554,7 @@ def test_record_counts_the_sliced_aggregates(tmp_path, monkeypatch,
 
 
 _NEW_PHASES = ("parseS", "dispatchS", "syncWaitS", "fetchWaitS",
-               "fetchUnpackS", "semaphoreWaitS", "coalesceS")
+               "fetchUnpackS", "semaphoreWaitS", "coalesceS", "relandS")
 
 
 def _check_phases(rec):
