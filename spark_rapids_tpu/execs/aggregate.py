@@ -37,16 +37,29 @@ kernel compiled at one slice runs in a loop over the batch's rows and the
 slices' partial tables merge like any other partials (_slices_of,
 _over_slices).
 
+Consecutive batches that lay RESIDENT when pulled (a cached table's,
+passing their coalesce) are a stream whose members wait for nothing: up to
+AGG_GROUP of them with one trace key and equal key dictionaries are taken
+by ONE program, the fast kernel once a member on that member's own
+operands (no batch is stacked or copied), their partial groups compacted
+into one partial table: what an enqueue costs the host is the program and
+its results, not its operands. A batch the pull built (an upload, a
+coalesce flush, an upstream exec's output), a sliced batch and the sorted
+path are groups of one: the batch's own program, at once (_pulled,
+_member, _over_members).
+
 A batch that lies row-sharded over the device mesh (a mesh-native scan's)
 is the same stream ACROSS chips: a row shard is a slice that lives on
 another chip. The fast kernel runs under ``shard_map`` on each chip's own
 rows, and only the shards' partial groups cross between chips
-(_shards_of, _over_shards). A batch this does not admit is re-landed on
-one chip first (execs/mesh.py)."""
+(_shards_of, _over_shards); a group of resident sharded batches is one
+such program, every chip running the kernel over its shard of each
+member. A batch this does not admit is re-landed on one chip first
+(execs/mesh.py)."""
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 from spark_rapids_tpu.dispatch import tpu_jit
@@ -91,6 +104,21 @@ SORT_ONLY_AGGS = (agg.CollectList, agg.CollectSet, agg.Percentile)
 #: rows by the kernel compiled at this capacity. Not an option: what is
 #: observed is the batch's capacity.
 AGG_SLICE = 1 << 21
+
+#: batches one program of the streaming aggregate takes at most. What an
+#: enqueue costs the host on a v5e is its RESULTS (about 0.05 ms each on
+#: one chip, 0.08 over four), hardly its operands: one compacted partial
+#: of 27 results costs 1.6, 1.7, 1.8 and 2.0 ms for 1, 4, 8 and 16 bodies
+#: on one chip and 3.0 ms for 1 to 8 over four (PERF.md section 6, PR
+#: 32), so every body after the first is nearly free to the host. Four,
+#: because the device starts only when the first group is enqueued (16
+#: batches take 30.8 ms of wall at 4 bodies, 31.4 at 8, 31.7 at 16; over
+#: four chips 2, 4 and 8 read alike) and the unrolled program's cold
+#: compile grows with its bodies (7.7 / 13.0 / 28.6 s at 4 / 8 / 16 on
+#: one chip's host, 10.2 / 15.2 s at 4 / 8 over four, where one body
+#: takes 6.8 and the four-chip cell's first run has 34 s to spare). Not
+#: an option: what is observed is where the batches lie.
+AGG_GROUP = 4
 
 
 _M32 = 0xFFFFFFFF
@@ -177,6 +205,24 @@ def _dec128_minmax_segments(is_min, sd, sv, gid, nseg, has_any):
     return (data, has_any)
 
 
+def _compact_parts(outs, ngroups, gpad: int, scope: str):
+    """The partial groups of several parts (slices, shards, a group's
+    members) compacted to one prefix, in part order: ``outs`` holds each
+    output column as (data, validity) of ``[parts..., gpad]`` rows,
+    ``ngroups`` each part's group count (a part's groups are a prefix of
+    its gpad rows). Returns what a fast kernel returns, at ``parts *
+    gpad`` rows."""
+    from spark_rapids_tpu.ops.scatter32 import compact_pairs
+    total = ngroups.size * gpad
+    exists = (jnp.arange(gpad, dtype=jnp.int32)
+              < ngroups.reshape(-1)[:, None]).reshape(-1)
+    with jax.named_scope(scope):
+        outs, count = compact_pairs(
+            [d.reshape((total,) + d.shape[v.ndim:]) for d, v in outs],
+            [v.reshape(-1) for _, v in outs], exists, total)
+    return list(outs), count
+
+
 def _over_slices(kernel, slices: int, rows: int, gpad: int):
     """A fast kernel built at ``rows`` rows, run over a batch of
     ``slices * rows`` rows inside one program: a scan over the slices'
@@ -185,8 +231,6 @@ def _over_slices(kernel, slices: int, rows: int, gpad: int):
     mask. Returns what the kernel returns, at ``slices * gpad`` rows:
     every slice's partial groups compacted to one prefix, and their
     count."""
-    from spark_rapids_tpu.ops.scatter32 import compact_pairs
-
     def sliced(cols, aux, nrows, sizes, strides, bases, live_in):
         def step(_, start):
             # read in place: scanning over the columns reshaped to
@@ -200,19 +244,49 @@ def _over_slices(kernel, slices: int, rows: int, gpad: int):
 
         _, (outs, ngroups) = jax.lax.scan(
             step, None, jnp.arange(slices, dtype=jnp.int32) * rows)
-        # a slice's groups are a prefix of its gpad rows
-        exists = (jnp.arange(gpad, dtype=jnp.int32)
-                  < ngroups[:, None]).reshape(-1)
-        with jax.named_scope("compact_slices"):
-            outs, total = compact_pairs(
-                [d.reshape((slices * gpad,) + d.shape[2:]) for d, _ in outs],
-                [v.reshape(-1) for _, v in outs], exists, slices * gpad)
-        return list(outs), total
+        return _compact_parts(outs, ngroups, gpad, "compact_slices")
 
     return sliced
 
 
-def _over_shards(kernel, mesh, row_axes, shards: int, rows: int, gpad: int):
+def _one_at_a_time(kernel, batches):
+    """``kernel`` over each of a group's members in turn, one body at a
+    time: a member's operands wait behind the partial before it (an
+    ``optimization_barrier``: no copy, no operation), so the compiler
+    schedules the bodies one after another and a body's temporaries
+    stay its own. Left free it interleaves the bodies' phases and holds
+    every body's temporaries at once (0.79 GB for 0.32 at 4 bodies of
+    Q1's, and 3.6% more device time at 16: PERF.md, PR 32). Returns the
+    members' partials stacked: each output column as (data, validity)
+    of ``[members, gpad]`` rows, and the members' group counts."""
+    parts = []
+    for b in batches:
+        if parts:
+            b, parts[-1] = jax.lax.optimization_barrier((b, parts[-1]))
+        parts.append(kernel(*b))
+    outs = [(jnp.stack([outs[i][0] for outs, _ in parts]),
+             jnp.stack([outs[i][1] for outs, _ in parts]))
+            for i in range(len(parts[0][0]))]
+    return outs, jnp.stack([n for _, n in parts])
+
+
+def _over_members(kernel, gpad: int):
+    """A fast kernel run over each of a group's batches inside one
+    program, each on its own operands where they lie (a member is the
+    kernel's argument tuple: no batch is stacked, sliced or copied).
+    Returns what the kernel returns, at ``members * gpad`` rows: every
+    member's partial groups compacted to one prefix in member order,
+    and their count: one set of results a program, because what an
+    enqueue costs the host is its results (PERF.md, PR 32)."""
+    def grouped(*batches):
+        outs, ngroups = _one_at_a_time(kernel, batches)
+        return _compact_parts(outs, ngroups, gpad, "compact_members")
+
+    return grouped
+
+
+def _over_shards(kernel, mesh, row_axes, shards: int, rows: int, gpad: int,
+                 members: int = 1):
     """A fast kernel built at ``rows`` rows (or ``_over_slices`` of one:
     ``gpad`` is then the slices' total), run on each of ``shards`` row
     shards where it lies: under ``shard_map`` over the mesh's row axes
@@ -222,30 +296,44 @@ def _over_shards(kernel, mesh, row_axes, shards: int, rows: int, gpad: int):
     cross chips: an ``all_gather`` of ``gpad`` rows a shard, in shard
     order, compacted to one prefix as ``_over_slices`` compacts its
     slices. Returns what the kernel returns, at ``shards * gpad`` rows,
-    the same on every chip."""
+    the same on every chip. With ``members`` > 1 the program takes that
+    many batches (``_over_members``): every chip runs the kernel over
+    its shard of each, one body at a time, the members' partial groups
+    cross in one exchange and come back at ``members * shards * gpad``
+    rows in (member, shard) order."""
     from jax.sharding import PartitionSpec as P
-    from spark_rapids_tpu.ops.scatter32 import compact_pairs
     by_row, same = P(row_axes), P()
+    member_specs = (by_row, same, same, same, same, same, by_row)
+
+    def shard_rows(nrows):
+        return jnp.clip(nrows - jax.lax.axis_index(row_axes) * rows, 0, rows)
 
     def on_shard(cols, aux, nrows, sizes, strides, bases, live_in):
-        first = jax.lax.axis_index(row_axes) * rows
-        outs, ngroups = kernel(cols, aux, jnp.clip(nrows - first, 0, rows),
+        outs, ngroups = kernel(cols, aux, shard_rows(nrows),
                                sizes, strides, bases, live_in)
         with jax.named_scope("exchange_partials"):
             outs, ngroups = jax.lax.all_gather((list(outs), ngroups),
                                                row_axes)
-        # a shard's groups are a prefix of its gpad rows
-        exists = (jnp.arange(gpad, dtype=jnp.int32)
-                  < ngroups[:, None]).reshape(-1)
-        with jax.named_scope("compact_shards"):
-            outs, total = compact_pairs(
-                [d.reshape((shards * gpad,) + d.shape[2:]) for d, _ in outs],
-                [v.reshape(-1) for _, v in outs], exists, shards * gpad)
-        return list(outs), total
+        return _compact_parts(outs, ngroups, gpad, "compact_shards")
 
+    def on_shard_group(*batches):
+        outs, ngroups = _one_at_a_time(kernel, [
+            (cols, aux, shard_rows(nrows), sizes, strides, bases, live_in)
+            for cols, aux, nrows, sizes, strides, bases, live_in in batches])
+        # the members' partials cross in one exchange and lie [shards,
+        # members, gpad] on every chip
+        with jax.named_scope("exchange_partials"):
+            outs, ngroups = jax.lax.all_gather((outs, ngroups), row_axes)
+        return _compact_parts(
+            [(jnp.swapaxes(d, 0, 1), jnp.swapaxes(v, 0, 1)) for d, v in outs],
+            jnp.swapaxes(ngroups, 0, 1), gpad, "compact_shards")
+
+    if members == 1:
+        return jax.shard_map(
+            on_shard, mesh=mesh, in_specs=member_specs,
+            out_specs=same, check_vma=False)
     return jax.shard_map(
-        on_shard, mesh=mesh,
-        in_specs=(by_row, same, same, same, same, same, by_row),
+        on_shard_group, mesh=mesh, in_specs=(member_specs,) * members,
         out_specs=same, check_vma=False)
 
 
@@ -269,6 +357,46 @@ def _sortable(data, validity):
     from spark_rapids_tpu.ops.ordering import comparable_operands, zero_invalid
     return ([(~validity).astype(jnp.int32)]
             + comparable_operands(zero_invalid(data, validity)))
+
+
+class _Member(NamedTuple):
+    """One batch as the aggregate's program takes it (_member): the
+    program's operands, the keys its program is found under, and what
+    the output's columns are built from."""
+
+    table: DeviceTable
+    args: tuple
+    schema: tuple
+    tkey: tuple
+    fast: Optional[tuple]
+    filter_preps: list
+    key_preps: list
+    val_preps: list
+    slices: int
+    shards: int
+    body_rows: int
+
+    def _output_dictionaries(self):
+        """What the output's columns carry beside the program's results:
+        each key's dictionary and domain, each string result's
+        dictionary."""
+        for preps in self.key_preps:
+            yield preps[-1].out_dict, preps[-1].out_domain
+        for per_child in self.val_preps:
+            if per_child:
+                yield per_child[-1][-1].out_dict, None
+
+    def joins(self, head: "_Member") -> bool:
+        """May one program aggregate this batch and ``head``, into one
+        partial table? The same program (schema and trace key), and the
+        same codes in every dictionary-coded output column, as the
+        coalesce asks before it concatenates without a union."""
+        from spark_rapids_tpu.columnar.table import _same_dictionary
+        return (self.schema == head.schema and self.tkey == head.tkey
+                and all(_same_dictionary(d0, d) and domain0 == domain
+                        for (d0, domain0), (d, domain) in zip(
+                            head._output_dictionaries(),
+                            self._output_dictionaries())))
 
 
 class TpuHashAggregateExec(TpuExec):
@@ -300,11 +428,8 @@ class TpuHashAggregateExec(TpuExec):
         from spark_rapids_tpu.runtime.retry import retry_block
         from spark_rapids_tpu.runtime.spill import BufferCatalog, SpillableBatch
 
-        from spark_rapids_tpu.columnar.table import merge_split_views
         from spark_rapids_tpu.parallel.mesh import MESH_SCOPE
-        # aggregation is partition-structure-blind: a repartition's
-        # same-split views mask-union back into one batch (no data moves)
-        it = merge_split_views(self.children[0].execute_masked())
+        it = self._pulled(iter(self.children[0].execute_masked()))
         # the node's record carries both counts of the streaming loop,
         # 0 where it read no partial's count and never waited
         self.add_metric("partialCountReads", 0)
@@ -317,12 +442,16 @@ class TpuHashAggregateExec(TpuExec):
         # aggregated where they lay, and the shards they held
         self.add_metric("meshAggBatches", 0)
         self.add_metric("meshAggShards", 0)
+        # and of the grouped aggregate: programs enqueued for two or
+        # more resident batches, and the batches they held
+        self.add_metric("groupedAggPrograms", 0)
+        self.add_metric("groupedAggBatches", 0)
         first = next(it, None)
         if first is None:
             return
         second = next(it, None)
         # every batch is placed once: on its shards, or on one chip
-        head = self._placed(first)
+        head = self._placed(*first)
         if second is None and head[1:3] == (1, 1):
             # single batch in one body: aggregate directly
             # (spill-and-replay on OOM)
@@ -339,6 +468,10 @@ class TpuHashAggregateExec(TpuExec):
         # Chan-style moment combination), followed by a finalize
         # projection (avg = s/n, ...). A single batch of several slices
         # is the same stream: its slices' partials merge below.
+        # Consecutive batches that lay resident when pulled aggregate
+        # several to a program (AGG_GROUP): they wait for nothing, so
+        # nothing is lost by enqueueing them together, and what a
+        # program's enqueue costs the host does not grow with them.
         plan = self._merge_plan()
         catalog = BufferCatalog.get()
         partials = []
@@ -347,10 +480,83 @@ class TpuHashAggregateExec(TpuExec):
         #: (count, input bytes) of the partials enqueued and not yet
         #: known complete, oldest first (_bound_run_ahead)
         ahead = deque()
+        #: the open group: resident batches that one program will take
+        group: List[_Member] = []
+        may_group = not self._reads_positions(
+            self.grouping, plan.partial_specs, self.filters)
+
+        def aggregate(members):
+            return self._aggregate(
+                members[0].table, self.grouping, plan.partial_specs,
+                self.grouping_names, self.filters,
+                shards=members[0].shards, members=members)
+
+        def enqueued(members):
+            """(partial, the batches it holds) of ``members`` in one
+            program; where its OOM persists after the replay, of each
+            member alone."""
+            from spark_rapids_tpu.runtime.retry import FatalDeviceOOM
+            try:
+                return [(retry_block(lambda: aggregate(members)),
+                         [m.table for m in members])]
+            except FatalDeviceOOM:
+                if len(members) == 1:
+                    raise
+            return [(retry_block(lambda m=m: aggregate([m])), [m.table])
+                    for m in members]
+
+        def keep(pt, batches):
+            # A partial's row count stays a device scalar
+            # (concat_device and the merge take it as one): reading
+            # it stalls the host until the batch's kernel has run,
+            # and nothing further is enqueued meanwhile. It is read
+            # only where the read can free something. THE CAPACITY
+            # RULE: the merge concat below buckets the SUM of
+            # partial capacities, so a chunked scan's N
+            # full-capacity partials would concat into an N-fold
+            # over-capacity table, exactly the over-budget resident
+            # the out-of-core contract forbids. A partial is kept
+            # as it is only while it is smaller than its input (the
+            # fast path's domain-sized output, not the sorted
+            # path's input-sized one), under EMBED_NROWS_CAP rows
+            # (where the sorted path's speculative capacities
+            # start: such a partial's batch costs far more than a
+            # sync, and so would a sorted merge over it), and the
+            # kept capacities together stay within one input
+            # batch's, so the merge costs at most one more batch.
+            # Any other partial is shrunk to its live-group bucket
+            # at the price of one row-count sync (shrink() reads
+            # nothing where no smaller bucket exists).
+            nonlocal kept_capacity
+            if not pt.num_rows_known:
+                if (pt.capacity < sum(b.capacity for b in batches)
+                        and pt.capacity < DeviceTable.EMBED_NROWS_CAP
+                        and kept_capacity + pt.capacity
+                        <= batches[0].capacity):
+                    kept_capacity += pt.capacity
+                else:
+                    pt = pt.shrink()
+            if pt.num_rows_known:
+                # read here, or by _aggregate's own shrink (the
+                # sorted path): the device is done up to this one
+                self.add_metric("partialCountReads", 1)
+                ahead.clear()
+            else:
+                # one entry a program: its batches are done together
+                ahead.append((pt.nrows_dev,
+                              sum(b.device_nbytes() for b in batches)))
+            partials.append(SpillableBatch(pt, catalog))
+            self.add_metric("partialAggBatches", len(batches))
+
+        def flush():
+            for pt, batches in enqueued(group):
+                keep(pt, batches)
+            group.clear()
+
         try:
             rest = it if second is None else chain([second], it)
-            for batch, shards, slices, fast in chain(
-                    [head], map(self._placed, rest)):
+            for batch, shards, slices, fast, resident in chain(
+                    [head], (self._placed(*pulled) for pulled in rest)):
                 if shards > 1:
                     self.add_metric("meshAggBatches", 1)
                     self.add_metric("meshAggShards", shards)
@@ -359,51 +565,33 @@ class TpuHashAggregateExec(TpuExec):
                 if slices > 1:
                     self.add_metric("slicedAggBatches", 1)
                     self.add_metric("aggSlices", shards * slices)
-                pt = retry_block(
-                    lambda b=batch, n=slices, m=shards, f=fast:
-                    self._aggregate(
-                        b, self.grouping, plan.partial_specs,
-                        self.grouping_names, self.filters, slices=n,
-                        shards=m, fast=f))
-                # A partial's row count stays a device scalar
-                # (concat_device and the merge take it as one): reading
-                # it stalls the host until the batch's kernel has run,
-                # and nothing further is enqueued meanwhile. It is read
-                # only where the read can free something. THE CAPACITY
-                # RULE: the merge concat below buckets the SUM of
-                # partial capacities, so a chunked scan's N
-                # full-capacity partials would concat into an N-fold
-                # over-capacity table, exactly the over-budget resident
-                # the out-of-core contract forbids. A partial is kept
-                # as it is only while it is smaller than its input (the
-                # fast path's domain-sized output, not the sorted
-                # path's input-sized one), under EMBED_NROWS_CAP rows
-                # (where the sorted path's speculative capacities
-                # start: such a partial's batch costs far more than a
-                # sync, and so would a sorted merge over it), and the
-                # kept capacities together stay within one input
-                # batch's, so the merge costs at most one more batch.
-                # Any other partial is shrunk to its live-group bucket
-                # at the price of one row-count sync (shrink() reads
-                # nothing where no smaller bucket exists).
-                if not pt.num_rows_known:
-                    if (pt.capacity < batch.capacity
-                            and pt.capacity < DeviceTable.EMBED_NROWS_CAP
-                            and kept_capacity + pt.capacity
-                            <= batch.capacity):
-                        kept_capacity += pt.capacity
-                    else:
-                        pt = pt.shrink()
-                if pt.num_rows_known:
-                    # read here, or by _aggregate's own shrink (the
-                    # sorted path): the device is done up to this one
-                    self.add_metric("partialCountReads", 1)
-                    ahead.clear()
+                # what may wait for its neighbours: a batch the pull
+                # found resident (nothing was built for it, so nothing
+                # is held for it), in one body, on the fast layout
+                member = None
+                if resident and slices == 1 and may_group:
+                    member = self._member(
+                        batch, self.grouping, plan.partial_specs,
+                        self.filters, 1, shards, fast)
+                    if not member.fast:
+                        member = None
+                if group and (member is None or not member.joins(group[0])):
+                    flush()
+                if member is None:
+                    pt = retry_block(
+                        lambda b=batch, n=slices, m=shards, f=fast:
+                        self._aggregate(
+                            b, self.grouping, plan.partial_specs,
+                            self.grouping_names, self.filters, slices=n,
+                            shards=m, fast=f))
+                    keep(pt, [batch])
                 else:
-                    ahead.append((pt.nrows_dev, batch.device_nbytes()))
-                partials.append(SpillableBatch(pt, catalog))
-                self.add_metric("partialAggBatches", 1)
+                    group.append(member)
+                    if len(group) == AGG_GROUP:
+                        flush()
                 self._bound_run_ahead(ahead)
+            if group:
+                flush()
 
             from spark_rapids_tpu.columnar.table import concat_device
 
@@ -662,12 +850,8 @@ class TpuHashAggregateExec(TpuExec):
         shards on their chips) whose partial groups merge: _slices_of's
         conditions but the capacity's. None where it may not."""
         from spark_rapids_tpu.ops import segsum as _ss
-        from spark_rapids_tpu.ops.expr import has_position_dependent
-        if any(c.is_nested for c in table.columns):
-            return None
-        exprs = (self.grouping + self.filters
-                 + [c for _, fn in self.agg_specs for c in fn.children])
-        if any(has_position_dependent(e) for e in exprs):
+        if any(c.is_nested for c in table.columns) or self._reads_positions(
+                self.grouping, self.agg_specs, self.filters):
             return None
         fast = self._fast_layout(
             self.grouping, _preps(self.grouping, PrepCtx(table)), rows)
@@ -704,33 +888,63 @@ class TpuHashAggregateExec(TpuExec):
             return 0, 0, None
         return shards, slices, fast
 
-    def _placed(self, batch: DeviceTable) -> tuple:
+    @staticmethod
+    def _pulled(batches):
+        """(batch, whether it lay resident when pulled) of each batch of
+        ``batches``, a repartition's same-split views mask-unioned back
+        into one batch (columnar/table.merge_split_views: aggregation
+        is partition-structure-blind, and no data moves). Resident: the
+        pull enqueued no program and the arbiter granted no landing, so
+        nothing was uploaded, copied or computed for the batch (a
+        cached scan's image passing through). What the pull BUILT is
+        not: it is aggregated at once."""
+        from spark_rapids_tpu.columnar.table import (
+            mergeable_views,
+            union_views,
+        )
+        from spark_rapids_tpu.dispatch import dispatch_count
+        from spark_rapids_tpu.runtime.memory import MEMORY
+        held = None
+        while True:
+            before = dispatch_count(), MEMORY.landings()
+            batch = next(batches, None)
+            if batch is None:
+                break
+            resident = (dispatch_count(), MEMORY.landings()) == before
+            if held is not None and mergeable_views(held[0], batch):
+                held = union_views(held[0], batch), False
+                continue
+            if held is not None:
+                yield held
+            held = batch, resident
+        if held is not None:
+            yield held
+
+    def _placed(self, batch: DeviceTable, resident: bool = False) -> tuple:
         """(``batch`` where the fast kernel takes it, its row shards,
-        the slices a shard, the parts' fast layout): on its shards where
+        the slices a shard, the parts' fast layout, whether it lies
+        where the pull found it resident): on its shards where
         _shards_of admits it, else gathered to one chip
         (execs/mesh.reland), where _slices_of counts."""
         from spark_rapids_tpu.execs.mesh import reland
         shards, slices, fast = self._shards_of(batch)
         if not shards:
-            batch, shards = reland(self, batch), 1
+            landed, shards = reland(self, batch), 1
+            resident = resident and landed is batch
+            batch = landed
             slices, fast = self._slices_of(batch)
-        return batch, shards, slices, fast
+        return batch, shards, slices, fast, resident
 
-    def _aggregate(self, table: DeviceTable, grouping, agg_specs,
-                   grouping_names, filters, slices: int = 1,
-                   shards: int = 1, fast=None) -> DeviceTable:
-        """One aggregation of ``table``. ``slices`` > 1 (_slices_of) or
-        ``shards`` > 1 (_shards_of, ``slices`` then counts a shard's;
-        the caller merges what comes back): the fast kernel runs once a
-        slice of every shard and the output holds every part's partial
-        groups, on one device. ``fast`` is the parts' layout where
-        _placed found one already."""
-        if table.live is not None:
-            from spark_rapids_tpu.ops.expr import has_position_dependent
-            exprs = (list(grouping) + list(filters)
-                     + [c for _, fn in agg_specs for c in fn.children])
-            if any(has_position_dependent(e) for e in exprs):
-                table = table.compacted()  # slot ids must match prefix form
+    def _member(self, table: DeviceTable, grouping, agg_specs, filters,
+                slices: int = 1, shards: int = 1, fast=None) -> "_Member":
+        """The host's part of one aggregation of ``table``: the prep
+        pass, the small operands where the program takes them, the
+        layout and the program's trace key. Enqueues nothing (but a
+        masked batch's compaction where an expression reads a row's
+        position)."""
+        if table.live is not None and self._reads_positions(
+                grouping, agg_specs, filters):
+            table = table.compacted()  # slot ids must match prefix form
         pctx, filter_preps, key_preps, val_preps = self._prep_all(
             table, grouping, agg_specs, filters)
         from spark_rapids_tpu.dispatch import (
@@ -745,8 +959,8 @@ class TpuHashAggregateExec(TpuExec):
         everywhere = None
         if shards > 1:
             from jax.sharding import NamedSharding, PartitionSpec
-            mesh = table.shard_spec.mesh
-            everywhere = NamedSharding(mesh, PartitionSpec())
+            everywhere = NamedSharding(table.shard_spec.mesh,
+                                       PartitionSpec())
         aux = prep_aux(pctx, everywhere)
         capacity = table.capacity
         #: rows the kernel body is built at: the batch, or one slice
@@ -756,13 +970,6 @@ class TpuHashAggregateExec(TpuExec):
         if fast is None:
             fast = self._fast_layout(grouping, key_preps, body_rows)
 
-        from spark_rapids_tpu.ops.expr import shared_traces
-        self._traces = shared_traces(
-            ("agg",
-             tuple(g.key() for g in grouping),
-             tuple(fn.key() for _, fn in agg_specs),
-             tuple(f.key() for f in filters),
-             table.schema_key()[0]))
         from spark_rapids_tpu.ops import segsum as _ss
         mode_key = ("fast", fast[0], fast[3]) if fast else ("sorted",)
         if slices > 1:
@@ -770,41 +977,17 @@ class TpuHashAggregateExec(TpuExec):
         if shards > 1:
             from spark_rapids_tpu.parallel.mesh import mesh_token
             mode_key = ("fast_mesh", fast[0], fast[3], slices, shards,
-                        mesh_token(mesh))
-        has_mask = table.live is not None
+                        mesh_token(table.shard_spec.mesh))
         tkey = (capacity, self.use_split, _ss.trace_key(),
-                mode_key, has_mask,
+                mode_key, table.live is not None,
                 tuple(_prep_trace_key(p) for p in filter_preps),
                 tuple(_prep_trace_key(p) for p in key_preps),
                 tuple(tuple(_prep_trace_key(p) for p in per_child)
                       for per_child in val_preps))
-        fn = self._traces.get(tkey)
-        if fn is None:
-            if fast:
-                kernel = self._build_fast_kernel(
-                    body_rows, fast[0], fast[3], filter_preps, key_preps,
-                    val_preps, grouping, agg_specs, filters)
-                if slices > 1:
-                    kernel = _over_slices(kernel, slices, body_rows, fast[3])
-                if shards > 1:
-                    fn = tpu_jit(_over_shards(
-                        kernel, mesh, table.shard_spec.spec[0], shards,
-                        slices * body_rows, slices * fast[3]),
-                        name="agg_fast_mesh")
-                elif slices > 1:
-                    fn = tpu_jit(kernel, name="agg_fast_sliced")
-                else:
-                    fn = tpu_jit(kernel, name="agg_fast")
-            else:
-                fn = tpu_jit(self._build_kernel(
-                    capacity, filter_preps, key_preps, val_preps,
-                    grouping, agg_specs, filters), name="agg_sorted")
-            self._traces[tkey] = fn
-
-        if fast:
-            _, sizes, strides, gpad, bases = fast
-            if _ss.takes_contraction(gpad, body_rows):
-                self.add_metric("countsByContraction", 1)
+        if not fast:
+            args = (cols, aux, table.nrows_dev, table.live)
+        else:
+            _, sizes, strides, _, bases = fast
             nrows = table.nrows_dev
             if shards > 1:
                 # a count the host knows is interned replicated; one
@@ -812,13 +995,94 @@ class TpuHashAggregateExec(TpuExec):
                 nrows = device_scalar(table.num_rows, sharding=everywhere) \
                     if table.num_rows_known \
                     else device_const(nrows, everywhere)
-            out_arrays, ngroups = fn(
+            args = (
                 cols, aux, nrows,
                 device_const(np.asarray(sizes, dtype=np.int32), everywhere),
                 device_const(np.asarray(strides, dtype=np.int32), everywhere),
                 device_const(np.asarray(bases, dtype=np.int64), everywhere),
                 table.live)
-            out_capacity = shards * slices * gpad
+        return _Member(table, args, table.schema_key()[0], tkey, fast,
+                       filter_preps, key_preps, val_preps, slices, shards,
+                       body_rows)
+
+    @staticmethod
+    def _reads_positions(grouping, agg_specs, filters) -> bool:
+        from spark_rapids_tpu.ops.expr import has_position_dependent
+        return any(has_position_dependent(e) for e in (
+            list(grouping) + list(filters)
+            + [c for _, fn in agg_specs for c in fn.children]))
+
+    def _aggregate(self, table: DeviceTable, grouping, agg_specs,
+                   grouping_names, filters, slices: int = 1,
+                   shards: int = 1, fast=None, members=None) -> DeviceTable:
+        """One aggregation of ``table``. ``slices`` > 1 (_slices_of) or
+        ``shards`` > 1 (_shards_of, ``slices`` then counts a shard's;
+        the caller merges what comes back): the fast kernel runs once a
+        slice of every shard and the output holds every part's partial
+        groups, on one device. ``fast`` is the parts' layout where
+        _placed found one already. ``members``: the group that
+        ``table`` is the first batch of, each member as _member gives
+        it, with equal keys and key dictionaries (_Member.joins): ONE
+        program aggregates them all and the output holds every
+        member's partial groups, in member order."""
+        if members is None:
+            members = [self._member(table, grouping, agg_specs, filters,
+                                    slices, shards, fast)]
+        head, count = members[0], len(members)
+        table, fast = head.table, head.fast
+        shards, slices, body_rows = head.shards, head.slices, head.body_rows
+        capacity = table.capacity
+        key_preps, val_preps = head.key_preps, head.val_preps
+
+        from spark_rapids_tpu.ops.expr import shared_traces
+        self._traces = shared_traces(
+            ("agg",
+             tuple(g.key() for g in grouping),
+             tuple(fn.key() for _, fn in agg_specs),
+             tuple(f.key() for f in filters),
+             head.schema))
+        from spark_rapids_tpu.ops import segsum as _ss
+        # a group of one is the batch's own program under its own key
+        tkey = head.tkey if count == 1 else head.tkey + (count,)
+        fn = self._traces.get(tkey)
+        if fn is None:
+            if fast:
+                kernel = self._build_fast_kernel(
+                    body_rows, fast[0], fast[3], head.filter_preps,
+                    key_preps, val_preps, grouping, agg_specs, filters)
+                if slices > 1:
+                    kernel = _over_slices(kernel, slices, body_rows, fast[3])
+                if shards > 1:
+                    # one name whatever the member count: the same work,
+                    # and the benchmark's readers match it exactly
+                    fn = tpu_jit(_over_shards(
+                        kernel, table.shard_spec.mesh,
+                        table.shard_spec.spec[0], shards,
+                        slices * body_rows, slices * fast[3],
+                        members=count), name="agg_fast_mesh")
+                elif count > 1:
+                    fn = tpu_jit(_over_members(kernel, fast[3]),
+                                 name="agg_fast_group")
+                elif slices > 1:
+                    fn = tpu_jit(kernel, name="agg_fast_sliced")
+                else:
+                    fn = tpu_jit(kernel, name="agg_fast")
+            else:
+                fn = tpu_jit(self._build_kernel(
+                    capacity, head.filter_preps, key_preps, val_preps,
+                    grouping, agg_specs, filters), name="agg_sorted")
+            self._traces[tkey] = fn
+
+        if fast:
+            gpad = fast[3]
+            if _ss.takes_contraction(gpad, body_rows):
+                self.add_metric("countsByContraction", 1)
+            out_arrays, ngroups = fn(*head.args) if count == 1 \
+                else fn(*[m.args for m in members])
+            if count > 1:
+                self.add_metric("groupedAggPrograms", 1)
+                self.add_metric("groupedAggBatches", count)
+            out_capacity = count * shards * slices * gpad
             if shards > 1:
                 # every chip holds the gathered partials: what follows
                 # takes the first device's, where it runs on one chip
@@ -828,7 +1092,7 @@ class TpuHashAggregateExec(TpuExec):
                 out_arrays, ngroups = replica_on_first_device(
                     (out_arrays, ngroups))
         else:
-            out_arrays, ngroups = fn(cols, aux, table.nrows_dev, table.live)
+            out_arrays, ngroups = fn(*head.args)
             out_capacity = capacity
 
         out_cols: List[DeviceColumn] = []

@@ -215,6 +215,8 @@ class MemoryArbiter:
         self._next_token = 0
         self._peak = 0
         self._violations = 0
+        #: grants made since the process began (landings())
+        self._landings = 0
         self._metrics = MEM_SCOPE
 
     # -- configuration -------------------------------------------------------
@@ -309,21 +311,32 @@ class MemoryArbiter:
         with self._lock:
             occ = self._reserved + self._ledger_total
             if occ + nbytes <= budget:
-                self._reserved += nbytes
-                self._note_peak_locked()
-                return MemoryReservation(self, nbytes)
+                return self._grant_locked(nbytes)
         self._spill_for(occ + nbytes - budget)
         with self._lock:
             occ = self._reserved + self._ledger_total
             if occ + nbytes <= budget:
-                self._reserved += nbytes
-                self._note_peak_locked()
-                return MemoryReservation(self, nbytes)
+                return self._grant_locked(nbytes)
         self._metrics.add("budgetRaises", 1)
         raise RetryOOM(
             f"device budget exhausted: want {nbytes}B"
             + (f" for {label}" if label else "")
             + f", {occ}/{budget}B accounted — spilling freed no room")
+
+    def _grant_locked(self, nbytes: int) -> MemoryReservation:
+        self._reserved += nbytes
+        self._landings += 1
+        self._note_peak_locked()
+        return MemoryReservation(self, nbytes)
+
+    def landings(self) -> int:
+        """Grants made since the process began. Every host-to-device
+        landing takes one first (DeviceTable.from_host), so a count
+        that did not move says nothing was uploaded meanwhile (the
+        ledger cannot say it: it also accounts views of resident
+        tables, a coalesce's buffered batches)."""
+        with self._lock:
+            return self._landings
 
     def account(self, table,
                 reservation: Optional[MemoryReservation] = None):

@@ -92,24 +92,30 @@ def _q1_aggregate():
     return exec_, next(iter(exec_.children[0].execute_masked()))
 
 
-def _compile_q1_aggregate(place, cap, slices=1, mesh=None):
+def _compile_q1_aggregate(place, cap, slices=1, mesh=None, members=1):
     """Q1's fast kernel over a `cap`-row batch, compiled for the chip:
     the whole-capacity body, or the body of `cap // slices` rows in a
     loop over the slices (the streaming path's partial specs). With
     `mesh` (`place` is then its (row sharding, replicated sharding)) the
     body runs on each chip's `cap // chips` rows and the shards' partial
-    groups are exchanged: `agg_fast_mesh`."""
+    groups are exchanged: `agg_fast_mesh`. With `members` > 1 the
+    program takes that many `cap`-row batches as separate operands: a
+    group of resident batches (`agg_fast_group`, `agg_fast_mesh`)."""
     import jax
     import jax.numpy as jnp
     from spark_rapids_tpu.dispatch import prep_aux
-    from spark_rapids_tpu.execs.aggregate import _over_shards, _over_slices
+    from spark_rapids_tpu.execs.aggregate import (
+        _over_members,
+        _over_shards,
+        _over_slices,
+    )
     from spark_rapids_tpu.ops.expr import DevVal
     exec_, batch = _q1_aggregate()
     assert exec_.use_split
     shards = 1 if mesh is None else mesh.devices.size
     by_row, same = place if mesh is not None else (place, place)
-    specs = exec_._merge_plan().partial_specs if slices * shards > 1 \
-        else exec_.agg_specs
+    specs = exec_._merge_plan().partial_specs \
+        if slices * shards * members > 1 else exec_.agg_specs
     rows = cap // (slices * shards)
     pctx, fpre, kpre, vpre = exec_._prep_all(
         batch, exec_.grouping, specs, exec_.filters)
@@ -122,17 +128,22 @@ def _compile_q1_aggregate(place, cap, slices=1, mesh=None):
         kernel = _over_slices(kernel, slices, rows, gpad)
     if mesh is not None:
         kernel = _over_shards(kernel, mesh, by_row.spec[0], shards,
-                              slices * rows, slices * gpad)
+                              slices * rows, slices * gpad, members=members)
+    elif members > 1:
+        kernel = _over_members(kernel, gpad)
     cols = tuple(DevVal(_shape(by_row, (cap,), c.data.dtype),
                         _shape(by_row, (cap,), jnp.bool_))
                  for c in batch.columns)
     aux = jax.tree.map(lambda a: _shape(same, a.shape, a.dtype),
                        prep_aux(pctx))
-    return _compile(
-        kernel, cols, aux, _shape(same, (), jnp.int32),
+    batch_args = (
+        cols, aux, _shape(same, (), jnp.int32),
         _shape(same, (len(sizes),), jnp.int32),
         _shape(same, (len(strides),), jnp.int32),
         _shape(same, (len(bases),), jnp.int64), None)
+    if members > 1:
+        return _compile(kernel, *[batch_args] * members)
+    return _compile(kernel, *batch_args)
 
 
 def test_q1_aggregate_fits_the_chip_at_the_coalesced_capacity(one_chip):
@@ -207,4 +218,39 @@ def test_q1_aggregate_runs_on_the_shards_of_a_four_chip_mesh(mesh4):
     assert memory.argument_size_in_bytes < 0.25 * GB
     assert memory.temp_size_in_bytes < 0.5 * GB, memory.temp_size_in_bytes
     # four shards' 16 partial groups, 15 columns: a few KB
+    assert memory.output_size_in_bytes < 64 * 1024
+
+
+@pytest.mark.parametrize("on_mesh", [False, True], ids=["one-chip", "2x2"])
+def test_q1_aggregate_takes_a_group_of_batches_one_body_at_a_time(
+        one_chip, mesh4, on_mesh):
+    """Four resident batches in ONE program (`agg_fast_group`; on the mesh
+    `agg_fast_mesh` with four members, each chip on its 2^21-row shard of
+    each): separate operands, no stacked copy, and the bodies scheduled
+    one after another, so the temporaries are one body's (0.32 GB) where
+    bodies left free hold 0.79 GB (PERF.md, PR 32). On the mesh the
+    members' partial groups cross in the same few collectives as one
+    batch's."""
+    import re
+    from spark_rapids_tpu.execs.aggregate import AGG_GROUP
+    assert AGG_GROUP == 4
+    if on_mesh:
+        mesh, by_row, same = mesh4
+        compiled = _compile_q1_aggregate((by_row, same), 1 << 23, mesh=mesh,
+                                         members=AGG_GROUP)
+    else:
+        compiled = _compile_q1_aggregate(one_chip, 1 << 21,
+                                         members=AGG_GROUP)
+    text = compiled.as_text()
+    assert text.count("ENTRY ") == 1
+    collectives = set(re.findall(
+        r" (all-gather|all-reduce|all-to-all|collective-permute|"
+        r"reduce-scatter)(?:-start)?\(", text))
+    assert collectives <= {"all-gather", "all-reduce"}, collectives
+    assert bool(collectives) == on_mesh
+    memory = compiled.memory_analysis()
+    # four batches' 7 columns (a chip's quarter of each on the mesh)
+    assert 0.4 * GB < memory.argument_size_in_bytes < 0.5 * GB
+    assert memory.temp_size_in_bytes < 0.5 * GB, memory.temp_size_in_bytes
+    # 4 members' (x 4 shards') 16 partial groups, 15 columns: a few KB
     assert memory.output_size_in_bytes < 64 * 1024

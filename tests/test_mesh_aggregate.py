@@ -396,3 +396,83 @@ def test_small_operands_are_interned_replicated_and_cleared(
     mesh.close()
     assert D.clear_device_constants() >= len(consts) + len(scalars)
     assert not D._CONST_CACHE and not D._SCALAR_CACHE
+
+
+# ---------------------------------------------------------------------------
+# resident sharded batches aggregate several to a program: one
+# `agg_fast_mesh` for a group, every chip running the body over its shard
+# of each member, the members' partial groups crossing in one exchange
+# ---------------------------------------------------------------------------
+
+def _programs_enqueued(monkeypatch):
+    """Names of the programs `tpu_jit` enqueues from here on, in order."""
+    from spark_rapids_tpu import dispatch
+    names = []
+    real = dispatch.phase_span
+
+    def spy(key, name, cat):
+        if cat == "dispatch":
+            names.append(name)
+        return real(key, name, cat)
+    monkeypatch.setattr(dispatch, "phase_span", spy)
+    return names
+
+
+@pytest.mark.parametrize("batches,cap,programs,held", [
+    (4, 4, 1, 4),
+    (5, 2, 2, 4),
+], ids=["4-in-one", "5-by-2"])
+def test_resident_sharded_batches_aggregate_in_one_mesh_program(
+        tmp_path, lineitem, monkeypatch, batches, cap, programs, held):
+    """Q1 over a table cached on a 2x2 mesh: grouped = one batch at a
+    time (exact columns bit-identical, floats under the cell's limit of
+    each other and of the float64 reference), nothing re-landed, and the
+    record counts the groups."""
+    from benchmarks import compare
+    from benchmarks.reference import q1 as reference
+    from spark_rapids_tpu.execs import aggregate as A
+    monkeypatch.setattr(A, "AGG_GROUP", cap)
+    want = reference.run(lineitem, {"DELTA": 90})
+    text = _q1_text()
+    mesh = _engine(tmp_path / "mesh", "2x2", batches=batches)
+    mesh.register(lineitem)
+    mesh.query(text)             # uploads in the pull: groups of one
+    assert _plan_total(mesh.session.last_event_record["plan"],
+                       "groupedAggPrograms") == 0
+    names = _programs_enqueued(monkeypatch)
+    got, record = mesh.query(text)
+    full = mesh.session.last_event_record
+    monkeypatch.setattr(A, "AGG_GROUP", 1)
+    alone, _ = mesh.query(text)
+    one_at_a_time = mesh.session.last_event_record
+    mesh.close()
+
+    mismatches, gap = compare.compare_answer(got, want)
+    assert mismatches == 0 and gap < FLOAT_LIMIT, (mismatches, gap)
+    for name in EXACT:
+        assert got[name] == alone[name], name
+    assert compare.compare_answer(got, alone)[1] < FLOAT_LIMIT
+
+    from benchmarks import sut
+    assert not sut.off_device_path(record)
+    plan = full["plan"]
+    assert _plan_total(plan, "groupedAggPrograms") == programs
+    assert _plan_total(plan, "groupedAggBatches") == held
+    assert _plan_total(plan, "meshAggBatches") == batches
+    assert _plan_total(plan, "meshAggShards") == 4 * batches
+    assert _plan_total(plan, "meshRelandRows") == 0
+    assert full["phasesS"]["relandS"] == 0.0 and full["hostSyncs"] == 1
+    assert _plan_total(one_at_a_time["plan"], "groupedAggPrograms") == 0
+    saved = held - programs + (1 if batches == held == cap else 0)
+    assert one_at_a_time["dispatches"] - full["dispatches"] == saved
+    # THE NAME IS A CONTRACT: a group of sharded batches is enqueued as
+    # `agg_fast_mesh`, whatever its member count, and as no other
+    # aggregate program (the `agg_fast` is the merge, on one chip). The
+    # benchmark's readers `mesh_agg_device_ms_per_query` and
+    # `mesh_agg_roofline` match the device's `jit_agg_fast_mesh` exactly
+    # (benchmarks/costs_coalesce.program_seconds): under another name the
+    # roofline share that bounds `rows_per_s` on `q1-mesh4` would read 0.
+    grouped_run = names[:full["dispatches"]]
+    partials = batches - held + programs
+    assert [n for n in grouped_run if n.startswith("agg_")] \
+        == ["agg_fast_mesh"] * partials + ["agg_fast"]

@@ -294,10 +294,11 @@ _TPU_JIT_SITES = _tpu_jit_sites()
 
 
 def test_every_tpu_jit_site_is_found():
-    assert len(_TPU_JIT_SITES) >= 37
+    assert len(_TPU_JIT_SITES) >= 38
     # the sliced aggregate and the aggregate on a mesh's shards are
     # programs of their own on the device timeline
-    assert {"agg_fast", "agg_fast_sliced", "agg_fast_mesh"} <= {
+    assert {"agg_fast", "agg_fast_sliced", "agg_fast_mesh",
+            "agg_fast_group"} <= {
         n for _, n in _TPU_JIT_SITES}
 
 
@@ -513,7 +514,9 @@ def test_event_log_golden_schema(tmp_path):
     (times its run-ahead bound waited), slicedAggBatches (batches the
     fast kernel walked in slices inside one program) and aggSlices (the
     slices they held), meshAggBatches (batches aggregated on a mesh's
-    resident shards) and meshAggShards (the shards they held), all 0 for
+    resident shards) and meshAggShards (the shards they held),
+    groupedAggPrograms (programs enqueued for two or more resident
+    batches) and groupedAggBatches (the batches they held), all 0 for
     the golden's single-batch aggregate."""
     s = _run_eventlog_query(tmp_path)
     got = _normalize(s.last_event_record)
@@ -549,6 +552,38 @@ def test_record_counts_the_sliced_aggregates(tmp_path, monkeypatch,
     assert plan_metric_total(s, "partialAggBatches") == 3
     assert plan_metric_total(s, "slicedAggBatches") == batches
     assert plan_metric_total(s, "aggSlices") == slices
+    on_disk = [json.loads(line) for line in open(s.last_event_path)]
+    assert on_disk[-1]["plan"] == rec["plan"]
+
+
+@pytest.mark.parametrize("cap,programs,held", [(4, 1, 4), (1, 0, 0)],
+                         ids=["grouped", "one-at-a-time"])
+def test_record_counts_the_grouped_aggregates(tmp_path, monkeypatch, cap,
+                                              programs, held):
+    """A streamed aggregate over four cached batches, warm: with a cap of
+    4 they are ONE `agg_fast_group` dispatch and the record's plan tree
+    says so, in memory and on disk; with a cap of 1 each is its own
+    `agg_fast` as before and both counts read 0."""
+    from spark_rapids_tpu.execs import aggregate as A
+    from tests.asserts import plan_metric_total
+    from tests.data_gen import DoubleGen, StringGen, gen_table
+    monkeypatch.setattr(A, "AGG_GROUP", cap)
+    s = TpuSession({"spark.rapids.sql.eventLog.enabled": "true",
+                    "spark.rapids.sql.eventLog.dir": str(tmp_path),
+                    "spark.rapids.sql.batchSizeBytes": "1024"})
+    table = gen_table({"k": StringGen(cardinality=5),
+                       "d": DoubleGen(nullable=False)}, 6000, 3)
+    s.create_dataframe(table, num_batches=4) \
+        .create_or_replace_temp_view("grouped_rec")
+    df = s.table("grouped_rec").group_by("k").agg(
+        F.count().alias("n"), F.min("d").alias("m"))
+    df.collect_table()            # uploads: nothing resident yet
+    assert plan_metric_total(s, "groupedAggPrograms") == 0
+    df.collect_table()
+    rec = s.last_event_record
+    assert plan_metric_total(s, "partialAggBatches") == 4
+    assert plan_metric_total(s, "groupedAggPrograms") == programs
+    assert plan_metric_total(s, "groupedAggBatches") == held
     on_disk = [json.loads(line) for line in open(s.last_event_path)]
     assert on_disk[-1]["plan"] == rec["plan"]
 
