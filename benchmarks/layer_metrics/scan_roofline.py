@@ -1,4 +1,4 @@
-"""Kernels (XLA programs and Pallas): the share of the HBM roofline the
+"""Kernels (XLA programs): the share of the HBM roofline the
 queries' device time reaches. The least time the chip could take is the
 bytes the query must read (costs.scan_bytes: rows x landed width of the
 columns its text names) over the chip's peak HBM bandwidth; it is divided

@@ -3,7 +3,9 @@
 scale, with no timing claimed:
 
 - the generator's invariants (dbgen's rules as the spec states them);
-- every query's plain reference against the engine's answer;
+- every query's plain reference against the engine's answer, and on a
+  cell whose table lies in files the four `scan_*` readers on that
+  query's record;
 - `trace_reduce.py` on the small TPU trace recorded in `data/`;
 - `tests/` (the float32 control and the planted faults come out as not
   correct through the harness's own `run_cell`).
@@ -148,7 +150,31 @@ def references_against_the_engine():
                   and not sut.off_device_path(record),
                   f"{cell['name']}: engine = reference for {query_id} "
                   f"(gap {gap:.3g}, {record['dispatches']} dispatches)")
+            if config.get("storage"):
+                scan_readers(cell["name"], record, sum(
+                    tables[t]["num_rows"] for t in config["storage"]))
         engine.close()
+
+
+def scan_readers(cell_name, record, rows):
+    """The four `scan_*` readers on one query's record of a cell whose
+    table lies in files."""
+    got = {}
+    for name in ("scan_ms_per_query", "scan_upload_ms_per_query",
+                 "scan_decode_wait_ms_per_query", "scan_rows_per_query"):
+        reader = importlib.import_module(f"benchmarks.layer_metrics.{name}")
+        got[name] = reader.read({"queries": [{"record": record}]})
+        check(reader.read({"queries": [{"record": {}}, {"error": "x"}]})
+              is None, f"{name}: a record without a file scan gives nothing")
+    check(all(v is not None and v > 0 for v in got.values())
+          and record.get("transferS", 0) > 0,
+          f"{cell_name}: every scan reader and transferS give a value {got}")
+    check(got["scan_rows_per_query"] == rows,
+          f"{cell_name}: scan_rows_per_query = the table's {rows} rows")
+    check(abs(got["scan_upload_ms_per_query"]
+              + got["scan_decode_wait_ms_per_query"]
+              - got["scan_ms_per_query"]) < 1e-6,
+          f"{cell_name}: scan_upload_ms + scan_decode_wait_ms = scan_ms")
 
 
 def trace_reduction():
