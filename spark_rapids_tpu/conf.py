@@ -249,9 +249,10 @@ DPP_ENABLED = bool_conf(
 
 JOIN_DIRECT_TABLE_MULT = int_conf(
     "spark.rapids.tpu.join.directTableMultiplier", 4,
-    "Direct-address join fast path: the key-range table is this multiple "
-    "of the build side's capacity; build key ranges wider than that fall "
-    "back to the sort-based join (speculatively validated).")
+    "Direct-address join body: a build side whose single integer key is "
+    "unique takes it when the key range, read once the build side is "
+    "ready, fits this multiple of the build side's capacity or 2^26 "
+    "slots, whichever is larger; a wider range takes the sorted body.")
 
 SHUFFLE_LOCAL_DEVICE_SPLIT = bool_conf(
     "spark.rapids.shuffle.localDeviceSplit.enabled", True,
@@ -758,6 +759,8 @@ def generate_docs() -> str:
         "name as its XLA module `jit_<program>`), `srt.sync.host_fetch`, "
         "`srt.fetch.resolve|wait|unpack`, `srt.wait.semaphore`, "
         "`srt.coalesce.flush` (a coalesce exec's multi-batch copy), "
+        "`srt.join.build|batch` (a join exec making its build side ready, "
+        "and joining one probe batch), "
         "`srt.mesh.reland` (the gather of a mesh-sharded batch to one "
         "device), "
         "`srt.transfer.encode|stage|upload|HostToDevice|DeviceToHost`, "
@@ -772,7 +775,8 @@ def generate_docs() -> str:
         "while a query's envelope collects. The event record's "
         "`phasesS` also holds the host seconds taken where the work "
         "happens (`parseS`, `dispatchS`, `syncWaitS`, `fetchWaitS`, "
-        "`fetchUnpackS`, `semaphoreWaitS`, `coalesceS`, `relandS`) and "
+        "`fetchUnpackS`, `semaphoreWaitS`, `coalesceS`, `relandS`, "
+        "`joinS`) and "
         "`hostSyncs` "
         "counts the "
         # (the removed switches' names are split across literals so that

@@ -161,7 +161,7 @@ _HOST_FETCHES = _ThreadCounter()
 #: the per-query host-clock phases taken where the work happens (keys of
 #: the event record's ``phasesS`` beside planS / executeS / collectS)
 PHASE_KEYS = ("dispatchS", "syncWaitS", "fetchWaitS", "fetchUnpackS",
-              "semaphoreWaitS", "coalesceS", "relandS")
+              "semaphoreWaitS", "coalesceS", "relandS", "joinS")
 
 
 class _PhaseSeconds(threading.local):

@@ -461,19 +461,27 @@ class TpuCoalesceExec(TpuExec):
     or not; batches that are concatenated are gathered to one device first
     (execs/mesh.py ``reland``): the copy builds one batch on one device.
 
-    Two passthroughs: a lone buffered batch, and — under TargetSize only —
+    Passthroughs: a lone buffered batch; under TargetSize only,
     capacity-sharing masked VIEWS from a local shuffle split
     (columnar/table.is_shared_view), which stream un-coalesced because
-    concatenating views of one table only multiplies capacity."""
+    concatenating views of one table only multiplies capacity; and, on a
+    join's probe side (``masked_pass``), every masked batch."""
 
     def __init__(self, child: TpuExec, target_bytes: int = 1 << 30,
                  require_single: bool = False,
-                 columns: Optional[Sequence[int]] = None):
+                 columns: Optional[Sequence[int]] = None,
+                 masked_pass: bool = False):
         super().__init__()
         self.children = (child,)
         self.target_bytes = target_bytes
         self.require_single = require_single
         self.columns = None if columns is None else tuple(columns)
+        #: a join's probe side: a MASKED batch (a filter's output) streams
+        #: on as it is. The join probes under the mask, and a copy of
+        #: masked batches is sized by their capacities, not their live
+        #: rows: it would pay the compaction scatter the mask defers and
+        #: shrink nothing
+        self.masked_pass = masked_pass and not require_single
 
     def output_schema(self):
         schema = self.children[0].output_schema()
@@ -504,7 +512,8 @@ class TpuCoalesceExec(TpuExec):
             for batch in self.children[0].execute_masked():
                 if self.columns is not None:
                     batch = batch.select_columns(self.columns)
-                if is_shared_view(batch) and not self.require_single:
+                if (batch.live is not None and self.masked_pass) or (
+                        is_shared_view(batch) and not self.require_single):
                     # capacity-sharing views (a local split's per-partition
                     # masks over ONE table): concatenation would only
                     # multiply capacity and pay the very scatters masking

@@ -1,8 +1,18 @@
 """TPU equi-join (reference: GpuShuffledHashJoinExec / GpuBroadcastHashJoin /
 GpuHashJoin.scala gather-map machinery + JoinGatherer — SURVEY.md §2.3).
 
-TPU-first design: hash tables are pointer-chasing and hostile to the VPU, so
-the join is SORT/SEARCH based with fully static shapes:
+TPU-first design: hash tables are pointer-chasing and hostile to the VPU.
+The build side is ONE table, the smaller side of an inner join by the
+plan's size estimates (overrides/rules.py), made ready once a query; the
+probe side streams. Two bodies, chosen from what is READ of the build side
+before the first probe batch (TpuJoinExec._plan_direct), never guessed:
+
+  * DIRECT ADDRESS (_DirectJoinKernel): a single integer key, unique on the
+    build side, within a bounded range — every foreign-key join. A table of
+    build row ids by ``key - min``; a probe batch is one lookup in it: by
+    windows of the table where the batch's keys are clustered, an
+    element a row where they are not (_direct_lookup).
+  * SORTED (JoinKernel), every other shape, with fully static shapes:
 
   1. evaluate key expressions on both sides (fused projections);
   2. dense-rank both sides' keys into ONE shared integer code space
@@ -24,7 +34,7 @@ post-filter for inner/cross; outer-with-condition falls back (tagged).
 from __future__ import annotations
 
 import contextvars
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 from spark_rapids_tpu.dispatch import tpu_jit
@@ -243,111 +253,221 @@ class JoinKernel:
         raise ColumnarProcessingError(f"expand kind {kind}")
 
 
+#: the largest direct-address table a build side may take beyond the
+#: conf's multiple of its capacity: 2^26 int32 slots (256 MiB), which
+#: holds dbgen's sparse o_orderkey (a range of 4 x rows) up to SF10
+DIRECT_MAX_SLOTS = 1 << 26
+
+#: probe batches of a direct inner join whose output row counts are read
+#: from the device in ONE fetch (the batches' gathers wait for it)
+PROBE_AHEAD = 8
+
+#: the clustered lookup of a direct probe (_direct_lookup): probe rows a
+#: block, and table slots a row of the table's 2-D view
+WINDOW_ROWS = 128
+WINDOW_SLOTS = 512
+
+
+def _windowed(slots: int) -> bool:
+    """Whether a direct table of ``slots`` is kept as rows of
+    WINDOW_SLOTS (the form _direct_lookup reads windows from)."""
+    return slots % WINDOW_SLOTS == 0 and slots >= 2 * WINDOW_SLOTS
+
+
+def _direct_lookup(rowid, pos, valid):
+    """(the table's entry at slot ``pos`` for the ``valid`` rows of a
+    probe batch, whether the batch was clustered). XLA:TPU gathers a
+    scalar a DMA: about 17 ns an element whatever the table's size, and a
+    time that differs from process to process (PERF.md §6 PR 34). Where
+    the valid slots of every WINDOW_ROWS consecutive probe rows lie
+    within WINDOW_SLOTS of each other (a fact table in its key's order:
+    dbgen's lineitem against orders), a block reads the two
+    WINDOW_SLOTS-slot rows of the table that hold its slots, two DMAs a
+    block, and each of its rows picks its slot by a compare. Which of the
+    two runs is decided on the device from the batch's own slots, inside
+    the program; the answer is the gather's either way."""
+    cap = pos.shape[0]
+    B, R = WINDOW_ROWS, WINDOW_SLOTS
+    if rowid.ndim == 1 or cap % B:
+        return rowid.reshape(-1)[pos], jnp.zeros((), jnp.bool_)
+    slots = rowid.size
+    nb = cap // B
+    pb = pos.reshape(nb, B)
+    vb = valid.reshape(nb, B)
+    lo = jnp.min(jnp.where(vb, pb, slots), axis=1)
+    hi = jnp.max(jnp.where(vb, pb, -1), axis=1)
+    clustered = jnp.all(hi - lo < R)
+
+    def windows():
+        r0 = jnp.clip(lo // R, 0, slots // R - 2)
+        win = rowid.at[jnp.stack([r0, r0 + 1], axis=1)].get(
+            mode="promise_in_bounds").reshape(nb, 2 * R)
+        off = pb - (r0 * R)[:, None]
+        slot = jnp.arange(2 * R, dtype=jnp.int32)
+        return jnp.sum(
+            jnp.where(off[:, :, None] == slot, win[:, None, :], 0),
+            axis=2, dtype=jnp.int32).reshape(cap)
+
+    return jax.lax.cond(clustered, windows,
+                        lambda: rowid.reshape(-1)[pos]), clustered
+
+
+class _DirectBuild(NamedTuple):
+    """A build side made ready for the direct-address body, once a
+    query: row ids by ``key - keymin`` (-1: no such key)."""
+    rowid: jax.Array      # int32[slots], as rows of WINDOW_SLOTS (_windowed)
+    keymin: jax.Array     # int64 scalar
+    slots: int
+
+
+class _DirectPending(NamedTuple):
+    """An inner join's probe batch whose kept rows are not counted yet."""
+    table: DeviceTable    # the probe batch
+    ri: jax.Array         # build row per probe row
+    keep: jax.Array       # probe rows that matched
+    idx: jax.Array        # their positions, in order, at the front
+    nout: jax.Array       # their count, on the device
+    clustered: jax.Array  # whether the lookup read windows (_direct_lookup)
+
+
 class _DirectJoinKernel:
     """Dense-domain direct-address join — the TPU answer to the build-side
     hash table (reference: GpuHashJoin.scala builds a cuDF hash table and
     probes it). Pointer-chasing hash tables are VPU-hostile, but the common
     case — a fact table probing a dimension/key table whose integer keys
-    occupy a bounded range (every foreign-key join) — needs no hash and no
-    sort: scatter build row ids into a static-capacity table indexed by
-    ``key - min(key)``, gather per probe key, done. One fused kernel does
-    probe + gather + compaction with ZERO host syncs; two device flags
-    (range fits, build keys unique) validate the speculation at collect
-    time (runtime/speculation.py), falling back to the sort-based join via
-    replay when the keys are too sparse or duplicated."""
+    are unique within a bounded range (every foreign-key join) — needs no
+    hash and no sort: scatter build row ids into a table indexed by
+    ``key - min(key)``, gather per probe key, done.
+
+    Nothing here is a guess. The build side's key range, live count and
+    uniqueness are READ once it is ready (``stats``, ``build``: two small
+    fetches a join and query), and the body is chosen from them before
+    the first probe batch; a probe batch then costs one gather from the
+    table (``probe``). An inner join's live output rows are few or many:
+    their count is read (one fetch for up to PROBE_AHEAD batches) and the
+    output is gathered into the bucket the count needs (``gather``), or
+    left in place under a mask where no smaller bucket exists
+    (``in_place``)."""
 
     _traces = {}
 
     SUPPORTED = ("inner", "left", "leftouter", "leftsemi", "leftanti")
 
     @classmethod
-    def run(cls, jt: str, lt: DeviceTable, rt: DeviceTable,
-            lkey: DevVal, rkey: DevVal, H: int, masked_out: bool):
-        """Returns ([(data, validity)...] for left cols [+ right cols],
-        live_out_or_None, nout_dev, fail_dev). With ``masked_out`` the
-        output stays IN PLACE (live rows marked by the returned mask — no
-        compaction scatter at all, columnar/table.py DeviceTable.live);
-        otherwise inner/semi/anti compact as before."""
-        key = (jt, H, lt.capacity, rt.capacity, masked_out,
-               lt.live is not None,
-               lt.schema_key()[0], rt.schema_key()[0],
-               str(lkey[0].dtype), str(rkey[0].dtype))
+    def _get(cls, key, make):
         fn = cls._traces.get(key)
         if fn is None:
-            fn = tpu_jit(cls._build(jt, H, lt.capacity, rt.capacity,
-                                    masked_out), name="join_direct")
-            cls._traces[key] = fn
-        l_cols = tuple((c.data, c.validity) for c in lt.columns)
-        r_cols = tuple((c.data, c.validity) for c in rt.columns)
-        return fn(l_cols, lkey, r_cols, rkey, lt.nrows_dev, rt.nrows_dev,
-                  lt.live)
+            fn = cls._traces[key] = make()
+        return fn
 
-    @staticmethod
-    def _build(jt: str, H: int, cap_l: int, cap_r: int, masked_out: bool):
-        def kernel(l_cols, lk, r_cols, rk, nl, nr, live_l_mask):
-            ld, lv = lk
-            rd, rv = rk
-            if live_l_mask is not None:
-                live_l = live_l_mask
-            else:
-                live_l = jnp.arange(cap_l, dtype=jnp.int32) < nl
-            live_r = jnp.arange(cap_r, dtype=jnp.int32) < nr
-            vl = lv & live_l
-            vr = rv & live_r
+    @classmethod
+    def stats(cls, rkey: DevVal, live_r):
+        """int64[4]: the live valid build keys' min, max and count, and
+        the live rows' count."""
+        def build():
+            def stats(rd, rv, live):
+                v = rv & live
+                k = rd.astype(jnp.int64)
+                big = jnp.asarray(np.iinfo(np.int64).max, jnp.int64)
+                return jnp.stack([
+                    jnp.min(jnp.where(v, k, big)),
+                    jnp.max(jnp.where(v, k, -big)),
+                    jnp.sum(v.astype(jnp.int64)),
+                    jnp.sum(live.astype(jnp.int64))])
+            return stats
+        rd, rv = rkey
+        return cls._get(
+            ("stats", rd.shape[0], str(rd.dtype)),
+            lambda: tpu_jit(build(), name="join_build_stats"))(
+                rd, rv, live_r)
 
-            rd64 = rd.astype(jnp.int64)
-            ld64 = ld.astype(jnp.int64)
-            I64MAX = jnp.asarray(np.iinfo(np.int64).max, jnp.int64)
-            keymin = jnp.min(jnp.where(vr, rd64, I64MAX))
-            any_r = jnp.any(vr)
-            keymin = jnp.where(any_r, keymin, 0)
-            pos = rd64 - keymin
-            fits = (~any_r) | (jnp.max(jnp.where(vr, pos, 0)) < H)
-            posc = jnp.clip(pos, 0, H - 1).astype(jnp.int32)
-            tgt_r = jnp.where(vr, posc, H)
-            cnt = jnp.zeros(H, jnp.int32).at[tgt_r].add(1, mode="drop")
-            unique = jnp.max(cnt) <= 1
-            rowid = jnp.full(H, -1, jnp.int32).at[tgt_r].max(
-                jnp.arange(cap_r, dtype=jnp.int32), mode="drop")
+    @classmethod
+    def build(cls, rkey: DevVal, live_r, keymin, slots: int):
+        """(row ids by ``key - keymin``, whether every key is unique)."""
+        def build():
+            def table(rd, rv, live, keymin):
+                v = rv & live
+                cap_r = rd.shape[0]
+                pos = jnp.clip(rd.astype(jnp.int64) - keymin, 0,
+                               slots - 1).astype(jnp.int32)
+                tgt = jnp.where(v, pos, slots)
+                cnt = jnp.zeros(slots, jnp.int32).at[tgt].add(
+                    1, mode="drop")
+                rowid = jnp.full(slots, -1, jnp.int32).at[tgt].max(
+                    jnp.arange(cap_r, dtype=jnp.int32), mode="drop")
+                if _windowed(slots):
+                    rowid = rowid.reshape(-1, WINDOW_SLOTS)
+                return rowid, jnp.max(cnt) <= 1
+            return table
+        rd, rv = rkey
+        return cls._get(
+            ("build", rd.shape[0], str(rd.dtype), slots),
+            lambda: tpu_jit(build(), name="join_direct_build"))(
+                rd, rv, live_r, keymin)
 
-            p = ld64 - keymin
-            inb = (p >= 0) & (p < H) & vl
-            ri = rowid[jnp.clip(p, 0, H - 1).astype(jnp.int32)]
-            matched = inb & (ri >= 0)
-            fail = ~(fits & unique)
-            safe_ri = jnp.where(matched, ri, 0)
+    @classmethod
+    def probe(cls, jt: str, lkey: DevVal, live_l, direct: _DirectBuild):
+        """(build row per probe row, matched, rows kept, their count,
+        for an inner join, whose output is gathered, their positions in
+        probe order at the front of an int32[capacity], and whether the
+        lookup found the batch clustered)."""
+        slots = direct.slots
 
-            if jt == "leftouter" or jt == "left":
-                # every live probe row emits exactly one output row in place
-                outs = list(l_cols)
-                for d, v in r_cols:
-                    outs.append((d[safe_ri], v[safe_ri] & matched))
-                nl_out = (jnp.sum(live_l.astype(jnp.int32))
-                          if live_l_mask is not None else nl)
-                return tuple(outs), live_l_mask, nl_out, fail
+        def build():
+            def probe(ld, lv, live, rowid, keymin):
+                cap_l = ld.shape[0]
+                p = ld.astype(jnp.int64) - keymin
+                inb = (p >= 0) & (p < slots) & lv & live
+                ri, clustered = _direct_lookup(
+                    rowid, jnp.clip(p, 0, slots - 1).astype(jnp.int32), inb)
+                matched = inb & (ri >= 0)
+                ri = jnp.where(matched, ri, 0)
+                keep = (live & ~matched) if jt == "leftanti" else matched
+                keep_i = keep.astype(jnp.int32)
+                nout = jnp.sum(keep_i)
+                if jt != "inner":
+                    return ri, matched, keep, nout, None, clustered
+                tgt = jnp.where(keep, jnp.cumsum(keep_i) - 1, cap_l)
+                idx = jnp.zeros(cap_l, jnp.int32).at[tgt].set(
+                    jnp.arange(cap_l, dtype=jnp.int32), mode="drop")
+                return ri, matched, keep, nout, idx, clustered
+            return probe
+        ld, lv = lkey
+        return cls._get(
+            ("probe", jt, ld.shape[0], str(ld.dtype), slots),
+            lambda: tpu_jit(build(), name="join_direct_probe"))(
+                ld, lv, live_l, direct.rowid, direct.keymin)
 
-            if jt in ("leftsemi", "leftanti"):
-                keep = matched if jt == "leftsemi" else (live_l & ~matched)
-            else:  # inner
-                keep = matched
-            nout = jnp.sum(keep.astype(jnp.int32))
-            if masked_out:
-                # deferred compaction: rows stay in place, keep is the mask
-                outs = list(l_cols)
-                if jt == "inner":
-                    for d, v in r_cols:
-                        outs.append((d[safe_ri], v[safe_ri] & matched))
-                return tuple(outs), keep, nout, fail
-            from spark_rapids_tpu.ops.scatter32 import compact_pairs
-            pairs = list(l_cols)
-            if jt == "inner":
-                pairs += [(d[safe_ri], v[safe_ri] & matched)
-                          for d, v in r_cols]
-            outs, _ = compact_pairs([d for d, _ in pairs],
-                                    [v for _, v in pairs], keep, cap_l)
-            return tuple(outs), None, nout, fail
+    @classmethod
+    def gather(cls, lt: DeviceTable, rt: DeviceTable, ri, idx, nout,
+               out_cap: int):
+        """The kept rows of an inner join in a bucket of ``out_cap``
+        rows: probe columns by position, build columns by row id."""
+        def build():
+            def gather(l_cols, r_cols, ri, idx, nout):
+                li = idx[:out_cap]
+                live = jnp.arange(out_cap, dtype=jnp.int32) < nout
+                rj = ri[li]
+                return ([(d[li], v[li] & live) for d, v in l_cols],
+                        [(d[rj], v[rj] & live) for d, v in r_cols])
+            return gather
+        key = ("gather", out_cap, lt.schema_key(), rt.schema_key())
+        return cls._get(
+            key, lambda: tpu_jit(build(), name="join_direct_gather"))(
+            tuple((c.data, c.validity) for c in lt.columns),
+            tuple((c.data, c.validity) for c in rt.columns), ri, idx, nout)
 
-        return kernel
+    @classmethod
+    def in_place(cls, rt: DeviceTable, ri, matched):
+        """The build columns beside the probe rows where they lie."""
+        def build():
+            def in_place(r_cols, ri, matched):
+                return [(d[ri], v[ri] & matched) for d, v in r_cols]
+            return in_place
+        key = ("inplace", ri.shape[0], rt.schema_key())
+        return cls._get(
+            key, lambda: tpu_jit(build(), name="join_direct_in_place"))(
+            tuple((c.data, c.validity) for c in rt.columns), ri, matched)
 
 
 class _ColumnGather:
@@ -401,10 +521,16 @@ class TpuJoinExec(TpuExec):
                  condition: Optional[Expression],
                  left_schema, right_schema,
                  subpartition_bytes: int = 1 << 30,
-                 max_subpartitions: int = 64):
+                 max_subpartitions: int = 64,
+                 build_left: Optional[bool] = None):
         super().__init__()
         self.children = (left, right)
         self.join_type = join_type.lower().replace("_", "")
+        #: which child is coalesced into the one build table: the right
+        #: one, but for a right outer join, and for an inner join whose
+        #: left side the planner found smaller (overrides/rules.py)
+        self.build_left = (self.join_type in ("right", "rightouter")
+                           if build_left is None else bool(build_left))
         self.left_keys = list(left_keys)
         self.right_keys = list(right_keys)
         self.condition = condition
@@ -416,21 +542,6 @@ class TpuJoinExec(TpuExec):
         self.max_subpartitions = max_subpartitions
         self._kernel = JoinKernel.get(len(self.left_keys))
         self._filter_kernel = None
-        self._site_base = "join:{}:{}:{}:{}:{}".format(
-            self.join_type,
-            tuple(k.key() for k in self.left_keys),
-            tuple(k.key() for k in self.right_keys),
-            tuple(self.left_names), tuple(self.right_names))
-
-    @property
-    def _site_key(self) -> str:
-        """Speculation site identity: join shape + PLAN POSITION (lore id,
-        assigned deterministically per plan walk) so two same-shaped join
-        operators — repeated subqueries, look-alike joins in unrelated
-        queries — do not share one blocklist entry (ADVICE r3). A repeated
-        identical query re-assigns the same lore id, so blocklisting still
-        sticks across executions."""
-        return f"{self._site_base}:op{getattr(self, '_lore_id', 0)}"
 
     def output_schema(self):
         jt = self.join_type
@@ -442,43 +553,55 @@ class TpuJoinExec(TpuExec):
         return ls + rs
 
     def describe(self):
-        return f"TpuJoin[{self.join_type}, keys={len(self.left_keys)}]"
+        side = "left" if self.build_left else "right"
+        return (f"TpuJoin[{self.join_type}, keys={len(self.left_keys)}, "
+                f"build={side}]")
 
     # -----------------------------------------------------------------------
     produces_masked = True
 
     def execute_masked(self):
         """Probe-side STREAMING execution: the build side is one coalesced
-        (spillable-protected) table; probe batches stream through one at a
-        time — the reference's join iterator shape (GpuShuffledHashJoinExec
-        streams the streamed side against the built hash table). Full-outer
+        (spillable-protected) table, made ready once (range
+        ``srt.join.build``: the coalesce, and for a single integer key
+        the direct-address table, _plan_direct); probe batches stream
+        through one at a time (range ``srt.join.batch`` each) — the
+        reference's join iterator shape (GpuShuffledHashJoinExec streams
+        the streamed side against the built hash table). Full-outer
         joins accumulate a build-side match bitmap across probe batches and
         emit unmatched build rows as a final batch. Probe batches may be
-        MASKED (filter output) and direct-join outputs stay masked —
-        liveness rides a device mask instead of a compaction scatter."""
+        MASKED (filter output). Both ranges add to ``phasesS.joinS``."""
+        from spark_rapids_tpu.dispatch import phase_span
         from spark_rapids_tpu.runtime.retry import retry_block
 
         jt = self.join_type
-        swapped = jt in ("right", "rightouter")
+        swapped = self.build_left
         build_child = self.children[0] if swapped else self.children[1]
         probe_child = self.children[1] if swapped else self.children[0]
+        self.add_metric("buildSideSwapped", int(swapped))
 
-        build = self._single(build_child)
-
-        # spill-aware threshold: a build side past the device budget's
-        # chunk share sub-partitions even when the conf threshold is
-        # higher — each partition rides the spill tiers independently
-        # instead of pinning one over-budget resident table
-        from spark_rapids_tpu.runtime.memory import MEMORY
-        sub_bytes = self.subpartition_bytes
-        if sub_bytes > 0:
-            sub_bytes = min(sub_bytes, MEMORY.scan_chunk_bytes())
-        nparts = 1
-        if (jt != "cross" and sub_bytes > 0
-                and build.device_nbytes() > sub_bytes):
-            nparts = min(
-                -(-build.device_nbytes() // sub_bytes),
-                self.max_subpartitions)
+        with phase_span("joinS", "build", "join"):
+            build = self._single(build_child)
+            # spill-aware threshold: a build side past the device budget's
+            # chunk share sub-partitions even when the conf threshold is
+            # higher — each partition rides the spill tiers independently
+            # instead of pinning one over-budget resident table
+            from spark_rapids_tpu.runtime.memory import MEMORY
+            sub_bytes = self.subpartition_bytes
+            if sub_bytes > 0:
+                sub_bytes = min(sub_bytes, MEMORY.scan_chunk_bytes())
+            nparts = 1
+            if (jt != "cross" and sub_bytes > 0
+                    and build.device_nbytes() > sub_bytes):
+                nparts = min(
+                    -(-build.device_nbytes() // sub_bytes),
+                    self.max_subpartitions)
+            direct, rows = self._plan_direct(build) if nparts == 1 \
+                else (None, None)
+            if rows is None and build.num_rows_known:
+                rows = build.num_rows
+            if rows is not None:
+                self.add_metric("buildRows", rows)
         if nparts > 1:
             yield from self._execute_subpartitioned(
                 build, probe_child, swapped, int(nparts))
@@ -489,8 +612,9 @@ class TpuJoinExec(TpuExec):
         # while the probe child computes, possibly paying its own
         # memory pressure — the idle build table may ride the
         # device->host->disk tiers and re-land at its original
-        # capacity for the next probe (traces and the full-outer match
-        # bitmap key on that capacity staying put)
+        # capacity for the next probe (traces, the direct table's row
+        # ids and the full-outer match bitmap key on that capacity and
+        # row order staying put)
         from spark_rapids_tpu.runtime.spill import (
             BufferCatalog,
             PRIORITY_ACTIVE,
@@ -502,19 +626,47 @@ class TpuJoinExec(TpuExec):
         del build
         full_outer = jt in ("full", "fullouter", "outer")
         r_matched_accum = None
+        #: direct inner probes whose output counts are not read yet
+        ahead: list = []
+
+        def finish_ahead():
+            with phase_span("joinS", "batch", "join"), \
+                    build_sb.pinned_batch() as bt:
+                outs = self._direct_finish(ahead, bt, swapped)
+            ahead.clear()
+            return outs
+
         try:
             for pb in probe_child.execute_masked():
-                with build_sb.pinned_batch() as bt:
-                    out, r_matched = retry_block(
-                        lambda b=pb, bb=bt: self._join_batch(
-                            b, bb, swapped))
+                self.add_metric("probeBatches", 1)
+                with phase_span("joinS", "batch", "join"), \
+                        build_sb.pinned_batch() as bt:
+                    if direct is not None:
+                        self.add_metric("directJoinBatches", 1)
+                        out = retry_block(
+                            lambda b=pb, bb=bt: self._direct_probe(
+                                b, bb, direct, swapped))
+                        r_matched = None
+                        if isinstance(out, _DirectPending):
+                            ahead.append(out)
+                            out = None
+                    else:
+                        self.add_metric("sortJoinBatches", 1)
+                        out, r_matched = retry_block(
+                            lambda b=pb, bb=bt: self._join_batch(
+                                b, bb, swapped))
                 if full_outer:
                     r_matched_accum = (
                         r_matched if r_matched_accum is None
                         else r_matched_accum | r_matched)
                 if out is not None:
                     yield self._apply_condition(out)
-                self.add_metric("probeBatches", 1)
+                if len(ahead) >= PROBE_AHEAD:
+                    for out in finish_ahead():
+                        yield self._apply_condition(out)
+            if ahead:
+                for out in finish_ahead():
+                    yield self._apply_condition(out)
 
             if full_outer:
                 if r_matched_accum is None:
@@ -524,6 +676,104 @@ class TpuJoinExec(TpuExec):
                         bt, r_matched_accum, swapped)
         finally:
             build_sb.release()
+
+    # -- the direct-address body --------------------------------------------
+    def _plan_direct(self, build: DeviceTable):
+        """(the build side's direct-address table, its live rows as
+        read). The table is None where the join takes the sorted body:
+        more than one key, a key that is no integer, a join type the
+        direct body does not have (full and right outer), a key range
+        wider than the table may be, or a key that repeats. Decided from
+        what is READ of the build side, two small fetches, before the
+        first probe batch: no flag undoes it."""
+        jt = self.join_type
+        if len(self.left_keys) != 1 or jt not in _DirectJoinKernel.SUPPORTED:
+            return None, None
+        keys = (self.left_keys, self.right_keys)
+        build_key, probe_key = (keys[0][0], keys[1][0]) if self.build_left \
+            else (keys[1][0], keys[0][0])
+        if not (isinstance(build_key.data_type, T.IntegralType)
+                and isinstance(probe_key.data_type, T.IntegralType)):
+            return None, None
+        from spark_rapids_tpu.dispatch import host_fetch
+        kc = compile_project([build_key], build)[0]
+        rkey = (kc.data, kc.validity)
+        live_r = build.row_mask()
+        kmin, kmax, nvalid, rows = (int(x) for x in host_fetch(
+            _DirectJoinKernel.stats(rkey, live_r)))
+        if nvalid == 0:
+            kmin = kmax = 0
+        span = kmax - kmin + 1
+        if span > max(DIRECT_TABLE_MULT.get() * build.capacity,
+                      DIRECT_MAX_SLOTS):
+            return None, rows
+        slots = bucket_for(max(span, 1))
+        from spark_rapids_tpu.dispatch import device_scalar
+        keymin = device_scalar(kmin, np.int64)
+        rowid, unique = _DirectJoinKernel.build(rkey, live_r, keymin, slots)
+        if not bool(host_fetch(unique)):
+            return None, rows
+        return _DirectBuild(rowid, keymin, slots), rows
+
+    def _direct_probe(self, lt: DeviceTable, rt: DeviceTable,
+                      direct: _DirectBuild, swapped: bool):
+        """One probe batch against the direct table. An inner join comes
+        back as a _DirectPending (its output is sized by a count that
+        _direct_finish reads); every other type as its output table,
+        in place: a left outer join keeps every probe row, a semi or
+        anti join masks them."""
+        jt = self.join_type
+        probe_keys = self.right_keys if swapped else self.left_keys
+        kc = compile_project(probe_keys, lt)[0]
+        ri, matched, keep, nout, idx, clustered = _DirectJoinKernel.probe(
+            jt, (kc.data, kc.validity), lt.row_mask(), direct)
+        if jt == "inner":
+            return _DirectPending(lt, ri, keep, idx, nout, clustered)
+        if jt in ("leftsemi", "leftanti"):
+            return DeviceTable(lt.names, lt.columns, nout, lt.capacity,
+                               live=keep)
+        nrows = lt.num_rows if lt.num_rows_known else lt.nrows_dev
+        return self._in_place(lt, rt, ri, matched, lt.live, nrows, swapped)
+
+    def _in_place(self, lt, rt, ri, matched, live, nrows,
+                  swapped) -> DeviceTable:
+        outs = _DirectJoinKernel.in_place(rt, ri, matched)
+        rcols = [c.with_arrays(d, v) for c, (d, v) in zip(rt.columns, outs)]
+        lcols = list(lt.columns)
+        return DeviceTable(self.left_names + self.right_names,
+                           rcols + lcols if swapped else lcols + rcols,
+                           nrows, lt.capacity, live=live)
+
+    def _direct_finish(self, ahead, rt: DeviceTable, swapped: bool):
+        """The outputs of inner-join probes whose counts were left on the
+        device: ONE fetch reads them all, then each batch's kept rows are
+        gathered into the bucket its count needs (few rows survive a
+        selective join: what follows then sorts thousands of rows, not a
+        probe batch's capacity), or stay in place under the mask where
+        no smaller bucket exists."""
+        from spark_rapids_tpu.dispatch import host_fetch
+        read = host_fetch([(p.nout, p.clustered) for p in ahead])
+        outs = []
+        for p, (n, clustered) in zip(ahead, read):
+            n = int(n)
+            self.add_metric("joinOutputRows", n)
+            self.add_metric("clusteredProbeBatches", int(clustered))
+            lt = p.table
+            out_cap = bucket_for(max(n, 1))
+            if out_cap >= lt.capacity:
+                outs.append(self._in_place(lt, rt, p.ri, p.keep, p.keep, n,
+                                           swapped))
+                continue
+            louts, routs = _DirectJoinKernel.gather(
+                lt, rt, p.ri, p.idx, p.nout, out_cap)
+            lcols = [c.with_arrays(d, v)
+                     for c, (d, v) in zip(lt.columns, louts)]
+            rcols = [c.with_arrays(d, v)
+                     for c, (d, v) in zip(rt.columns, routs)]
+            outs.append(DeviceTable(
+                self.left_names + self.right_names,
+                rcols + lcols if swapped else lcols + rcols, n, out_cap))
+        return outs
 
     def _execute_subpartitioned(self, build: DeviceTable, probe_child,
                                 swapped: bool, nparts: int):
@@ -549,18 +799,26 @@ class TpuJoinExec(TpuExec):
         del build
         self.add_metric("subPartitions", nparts)
         r_matched = [None] * nparts
+        #: each build partition's direct table (None: the sorted body),
+        #: made when its first probe rows come
+        directs = {}
+        from spark_rapids_tpu.dispatch import phase_span
         try:
             for pb in probe_child.execute_masked():
+                self.add_metric("probeBatches", 1)
                 for p, pp in enumerate(self._split(pb, pparter)):
-                    with build_parts[p].pinned_batch() as bt:
+                    with phase_span("joinS", "batch", "join"), \
+                            build_parts[p].pinned_batch() as bt:
+                        if p not in directs:
+                            directs[p], _rows = self._plan_direct(bt)
                         out, rm = retry_block(
-                            lambda a=pp, b=bt: self._join_batch(a, b, swapped))
+                            lambda a=pp, b=bt, d=directs[p]:
+                            self._join_one(a, b, d, swapped))
                     if full_outer and rm is not None:
                         r_matched[p] = (rm if r_matched[p] is None
                                         else r_matched[p] | rm)
                     if out is not None:
                         yield self._apply_condition(out)
-                self.add_metric("probeBatches", 1)
 
             if full_outer:
                 for p in range(nparts):
@@ -619,9 +877,23 @@ class TpuJoinExec(TpuExec):
             raise ColumnarProcessingError("join requires a coalesced build side")
         return batches[0]
 
+    def _join_one(self, lt: DeviceTable, rt: DeviceTable,
+                  direct: Optional[_DirectBuild], swapped: bool):
+        """One probe batch joined to the end, by the body ``direct``
+        names (a sub-partition's pair: its count is read at once)."""
+        if direct is None:
+            self.add_metric("sortJoinBatches", 1)
+            return self._join_batch(lt, rt, swapped)
+        self.add_metric("directJoinBatches", 1)
+        out = self._direct_probe(lt, rt, direct, swapped)
+        if isinstance(out, _DirectPending):
+            out = self._direct_finish([out], rt, swapped)[0]
+        return out, None
+
     def _join_batch(self, lt: DeviceTable, rt: DeviceTable, swapped: bool):
-        """Join ONE probe batch (lt) against the build table (rt). Returns
-        (output table or None, build-match bitmap or None)."""
+        """Join ONE probe batch (lt) against the build table (rt) by the
+        SORTED body. Returns (output table or None, build-match bitmap
+        or None)."""
         jt = self.join_type
         if jt == "cross":
             return self._cross(lt, rt, swapped), None
@@ -645,11 +917,6 @@ class TpuJoinExec(TpuExec):
 
         full_outer = jt in ("full", "fullouter", "outer")
 
-        direct = self._try_direct(jt, lt, rt, lkeys, rkeys, swapped,
-                                  full_outer)
-        if direct is not None:
-            return direct, None
-
         (lo, counts, total_d, matched_l, rs_perm, live_l, live_r) = \
             self._kernel.probe(lkeys, rkeys, lt.nrows_dev, rt.nrows_dev,
                                lt.capacity, rt.capacity, lt.live)
@@ -669,28 +936,20 @@ class TpuJoinExec(TpuExec):
                                    lt.capacity, live=keep), None
             return self._compact(lt, keep), None
 
-        from spark_rapids_tpu.runtime import speculation as spec
-        size_site = self._site_key + ":size"
-        ctx = None if full_outer else spec.allowed(size_site)
-        if ctx is not None:
-            # speculative static bound: FK-join shape — output rows fit the
-            # probe side's bucket. The exact i64 total stays on device; the
-            # flag is validated by the collect's packed fetch and a miss
-            # replays this site on the exact path below.
-            out_cap = bucket_for(max(lt.capacity, 1))
-            ctx.add_flag(size_site, self._size_flag(
-                jt, total_d, counts, live_l, out_cap, lt.capacity))
+        # the output's size is READ, one host sync a probe batch (the
+        # reference's JoinGatherer row count): a build key that repeats
+        # can make any number of rows
+        from spark_rapids_tpu.dispatch import host_fetch
+        total = int(host_fetch(total_d))
+        self.add_metric("joinOutputRows", total)
+        if jt in ("left", "leftouter", "right", "rightouter") or full_outer:
+            # each unmatched probe row adds at most one output row; use
+            # the probe CAPACITY as the static bound rather than paying a
+            # second device round trip for the exact count (<=2x bucket)
+            upper = total + lt.capacity
         else:
-            from spark_rapids_tpu.dispatch import host_fetch
-            total = int(host_fetch(total_d))  # one host sync per batch
-            if jt in ("left", "leftouter", "right", "rightouter") or full_outer:
-                # each unmatched probe row adds at most one output row; use
-                # the probe CAPACITY as the static bound rather than paying a
-                # second device round trip for the exact count (<=2x bucket)
-                upper = total + lt.capacity
-            else:
-                upper = total
-            out_cap = bucket_for(max(upper, 1))
+            upper = total
+        out_cap = bucket_for(max(upper, 1))
 
         if jt == "inner":
             li, ri, null_l, null_r, nout = self._kernel.expand(
@@ -709,64 +968,6 @@ class TpuJoinExec(TpuExec):
         names = self.left_names + self.right_names
         cols = rcols + lcols if swapped else lcols + rcols
         return DeviceTable(names, cols, nout, out_cap), r_matched
-
-    def _size_flag(self, jt, total_d, counts, live_l, out_cap, cap_l):
-        """Device bool: True iff the speculative out_cap was too small.
-        i64 throughout so a pathological many-to-many total can't wrap."""
-        key = ("sizeflag", jt, out_cap, cap_l, counts.shape[0])
-        fn = self._kernel._aux_traces.get(key)
-        if fn is None:
-            outer = jt in ("left", "leftouter", "right", "rightouter")
-
-            def flag(total_d, counts, live_l):
-                tot = total_d.astype(jnp.int64)
-                if outer:
-                    tot = tot + jnp.sum(
-                        (live_l & (counts == 0)).astype(jnp.int64))
-                return tot > out_cap
-
-            fn = tpu_jit(flag, name="join_size_flag")
-            self._kernel._aux_traces[key] = fn
-        return fn(total_d, counts, live_l)
-
-    def _try_direct(self, jt, lt, rt, lkeys, rkeys, swapped, full_outer):
-        """Dense-domain direct-address fast path (see _DirectJoinKernel).
-        Returns the output table, or None when the shape doesn't qualify
-        (multi-key, non-integer key, full outer, residual condition on a
-        non-inner join, or a prior failure blocklisted the site)."""
-        if (len(lkeys) != 1 or full_outer
-                or jt not in _DirectJoinKernel.SUPPORTED):
-            return None
-        if not (jnp.issubdtype(lkeys[0][0].dtype, jnp.integer)
-                and jnp.issubdtype(rkeys[0][0].dtype, jnp.integer)):
-            return None
-        from spark_rapids_tpu.runtime import speculation as spec
-        site = self._site_key + ":direct"
-        ctx = spec.allowed(site)
-        if ctx is None:
-            return None
-        from spark_rapids_tpu.execs.base import MASKED_ENABLED
-        masked_out = MASKED_ENABLED.get()
-        H = bucket_for(max(DIRECT_TABLE_MULT.get() * rt.capacity, 1))
-        outs, live_out, nout, fail = _DirectJoinKernel.run(
-            jt, lt, rt, lkeys[0], rkeys[0], H, masked_out)
-        ctx.add_flag(site, fail)
-        self.add_metric("directJoinBatches", 1)
-        if jt in ("leftsemi", "leftanti"):
-            cols = [c.with_arrays(d, v)
-                    for c, (d, v) in zip(lt.columns, outs)]
-            return DeviceTable(lt.names, cols, nout, lt.capacity,
-                               live=live_out)
-        lcols = [c.with_arrays(d, v)
-                 for c, (d, v) in zip(lt.columns, outs[:len(lt.columns)])]
-        rcols = []
-        for c, (d, v) in zip(rt.columns, outs[len(lt.columns):]):
-            rcols.append(DeviceColumn(c.dtype, d, v, dictionary=c.dictionary,
-                                      dict_sorted=c.dict_sorted,
-                                      domain=c.domain))
-        names = self.left_names + self.right_names
-        cols = rcols + lcols if swapped else lcols + rcols
-        return DeviceTable(names, cols, nout, lt.capacity, live=live_out)
 
     def _unmatched_build_batch(self, rt: DeviceTable, r_matched,
                                swapped: bool) -> DeviceTable:

@@ -147,7 +147,20 @@ EVENT_LOG_DIR = str_conf(
 #: dispatchS and syncWaitS count too); 0.0 for a query that gathered no
 #: sharded batch to one device (no mesh, or every consumer ran on the
 #: resident shards).
-EVENT_SCHEMA_VERSION = 14
+#: v15 (join PR): phasesS gains joinS — host seconds inside the join
+#: execs' work (ranges ``srt.join.build``: making the build side ready,
+#: its coalesce and, for the direct-address body, the reads of its key
+#: range and uniqueness; ``srt.join.batch``: each probe batch's join and
+#: the read of the output counts), which hold dispatches and host
+#: fetches that dispatchS and syncWaitS count too, and for the build
+#: range the build child's own execution; 0.0 for a query without a
+#: join. The join exec's plan-tree metrics say which body ran
+#: (``directJoinBatches`` / ``sortJoinBatches``), on what
+#: (``buildRows``, ``probeBatches``, ``joinOutputRows``), which side
+#: was built (``buildSideSwapped``) and, for a direct inner join, how
+#: many probe batches the device found clustered and looked up by
+#: windows of the table (``clusteredProbeBatches``).
+EVENT_SCHEMA_VERSION = 15
 
 
 def plan_tree(executable) -> dict:

@@ -261,14 +261,27 @@ def _batched_unblocked_split(cols, gid, num_segments: int, counts=None):
     lane width is 128 and a 2-D scatter pads the tiny minor dim to it."""
     m = len(cols)
     with jax.named_scope("split_sums"):
-        his, los = [], []
+        his, tops, rests, los = [], [], [], []
         for c in cols:
             hi, lo = split_f64_hi_lo(c)
+            # hi goes in as its leading 12 significant bits and the 12
+            # that follow, each exactly: an f32 scatter-add of the
+            # leading parts is EXACT while a segment's rows span under
+            # 2^12 in count and magnitude together (a group of a join's
+            # few rows always), and the roundings of the two small
+            # streams lie 2^-12 lower, so such a sum is good to about
+            # 2^-36 where one stream of hi rounded at 2^-24 an add, as
+            # float32 arithmetic does. Larger segments round as before.
+            top = jax.lax.bitcast_convert_type(
+                jax.lax.bitcast_convert_type(hi, jnp.uint32)
+                & jnp.uint32(0xFFFFF000), jnp.float32)
             his.append(hi)
+            tops.append(top)
+            rests.append(jnp.where(jnp.isfinite(hi), hi - top, 0.0))
             los.append(lo)
         parts = jnp.stack(
             [jax.ops.segment_sum(st, gid, num_segments=num_segments)
-             for st in his + los], axis=1)
+             for st in tops + rests + los], axis=1)
     if counts is None:
         any_nz = jnp.zeros(cols[0].shape, dtype=jnp.bool_)
         for c in cols:
@@ -279,8 +292,10 @@ def _batched_unblocked_split(cols, gid, num_segments: int, counts=None):
         cnt2 = counts if counts.ndim == 2 else counts[:, None]
     with jax.named_scope("merge"):
         p64 = parts.astype(jnp.float64)
-        shi, slo = p64[:, :m], p64[:, m:2 * m]
-        split_sum = shi + slo
+        # small streams first: their sum loses nothing to the large one
+        slow = p64[:, m:2 * m] + p64[:, 2 * m:]
+        shi = p64[:, :m] + p64[:, m:2 * m]
+        split_sum = p64[:, :m] + slow
 
         all_nonneg = jnp.ones((), dtype=jnp.bool_)
         for hi in his:

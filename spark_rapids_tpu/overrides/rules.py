@@ -619,6 +619,13 @@ def _convert_join(node: P.Join, children, conf):
 
     jt = node.join_type.lower().replace("_", "")
     swapped = jt in ("right", "rightouter")
+    if jt == "inner" and lkeys:
+        # an inner equi join builds its SMALLER side (Spark's
+        # JoinSelection.getSmallerSide): unknown counts as larger, a tie
+        # keeps the right side
+        l_est = node.children[0].estimate_bytes()
+        r_est = node.children[1].estimate_bytes()
+        swapped = l_est is not None and (r_est is None or l_est < r_est)
     target = conf.batch_size_bytes
 
     if not lkeys and (node.condition is not None or jt != "cross"):
@@ -656,9 +663,11 @@ def _convert_join(node: P.Join, children, conf):
 
     if swapped:
         left = wrap_build(children[0])
-        right = TpuCoalesceExec(children[1], target_bytes=target)
+        right = TpuCoalesceExec(children[1], target_bytes=target,
+                                masked_pass=True)
     else:
-        left = TpuCoalesceExec(children[0], target_bytes=target)
+        left = TpuCoalesceExec(children[0], target_bytes=target,
+                               masked_pass=True)
         right = wrap_build(children[1])
     from spark_rapids_tpu.conf import JOIN_MAX_SUBPARTITIONS
     join = TpuJoinExec(left, right, node.join_type, lkeys, rkeys,
@@ -666,7 +675,8 @@ def _convert_join(node: P.Join, children, conf):
                        node.children[0].output_schema(),
                        node.children[1].output_schema(),
                        subpartition_bytes=conf.get_entry(JOIN_SUBPARTITION_BYTES),
-                       max_subpartitions=conf.get_entry(JOIN_MAX_SUBPARTITIONS))
+                       max_subpartitions=conf.get_entry(JOIN_MAX_SUBPARTITIONS),
+                       build_left=swapped)
     from spark_rapids_tpu.conf import DPP_ENABLED
     if broadcast and conf.get_entry(DPP_ENABLED) and not swapped:
         # only inner/leftsemi qualify (checked inside), so the probe is

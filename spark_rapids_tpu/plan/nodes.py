@@ -419,6 +419,19 @@ class Join(PlanNode):
     def describe(self):
         return f"Join[{self.join_type}]"
 
+    def estimate_bytes(self):
+        """A keyed join's rows are taken to be no more than its larger
+        side's (every foreign-key join), each with both sides' columns:
+        the two estimates added. A semi/anti join keeps left rows. A
+        keyless join is a product: unknown."""
+        left = self.children[0].estimate_bytes()
+        if self.join_type in ("leftsemi", "leftanti"):
+            return left
+        right = self.children[1].estimate_bytes()
+        if not self.left_keys or left is None or right is None:
+            return None
+        return left + right
+
 
 class Generate(PlanNode):
     """Generator node (explode/posexplode [outer]) — reference:

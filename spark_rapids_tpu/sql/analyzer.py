@@ -293,6 +293,105 @@ class Analyzer:
         return Scope(DataFrame(join, self.session), merged_aliases,
                      ls.visible + rs.visible, display=merged_display)
 
+    def _lower_from_list(self, rel: A.FromList, where: Optional[A.Node]):
+        """``FROM a, b, c WHERE ...``: an inner join of the items (Spark's
+        ReorderJoin + PushPredicateThroughJoin, done here because the
+        plan layer has neither). Returns (scope, what is left of WHERE).
+
+        Each WHERE conjunct that names ONE item's columns alone is a
+        Filter on that item, below the join. The items are joined in
+        FROM order; a conjunct ``x = y`` with x over the items joined so
+        far and y over the next item is a key pair of that join. An item
+        no such conjunct ties to what is joined so far waits for a later
+        turn; it is cross joined only when none of the waiting items is
+        tied. Everything else (an OR across items, a subquery, a name
+        two items share) stays a Filter above the joins."""
+        from spark_rapids_tpu.plan import DataFrame
+        scopes = [self.lower_relation(it) for it in rel.items]
+
+        def owners(node: A.Node) -> Optional[frozenset]:
+            """Indices of the items ``node``'s columns belong to; None
+            where that cannot be told here."""
+            found = set()
+            stack = [node]
+            while stack:
+                x = stack.pop()
+                if isinstance(x, (A.ScalarSubquery, A.InSubquery, A.Query)):
+                    return None
+                if isinstance(x, A.Ident):
+                    if len(x.parts) == 2:
+                        q = x.parts[0].lower()
+                        hits = [i for i, sc in enumerate(scopes)
+                                if q in sc.aliases]
+                    else:
+                        hits = [i for i, sc in enumerate(scopes)
+                                if x.parts[0] in sc.columns]
+                    if len(hits) != 1:
+                        return None
+                    found.add(hits[0])
+                    continue
+                stack.extend(_ast_children(x))
+            return frozenset(found)
+
+        conjuncts = _split_conjuncts(where) if where is not None else []
+        local: List[List[A.Node]] = [[] for _ in scopes]
+        rest: List[A.Node] = []
+        for c in conjuncts:
+            own = owners(c)
+            if own is not None and len(own) == 1:
+                local[next(iter(own))].append(c)
+            else:
+                rest.append(c)
+        for i, cs in enumerate(local):
+            if cs:
+                scopes[i] = scopes[i].with_df(scopes[i].df.filter(
+                    self.lower_expr(_and_all(cs), scopes[i])))
+
+        #: the equalities of ``rest`` with the items each side names
+        equalities = [(c, owners(c.left), owners(c.right)) for c in rest
+                      if isinstance(c, A.BinOp) and c.op == "="]
+        equalities = [e for e in equalities if e[1] and e[2]]
+
+        def key_pairs(joined: frozenset, nxt: int):
+            """[(conjunct, node over the joined items, node over item
+            ``nxt``)] of the equalities that tie ``nxt`` to ``joined``
+            (one already used ties nothing: its sides are joined)."""
+            out = []
+            for c, lo, ro in equalities:
+                if lo <= joined and ro == {nxt}:
+                    out.append((c, c.left, c.right))
+                elif ro <= joined and lo == {nxt}:
+                    out.append((c, c.right, c.left))
+            return out
+
+        acc = scopes[0]
+        joined = frozenset([0])
+        waiting = list(range(1, len(scopes)))
+        while waiting:
+            nxt, pairs = waiting[0], []
+            for i in waiting:
+                tied = key_pairs(joined, i)
+                if tied:
+                    nxt, pairs = i, tied
+                    break
+            waiting.remove(nxt)
+            rs, _ = self._disambiguate_right(acc, scopes[nxt])
+            if pairs:
+                plan = P.Join(
+                    acc.df.plan, rs.df.plan, "inner",
+                    [self.lower_expr(l, acc) for _, l, _ in pairs],
+                    [self.lower_expr(r, rs) for _, _, r in pairs])
+                df = DataFrame(plan, self.session)
+                used = {id(c) for c, _, _ in pairs}
+                rest = [c for c in rest if id(c) not in used]
+            else:
+                df = acc.df.join(rs.df, on=None)
+            acc = Scope(df, {**acc.aliases, **rs.aliases},
+                        acc.visible + rs.visible,
+                        display={**acc.display, **rs.display})
+            joined = joined | {nxt}
+        return acc, (_and_all(rest) if rest else None)
+
     def _lower_outer_using(self, ls: Scope, rs: Scope, rel: A.JoinRel,
                            how: str) -> Scope:
         """RIGHT/FULL JOIN ... USING: the merged key column is
@@ -389,7 +488,10 @@ class Analyzer:
         from spark_rapids_tpu.plan import DataFrame
 
         # FROM (a FROM-less select evaluates over one synthetic row)
-        if sel.from_ is not None:
+        where = sel.where
+        if isinstance(sel.from_, A.FromList):
+            scope, where = self._lower_from_list(sel.from_, where)
+        elif sel.from_ is not None:
             scope = self.lower_relation(sel.from_)
         else:
             scope = Scope(DataFrame(P.RangeNode(0, 1, 1), self.session),
@@ -418,8 +520,8 @@ class Analyzer:
 
         # WHERE (subquery rewrites first, then one Filter preserving the
         # original predicate tree so SQL text and DSL build equal plans)
-        if sel.where is not None:
-            scope = self._apply_where(scope, sel.where)
+        if where is not None:
+            scope = self._apply_where(scope, where)
 
         # expand stars / assign positions
         items = self._expand_items(sel.items, scope)
@@ -1161,6 +1263,14 @@ def _ast_children(node: A.Node):
     for c, v in getattr(node, "branches", ()) or ():
         yield c
         yield v
+
+
+def _and_all(conjuncts: Sequence[A.Node]) -> A.Node:
+    out = conjuncts[0]
+    for nxt in conjuncts[1:]:
+        out = A.BinOp(op="AND", left=out, right=nxt,
+                      line=nxt.line, col=nxt.col)
+    return out
 
 
 def _split_conjuncts(node: A.Node) -> List[A.Node]:

@@ -164,6 +164,13 @@ class JoinRel(Node):
     using: Sequence[str] = ()
 
 
+@dataclass
+class FromList(Node):
+    """``FROM a, b, c``: an inner join of the items whose keys the
+    analyzer takes from WHERE (Spark's ReorderJoin)."""
+    items: Sequence["Node"] = ()       # TableRef | SubqueryRef | JoinRel
+
+
 # -- query structure ---------------------------------------------------------
 
 @dataclass
@@ -185,6 +192,7 @@ class Select(Node):
     hints: Sequence[Tuple[str, Sequence[str]]] = ()
     items: Sequence["Node"] = ()        # SelectItem | Star
     from_: Optional["Node"] = None      # TableRef | SubqueryRef | JoinRel
+                                        # | FromList
     where: Optional["Node"] = None
     group_by: Sequence["Node"] = ()
     having: Optional["Node"] = None

@@ -9,7 +9,8 @@ lower):
   query       := [WITH name AS '(' query ')' [,...]] setExpr
                  [ORDER BY sortItem [,...]] [LIMIT n]
   setExpr     := select (UNION [ALL|DISTINCT] select)*
-  select      := SELECT [hint] [DISTINCT] item [,...] [FROM relation]
+  select      := SELECT [hint] [DISTINCT] item [,...]
+                 [FROM relation [,...]]
                  [WHERE expr] [GROUP BY expr [,...]] [HAVING expr]
                | '(' query ')'
   relation    := relPrimary (join)*
@@ -247,7 +248,7 @@ class Parser:
                 break
         from_ = None
         if self.eat_kw("FROM"):
-            from_ = self._relation()
+            from_ = self._from_list()
         where = None
         if self.eat_kw("WHERE"):
             where = self.parse_expr()
@@ -338,6 +339,17 @@ class Parser:
         return out
 
     # -- relations -----------------------------------------------------------
+    def _from_list(self) -> A.Node:
+        """One relation, or the comma list of TPC-H's texts (an inner
+        join whose keys the analyzer takes from WHERE)."""
+        t = self.peek()
+        items = [self._relation()]
+        while self.eat_op(","):
+            items.append(self._relation())
+        if len(items) == 1:
+            return items[0]
+        return self._at(A.FromList(items=items), t)
+
     def _relation(self) -> A.Node:
         left = self._rel_primary()
         while True:

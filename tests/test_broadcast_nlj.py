@@ -144,12 +144,15 @@ def test_aqe_runtime_broadcast_conversion(session, cpu_session):
         {"k": np.arange(50, dtype=np.int64),
          "w": np.arange(50, dtype=np.int64) * 10})
 
-    # hide the static estimate so the planner cannot prove broadcast
+    # hide the static estimates so the planner cannot prove broadcast
+    # (both: an inner join builds the side it KNOWS to be smaller, and
+    # with neither known it keeps the right one)
     scan = P.LocalScan([small])
     scan.estimate_bytes = lambda: None
+    probe = P.LocalScan([big])
+    probe.estimate_bytes = lambda: None
 
-    join = P.Join(P.LocalScan([big]), scan, "inner",
-                  [col("k")], [col("k")])
+    join = P.Join(probe, scan, "inner", [col("k")], [col("k")])
     executable, _meta = apply_overrides(join, session.conf)
 
     ab = _find_adaptive(executable)

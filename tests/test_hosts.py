@@ -170,7 +170,7 @@ def test_two_process_scan_bit_identity(cluster2, corpus, tmp_path):
     assert after.get("hostShardsLanded", 0) - before.get(
         "hostShardsLanded", 0) == 8
     rec = s.last_event_record
-    assert rec["schema"] == 14
+    assert rec["schema"] == 15
     assert rec["hostTopology"] == "2"
     assert rec["hostsLost"] == 0 and rec["hostRelands"] == 0
 

@@ -1088,6 +1088,10 @@ def test_lock_witness_rank_inversion_raises():
                 low.acquire()
     finally:
         lockorder.disarm_witness()
+        # the two violations provoked above are this test's own: the
+        # witness's process-wide count is read by tests that run after
+        # it in the same worker (test_service's chaos slice)
+        lockorder.reset_witness_violations()
 
 
 def test_lock_witness_condition_wait_releases():

@@ -294,7 +294,7 @@ _TPU_JIT_SITES = _tpu_jit_sites()
 
 
 def test_every_tpu_jit_site_is_found():
-    assert len(_TPU_JIT_SITES) >= 38
+    assert len(_TPU_JIT_SITES) >= 41
     # the sliced aggregate and the aggregate on a mesh's shards are
     # programs of their own on the device timeline
     assert {"agg_fast", "agg_fast_sliced", "agg_fast_mesh",
@@ -380,7 +380,8 @@ def test_event_log_written_and_valid(tmp_path):
     lines = open(s.last_event_path).read().strip().splitlines()
     assert len(lines) == 1
     rec = json.loads(lines[0])
-    # schema v14: phasesS gains relandS (mesh re-lands); v13: coalesceS
+    # schema v15: phasesS gains joinS (the join execs' build and probe
+    # batches); v14: relandS (mesh re-lands); v13: coalesceS
     # (the coalesce exec's multi-batch flushes); v12: the tracing PR added hostSyncs and the
     # dispatch / sync / fetch / semaphore seconds under phasesS (tested
     # below);
@@ -393,7 +394,7 @@ def test_event_log_written_and_valid(tmp_path):
     # fault-domain fields, v6's mesh-native fields, v5's
     # transactional-write fields and v4's survivability fields — see
     # obs/events.py
-    assert rec["schema"] == 14
+    assert rec["schema"] == 15
     assert rec["healthState"] == "HEALTHY"
     assert rec["quarantined"] is False
     assert rec["deviceReinits"] == 0 and rec["workerRestarts"] == 0
@@ -507,7 +508,10 @@ def test_event_log_golden_schema(tmp_path):
     coalesce passed its batches on);
     v14 = phasesS gains relandS (host seconds inside mesh re-lands, the
     range srt.mesh.reland; 0.0 where no sharded batch was gathered to
-    one device).
+    one device);
+    v15 = phasesS gains joinS (host seconds inside the join execs'
+    ranges srt.join.build and srt.join.batch; 0.0 for a query without
+    a join).
     Exec metrics in the plan tree are no schema fields (no bump): every
     TpuHashAggregateExec node carries partialCountReads (partials whose
     row count the streaming loop read to shrink them) and runAheadWaits
@@ -589,7 +593,8 @@ def test_record_counts_the_grouped_aggregates(tmp_path, monkeypatch, cap,
 
 
 _NEW_PHASES = ("parseS", "dispatchS", "syncWaitS", "fetchWaitS",
-               "fetchUnpackS", "semaphoreWaitS", "coalesceS", "relandS")
+               "fetchUnpackS", "semaphoreWaitS", "coalesceS", "relandS",
+               "joinS")
 
 
 def _check_phases(rec):

@@ -9,9 +9,10 @@ import numpy as np
 import pytest
 
 from spark_rapids_tpu import functions as F
-from spark_rapids_tpu.ops.expr import col, lit
+from spark_rapids_tpu.ops.expr import col
 from spark_rapids_tpu.runtime import speculation as spec
 from spark_rapids_tpu.session import TpuSession
+from tests.test_joins import _join_metrics, _logged_session
 
 
 @pytest.fixture(autouse=True)
@@ -62,71 +63,70 @@ def test_flags_validated_and_cleared_on_success():
     assert not spec._BLOCKLIST
 
 
-def test_duplicate_build_keys_fail_replay_blocklist_exact():
-    """Duplicate build-side keys break the direct join's uniqueness
-    speculation: the flag must fire, the query must REPLAY to an exact
-    result, and the site must be blocklisted so the second run never
-    replays."""
+def _join_metric(s, name):
+    """Sum of a TpuJoinExec metric over the last query's plan tree."""
+    return sum(m.get(name, 0) for m in _join_metrics(s.last_event_record))
+
+
+def test_duplicate_build_keys_take_the_sorted_body_without_replay(tmp_path):
+    """Duplicate build-side keys rule the direct-address body out. The
+    join READS that of its build side before the first probe batch
+    (execs/join.py _plan_direct): the sorted body runs, exact, and
+    nothing is guessed, replayed or blocklisted, on the first run and
+    the second."""
     rng = np.random.default_rng(1)
     n = 8000
     fact = {"k": rng.integers(0, 50, n).astype(np.int64),
             "v": rng.random(n)}
     dup = {"k": np.concatenate([np.arange(50), np.arange(50)]).astype(
         np.int64), "w": np.arange(100, dtype=np.int64)}
-    s = TpuSession()
-    got = sorted(
-        s.create_dataframe(fact).join(s.create_dataframe(dup), on="k",
-                                      how="inner")
+    s = _logged_session(tmp_path)
+    q = lambda ss: sorted(
+        ss.create_dataframe(fact).join(ss.create_dataframe(dup), on="k",
+                                       how="inner")
         .group_by("k").agg(F.count().alias("c")).collect())
-    want = sorted(
-        _cpu().create_dataframe(fact).join(
-            _cpu().create_dataframe(dup), on="k", how="inner")
-        .group_by("k").agg(F.count().alias("c")).collect())
-    assert got == want
-    assert any(":direct" in site for site in spec._BLOCKLIST), \
-        spec._BLOCKLIST
-    blocked = set(spec._BLOCKLIST)
-    # second run: the blocklisted site takes the sort-based path directly
-    got2 = sorted(
-        s.create_dataframe(fact).join(s.create_dataframe(dup), on="k",
-                                      how="inner")
-        .group_by("k").agg(F.count().alias("c")).collect())
-    assert got2 == want
-    assert set(spec._BLOCKLIST) == blocked  # no new failures
+    want = q(_cpu())
+    for _ in range(2):
+        assert q(s) == want
+        assert not spec._BLOCKLIST
+        assert "speculationReplays" not in s.last_metrics()
+        assert _join_metric(s, "sortJoinBatches") == 1
+        assert _join_metric(s, "directJoinBatches") == 0
+        assert _join_metric(s, "joinOutputRows") == 2 * n
 
 
-def test_sparse_key_range_falls_back_exact():
-    """Build keys spread over a range far wider than the direct table
-    capacity: the range-fits flag fires and the replay is exact."""
+def test_sparse_key_range_takes_the_sorted_body_exact(tmp_path):
+    """Build keys spread over a range far wider than a direct table may
+    be (10^9 against 2^26 slots): the range is read, the sorted body
+    runs, the answer is exact and nothing replays."""
     rng = np.random.default_rng(2)
     n = 4000
     sparse_keys = rng.choice(10**9, size=200, replace=False).astype(np.int64)
     fact = {"k": sparse_keys[rng.integers(0, 200, n)],
             "v": rng.random(n)}
     dim = {"k": sparse_keys, "w": np.arange(200, dtype=np.int64)}
-    s = TpuSession()
+    s = _logged_session(tmp_path)
     got = _join_q(s, fact, dim)
     _rows_close(got, _join_q(_cpu(), fact, dim))
-    assert any(":direct" in site for site in spec._BLOCKLIST)
+    assert not spec._BLOCKLIST
+    assert _join_metric(s, "sortJoinBatches") == 1
 
 
 def test_blocklist_is_per_operator_site():
-    """Two same-shaped joins at different plan positions blocklist
-    independently (ADVICE r3: _site_key shares look-alike operators)."""
-    from spark_rapids_tpu.execs.join import TpuJoinExec
+    """Two same-shaped aggregates at different plan positions blocklist
+    independently (ADVICE r3: a site key shared by look-alike
+    operators). The join has no speculation site since it reads its
+    build side."""
+    from spark_rapids_tpu.execs.aggregate import TpuHashAggregateExec
     from spark_rapids_tpu.ops.expr import BoundReference
     from spark_rapids_tpu import types as T
-    mk = lambda: TpuJoinExec.__new__(TpuJoinExec)
+    mk = lambda: TpuHashAggregateExec.__new__(TpuHashAggregateExec)
     a, b = mk(), mk()
     for j, lid in ((a, 3), (b, 9)):
-        j.join_type = "inner"
-        j.left_keys = [BoundReference(0, T.LONG)]
-        j.right_keys = [BoundReference(0, T.LONG)]
-        j.left_names = ["k"]
-        j.right_names = ["k"]
-        j._site_base = "join:shape"
+        j.grouping = [BoundReference(0, T.LONG)]
+        j.agg_specs = []
         j._lore_id = lid
-    assert a._site_key != b._site_key
+    assert a._spec_site_key() != b._spec_site_key()
 
 
 def test_conf_off_takes_exact_path():
@@ -239,14 +239,11 @@ def test_agg_speculative_shrink_site_blocklists_once():
 
 
 def test_replay_metric_recorded():
-    rng = np.random.default_rng(7)
-    n = 8000
-    fact = {"k": rng.integers(0, 50, n).astype(np.int64)}
-    dup = {"k": np.concatenate([np.arange(50), np.arange(50)]).astype(
-        np.int64), "w": np.arange(100, dtype=np.int64)}
-    s = TpuSession()
-    _ = (s.create_dataframe(fact).join(s.create_dataframe(dup), on="k",
-                                       how="inner")
-         .group_by("k").agg(F.count().alias("c")).collect())
+    """A replay is counted: the all-distinct-keys aggregate misses its
+    shrink speculation once (the join guesses nothing any more)."""
+    n = 150_000
+    s = TpuSession({"spark.rapids.tpu.agg.maxKeyDomainGroups": 0})
+    _ = s.create_dataframe({"k": np.arange(n, dtype=np.int64)}) \
+        .group_by("k").agg(F.count().alias("c")).collect()
     m = s.last_metrics()
     assert "speculationReplays" in m, m

@@ -322,6 +322,100 @@ def test_cross_join(s):
           lambda s: t(s).join(u(s)).select(col("id"), col("w")))
 
 
+# -- comma-separated FROM (TPC-H's spelling: an inner join whose keys come
+# from WHERE) --------------------------------------------------------------
+
+def _plan_text(s, sql):
+    return s.sql(sql).plan.tree_string()
+
+
+def test_comma_from_two_tables_is_a_keyed_inner_join(s):
+    sql = "SELECT id, v, w FROM t, u WHERE t.k = u.k AND w > 1.0 AND id < 6"
+    check(s, sql,
+          lambda s: t(s).filter(col("id") < lit(6)).join(
+              u(s).filter(col("w") > lit(1.0)), on=["k"], how="inner")
+          .select(col("id"), col("v"), col("w")))
+    plan = _plan_text(s, sql)
+    # the equality is the join's key, each one-table conjunct a Filter
+    # below it, and nothing is left above
+    assert "Join[inner]" in plan and "Join[cross]" not in plan
+    assert plan.count("Filter[") == 2
+    assert plan.index("Join[inner]") < plan.index("Filter[")
+
+
+def test_comma_from_three_tables_joins_in_from_order(s):
+    s.create_dataframe({"w": np.array([1.0, 2.0, 9.0]),
+                        "z": np.array([7, 8, 9], dtype=np.int64)}) \
+        .create_or_replace_temp_view("x3")
+    sql = ("SELECT id, z FROM t, u, x3 "
+           "WHERE t.k = u.k AND u.w = x3.w AND z > 7")
+    check(s, sql,
+          lambda s: t(s).join(u(s), on=["k"], how="inner")
+          .join(s.table("x3").filter(col("z") > lit(7))
+                .select(col("w").alias("w3"), col("z")),
+                on=(col("w") == col("w3")), how="inner")
+          .select(col("id"), col("z")))
+    plan = _plan_text(s, sql)
+    assert plan.count("Join[inner]") == 2 and "Join[cross]" not in plan
+    # a table no equality ties to what is joined so far waits its turn:
+    # x3 is listed second and joined last
+    swapped = ("SELECT id, z FROM t, x3, u "
+               "WHERE t.k = u.k AND u.w = x3.w AND z > 7")
+    assert _canon(s.sql(swapped).collect()) == _canon(s.sql(sql).collect())
+    assert "Join[cross]" not in _plan_text(s, swapped)
+
+
+def test_comma_from_with_aliases(s):
+    check(s, "SELECT a.id, b.w FROM t AS a, u b WHERE a.k = b.k",
+          lambda s: t(s).join(u(s), on=["k"], how="inner")
+          .select(col("id"), col("w")))
+
+
+def test_comma_from_keeps_a_cross_table_or_above_the_join(s):
+    sql = ("SELECT id, w FROM t, u "
+           "WHERE t.k = u.k AND (id > 5 OR w > 1.5)")
+    check(s, sql,
+          lambda s: t(s).join(u(s), on=["k"], how="inner")
+          .filter((col("id") > lit(5)) | (col("w") > lit(1.5)))
+          .select(col("id"), col("w")))
+    plan = _plan_text(s, sql)
+    assert plan.index("Filter[") < plan.index("Join[inner]")
+    assert plan.count("Filter[") == 1
+
+
+def test_comma_from_without_an_equality_is_a_cross_join(s):
+    sql = "SELECT id, w FROM t, u WHERE id < 3"
+    check(s, sql,
+          lambda s: t(s).filter(col("id") < lit(3)).join(u(s))
+          .select(col("id"), col("w")))
+    assert "Join[cross]" in _plan_text(s, sql)
+    check(s, "SELECT id, w FROM t, u",
+          lambda s: t(s).join(u(s)).select(col("id"), col("w")))
+
+
+def test_comma_from_self_join_by_alias(s):
+    sql = ("SELECT a.id, b.id FROM t a, t b "
+           "WHERE a.k = b.k AND a.id < b.id")
+    got = _canon(s.sql(sql).collect())
+    rows = s.table("t").select(col("id"), col("k")).collect()
+    want = _canon([(i, j) for i, ki in rows for j, kj in rows
+                   if ki is not None and ki == kj and i < j])
+    assert got == want and got
+    assert "Join[inner]" in _plan_text(s, sql)
+
+
+def test_comma_from_beside_an_explicit_join(s):
+    s.create_dataframe({"w": np.array([1.0, 2.0, 9.0]),
+                        "z": np.array([7, 8, 9], dtype=np.int64)}) \
+        .create_or_replace_temp_view("x3")
+    check(s, "SELECT id, z FROM t JOIN u ON t.k = u.k, x3 "
+             "WHERE u.w = x3.w",
+          lambda s: t(s).join(u(s), on=["k"], how="inner")
+          .join(s.table("x3").select(col("w").alias("w3"), col("z")),
+                on=(col("w") == col("w3")), how="inner")
+          .select(col("id"), col("z")))
+
+
 def test_semi_anti_join(s):
     check(s, "SELECT id FROM t LEFT SEMI JOIN u USING (k)",
           lambda s: t(s).join(u(s), on=["k"], how="leftsemi")
