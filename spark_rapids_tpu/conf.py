@@ -137,11 +137,12 @@ SPECULATIVE_SIZING = bool_conf(
 
 COLUMN_PRUNING = bool_conf(
     "spark.rapids.tpu.sql.columnPruning.enabled", True,
-    "Prune unreferenced columns below joins/aggregates (Spark's "
-    "ColumnPruning logical rule, which the reference inherits from Spark; "
-    "this engine owns its logical plans so it applies the rule itself — "
-    "overrides/pruning.py). Every pruned column avoids per-operator "
-    "gathers/scatters of emulated 64-bit halves on TPU.")
+    "Prune unreferenced columns below joins/aggregates and out of file "
+    "scans (Spark's ColumnPruning logical rule, which the reference "
+    "inherits from Spark; this engine owns its logical plans so it "
+    "applies the rule itself — overrides/pruning.py). Every pruned column "
+    "avoids per-operator gathers/scatters of emulated 64-bit halves on "
+    "TPU, and a file scan decodes and uploads only the columns left.")
 
 MASKED_BATCHES = bool_conf(
     "spark.rapids.tpu.maskedBatches.enabled", True,

@@ -199,6 +199,9 @@ class DeltaScanNode(FileScanNode):
         if self._schema is not None:
             return
         parts = set(self.snap.metadata.partition_columns)
+        self._discovered = (
+            [f for f in self.snap.schema if f[0] not in parts],
+            [f for f in self.snap.schema if f[0] in parts])
         full = self.output_schema()
         self._schema = full
         self._data_schema = [(n, dt) for n, dt in full if n not in parts]
@@ -259,7 +262,7 @@ class DeltaScanNode(FileScanNode):
 
     def describe(self):
         return (f"DeltaScan[v{self.snap.version}, "
-                f"{len(self.snap.files)} files]")
+                f"{len(self.snap.files)} files{self._describe_columns()}]")
 
 
 def _mask_table(table: HostTable, keep: np.ndarray) -> HostTable:

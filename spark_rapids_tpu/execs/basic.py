@@ -162,6 +162,12 @@ class TpuFileScanExec(TpuExec):
         from spark_rapids_tpu.runtime.memory import scan_chunks
         from spark_rapids_tpu.runtime.retry import retry_block
         sharding, _ = _scan_sharding(self)
+        # set, not added: what this execution reads of the files' columns
+        # (overrides/pruning.py narrows the node to what the plan reads)
+        read = self.scan_node.read_width()
+        self.metrics["scanColumnsRead"] = read
+        self.metrics["scanColumnsPruned"] = max(
+            0, self.scan_node.full_width() - read)
         for batch in self.scan_node.execute_cpu(
                 dynamic_prunes=self._dynamic_prunes or None,
                 metrics=self.metrics):

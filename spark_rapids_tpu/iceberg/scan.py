@@ -42,9 +42,13 @@ class IcebergScanNode(FileScanNode):
                                                    snapshot_id)
         self._seq_by_path = {d.file_path: d.sequence_number
                              for d in self.snap.data_files}
-        self._pos_deletes: Optional[Dict[str, np.ndarray]] = None
-        self._eq_deletes: Optional[List[Tuple[int, List[str], Set[tuple]]]] \
-            = None
+        #: the delete files as loaded, [(positional, equality)]: filled by
+        #: the first read, and one list for this node and every narrowed
+        #: copy of it, so planning reads no delete file and no copy reads
+        #: them again
+        self._deletes: List[Tuple[
+            Dict[str, np.ndarray],
+            List[Tuple[int, List[str], Set[tuple]]]]] = []
         paths = [d.file_path for d in self.snap.data_files]
         self._empty = not paths
         super().__init__(paths or ["<empty>"], conf, columns=columns,
@@ -67,6 +71,7 @@ class IcebergScanNode(FileScanNode):
     def _resolve_schemas(self):
         if self._schema is not None:
             return
+        self._discovered = (list(self.meta.schema), [])
         self._schema = self.output_schema()
         self._data_schema = self._schema
         self._partition_schema = []
@@ -76,8 +81,9 @@ class IcebergScanNode(FileScanNode):
 
     # -- delete files --------------------------------------------------------
     def _load_deletes(self):
-        if self._pos_deletes is not None:
-            return
+        """(positional deletes by data file, equality deletes)."""
+        if self._deletes:
+            return self._deletes[0]
         import pyarrow.parquet as pq
         pos: Dict[str, List[np.ndarray]] = {}
         eqs: List[Tuple[int, List[str], Set[tuple]]] = []
@@ -100,9 +106,9 @@ class IcebergScanNode(FileScanNode):
                 for row in zip(*data):
                     keys.add(row)
                 eqs.append((d.sequence_number, cols, keys))
-        self._pos_deletes = {p: np.unique(np.concatenate(v))
-                             for p, v in pos.items()}
-        self._eq_deletes = eqs
+        self._deletes.append((
+            {p: np.unique(np.concatenate(v)) for p, v in pos.items()}, eqs))
+        return self._deletes[0]
 
     def _norm(self, p: str) -> str:
         if p.startswith("file://"):
@@ -114,9 +120,9 @@ class IcebergScanNode(FileScanNode):
 
         from spark_rapids_tpu.io.arrow_convert import decode_to_schema
         self._resolve_schemas()
-        self._load_deletes()
+        pos_deletes, eq_deletes = self._load_deletes()
         # equality deletes may need columns beyond the projection
-        eq_cols = {c for seq, cols, _k in self._eq_deletes for c in cols
+        eq_cols = {c for seq, cols, _k in eq_deletes for c in cols
                    if seq > self._seq_by_path.get(path, 0)}
         proj = [n for n, _ in self._data_schema]
         read_cols = list(dict.fromkeys(proj + sorted(eq_cols)))
@@ -125,11 +131,11 @@ class IcebergScanNode(FileScanNode):
         table = decode_to_schema(t, [(n, all_schema[n]) for n in read_cols])
 
         keep = np.ones(table.num_rows, dtype=bool)
-        dv = self._pos_deletes.get(self._norm(path))
+        dv = pos_deletes.get(self._norm(path))
         if dv is not None:
             keep[dv[dv < table.num_rows]] = False
         my_seq = self._seq_by_path.get(path, 0)
-        for seq, cols, keys in self._eq_deletes:
+        for seq, cols, keys in eq_deletes:
             if seq <= my_seq:
                 continue  # deletes only apply to OLDER data
             idx = [list(table.names).index(c) for c in cols]
@@ -165,4 +171,5 @@ class IcebergScanNode(FileScanNode):
     def describe(self):
         return (f"IcebergScan[snap={self.snap.snapshot_id}, "
                 f"{len(self.snap.data_files)} data files, "
-                f"{len(self.snap.delete_files)} delete files]")
+                f"{len(self.snap.delete_files)} delete files"
+                f"{self._describe_columns()}]")

@@ -22,7 +22,7 @@ from __future__ import annotations
 import json
 import struct
 import zlib
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -30,7 +30,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar import HostColumn, HostTable
 from spark_rapids_tpu.conf import RapidsConf, str_conf
 from spark_rapids_tpu.errors import ColumnarProcessingError
-from spark_rapids_tpu.io.common import FileScanNode
+from spark_rapids_tpu.io.common import FileScanNode, row_carrier_table
 from spark_rapids_tpu.plan.nodes import Schema
 
 AVRO_READER_TYPE = str_conf(
@@ -197,22 +197,26 @@ def _decompress_block(codec: str, data: bytes) -> bytes:
     raise ColumnarProcessingError(f"unsupported avro codec {codec!r}")
 
 
-def decode_file(buf: bytes) -> HostTable:
-    """Decode a whole container file to a HostTable."""
+def decode_file(buf: bytes, keep: Optional[Sequence[str]] = None
+                ) -> HostTable:
+    """Decode a whole container file to a HostTable. With ``keep``, only
+    the named fields become columns (in the file's order): the others'
+    bytes are still walked, a row-wise container has no other way past
+    them, but no value of theirs is held or converted. ``keep`` empty
+    gives the row-count carrier."""
     info = read_header(buf)
     schema = info.schema_json
     if schema.get("type") != "record":
         raise ColumnarProcessingError("avro top-level schema must be a record")
     fields = schema["fields"]
-    names = [f["name"] for f in fields]
-    spark_types = []
-    decoders = []
-    for f in fields:
-        dt, _nullable = _spark_type_of(f["type"])
-        spark_types.append(dt)
-        decoders.append(_decoder_of(f["type"]))
-
+    kept = [keep is None or f["name"] in keep for f in fields]
+    decoders = [_decoder_of(f["type"]) for f in fields]
     values: List[List[Any]] = [[] for _ in fields]
+    # a skipped field's values go to a sink nobody reads
+    sinks = [(dec, out.append if k else _discard)
+             for dec, out, k in zip(decoders, values, kept)]
+
+    nrows = 0
     r = ByteReader(buf, info.blocks_offset)
     while not r.at_end():
         count = r.read_long()
@@ -221,19 +225,30 @@ def decode_file(buf: bytes) -> HostTable:
         if r.read(16) != info.sync:
             raise ColumnarProcessingError("avro sync marker mismatch")
         for _ in range(count):
-            for dec, out in zip(decoders, values):
-                out.append(dec(block))
+            for dec, sink in sinks:
+                sink(dec(block))
+        nrows += count
 
-    cols = []
-    for dt, vals in zip(spark_types, values):
+    names, cols = [], []
+    for f, vals, k in zip(fields, values, kept):
+        if not k:
+            continue
+        dt, _nullable = _spark_type_of(f["type"])
         validity = np.array([v is not None for v in vals], dtype=np.bool_)
         if isinstance(dt, T.StringType):
             data = np.array(vals, dtype=object)
         else:
             fill = [v if v is not None else 0 for v in vals]
             data = np.asarray(fill, dtype=dt.np_dtype)
+        names.append(f["name"])
         cols.append(HostColumn(dt, data, validity))
+    if not cols:
+        return row_carrier_table(nrows)
     return HostTable(names, cols)
+
+
+def _discard(_value) -> None:
+    pass
 
 
 class AvroScanNode(FileScanNode):
@@ -256,13 +271,9 @@ class AvroScanNode(FileScanNode):
     def read_file(self, path: str) -> HostTable:
         with open(path, "rb") as f:
             buf = f.read()
-        table = decode_file(buf)
-        if self.columns is not None:
-            data_names = [n for n, _ in self.data_schema]
-            idx = {n: i for i, n in enumerate(table.names)}
-            table = HostTable([n for n in data_names],
-                              [table.columns[idx[n]] for n in data_names])
-        return table
+        if self.columns is None:
+            return decode_file(buf)
+        return decode_file(buf, keep={n for n, _ in self.data_schema})
 
 
 # -- generic (nested) record decoding ----------------------------------------

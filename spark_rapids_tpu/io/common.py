@@ -19,6 +19,7 @@ modes govern prefetch/stitching exactly as in the reference. Hive-style
 from __future__ import annotations
 
 import concurrent.futures as cf
+import copy
 import glob as _glob
 import os
 from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
@@ -153,9 +154,16 @@ def coalesce_batches(batches: Iterable[HostTable], target_bytes: int
 
 
 class FileScanNode(PlanNode):
-    """Base scan node. Subclasses implement ``read_file`` (whole-file decode
-    to an Arrow table) and ``file_arrow_schema``; COALESCING may be refined
-    per-format (parquet splits at row-group granularity)."""
+    """Base scan node. Subclasses implement ``read_file`` (one file decoded
+    to the host columns of ``data_schema``) and ``file_schema``; COALESCING
+    may be refined per-format (parquet splits at row-group granularity).
+
+    ``columns`` restricts the node's output, and with it what the reader
+    decodes and what ``TpuFileScanExec`` uploads. A node is shared by every
+    query over its DataFrame or temp view, so a query never sets it:
+    column pruning (overrides/pruning.py ``_visit``) asks for
+    ``narrowed(names)``, a copy restricted to the columns the plan reads
+    that keeps the files listed and the schema discovered by this node."""
 
     format_name = "file"
 
@@ -170,6 +178,54 @@ class FileScanNode(PlanNode):
         self._schema: Optional[Schema] = None
         self._data_schema: Optional[Schema] = None
         self._partition_schema: Optional[Schema] = None
+        #: (data schema, partition schema) of the files before ``columns``
+        #: narrows them: found once, carried by every narrowed copy
+        self._discovered: Optional[Tuple[Schema, Schema]] = None
+
+    def narrowed(self, names: Sequence[str]) -> "FileScanNode":
+        """A copy of this node whose output is ``names`` (columns of this
+        node's output, in its order): the reader decodes only those. The
+        copy shares the file list, the conf, the options and the
+        subclass's own state, and resolves its schemas from what this node
+        discovered, so it lists no directory and opens no file to plan."""
+        self._resolve_schemas()
+        out = copy.copy(self)
+        out.columns = list(names)
+        out._schema = out._data_schema = out._partition_schema = None
+        return out
+
+    def cheapest_column(self) -> int:
+        """Ordinal of the output column that costs least to produce, for
+        a plan that reads none (``count(*)``) and needs one to carry its
+        rows: a partition column, whose values come from the path, else
+        the narrowest fixed-width data column, else column 0."""
+        self._resolve_schemas()
+        parts = {n for n, _ in self._partition_schema or ()}
+        best, best_width = 0, None
+        for i, (name, dt) in enumerate(self._schema):
+            if name in parts:
+                return i
+            np_dtype = getattr(dt, "np_dtype", None)
+            if np_dtype is None or np_dtype == object \
+                    or isinstance(dt, T.DecimalType):
+                continue  # decoded a row at a time through Python objects
+            width = np_dtype.itemsize
+            if best_width is None or width < best_width:
+                best, best_width = i, width
+        return best
+
+    def read_width(self) -> int:
+        """Columns this node reads: its output without the hidden
+        provenance columns."""
+        self._resolve_schemas()
+        return len(self._schema)
+
+    def full_width(self) -> int:
+        """Columns the files (and their partition directories) hold, before
+        any narrowing."""
+        self._resolve_schemas()
+        data_schema, part_schema = self._discovered
+        return len(data_schema) + len(part_schema)
 
     def _effective_paths(self, dynamic_prunes) -> list:
         """File list after dynamic partition pruning
@@ -220,10 +276,18 @@ class FileScanNode(PlanNode):
         by the driver loop)."""
         raise NotImplementedError
 
+    def _file_columns(self) -> Optional[List[str]]:
+        """The names a columnar reader asks the file for: None for all of
+        them, else the kept data columns (none when ``data_schema`` is
+        empty: the rows are then counted, not read)."""
+        if self.columns is None:
+            return None
+        return [n for n, _ in self.data_schema]
+
     # -- schema -------------------------------------------------------------
-    def _resolve_schemas(self):
-        if self._schema is not None:
-            return
+    def _discover_schemas(self) -> Tuple[Schema, Schema]:
+        """(data schema, partition schema) of the files as they lie: opens
+        the first file and walks every path's directories."""
         data_schema = self.file_schema(self.paths[0])
         data_names = {n for n, _ in data_schema}
         # partition columns from Hive-style dirs, in first-seen key order
@@ -234,6 +298,14 @@ class FileScanNode(PlanNode):
                     part_values.setdefault(k, []).append(v)
         part_schema = [(k, _infer_partition_type(vs))
                        for k, vs in part_values.items()]
+        return data_schema, part_schema
+
+    def _resolve_schemas(self):
+        if self._schema is not None:
+            return
+        if self._discovered is None:
+            self._discovered = self._discover_schemas()
+        data_schema, part_schema = self._discovered
         full = data_schema + part_schema
         if self.columns is not None:
             by_name = dict(full)
@@ -311,9 +383,8 @@ class FileScanNode(PlanNode):
         the input-file provenance columns) and order to the output
         schema."""
         self._resolve_schemas()
-        if not self._partition_schema:
-            return self._attach_file_info(table, path)
-        spec = dict(partition_spec_of(path))
+        spec = dict(partition_spec_of(path)) if self._partition_schema \
+            else {}
         n = table.num_rows
         names = list(table.names)
         cols = list(table.columns)
@@ -335,10 +406,13 @@ class FileScanNode(PlanNode):
                     data = np.full(n, int(raw), dtype=np.int64)
             names.append(name)
             cols.append(HostColumn(dt, data, validity))
-        by_name = dict(zip(names, cols))
         out_names = [n for n, _ in self._schema]
-        out = HostTable(out_names, [by_name[n] for n in out_names])
-        return self._attach_file_info(out, path)
+        if self._partition_schema or names != out_names:
+            # partition columns appended, a row carrier to drop, or a
+            # reader that hands columns up in the file's order
+            by_name = dict(zip(names, cols))
+            table = HostTable(out_names, [by_name[n] for n in out_names])
+        return self._attach_file_info(table, path)
 
     # -- PlanNode -----------------------------------------------------------
     def execute_cpu(self, dynamic_prunes=None,
@@ -434,9 +508,17 @@ class FileScanNode(PlanNode):
                     next_submit += 1
                 yield futures.pop(i).result()
 
+    def _describe_columns(self) -> str:
+        """", 7 of 16 columns: a, b, ..." when the node reads fewer columns
+        than the files hold, else ""."""
+        if self.columns is None:
+            return ""
+        return (f", {len(self.columns)} of {self.full_width()} columns: "
+                + ", ".join(self.columns))
+
     def describe(self):
         return (f"{type(self).__name__}[{len(self.paths)} files, "
-                f"{self.reader_type}]")
+                f"{self.reader_type}{self._describe_columns()}]")
 
 
 def row_carrier_table(n: int) -> HostTable:

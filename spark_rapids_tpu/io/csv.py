@@ -33,7 +33,7 @@ from spark_rapids_tpu.io.arrow_convert import (
     host_table_to_arrow,
     spark_type_to_arrow,
 )
-from spark_rapids_tpu.io.common import FileScanNode
+from spark_rapids_tpu.io.common import FileScanNode, row_carrier_table
 from spark_rapids_tpu.io.writer import write_partitioned
 from spark_rapids_tpu.plan.nodes import Schema
 
@@ -211,8 +211,19 @@ class CsvScanNode(FileScanNode):
                             convert_options=convert)
         return tbl, salvage
 
+    def narrowed(self, names):
+        if self.mode != "PERMISSIVE" and self._custom_floats:
+            # which rows DROPMALFORMED drops, and whether FAILFAST raises,
+            # depends on the float columns that are converted: every
+            # query converts the same ones
+            return self
+        return super().narrowed(names)
+
     def read_file(self, path: str) -> HostTable:
         tbl, salvage = self._read_arrow(path)
+        if not self.data_schema:
+            # only partition columns are read: the rows still count
+            return row_carrier_table(tbl.num_rows + len(salvage))
         host = decode_to_schema(tbl, self._pre_float_schema())
         host = self._post_process(host)
         if salvage:
@@ -226,8 +237,9 @@ class CsvScanNode(FileScanNode):
         reordered) output columns; appended at the end (row order within a
         file is not part of the engine's contract)."""
         # physical file order = the full user/file schema, NOT host.names
+        # and not data_schema, which a narrowed copy cuts to what it keeps
         file_schema = list(self.user_schema) if self.user_schema else \
-            list(self.data_schema)
+            list(self._discovered[0])
         file_pos = {n: j for j, (n, _) in enumerate(file_schema)}
         schema = [(n, c.dtype) for n, c in zip(host.names, host.columns)]
         extra = []

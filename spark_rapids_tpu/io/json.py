@@ -22,7 +22,7 @@ from spark_rapids_tpu.io.arrow_convert import (
     decode_to_schema,
     spark_type_to_arrow,
 )
-from spark_rapids_tpu.io.common import FileScanNode
+from spark_rapids_tpu.io.common import FileScanNode, row_carrier_table
 from spark_rapids_tpu.io.writer import write_partitioned
 from spark_rapids_tpu.plan.nodes import Schema
 
@@ -142,6 +142,9 @@ class JsonScanNode(FileScanNode):
 
     def read_file(self, path: str) -> HostTable:
         tbl = self._read_arrow(path)
+        if not self.data_schema:
+            # only partition columns are read: the rows still count
+            return row_carrier_table(tbl.num_rows)
         if self.primitives_as_string and self.user_schema is None:
             cols = []
             for i in range(tbl.num_columns):

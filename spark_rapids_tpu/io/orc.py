@@ -12,7 +12,7 @@ import pyarrow.orc as po
 from spark_rapids_tpu.columnar import HostTable
 from spark_rapids_tpu.conf import str_conf
 from spark_rapids_tpu.io.arrow_convert import arrow_schema_to_spark, decode_to_schema
-from spark_rapids_tpu.io.common import FileScanNode
+from spark_rapids_tpu.io.common import FileScanNode, row_carrier_table
 from spark_rapids_tpu.io.writer import write_partitioned
 from spark_rapids_tpu.plan.nodes import Schema
 
@@ -31,26 +31,23 @@ class OrcScanNode(FileScanNode):
     def file_schema(self, path: str) -> Schema:
         return arrow_schema_to_spark(po.ORCFile(path).schema)
 
-    def _file_columns(self):
-        if self.columns is None:
-            return None
-        data_names = {n for n, _ in self.data_schema}
-        return [c for c in self.columns if c in data_names]
-
     def read_file(self, path: str) -> HostTable:
-        cols = self._file_columns()
-        if cols is not None and not cols:
-            from spark_rapids_tpu.io.common import row_carrier_table
+        if not self.data_schema:
             return row_carrier_table(po.ORCFile(path).nrows)
-        t = po.ORCFile(path).read(columns=cols)
+        t = po.ORCFile(path).read(columns=self._file_columns())
         return decode_to_schema(t, self.data_schema)
 
     def _coalescing_chunks(self, paths=None) -> Iterator[HostTable]:
         """Stripe-granular chunks (MultiFileOrcPartitionReader analog)."""
+        cols = self._file_columns()
         for path in (self.paths if paths is None else paths):
+            if not self.data_schema:
+                # nothing of the file's contents is read: one chunk a file
+                yield self._read_with_partitions(path)
+                continue
             f = po.ORCFile(path)
             for s in range(f.nstripes):
-                batch = f.read_stripe(s, columns=self._file_columns())
+                batch = f.read_stripe(s, columns=cols)
                 yield self._with_partition_columns(
                     decode_to_schema(pa.Table.from_batches([batch]),
                                      self.data_schema),

@@ -15,7 +15,7 @@ import pyarrow.parquet as pq
 from spark_rapids_tpu.columnar import HostTable
 from spark_rapids_tpu.conf import PARQUET_READER_TYPE, RapidsConf
 from spark_rapids_tpu.io.arrow_convert import arrow_schema_to_spark, decode_to_schema
-from spark_rapids_tpu.io.common import FileScanNode
+from spark_rapids_tpu.io.common import FileScanNode, row_carrier_table
 from spark_rapids_tpu.io.writer import write_partitioned
 from spark_rapids_tpu.plan.nodes import Schema
 
@@ -40,18 +40,21 @@ class ParquetScanNode(FileScanNode):
     def file_schema(self, path: str) -> Schema:
         return arrow_schema_to_spark(pq.read_schema(path))
 
-    def _file_columns(self) -> Optional[List[str]]:
-        if self.columns is None:
-            return None
-        data_names = {n for n, _ in self.data_schema}
-        return [c for c in self.columns if c in data_names]
+    def _count_rows(self, path: str) -> int:
+        """The rows a scan that reads no data column still yields: the
+        footer's count, or with pushdown filters the rows that pass them
+        (their own columns are read for that)."""
+        if self.filters is None:
+            return pq.ParquetFile(path).metadata.num_rows
+        names = sorted({term[0] for term in _flat_filters(self.filters)})
+        return pq.read_table(path, columns=names,
+                             filters=self.filters).num_rows
 
     def read_file(self, path: str) -> HostTable:
-        cols = self._file_columns()
-        if cols is not None and not cols:
-            from spark_rapids_tpu.io.common import row_carrier_table
-            return row_carrier_table(pq.ParquetFile(path).metadata.num_rows)
-        t = pq.read_table(path, columns=cols, filters=self.filters)
+        if not self.data_schema:
+            return row_carrier_table(self._count_rows(path))
+        t = pq.read_table(path, columns=self._file_columns(),
+                          filters=self.filters)
         return decode_to_schema(t, self.data_schema)
 
     def _coalescing_chunks(self, paths=None) -> Iterator[HostTable]:
@@ -61,12 +64,30 @@ class ParquetScanNode(FileScanNode):
         if self.filters is not None:
             yield from self._perfile(paths)
             return
+        cols = self._file_columns()
         for path in (self.paths if paths is None else paths):
             f = pq.ParquetFile(path)
             for rg in range(f.metadata.num_row_groups):
-                t = f.read_row_group(rg, columns=self._file_columns())
-                yield self._with_partition_columns(
-                    decode_to_schema(t, self.data_schema), path)
+                if not self.data_schema:
+                    chunk = row_carrier_table(
+                        f.metadata.row_group(rg).num_rows)
+                else:
+                    chunk = decode_to_schema(
+                        f.read_row_group(rg, columns=cols), self.data_schema)
+                yield self._with_partition_columns(chunk, path)
+
+
+def _flat_filters(filters) -> list:
+    """The (column, op, value) terms of a pyarrow filter in either of its
+    forms: one conjunction, or a list of them."""
+    terms = []
+    for f in filters:
+        if isinstance(f, (list, tuple)) and f and \
+                isinstance(f[0], (list, tuple)):
+            terms.extend(f)
+        else:
+            terms.append(f)
+    return terms
 
 
 def write_parquet(table: HostTable, path: str,

@@ -197,6 +197,12 @@ register_metric("h2dBatches", "count", "MODERATE",
                 "batches uploaded at the HostToDevice transition")
 register_metric("scanUploadTime", "timing", "MODERATE",
                 "host->device upload time at file scans")
+register_metric("scanColumnsRead", "count", "ESSENTIAL",
+                "columns a file scan decodes and uploads (its own and "
+                "its partition columns; set once an execution)")
+register_metric("scanColumnsPruned", "count", "ESSENTIAL",
+                "columns of the files that column pruning kept a file "
+                "scan from reading")
 register_metric("shuffleWriteTime", "timing", "MODERATE",
                 "shuffle partition split + write time")
 register_metric("shuffleReadTime", "timing", "MODERATE",

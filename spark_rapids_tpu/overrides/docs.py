@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import List
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.io.common import FileScanNode
 
 #: probe instance per doc column — a sig supports the column iff it
 #: supports this representative type
@@ -95,7 +96,11 @@ def generate_supported_ops() -> str:
         # flows through; per-construct carve-outs — e.g. avg over a
         # dec128 input — still tag fallback at the expression level)
         sig = _EXEC_SIGS.get(node_cls, COMMON_128)
-        lines.append(_matrix_row(node_cls.__name__, sig))
+        # a file scan (io/common.py FileScanNode) is narrowed by column
+        # pruning to what the plan reads
+        note = ("decodes and uploads the referenced columns only"
+                if issubclass(node_cls, FileScanNode) else "")
+        lines.append(_matrix_row(node_cls.__name__, sig, note))
     lines += [
         "",
         "## Expressions",

@@ -1,25 +1,41 @@
 """Column pruning (reference: Spark's ColumnPruning logical rule, which the
-reference plugin inherits for free by overriding PHYSICAL plans —
-GpuOverrides.scala consumes already-pruned plans. This engine builds its own
-logical plans, so it needs the rule itself).
+reference plugin inherits for free by overriding PHYSICAL plans:
+GpuOverrides.scala consumes plans that are already pruned, down to the
+``readDataSchema`` of their file scans. This engine builds its own logical
+plans, so it needs the rule itself).
 
-On TPU the payoff is direct: every column that survives to a join is a
-1M-row gather (and, on the sort path, a scatter) of emulated-64-bit halves
-— measured ~10-30ms per column per operator at 1M rows (PERF.md). A q3-
-style plan carries 4 dead columns through two joins; pruning removes every
-gather for them.
+``prune_plan(root)`` returns an equivalent plan in which every node's input
+carries only the columns referenced above it (plus the node's own keys and
+conditions), and pushes what is left INTO the leaf where the leaf can act
+on it:
 
-``prune_plan(root)`` returns an equivalent plan in which each Join input
-carries only the columns referenced above it (plus its own keys/condition).
+- a file scan (io/common.py ``FileScanNode``) is replaced by
+  ``node.narrowed(names)``, a copy that reads only those columns: the
+  reader decodes, and ``TpuFileScanExec`` stages and uploads, nothing
+  else. The node of a DataFrame or temp view is shared by every query
+  over it and is never changed. A plan that reads no column at all
+  (``count(*)``) keeps the scan's cheapest one, see ``_default_column``;
+- any other leaf (a cached table's ``LocalScan``, a range) is kept whole
+  under a Project of the kept columns, which the consumers that peel
+  their input chain (execs/fuse.py) turn into ``narrow_to_references``.
+
+On TPU the payoff is direct. A column that survives to a join is a 1M-row
+gather (and, on the sort path, a scatter) of emulated-64-bit halves,
+~10-30 ms per column per operator at 1M rows (PERF.md). A column that
+survives to a file scan is decoded on the host, string rows through Python
+objects, and uploaded: TPC-H Q1 reads 7 of lineitem's 16.
+
 The pass rewrites BOUND expressions (BoundReference ordinals), preserving
-output names exactly — the root's schema is unchanged.
+output names exactly: the root's schema is unchanged.
 """
 
 from __future__ import annotations
 
 from typing import FrozenSet, List, Sequence
 
+from spark_rapids_tpu.io.common import FileScanNode
 from spark_rapids_tpu.ops.expr import Alias, BoundReference, Expression
+from spark_rapids_tpu.ops.inputfile import FILE_INFO_COLS
 from spark_rapids_tpu.plan import nodes as P
 
 
@@ -48,17 +64,42 @@ def _keep_project(node: P.PlanNode, keep: List[int]) -> P.PlanNode:
     return P.Project(node, exprs)
 
 
+#: nodes whose output schema is their child's, column for column
+_SCHEMA_TRANSPARENT = (P.Filter, P.Sort, P.Limit, P.CollectLimit)
+
+
+def _default_column(node: P.PlanNode) -> int:
+    """The one column ``node`` keeps when nothing above reads any (row
+    counts need one to carry them). Ordinal 0, except over a file scan,
+    where it is the cheapest to produce: a partition column (its values
+    come from the path, no byte of the file is decoded) or else the
+    narrowest fixed-width data column. ``count(*)`` over files must not
+    decode a string column."""
+    while isinstance(node, _SCHEMA_TRANSPARENT):
+        node = node.children[0]
+    if isinstance(node, FileScanNode):
+        return node.cheapest_column()
+    return 0
+
+
+def _kept(node: P.PlanNode, required) -> List[int]:
+    """The ordinals ``_visit(node, required)`` outputs, in order."""
+    nall = len(node.output_schema())
+    kept = sorted(frozenset(i for i in required if i < nall))
+    if not kept and nall:
+        kept = [_default_column(node)]
+    return kept
+
+
 def _visit(node: P.PlanNode, required: FrozenSet[int]):
     """Rewrite ``node`` so its output is exactly
-    ``[schema[i] for i in sorted(required)]``. Returns the new node; the
-    caller remaps its ordinals via ``sorted(required).index(old)``."""
+    ``[schema[i] for i in _kept(node, required)]``: ``sorted(required)``,
+    or the node's default column when nothing is required. Returns the new
+    node; the caller remaps its ordinals via ``_kept(...).index(old)``."""
     schema = node.output_schema()
     nall = len(schema)
-    required = frozenset(i for i in required if i < nall)
-    if not required and nall:
-        required = frozenset([0])  # keep one column (row counts need one)
-    kept = sorted(required)
-    mapping = {o: i for i, o in enumerate(kept)}
+    kept = _kept(node, required)
+    required = frozenset(kept)
 
     if isinstance(node, P.Project):
         exprs = [node.exprs[i] for i in kept]
@@ -67,8 +108,7 @@ def _visit(node: P.PlanNode, required: FrozenSet[int]):
         for e in exprs:
             _collect_refs(e, creq)
         child = _visit(node.children[0], frozenset(creq))
-        cmap = {o: i for i, o in enumerate(sorted(
-            o for o in creq if o < len(node.children[0].output_schema())))}
+        cmap = {o: i for i, o in enumerate(_kept(node.children[0], creq))}
         new = P.Project(child, [Alias(_remap_strip(e, cmap), n)
                                 for e, n in zip(exprs, names)])
         return new
@@ -77,7 +117,7 @@ def _visit(node: P.PlanNode, required: FrozenSet[int]):
         creq: set = set(kept)
         _collect_refs(node.condition, creq)
         child = _visit(node.children[0], frozenset(creq))
-        ckept = sorted(frozenset(i for i in creq if i < nall) or {0})
+        ckept = _kept(node.children[0], creq)
         cmap = {o: i for i, o in enumerate(ckept)}
         new = P.Filter(child, _remap(node.condition, cmap))
         if ckept != kept:
@@ -100,11 +140,8 @@ def _visit(node: P.PlanNode, required: FrozenSet[int]):
             rreq |= {o - nl for o in cond_refs if o >= nl}
         left = _visit(node.children[0], frozenset(lreq))
         right = _visit(node.children[1], frozenset(rreq))
-        lkept = sorted(frozenset(
-            o for o in lreq if o < nl) or {0})
-        rkept = sorted(frozenset(
-            o for o in rreq
-            if o < len(node.children[1].output_schema())) or {0})
+        lkept = _kept(node.children[0], lreq)
+        rkept = _kept(node.children[1], rreq)
         lmap = {o: i for i, o in enumerate(lkept)}
         rmap = {o: i for i, o in enumerate(rkept)}
         jmap = dict(lmap)
@@ -128,9 +165,7 @@ def _visit(node: P.PlanNode, required: FrozenSet[int]):
         for _, fn in node.agg_specs:
             _collect_refs(fn, creq)
         child = _visit(node.children[0], frozenset(creq))
-        ckept = sorted(frozenset(
-            o for o in creq
-            if o < len(node.children[0].output_schema())) or {0})
+        ckept = _kept(node.children[0], creq)
         cmap = {o: i for i, o in enumerate(ckept)}
         new = P.Aggregate.__new__(P.Aggregate)
         new.children = (child,)
@@ -154,9 +189,7 @@ def _visit(node: P.PlanNode, required: FrozenSet[int]):
         else:
             creq |= set(kept)
         child = _visit(node.children[0], frozenset(creq))
-        ckept = sorted(frozenset(
-            o for o in creq
-            if o < len(node.children[0].output_schema())) or {0})
+        ckept = _kept(node.children[0], creq)
         cmap = {o: i for i, o in enumerate(ckept)}
         orders = [P.SortOrder(_remap(o.expr, cmap), o.ascending,
                               o.nulls_first) for o in node.orders]
@@ -192,10 +225,34 @@ def _visit(node: P.PlanNode, required: FrozenSet[int]):
         # each child now outputs exactly sorted(required) — schemas align
         return P.Union(kids)
 
-    # conservative default: keep the node whole, prune nothing below it
     if kept == list(range(nall)):
         return node
+    if isinstance(node, FileScanNode):
+        return _narrow_scan(node, kept)
+    # conservative default: keep the node whole, prune nothing below it
     return _keep_project(node, kept)
+
+
+def _narrow_scan(node: FileScanNode, kept: List[int]) -> P.PlanNode:
+    """A file scan that outputs exactly the ordinals ``kept`` of ``node``:
+    a narrowed copy, never the shared node changed. The hidden provenance
+    columns of overrides/input_file.py follow the scan's own, all or none:
+    the copy goes on appending them, and a Project drops those nothing
+    reads."""
+    names = [n for n, _ in node.output_schema()]
+    nbase = len(names)
+    if tuple(names[-len(FILE_INFO_COLS):]) == FILE_INFO_COLS:
+        nbase -= len(FILE_INFO_COLS)
+    base = [o for o in kept if o < nbase] or [node.cheapest_column()]
+    new = node if len(base) == nbase \
+        else node.narrowed([names[o] for o in base])
+    if new is node:
+        # nothing of the scan's own to drop, or a reader that opts out
+        return _keep_project(node, kept)
+    out = base + list(range(nbase, len(names)))
+    if out == kept:
+        return new
+    return _keep_project(new, [out.index(o) for o in kept])
 
 
 def _remap_strip(e: Expression, cmap: dict) -> Expression:

@@ -1186,6 +1186,16 @@ def _insert_window_group_limits(node: P.PlanNode) -> P.PlanNode:
     return new_filter
 
 
+def _pruned(plan: P.PlanNode, conf: RapidsConf) -> P.PlanNode:
+    """The plan after column pruning (overrides/pruning.py), where
+    ``spark.rapids.tpu.sql.columnPruning.enabled`` has it on."""
+    from spark_rapids_tpu.conf import COLUMN_PRUNING
+    if not conf.get_entry(COLUMN_PRUNING):
+        return plan
+    from spark_rapids_tpu.overrides.pruning import prune_plan
+    return prune_plan(plan)
+
+
 def apply_overrides(plan: P.PlanNode, conf: RapidsConf):
     """GpuOverrides.apply analog: tag + CBO + convert (or explain-only)."""
     if not conf.sql_enabled:
@@ -1195,10 +1205,7 @@ def apply_overrides(plan: P.PlanNode, conf: RapidsConf):
     # (idempotent when the session's placement layer already prepared)
     from spark_rapids_tpu.parallel.mesh import MESH
     MESH.configure(conf)
-    from spark_rapids_tpu.conf import COLUMN_PRUNING
-    if conf.get_entry(COLUMN_PRUNING):
-        from spark_rapids_tpu.overrides.pruning import prune_plan
-        plan = prune_plan(plan)
+    plan = _pruned(plan, conf)
     plan = _insert_window_group_limits(plan)
     meta = wrap_plan(plan, conf)
     from spark_rapids_tpu.overrides.optimizer import apply_cbo
@@ -1222,6 +1229,10 @@ def explain_plan(plan: P.PlanNode, conf: RapidsConf) -> str:
     # on, not a stale (or never-configured) mesh
     from spark_rapids_tpu.parallel.mesh import MESH
     MESH.configure(conf)
+    # and the same pruning: the plan shown is the plan that runs, down to
+    # the columns its file scans read
+    if conf.sql_enabled:
+        plan = _pruned(plan, conf)
     meta = wrap_plan(plan, conf)
     out = meta.explain(only_fallback=conf.explain_mode != "ALL")
     # poison-query quarantine (runtime/health.py): a template with a

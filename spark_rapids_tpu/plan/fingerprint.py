@@ -338,8 +338,13 @@ def _fp_value_table(table) -> str:
 
 
 #: per-node attributes that never affect results (caches, back-refs;
-#: the session conf folds into the fingerprint separately)
-_SKIP_ATTRS = {"_session", "_table", "conf", "_conf"}
+#: the session conf folds into the fingerprint separately;
+#: ``_discovered`` is a file scan's schema before ``columns`` narrows it,
+#: which the node's ``columns`` and resolved schemas already say, and
+#: ``_deletes`` an Iceberg scan's delete files once read, which its
+#: snapshot already says)
+_SKIP_ATTRS = {"_session", "_table", "conf", "_conf", "_discovered",
+               "_deletes"}
 
 
 def _fp_node(node, depth: int = 0, strip_literals: bool = False) -> str:
