@@ -160,7 +160,14 @@ EVENT_LOG_DIR = str_conf(
 #: was built (``buildSideSwapped``) and, for a direct inner join, how
 #: many probe batches the device found clustered and looked up by
 #: windows of the table (``clusteredProbeBatches``).
-EVENT_SCHEMA_VERSION = 15
+#: v16 (tracing PR): phasesS gains gcS — host seconds this query's
+#: thread spent in Python's collector from the envelope's start to the
+#: record (plan, execute, collect and the observation's row-count fetch;
+#: a collection on another thread is that thread's), by the process-wide
+#: ``gc.callbacks`` hook of obs/spans.py, which also opens each
+#: collection as the range ``srt.gc.gen<N>``. It overlaps whichever
+#: phase the collection interrupted.
+EVENT_SCHEMA_VERSION = 16
 
 
 def plan_tree(executable) -> dict:

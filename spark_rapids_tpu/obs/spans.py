@@ -32,10 +32,16 @@ Two layers:
   ALWAYS — row counts that only exist on device are deferred and
   resolved in ONE batched fetch by :func:`finalize_observation`, never
   a per-batch sync.
+
+And Python's collector: :func:`install_gc_hook` (once a process, at the
+first observed query) opens every collection as ``srt.gc.gen<N>`` on the
+thread it interrupts and counts its seconds there (:func:`gc_seconds`;
+the record's ``phasesS.gcS``).
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
@@ -349,14 +355,74 @@ def span(name: str, cat: str = "op", **args):
         full = "srt." + name   # srt.query; names that carry their cat
     else:
         full = f"srt.{cat}.{name}"
-    query = getattr(TRACER._tls, "query", None)
-    if query is None:
-        ann = TraceAnnotation(full, **args)
-    else:
-        ann = TraceAnnotation(full, query=query, **args)
+    ann = _annotation(full, args)
     if not TRACER.enabled:
         return ann
     return _LiveSpan(ann, name, cat, args)
+
+
+def _annotation(full: str, args: dict) -> TraceAnnotation:
+    """A range's profiler half: ``full`` with the thread's query index
+    and ``args`` as metadata."""
+    query = getattr(TRACER._tls, "query", None)
+    if query is None:
+        return TraceAnnotation(full, **args)
+    return TraceAnnotation(full, query=query, **args)
+
+
+# ---------------------------------------------------------------------------
+# Python's collector
+# ---------------------------------------------------------------------------
+
+
+class _GcState(threading.local):
+    """This thread's seconds in Python's collector since the hook went in
+    (a collection runs on the thread whose allocation set it off), and
+    the collection open on it."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.open = None
+
+
+_GC = _GcState()
+#: {"installed": the token of the call that put the hook in}
+_GC_HOOK: Dict[str, object] = {}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: each collection is the range
+    ``srt.gc.gen<N>`` and adds its seconds to this thread's count. Two
+    ``perf_counter`` reads and one annotation a collection. Only the
+    annotation half of :func:`span`: the tracer's half takes its lock,
+    which the collection may have interrupted this thread holding."""
+    if phase == "start":
+        ann = _annotation(f"srt.gc.gen{info['generation']}", {})
+        ann.__enter__()
+        _GC.open = (ann, time.perf_counter())
+    elif _GC.open is not None:
+        ann, t0 = _GC.open
+        _GC.open = None
+        _GC.seconds += time.perf_counter() - t0
+        ann.__exit__(None, None, None)
+
+
+def install_gc_hook() -> None:
+    """Put :func:`_on_gc` on ``gc.callbacks``, once a process (the
+    session calls it for the first query its event log or tracing
+    observes)."""
+    if "installed" in _GC_HOOK:
+        return
+    token = object()
+    # setdefault is atomic: of two first queries, one puts the hook in
+    if _GC_HOOK.setdefault("installed", token) is token:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_seconds() -> float:
+    """This thread's seconds in the collector since the hook went in: a
+    query's ``phasesS.gcS`` is the difference across its envelope."""
+    return _GC.seconds
 
 
 # ---------------------------------------------------------------------------

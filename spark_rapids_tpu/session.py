@@ -309,7 +309,12 @@ class TpuSession:
         state is thread-local and the span tracer scopes each query to
         its executing thread."""
         from spark_rapids_tpu.obs import events as E
-        from spark_rapids_tpu.obs.spans import TRACE_ENABLED, TRACER, span
+        from spark_rapids_tpu.obs.spans import (
+            TRACE_ENABLED,
+            TRACER,
+            install_gc_hook,
+            span,
+        )
 
         q = self._q
         tags = {"query_tag": q.next_tag, "sql_text": q.next_sql,
@@ -344,6 +349,7 @@ class TpuSession:
             qidx = self._obs_query_seq
             self._obs_query_seq += 1
         if ev_enabled or tr_enabled:
+            install_gc_hook()
             TRACER.begin_query(qidx)
         else:
             # no envelope for THIS query, but another session's
@@ -371,18 +377,27 @@ class TpuSession:
         import time as _time
 
         from spark_rapids_tpu.obs import events as E
-        from spark_rapids_tpu.obs.spans import TRACE_DIR, TRACER, span
+        from spark_rapids_tpu.obs.spans import (
+            TRACE_DIR,
+            TRACER,
+            gc_seconds,
+            span,
+        )
 
         q = self._q
         obs_active = ev_enabled or tr_enabled
+        gc_before = gc_seconds()
         if obs_active:
-            from spark_rapids_tpu.obs.metrics import scopes_snapshot
-            from spark_rapids_tpu.runtime.faults import FAULTS, RECOVERY
-            from spark_rapids_tpu.runtime.health import HEALTH
-            before_scopes = scopes_snapshot()
-            before_recovery = RECOVERY.snapshot()
-            before_fires = FAULTS.counters()
-            before_health = HEALTH.snapshot()
+            # the observation's first half: the counters the record
+            # reports as deltas, read before the run
+            with span("observe", "phase"):
+                from spark_rapids_tpu.obs.metrics import scopes_snapshot
+                from spark_rapids_tpu.runtime.faults import FAULTS, RECOVERY
+                from spark_rapids_tpu.runtime.health import HEALTH
+                before_scopes = scopes_snapshot()
+                before_recovery = RECOVERY.snapshot()
+                before_fires = FAULTS.counters()
+                before_health = HEALTH.snapshot()
         q.exec_depth = 1
         t0 = _time.perf_counter()
         try:
@@ -403,20 +418,22 @@ class TpuSession:
             return result
         wall_s = _time.perf_counter() - t0
         spans = TRACER.end_query()
-
-        from spark_rapids_tpu.obs.spans import (
-            finalize_observation,
-            summarize_spans,
-            write_chrome_trace,
-        )
-        from spark_rapids_tpu.parallel.mesh import MESH
-        from spark_rapids_tpu.runtime.cluster import CLUSTER, host_scan_stats
-        from spark_rapids_tpu.runtime.faults import CIRCUIT_BREAKER
-        stream_deltas = tags["stream_deltas"]
-        phases = dict(q.phases or {})
-        if tags["parse_s"] is not None:
-            phases["parseS"] = tags["parse_s"]
         with span("observe", "phase"):
+            from spark_rapids_tpu.obs.spans import (
+                finalize_observation,
+                summarize_spans,
+                write_chrome_trace,
+            )
+            from spark_rapids_tpu.parallel.mesh import MESH
+            from spark_rapids_tpu.runtime.cluster import (
+                CLUSTER,
+                host_scan_stats,
+            )
+            from spark_rapids_tpu.runtime.faults import CIRCUIT_BREAKER
+            stream_deltas = tags["stream_deltas"]
+            phases = dict(q.phases or {})
+            if tags["parse_s"] is not None:
+                phases["parseS"] = tags["parse_s"]
             executable = q.executable
             if executable is not None:
                 finalize_observation(executable)
@@ -441,6 +458,7 @@ class TpuSession:
                 return int(after_scopes.get(scope, {}).get(key, 0)
                            - before_scopes.get(scope, {}).get(key, 0))
 
+            phases["gcS"] = gc_seconds() - gc_before
             record = E.build_query_record(
                 query_index=qidx,
                 wall_s=wall_s,
@@ -573,6 +591,30 @@ class TpuSession:
                 return
             stack.extend(getattr(node, "children", ()))
 
+    def _configure_runtime(self) -> None:
+        """The runtime follows this session's conf, once a query, before
+        its first attempt plans (range ``srt.phase.configure``)."""
+        from spark_rapids_tpu.conf import TEST_FAULTS
+        from spark_rapids_tpu.runtime import faults as F
+        F.FAULTS.arm(str(self.conf.get_entry(TEST_FAULTS) or ""))
+        # a host that relies on JAX auto-detection (no JAX_PLATFORMS)
+        # has no cache decision from import time: take it here, where
+        # the backend is about to be used anyway (a flag read once the
+        # cache is on; two cached lookups on the CPU backend)
+        import spark_rapids_tpu as _pkg
+        _pkg.ensure_compile_cache()
+        # telemetry sampler + flight-recorder defaults follow this
+        # session's conf (cheap no-op when unchanged, the arm contract)
+        from spark_rapids_tpu.obs.telemetry import TELEMETRY
+        TELEMETRY.configure(self.conf)
+        # the device memory arbiter's hard budget follows it too
+        from spark_rapids_tpu.runtime import memory as _memory
+        _memory.MEMORY.configure(self.conf)
+        # runtime lock witness (construction-time election — locks
+        # built after this point are wrapped iff the conf arms it)
+        from spark_rapids_tpu import lockorder as _lockorder
+        _lockorder.configure(self.conf)
+
     def _execute_with_recovery(self, plan: P.PlanNode) -> HostTable:
         """Plan, verify, and drain a query — wrapped in TWO distinct
         recovery layers:
@@ -595,7 +637,6 @@ class TpuSession:
         from spark_rapids_tpu.conf import (
             RUNTIME_FALLBACK_ENABLED,
             RUNTIME_FALLBACK_MAX_FAILURES,
-            TEST_FAULTS,
         )
         from spark_rapids_tpu.errors import DeviceLostError, KernelCrashError
         from spark_rapids_tpu.runtime import faults as F
@@ -604,24 +645,9 @@ class TpuSession:
             is_fatal_device_error,
         )
 
-        F.FAULTS.arm(str(self.conf.get_entry(TEST_FAULTS) or ""))
-        # a host that relies on JAX auto-detection (no JAX_PLATFORMS)
-        # has no cache decision from import time: take it here, where
-        # the backend is about to be used anyway (a flag read once the
-        # cache is on; two cached lookups on the CPU backend)
-        import spark_rapids_tpu as _pkg
-        _pkg.ensure_compile_cache()
-        # telemetry sampler + flight-recorder defaults follow this
-        # session's conf (cheap no-op when unchanged, the arm contract)
-        from spark_rapids_tpu.obs.telemetry import TELEMETRY
-        TELEMETRY.configure(self.conf)
-        # the device memory arbiter's hard budget follows it too
-        from spark_rapids_tpu.runtime import memory as _memory
-        _memory.MEMORY.configure(self.conf)
-        # runtime lock witness (construction-time election — locks
-        # built after this point are wrapped iff the conf arms it)
-        from spark_rapids_tpu import lockorder as _lockorder
-        _lockorder.configure(self.conf)
+        from spark_rapids_tpu.obs.spans import span
+        with span("configure", "phase"):
+            self._configure_runtime()
         rf_enabled = bool(self.conf.get_entry(RUNTIME_FALLBACK_ENABLED))
         max_failures = int(self.conf.get_entry(RUNTIME_FALLBACK_MAX_FAILURES))
         # enough budget to demote every op in a pathological plan without
@@ -678,19 +704,22 @@ class TpuSession:
             try:
                 with attempt_ctx, cluster_ctx, chunk_ctx:
                     result = self._execute_attempt(plan)
-                self.last_fault_replays = replays
-                if replays and hasattr(self._last_executable, "metrics"):
-                    self._last_executable.metrics["runtimeFaultReplays"] = \
-                        replays
-                from spark_rapids_tpu.runtime.health import HEALTH
-                # the MESH ladder only resets on a mesh-NATIVE success:
-                # a suppressed (single-device) convergence proves
-                # nothing about the mesh's health — and the HOST ladder
-                # likewise only on a cluster-NATIVE success
-                HEALTH.note_success(
-                    mesh_native=not was_suppressed and _mesh.MESH.enabled,
-                    cluster_native=(not was_csuppressed
-                                    and _cluster.CLUSTER.active()))
+                # the run is accounted: its replays, and the health
+                # ladders told it succeeded
+                with span("account", "phase"):
+                    self.last_fault_replays = replays
+                    if replays and hasattr(self._last_executable, "metrics"):
+                        self._last_executable.metrics[
+                            "runtimeFaultReplays"] = replays
+                    from spark_rapids_tpu.runtime.health import HEALTH
+                    # the MESH ladder only resets on a mesh-NATIVE success:
+                    # a suppressed (single-device) convergence proves
+                    # nothing about the mesh's health — and the HOST ladder
+                    # likewise only on a cluster-NATIVE success
+                    HEALTH.note_success(
+                        mesh_native=not was_suppressed and _mesh.MESH.enabled,
+                        cluster_native=(not was_csuppressed
+                                        and _cluster.CLUSTER.active()))
                 return result
             except Exception as exc:
                 from spark_rapids_tpu.errors import FatalDeviceOOM
@@ -995,39 +1024,43 @@ class TpuSession:
             executable, meta, tok = self._plan(plan)
         phases = {"planS": _time.perf_counter() - t_phase}
 
-        inject = str(self.conf.get_entry(TEST_INJECT_RETRY_OOM) or "")
-        if inject:
-            kind, _, num = inject.partition(":")
-            count = int(num) if num else 1
-            if kind.strip().lower() == "retry":
-                RMM_TPU.force_retry_oom(count)
-            elif kind.strip().lower() == "split":
-                RMM_TPU.force_split_and_retry_oom(count)
+        # the planned tree armed for its run: injected OOMs, the root's
+        # async fetch, the retry budget, the per-query counters reset
+        with span("arm", "phase"):
+            inject = str(self.conf.get_entry(TEST_INJECT_RETRY_OOM) or "")
+            if inject:
+                kind, _, num = inject.partition(":")
+                count = int(num) if num else 1
+                if kind.strip().lower() == "retry":
+                    RMM_TPU.force_retry_oom(count)
+                elif kind.strip().lower() == "split":
+                    RMM_TPU.force_split_and_retry_oom(count)
 
-        # async result fetch: arm the ROOT transition only — mid-plan
-        # DeviceToHost nodes feed CPU fallback operators that expect
-        # plain host batches. Re-set either way so a cached executable
-        # never carries a previous query's flag.
-        from spark_rapids_tpu.conf import ASYNC_RESULT_FETCH
-        from spark_rapids_tpu.execs.base import DeviceToHost as _D2H
-        if isinstance(executable, _D2H):
-            executable._async_fetch = bool(
-                self.conf.get_entry(ASYNC_RESULT_FETCH))
+            # async result fetch: arm the ROOT transition only — mid-plan
+            # DeviceToHost nodes feed CPU fallback operators that expect
+            # plain host batches. Re-set either way so a cached executable
+            # never carries a previous query's flag.
+            from spark_rapids_tpu.conf import ASYNC_RESULT_FETCH
+            from spark_rapids_tpu.execs.base import DeviceToHost as _D2H
+            if isinstance(executable, _D2H):
+                executable._async_fetch = bool(
+                    self.conf.get_entry(ASYNC_RESULT_FETCH))
 
-        token = MAX_RETRIES_VAR.set(self.conf.get_entry(RETRY_OOM_MAX_RETRIES))
-        from spark_rapids_tpu.dispatch import (
-            dispatch_count,
-            reset_compile_stats,
-            reset_dispatch_count,
-            reset_query_phases,
-        )
-        reset_dispatch_count()
-        if q.exec_depth == 1:
-            # top level only: a NESTED execute resetting mid-drain
-            # would zero the outer query's trace/pad-waste accounting
-            # and its dispatch/sync/fetch seconds
-            reset_compile_stats()
-            reset_query_phases()
+            token = MAX_RETRIES_VAR.set(
+                self.conf.get_entry(RETRY_OOM_MAX_RETRIES))
+            from spark_rapids_tpu.dispatch import (
+                dispatch_count,
+                reset_compile_stats,
+                reset_dispatch_count,
+                reset_query_phases,
+            )
+            reset_dispatch_count()
+            if q.exec_depth == 1:
+                # top level only: a NESTED execute resetting mid-drain
+                # would zero the outer query's trace/pad-waste accounting
+                # and its dispatch/sync/fetch seconds
+                reset_compile_stats()
+                reset_query_phases()
         t_phase = _time.perf_counter()
         try:
             with span("execute", "phase"), self.profiler.profile_query():
@@ -1059,18 +1092,19 @@ class TpuSession:
             # dispatch layer took where the work happened join the
             # phases here too
             if q.exec_depth == 1:
-                from spark_rapids_tpu.dispatch import (
-                    compile_stats,
-                    flush_trace_cache_hits,
-                    host_fetch_count,
-                    phase_seconds,
-                )
-                traces, compile_s, pad = compile_stats()
-                self.last_compile_ms = round(compile_s * 1000.0, 3)
-                self.last_pad_waste_rows = pad
-                flush_trace_cache_hits()
-                phases.update(phase_seconds())
-                q.host_syncs = host_fetch_count()
+                with span("account", "phase"):
+                    from spark_rapids_tpu.dispatch import (
+                        compile_stats,
+                        flush_trace_cache_hits,
+                        host_fetch_count,
+                        phase_seconds,
+                    )
+                    traces, compile_s, pad = compile_stats()
+                    self.last_compile_ms = round(compile_s * 1000.0, 3)
+                    self.last_pad_waste_rows = pad
+                    flush_trace_cache_hits()
+                    phases.update(phase_seconds())
+                    q.host_syncs = host_fetch_count()
         # a fully successful run fills its executable-cache slot (the
         # entry stays checked out until the query envelope releases it)
         if tok is not None and not tok.hit:

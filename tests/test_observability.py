@@ -203,10 +203,35 @@ _SQL = "select k, sum(v) as sv, count(*) as c from t where v > 10 group by k"
 
 #: the ranges of one query, inside its srt.query (parse runs in sql(),
 #: before execute() opens the query)
-_QUERY_RANGES = ("srt.phase.plan", "srt.phase.execute", "srt.phase.collect",
-                 "srt.phase.observe", "srt.fetch.resolve", "srt.fetch.wait",
-                 "srt.fetch.unpack", "srt.wait.semaphore",
-                 "srt.eventlog.write")
+_QUERY_RANGES = ("srt.phase.configure", "srt.phase.plan", "srt.phase.arm",
+                 "srt.phase.execute", "srt.phase.collect",
+                 "srt.phase.account", "srt.phase.observe",
+                 "srt.fetch.resolve", "srt.fetch.wait", "srt.fetch.unpack",
+                 "srt.wait.semaphore", "srt.eventlog.write")
+
+
+def _xprof_query(s, run, trace_dir):
+    """Run ``run()`` under a jax.profiler session; the /host:CPU plane
+    of its .xplane.pb."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"]
+    assert host, "no /host:CPU plane in the trace"
+    return host[0]
 
 
 def test_srt_spans_lie_on_the_profilers_host_timeline(tmp_path):
@@ -214,33 +239,19 @@ def test_srt_spans_lie_on_the_profilers_host_timeline(tmp_path):
     the engine's ranges are events of /host:CPU in the .xplane.pb — the
     clock the device planes share — named srt.<cat>.<name>, each inside
     the srt.query of its thread and carrying the query index."""
-    import glob
-
-    import jax
-    from jax.profiler import ProfileData
-
     s = _sql_session(tmp_path)
     s.sql(_SQL).collect_table()  # warm: keep compiles out of the trace
-    trace_dir = str(tmp_path / "xprof")
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    options.host_tracer_level = 2
-    jax.profiler.start_trace(trace_dir, profiler_options=options)
-    try:
-        s.sql(_SQL).collect_table()
-    finally:
-        jax.profiler.stop_trace()
+    host = _xprof_query(s, lambda: s.sql(_SQL).collect_table(),
+                        str(tmp_path / "xprof"))
     qidx = s.last_event_record["queryIndex"]
-    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                        recursive=True)
-    host = [p for p in ProfileData.from_file(path).planes
-            if p.name == "/host:CPU"]
-    assert host, "no /host:CPU plane in the trace"
     by_line = {}
-    for line in host[0].lines:
+    for line in host.lines:
+        # a collection opens srt.gc.gen<N> on whichever thread it falls
+        # (test_a_collection_inside_a_query_is_a_range_and_a_count)
         events = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
                    dict(e.stats)) for e in line.events
-                  if e.name.startswith("srt.")]
+                  if e.name.startswith("srt.")
+                  and not e.name.startswith("srt.gc.")]
         if events:
             by_line[line.name] = events
     assert len(by_line) == 1, f"srt ranges on lines {sorted(by_line)}"
@@ -263,6 +274,101 @@ def test_srt_spans_lie_on_the_profilers_host_timeline(tmp_path):
             continue
         assert q0 <= t0 and t1 <= q1, f"{name} outside its srt.query"
         assert stats.get("query") == qidx, (name, stats)
+
+
+def _collect_forcing_gc(monkeypatch):
+    """The collect phase runs Python's collector once (generation 2)."""
+    import gc
+
+    from spark_rapids_tpu.columnar import HostTable
+    concat = HostTable.concat
+
+    def collecting_concat(batches):
+        gc.collect()
+        return concat(batches)
+
+    monkeypatch.setattr(HostTable, "concat", staticmethod(collecting_concat))
+
+
+def test_a_collection_inside_a_query_is_a_range_and_a_count(
+        tmp_path, monkeypatch):
+    """Python's collector, seen by the program: a collection forced
+    inside a query is the range srt.gc.gen2 inside that query's
+    srt.query, on its thread and carrying its index, and its seconds are
+    the record's phasesS.gcS."""
+    from spark_rapids_tpu.obs import spans
+
+    s = _sql_session(tmp_path)
+    s.sql(_SQL).collect_table()
+    assert s.last_event_record["phasesS"]["gcS"] >= 0
+    _collect_forcing_gc(monkeypatch)
+    host = _xprof_query(s, lambda: s.sql(_SQL).collect_table(),
+                        str(tmp_path / "xprof"))
+    rec = s.last_event_record
+    (line,) = [ln for ln in host.lines
+               if any(e.name == "srt.query" for e in ln.events)]
+    (query,) = [e for e in line.events if e.name == "srt.query"]
+    gen2 = [e for e in line.events if e.name == "srt.gc.gen2"]
+    assert len(gen2) >= 1
+    for e in gen2:
+        assert query.start_ns <= e.start_ns
+        assert e.start_ns + e.duration_ns <= query.start_ns \
+            + query.duration_ns
+        assert dict(e.stats).get("query") == rec["queryIndex"]
+    in_query = sum(e.duration_ns for e in line.events
+                   if e.name.startswith("srt.gc.")
+                   and query.start_ns <= e.start_ns
+                   and e.start_ns < query.start_ns + query.duration_ns)
+    # the counter and the ranges time the same collections: the counter
+    # reads inside the ranges' bounds, rounded to the microsecond
+    assert rec["phasesS"]["gcS"] > 0
+    assert rec["phasesS"]["gcS"] <= in_query / 1e9 + 1e-5
+    assert rec["phasesS"]["gcS"] <= rec["wallS"]
+    assert spans.gc_seconds() > 0
+
+
+def test_the_collector_hook_is_installed_once(tmp_path):
+    import gc
+
+    from spark_rapids_tpu.obs import spans
+    for i in range(2):
+        s = _sql_session(tmp_path / str(i))
+        s.sql(_SQL).collect_table()
+        assert gc.callbacks.count(spans._on_gc) == 1
+    spans.install_gc_hook()
+    assert gc.callbacks.count(spans._on_gc) == 1
+
+
+def test_gc_seconds_are_the_collecting_threads():
+    """A collection counts on the thread that ran it (the one whose
+    allocation set it off), as the phase accumulators do: another
+    thread's collection is not this thread's query's gcS."""
+    import gc
+    import threading
+
+    from spark_rapids_tpu.obs import spans
+    spans.install_gc_hook()
+    was_enabled = gc.isenabled()
+    gc.disable()  # only the explicit collection below runs
+    try:
+        before = spans.gc_seconds()
+        seen = {}
+
+        def other():
+            start = spans.gc_seconds()
+            gc.collect()
+            seen["other"] = spans.gc_seconds() - start
+
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=60)
+        assert seen["other"] > 0
+        assert spans.gc_seconds() == before
+        gc.collect(0)
+        assert spans.gc_seconds() > before
+    finally:
+        if was_enabled:
+            gc.enable()
 
 
 def _tpu_jit_sites():
@@ -380,7 +486,8 @@ def test_event_log_written_and_valid(tmp_path):
     lines = open(s.last_event_path).read().strip().splitlines()
     assert len(lines) == 1
     rec = json.loads(lines[0])
-    # schema v15: phasesS gains joinS (the join execs' build and probe
+    # schema v16: phasesS gains gcS (the query thread's seconds in
+    # Python's collector); v15: joinS (the join execs' build and probe
     # batches); v14: relandS (mesh re-lands); v13: coalesceS
     # (the coalesce exec's multi-batch flushes); v12: the tracing PR added hostSyncs and the
     # dispatch / sync / fetch / semaphore seconds under phasesS (tested
@@ -394,7 +501,7 @@ def test_event_log_written_and_valid(tmp_path):
     # fault-domain fields, v6's mesh-native fields, v5's
     # transactional-write fields and v4's survivability fields — see
     # obs/events.py
-    assert rec["schema"] == 15
+    assert rec["schema"] == 16
     assert rec["healthState"] == "HEALTHY"
     assert rec["quarantined"] is False
     assert rec["deviceReinits"] == 0 and rec["workerRestarts"] == 0
@@ -511,7 +618,10 @@ def test_event_log_golden_schema(tmp_path):
     one device);
     v15 = phasesS gains joinS (host seconds inside the join execs'
     ranges srt.join.build and srt.join.batch; 0.0 for a query without
-    a join).
+    a join);
+    v16 = phasesS gains gcS (host seconds the query's thread spent in
+    Python's collector, each collection also the range srt.gc.gen<N>;
+    0.0 for a query no collection interrupted).
     Exec metrics in the plan tree are no schema fields (no bump): every
     TpuHashAggregateExec node carries partialCountReads (partials whose
     row count the streaming loop read to shrink them) and runAheadWaits
@@ -594,7 +704,7 @@ def test_record_counts_the_grouped_aggregates(tmp_path, monkeypatch, cap,
 
 _NEW_PHASES = ("parseS", "dispatchS", "syncWaitS", "fetchWaitS",
                "fetchUnpackS", "semaphoreWaitS", "coalesceS", "relandS",
-               "joinS")
+               "joinS", "gcS")
 
 
 def _check_phases(rec):

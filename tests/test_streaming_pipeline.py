@@ -334,7 +334,7 @@ def test_schema_v11_streaming_fields(tmp_path):
         _append(s, base, {"k": [2], "v": [5]})
         mv.read()
         rec = s.last_event_record
-        assert rec["schema"] == 15
+        assert rec["schema"] == 16
         assert rec["mvEpoch"] == mv.epoch()
         assert rec["queryTag"] == f"mv:agg@v{mv.epoch()}"
 
