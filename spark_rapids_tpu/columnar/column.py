@@ -144,7 +144,12 @@ class HostColumn:
 
     ``_cache`` memoizes derived per-column artifacts (dictionary encoding,
     all-valid flag) so repeated uploads of the same host column — re-collects,
-    multi-query reuse of an in-memory table — don't redo O(n) host work."""
+    multi-query reuse of an in-memory table — don't redo O(n) host work. A
+    reader may supply the ``encode`` memo itself: a STRING column decoded
+    from Arrow (io/arrow_convert.py) comes with its (codes, sorted
+    dictionary), so its upload encodes nothing. The memo holds because
+    ``data`` is never mutated once built; slice and concat build new
+    columns, which encode from their objects."""
 
     __slots__ = ("dtype", "data", "validity", "_cache")
 

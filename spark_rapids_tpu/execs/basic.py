@@ -178,6 +178,13 @@ class TpuFileScanExec(TpuExec):
             if len(chunks) > 1:
                 self.add_metric("scanChunks", len(chunks))
             for ch in chunks:
+                # strings the reader encoded as it decoded (io/
+                # arrow_convert.py); a chunk, a stitched or partition
+                # column encodes at the upload
+                self.add_metric("scanStringsPreEncoded", sum(
+                    1 for c in ch.columns
+                    if isinstance(c.dtype, T.StringType)
+                    and "encode" in c._cache))
                 t0 = time.perf_counter()
                 # mesh-native: each decoded file/row-group batch lands
                 # SPLIT across the mesh (execs/basic._upload_sharded)

@@ -3,8 +3,13 @@
 Arrow is the host interchange format (SURVEY.md §7: "Columnar batches live in
 HBM as XLA buffers; Arrow is the host format"). Spark internal representations
 are preserved: DATE as int32 days, TIMESTAMP as int64 micros UTC, DECIMAL(p<=18)
-as int64 unscaled, STRING as Python-str object arrays (dictionary-encoded at
-device upload time)."""
+as int64 unscaled, STRING as Python-str object arrays.
+
+A STRING column is dictionary-encoded here, by Arrow's own kernels, into the
+order-preserving encoding the device upload needs (codes into a dictionary
+sorted in UTF-8 byte order): the column carries it in its ``encode`` memo, so
+``DeviceColumn._encode_strings`` finds it and walks no object a row. Only the
+distinct values become Python objects; the rows of ``data`` share them."""
 
 from __future__ import annotations
 
@@ -12,6 +17,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 import pyarrow as pa
+import pyarrow.compute as pc
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar import HostColumn, HostTable
@@ -84,6 +90,32 @@ def _chunked_to_array(col: pa.ChunkedArray) -> pa.Array:
     return col.combine_chunks() if col.num_chunks != 1 else col.chunk(0)
 
 
+def _string_host_column(arr: pa.Array, dt: T.DataType,
+                        validity: np.ndarray) -> HostColumn:
+    """A STRING column with its upload encoding already made: the same
+    (codes int32, sorted dictionary) that ``native.encode_sorted_dict``
+    gives for ``np.where(validity, data, "")``, with nulls as ``""`` as
+    there. Arrow sorts strings by their bytes, which for UTF-8 is
+    code-point order: Python's, and Spark's UTF8String order."""
+    if not (pa.types.is_string(arr.type) or pa.types.is_large_string(arr.type)):
+        arr = arr.cast(pa.string())  # an all-null column typed null
+    if arr.null_count:
+        arr = arr.fill_null("")
+    enc = arr.dictionary_encode()
+    order = pc.sort_indices(enc.dictionary)
+    k = len(enc.dictionary)
+    rank = np.empty(k, dtype=np.int32)
+    rank[order.to_numpy()] = np.arange(k, dtype=np.int32)
+    codes = rank[enc.indices.to_numpy()]
+    dictionary = enc.dictionary.take(order).to_numpy(zero_copy_only=False)
+    data = dictionary[codes]
+    if not validity.all():
+        data[~validity] = None
+    col = HostColumn(dt, data, validity)
+    col._cache["encode"] = (codes, dictionary)
+    return col
+
+
 def arrow_array_to_host_column(arr, dt: T.DataType) -> HostColumn:
     if isinstance(arr, pa.ChunkedArray):
         arr = _chunked_to_array(arr)
@@ -95,11 +127,7 @@ def arrow_array_to_host_column(arr, dt: T.DataType) -> HostColumn:
         validity = ~np.asarray(arr.is_null())
 
     if isinstance(dt, T.StringType):
-        data = np.empty(n, dtype=object)
-        pylist = arr.to_pylist()
-        for i, v in enumerate(pylist):
-            data[i] = v
-        return HostColumn(dt, data, validity)
+        return _string_host_column(arr, dt, validity)
     if isinstance(dt, T.TimestampType):
         micros = arr.cast(pa.timestamp("us"))
         vals = np.asarray(micros.fill_null(0)).astype("datetime64[us]").astype(np.int64)

@@ -203,6 +203,10 @@ register_metric("scanColumnsRead", "count", "ESSENTIAL",
 register_metric("scanColumnsPruned", "count", "ESSENTIAL",
                 "columns of the files that column pruning kept a file "
                 "scan from reading")
+register_metric("scanStringsPreEncoded", "count", "ESSENTIAL",
+                "string columns of a file scan's landed batches whose "
+                "dictionary encoding came with the decoded batch (the "
+                "upload walked no Python object a row)")
 register_metric("shuffleWriteTime", "timing", "MODERATE",
                 "shuffle partition split + write time")
 register_metric("shuffleReadTime", "timing", "MODERATE",
