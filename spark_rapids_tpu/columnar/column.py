@@ -603,44 +603,48 @@ def null_data_array(dt: T.DataType, capacity: int):
     return jnp.zeros(capacity, dtype=dt.np_dtype)
 
 
+def _pad(values, cap: int, dtype) -> np.ndarray:
+    """``values`` copied into a ``cap``-slot array of ``dtype``: one pass
+    over the rows, and only the tail past them zeroed."""
+    n = len(values)
+    out = np.empty(cap, dtype=dtype)
+    out[:n] = values
+    out[n:] = 0
+    return out
+
+
 def stage_upload(host: HostColumn, cap: int, split_f64: bool):
     """Host side of the fast H2D path: turn one column into (recipe, staged
-    numpy arrays, dictionary). Raw f32/i64/u32/i8 buffers transfer as they
-    are, while f64 (its on-device form is an f32 pair), i32 and bool were
-    converted slowly on the host by the backend this path was tuned on
-    (not re-measured on an attached chip) — so stage every column as a
-    plainly-transferring dtype and let the jitted assemble kernel
-    (table.py) rebuild the logical dtype on device:
+    numpy arrays, dictionary). Every column is staged as a dtype that
+    transfers as it is, padded to the bucket, and the jitted assemble
+    program (table.py) rebuilds the logical dtype on device:
 
-      f64   -> (hi, lo) f32 pair with hi = f32(x), lo = f32(x - hi); the
-               device sum hi+lo is bit-identical to what the native f64
-               transfer produces on the tpu backend (chip_smoke.py's
-               probe on a v5e; the one exception is the f32-denormal
-               range, where the native transfer keeps a denormal high
-               limb and this sum flushes it to zero), and exact f64
-               rides unchanged on CPU backends (split_f64=False there);
+      f64   -> with ``split_f64`` (every non-CPU backend, where a device
+               f64 is an (f32, f32) pair) its raw 64-bit words as int64
+               (``f64bits``): the program splits them into the pair by
+               integer operations (ops/limbs.f64_bits_hi_lo), the same
+               bits a host split f32(x), f32(x - f32(x)) gives, and adds
+               the halves as before (on the tpu backend bit-identical to
+               a native f64 transfer except in the f32-denormal range,
+               where the native transfer keeps a denormal high limb and
+               the sum flushes it to zero); exact f64 rides unchanged on
+               CPU backends (split_f64=False there);
       i32   -> u32 view (astype back is value-exact mod 2^32 = bit-exact);
       bool  -> i8 (compare != 0 on device);
       rest  -> direct (i8/i16/i64/f32 transfer fast natively);
       validity -> omitted when all-valid (device row mask), else i8.
     """
-    n = len(host)
     if isinstance(host.dtype, T.StringType):
         codes, dictionary = DeviceColumn._encode_strings(host)
         # narrow the code transfer to the dictionary's width: low-cardinality
         # string columns (the common case) ship 1 byte/row instead of 4
         if len(dictionary) <= 0xFF:
-            padded = np.zeros(cap, dtype=np.uint8)
-            padded[:n] = codes
-            kind, arrays = "u8codes", [padded]
+            kind, arrays = "u8codes", [_pad(codes, cap, np.uint8)]
         elif len(dictionary) <= 0xFFFF:
-            padded = np.zeros(cap, dtype=np.uint16)
-            padded[:n] = codes
-            kind, arrays = "u16codes", [padded]
+            kind, arrays = "u16codes", [_pad(codes, cap, np.uint16)]
         else:
-            padded = np.zeros(cap, dtype=np.int32)
-            padded[:n] = codes
-            kind, arrays = "u32", [padded.view(np.uint32)]
+            kind, arrays = "u32", [_pad(codes, cap, np.int32)
+                                   .view(np.uint32)]
     elif T.is_dec128(host.dtype):
         limbs = dec128_limbs(host.data, host.validity, cap)
         dictionary = None
@@ -649,19 +653,9 @@ def stage_upload(host: HostColumn, cap: int, split_f64: bool):
     else:
         np_dtype = host.dtype.np_dtype
         dictionary = None
-        padded = np.zeros(cap, dtype=np_dtype)
-        padded[:n] = host.data
+        padded = _pad(host.data, cap, np_dtype)
         if np_dtype == np.float64 and split_f64:
-            hi = padded.astype(np.float32)
-            # inf/overflowed values: hi is +/-inf and x - hi would be NaN;
-            # lo=0 keeps hi+lo == +/-inf on device (NaN hi propagates fine)
-            with np.errstate(invalid="ignore", over="ignore"):
-                lo = np.where(np.isfinite(hi),
-                              padded - hi.astype(np.float64),
-                              0.0).astype(np.float32)
-                # keep -0.0: lo carries the signed zero so hi+lo preserves it
-                lo = np.where(padded == 0.0, hi, lo)
-            kind, arrays = "f64split", [hi, lo]
+            kind, arrays = "f64bits", [padded.view(np.int64)]
         elif np_dtype == np.int32:
             kind, arrays = "u32", [padded.view(np.uint32)]
         elif np_dtype == np.bool_:
@@ -671,9 +665,7 @@ def stage_upload(host: HostColumn, cap: int, split_f64: bool):
     if host.all_valid:
         vkind = "ones"
     else:
-        vpad = np.zeros(cap, dtype=np.int8)
-        vpad[:n] = host.validity
         vkind = "i8"
-        arrays.append(vpad)
+        arrays.append(_pad(host.validity, cap, np.int8))
     recipe = (kind, vkind, str(host.dtype))
     return recipe, arrays, dictionary

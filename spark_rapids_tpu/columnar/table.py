@@ -41,19 +41,22 @@ def _get_assemble(recipes: tuple, cap: int):
     key = (recipes, cap)
     fn = _ASSEMBLE_CACHE.get(key)
     if fn is None:
+        from spark_rapids_tpu.ops.limbs import f64_bits_hi_lo
+
         def assemble(arrays, nrows):
             row_mask = jnp.arange(cap, dtype=jnp.int32) < nrows
             outs = []
             i = 0
             for kind, vkind, _ in recipes:
-                if kind == "f64split":
-                    h64 = arrays[i].astype(jnp.float64)
-                    l64 = arrays[i + 1].astype(jnp.float64)
+                if kind == "f64bits":
+                    hi, lo = f64_bits_hi_lo(arrays[i])
+                    h64 = hi.astype(jnp.float64)
+                    l64 = lo.astype(jnp.float64)
                     # emulated f64 add flushes -0.0 + -0.0 to +0.0; take hi
                     # directly for zeros so the signed zero survives
                     data = jnp.where((h64 == 0.0) & (l64 == 0.0), h64,
                                      h64 + l64)
-                    i += 2
+                    i += 1
                 elif kind == "dec128":
                     data = jnp.stack([arrays[i], arrays[i + 1]], axis=1)
                     i += 2
@@ -77,6 +80,29 @@ def _get_assemble(recipes: tuple, cap: int):
         fn = tpu_jit(assemble, name="assemble")
         _ASSEMBLE_CACHE[key] = fn
     return fn
+
+
+def split_f64_on_device() -> bool:
+    """Whether a staged landing hands a DOUBLE over as its raw 64-bit
+    words for the assemble program to split into the device's (f32,
+    f32) pair (recipe ``f64bits``): every backend but the CPU, whose
+    f64 is exact and lands as it is."""
+    return jax.default_backend() != "cpu"
+
+
+def _has_nested(host: HostTable) -> bool:
+    """A nested column sends a whole batch past the staged landing."""
+    return any(isinstance(c.dtype, (T.ArrayType, T.StructType, T.MapType))
+               for c in host.columns)
+
+
+def f64_bits_columns(host: HostTable) -> int:
+    """DOUBLE columns of ``host`` that DeviceTable.from_host lands as
+    ``f64bits`` (split on the device): none on the CPU backend, nor in a
+    batch with a nested column (it lands column by column)."""
+    if not split_f64_on_device() or _has_nested(host):
+        return 0
+    return sum(1 for c in host.columns if isinstance(c.dtype, T.DoubleType))
 
 
 #: jitted pack kernels for DeviceTable.to_host, keyed by (kinds, k, cap)
@@ -802,9 +828,7 @@ class DeviceTable:
                 return MEMORY.account(
                     DeviceTable(host.names, [], host.num_rows, cap),
                     reservation)
-            if any(isinstance(c.dtype,
-                              (T.ArrayType, T.StructType, T.MapType))
-                   for c in host.columns):
+            if _has_nested(host):
                 # nested columns bypass the staged fast path (per-column
                 # upload) and stay single-device — the exchange layer
                 # excludes them from collectives for the same reason
@@ -826,12 +850,13 @@ class DeviceTable:
                           sharding) -> "DeviceTable":
         """The staged fast-path upload body of :meth:`from_host` (all
         budget accounting happens in the caller)."""
-        split_f64 = jax.default_backend() != "cpu"
+        split_f64 = split_f64_on_device()
         recipes, staged, dicts = [], [], []
         # the landing's host side, one range each: srt.transfer.stage
-        # (f64 split, padding to the bucket; a string column's
+        # (padding every column to the bucket; a string column's
         # srt.transfer.encode nests in it), srt.transfer.upload, then
-        # the assemble program's srt.dispatch.assemble
+        # the assemble program's srt.dispatch.assemble (a DOUBLE's f32
+        # pair split from its 64-bit words, the dtypes rebuilt)
         with span("stage", "transfer", columns=len(host.columns)):
             for c in host.columns:
                 recipe, arrays, dictionary = stage_upload(c, cap, split_f64)

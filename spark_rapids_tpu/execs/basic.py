@@ -14,6 +14,7 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar import DeviceColumn, DeviceTable, HostTable, bucket_for
 from spark_rapids_tpu.columnar.column import MIN_BUCKET
+from spark_rapids_tpu.columnar.table import f64_bits_columns
 from spark_rapids_tpu.execs.base import TpuExec
 from spark_rapids_tpu.ops.expr import (
     DevVal,
@@ -185,6 +186,9 @@ class TpuFileScanExec(TpuExec):
                     1 for c in ch.columns
                     if isinstance(c.dtype, T.StringType)
                     and "encode" in c._cache))
+                # DOUBLE columns handed over as their 64-bit words, split
+                # into the f32 pair by the assemble program
+                self.add_metric("scanF64SplitOnDevice", f64_bits_columns(ch))
                 t0 = time.perf_counter()
                 # mesh-native: each decoded file/row-group batch lands
                 # SPLIT across the mesh (execs/basic._upload_sharded)

@@ -207,6 +207,10 @@ register_metric("scanStringsPreEncoded", "count", "ESSENTIAL",
                 "string columns of a file scan's landed batches whose "
                 "dictionary encoding came with the decoded batch (the "
                 "upload walked no Python object a row)")
+register_metric("scanF64SplitOnDevice", "count", "ESSENTIAL",
+                "DOUBLE columns of a file scan's landed batches handed "
+                "over as their 64-bit words and split into the f32 pair "
+                "on the device (0 on the CPU backend)")
 register_metric("shuffleWriteTime", "timing", "MODERATE",
                 "shuffle partition split + write time")
 register_metric("shuffleReadTime", "timing", "MODERATE",

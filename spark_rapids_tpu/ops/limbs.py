@@ -20,9 +20,11 @@ format"): about 48 mantissa bits (1+2^-52 and 2^53-1 survive; 1/3 and
 Before this module the split/recombine recipes were hand-rolled in
 three places (ops/scatter32.py, ops/segsum.py, segment_minmax_64) and
 had started to drift; now the scatter/sort/segment paths and the d2h
-pack all import the one definition here. The numpy staging variant
-(host-side upload split) remains in columnar/column.py stage_upload —
-it runs on host buffers before any device array exists.
+pack all import the one definition here. The upload's split is here
+too: columnar/column.py stage_upload hands a DOUBLE column over as its
+raw 64-bit words and the assemble program (columnar/table.py) splits
+them with f64_bits_hi_lo, by integer operations, into the pair the host
+split used to make.
 """
 
 from __future__ import annotations
@@ -46,6 +48,93 @@ def split_f64_hi_lo(x):
                    (x - hi.astype(jnp.float64)).astype(jnp.float32), 0.0)
     lo = jnp.where(x == 0.0, hi, lo)
     return hi, lo
+
+
+def _round_up(q, round_bit, sticky):
+    """1 where a right shift with quotient ``q`` rounds up to nearest
+    even: the dropped bits are over half (the round bit and any bit
+    below it), or exactly half and ``q`` odd; else 0 (all u32)."""
+    return round_bit & (sticky | (q & 1))
+
+
+def _low_mask(k):
+    """u32 mask of the ``k`` low bits, 0 <= k <= 31."""
+    return (jnp.uint32(1) << k) - 1
+
+
+def f64_bits_hi_lo(bits):
+    """The (hi, lo) f32 pair of f64 values handed over as their IEEE
+    bits (an int64 array), computed by 32-bit integer operations alone,
+    so no f64 arithmetic (an emulated pair on TPU) and no float rounding
+    mode or denormal flush of the device takes part. Bit for bit the
+    split a host makes in IEEE arithmetic: ``hi = f32(x)`` rounded to
+    nearest even (into f32's subnormals, to zero, to +-inf past f32's
+    range; a NaN keeps its sign and the top 23 bits of its payload and
+    is made quiet), ``lo = f32(x - hi)`` the same way, ``lo = +0`` where
+    hi is not finite, ``lo = hi`` where x is +-0 (split_f64_hi_lo's
+    rules, so combine_f64 and the signed-zero select reassemble it).
+    A bitcast of the words to f64 followed by split_f64_hi_lo compiles
+    on a v5e but gives other bits for subnormals, ties and most low
+    halves (PERF.md, PR 40), hence the integer path."""
+    h32, low = split_i64_hi_lo(bits)
+    high = jax.lax.bitcast_convert_type(h32, jnp.uint32)
+    sign = high >> 31
+    biased = ((high >> 20) & 0x7FF).astype(jnp.int32)
+    mant_high = high & 0xFFFFF
+    sig_high = jnp.where(biased != 0, mant_high | 0x100000, mant_high)
+    # the significand's top 24 bits, and the 29 below them
+    top24 = (sig_high << 3) | (low >> 29)
+    rest29 = low & 0x1FFFFFFF
+
+    # hi: an f32-normal result (biased 897..1150) drops the 29 bits; a
+    # subnormal one drops k more of top24 (an f64 subnormal: all of it)
+    k = jnp.where(biased == 0, 25,
+                  jnp.clip(897 - biased, 0, 25)).astype(jnp.uint32)
+    km1 = jnp.maximum(k, 1) - 1
+    q = top24 >> k
+    round_bit = jnp.where(k == 0, (rest29 >> 28) & 1, (top24 >> km1) & 1)
+    below = jnp.where(k == 0, rest29 & 0x0FFFFFFF,
+                      (top24 & _low_mask(km1)) | rest29)
+    sticky = (below != 0).astype(jnp.uint32)
+    up = _round_up(q, round_bit, sticky)
+    # the biased f32 exponent less one: a carry of the rounding into bit
+    # 24 bumps it (and past 2^128 makes exactly +inf's bits)
+    exp_field = jnp.maximum(biased - 897, 0).astype(jnp.uint32)
+    hi_mag = q + up + (exp_field << 23)
+    hi_mag = jnp.where(biased > 1150, jnp.uint32(0x7F800000), hi_mag)
+    is_nan = (mant_high | low) != 0
+    hi_mag = jnp.where(
+        biased == 0x7FF,
+        jnp.where(is_nan, 0x7FC00000 | (top24 & 0x7FFFFF),
+                  jnp.uint32(0x7F800000)),
+        hi_mag)
+    hi = (sign << 31) | hi_mag
+
+    # lo: where hi is f32-normal (k == 0) the residual x - hi is exactly
+    # r * 2^(biased - 1075) with r < 2^29 of the opposite sign if hi
+    # rounded up; where hi is subnormal or zero |x - hi| <= 2^-150, which
+    # rounds to a zero of the residual's sign
+    r_sign = sign ^ up
+    r = jnp.where(up != 0, (jnp.uint32(1) << 29) - rest29, rest29)
+    lsb = biased - 1075
+    top = 31 - jax.lax.clz(r).astype(jnp.int32)
+    shift = jnp.maximum(top - 23, -149 - lsb)  # bits r drops (< 0: gains)
+    s = jnp.clip(shift, 1, 29).astype(jnp.uint32)
+    lq = r >> s
+    lo_round = jnp.where(
+        shift <= 0, r << jnp.clip(-shift, 0, 31).astype(jnp.uint32),
+        lq + _round_up(lq, (r >> (s - 1)) & 1,
+                       ((r & _low_mask(s - 1)) != 0).astype(jnp.uint32)))
+    lo_mag = lo_round + (
+        jnp.maximum(lsb + top + 126, 0).astype(jnp.uint32) << 23)
+    r_nonzero = jnp.where(k == 0, r != 0, (round_bit | sticky) != 0)
+    lo = jnp.where(r_nonzero,
+                   (r_sign << 31) | jnp.where(k == 0, lo_mag, 0),
+                   jnp.uint32(0))
+    lo = jnp.where(hi_mag < 0x7F800000, lo, jnp.uint32(0))
+    lo = jnp.where(((high & 0x7FFFFFFF) | low) == 0, hi, lo)
+    return (jax.lax.bitcast_convert_type(hi, jnp.float32),
+            jax.lax.bitcast_convert_type(lo, jnp.float32))
 
 
 def combine_f64(hi, lo):

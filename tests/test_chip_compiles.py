@@ -254,3 +254,37 @@ def test_q1_aggregate_takes_a_group_of_batches_one_body_at_a_time(
     assert memory.temp_size_in_bytes < 0.5 * GB, memory.temp_size_in_bytes
     # 4 members' (x 4 shards') 16 partial groups, 15 columns: a few KB
     assert memory.output_size_in_bytes < 64 * 1024
+
+
+@pytest.mark.parametrize("on_mesh", [False, True], ids=["one-chip", "2x2"])
+def test_assemble_splits_doubles_from_their_words(one_chip, mesh4, on_mesh):
+    """The landing's `assemble` program with Q1's 7 columns as a file scan
+    stages them: 4 DOUBLE columns as their 64-bit words (`f64bits`), split
+    into the f32 pair by 32-bit integer operations on the chip, a DATE as
+    u32 and 2 flag codes as u8; at a scan batch's 2^20 rows on one chip,
+    and at the mesh cell's 2^23 rows row-sharded on the 2x2 mesh, where
+    the split is elementwise and moves no row between chips."""
+    import re
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.table import _get_assemble
+    if on_mesh:
+        mesh, place, same = mesh4
+        cap, chips = 1 << 23, mesh.devices.size
+    else:
+        place = same = one_chip
+        cap, chips = 1 << 20, 1
+    recipes = (("f64bits", "ones", "double"),) * 4 + (
+        ("u32", "ones", "date"),) + (("u8codes", "ones", "string"),) * 2
+    staged = tuple(_shape(place, (cap,), dt) for dt in
+                   [jnp.int64] * 4 + [jnp.uint32] + [jnp.uint8] * 2)
+    assemble = _get_assemble(recipes, cap).__wrapped__
+    compiled = assemble.lower(staged, _shape(same, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert not re.search(r" (all-gather|all-reduce|all-to-all|"
+                         r"collective-permute|reduce-scatter)", text)
+    memory = compiled.memory_analysis()
+    rows = cap // chips
+    assert memory.argument_size_in_bytes < rows * (4 * 8 + 4 + 2) + 4096
+    # the columns (a DOUBLE is its f32 pair) and the row masks
+    assert memory.output_size_in_bytes >= rows * (4 * 8 + 3 * 4)
+    assert memory.temp_size_in_bytes < rows * 64, memory.temp_size_in_bytes
