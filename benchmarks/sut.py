@@ -167,7 +167,8 @@ def write_parquet_parts(table: dict, directory: str, spec: dict) -> list:
     `useDoubleForDecimal = true`: BIGINT as INT64, INT as INT32, DOUBLE,
     DATE as date32, STRING as UTF8 (a dictionary column from its codes, a
     text column from the pool's bytes: no Python object a row). No NULLs,
-    no partition directories. Returns the paths written."""
+    no partition directories. Each file and then the directory is synced
+    to the disk before it returns. Returns the paths written."""
     import pyarrow as pa
     import pyarrow.parquet as pq
     files = int(spec["files"])
@@ -203,12 +204,26 @@ def write_parquet_parts(table: dict, directory: str, spec: dict) -> list:
         pq.write_table(
             pa.Table.from_arrays(arrays, names=list(table["columns"])), path,
             compression=codec, row_group_size=-(-max(hi - lo, 1) // groups))
+        _fsync(path)
         return path
 
     # numpy's gather and pyarrow's writer release the GIL: a few parts at
     # once shorten set-up, which every run pays
     with concurrent.futures.ThreadPoolExecutor(max_workers=4) as executor:
-        return list(executor.map(write_part, range(files)))
+        paths = list(executor.map(write_part, range(files)))
+    _fsync(directory)
+    return paths
+
+
+def _fsync(path: str) -> None:
+    """Write a file's (or a directory's entries') dirty pages back now, in
+    set-up: left to the kernel's write-back, they could be written during
+    the timed window. The pages stay cached, clean."""
+    fd = os.open(path, os.O_RDONLY)
+    try:
+        os.fsync(fd)
+    finally:
+        os.close(fd)
 
 
 def _text_array(pool: np.ndarray, starts: np.ndarray, lengths: np.ndarray):

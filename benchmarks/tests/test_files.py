@@ -1,18 +1,24 @@
-"""The cell `q1-sf1-parquet` and the harness's `storage` arm, through the
+"""The cell `q1-sf5-parquet` and the harness's `storage` arm, through the
 harness's own `run_cell` on the CPU backend at a tiny scale (no timing
-claimed): a sound run of the file-backed cell is correct and every query
+claimed): `q1-sf1-parquet` is the same deployment at a fifth of the
+rows; a sound run of the file-backed cell is correct and every query
 reads the files; the files read back with pyarrow alone are the
-generator's table value for value and type for type; the float32 control
+generator's table value for value and type for type, and each part file
+and the directory are synced to the disk in set-up; the float32 control
 is not correct; the faults the cell can have come out wrong (a part file
 deleted after `register`, an engine that answers from a copy it kept);
-and a configuration WITHOUT `storage` makes the same calls in the same
-order as before the arm (the guard that no accepted cell's path moved).
+a configuration WITHOUT `storage` makes the same calls in the same order
+as before the arm (the guard that no accepted cell's path moved); the
+file cells report the rate and the tail under the bounds every cell has,
+the steady cells besides under their own; and every name `BENCHMARK.json` and `limits/` give names a cell
+or a configuration that exists.
 
     python3 -m pytest benchmarks/tests -q        (or benchmarks/selfcheck.py)
 """
 
 import glob
 import importlib
+import json
 import os
 import sys
 
@@ -30,7 +36,7 @@ from benchmarks.datagen import tpch  # noqa: E402
 from benchmarks.tests.test_correct import (  # noqa: E402
     BENCH, CONTROL_SCALE, CPU_DEVICE, TINY_SCALE, reference_engine)
 
-CELL = "q1-sf1-parquet"
+CELL = "q1-sf5-parquet"
 SCAN_READERS = ("scan_ms_per_query", "scan_upload_ms_per_query",
                 "scan_decode_wait_ms_per_query", "scan_rows_per_query")
 
@@ -69,12 +75,14 @@ def keeping_records():
 def test_the_configuration_is_the_deployment_the_entry_names():
     from spark_rapids_tpu.conf import BATCH_SIZE_BYTES, PARQUET_READER_TYPE
     from spark_rapids_tpu.io.filecache import FILECACHE_ENABLED
-    _cell, config, _mix, _limits = bench_run.resolve_cell(BENCH, CELL)
+    cell, config, _mix, _limits = bench_run.resolve_cell(BENCH, CELL)
     assert "session_conf" not in config and "batches" not in config
     assert config["storage"] == {"lineitem": {
         "format": "parquet", "files": 8, "compression": "snappy",
-        "row_groups_per_file": 1}}
+        "row_groups_per_file": 2}}
     assert config["tables"]["lineitem"] == list(tpch.COLUMN_TYPES["lineitem"])
+    assert config["scale_factor"] == 5
+    assert cell["chips"] == 1 and list(config["chips"]) == ["1"]
     assert "read" in config["guarantees"]
     assert set(config["reduced"]) == {"scale_factor", "tables"}
     entry = {c["name"]: c for c in BENCH["configs"]}[config["name"]]
@@ -86,12 +94,35 @@ def test_the_configuration_is_the_deployment_the_entry_names():
     assert FILECACHE_ENABLED.default is False
 
 
+def test_the_sf1_file_cell_is_the_same_deployment_at_its_own_scale():
+    """`q1-sf1-parquet` keeps the configuration it was accepted with: the
+    SF5 file cell's deployment, guarantees and chip at a fifth of the
+    rows, one row group a 29 MB file."""
+    small, small_config, _mix, _limits = bench_run.resolve_cell(
+        BENCH, "q1-sf1-parquet")
+    large, large_config, _mix, _limits = bench_run.resolve_cell(BENCH, CELL)
+    assert small["traffic"] == large["traffic"]
+    assert small["chips"] == large["chips"] == 1
+    assert small_config["scale_factor"] == 1
+    assert small_config["storage"]["lineitem"] == dict(
+        large_config["storage"]["lineitem"], row_groups_per_file=1)
+    differ = {"name", "source", "scale_factor", "storage", "reduced",
+              "assumed"}
+    assert set(small_config) == set(large_config)
+    for key in set(small_config) - differ:
+        assert small_config[key] == large_config[key], key
+    assert set(small_config["reduced"]) == set(large_config["reduced"])
+
+
 def test_sound_run_is_correct_and_every_query_reads_the_files():
     engine = keeping_records()
     result = drive(engine)
     assert result["correct"] is True, result["checks"]
     assert result["failed"] == 0 and result["attempted"] >= 1
     assert result["setup_split_s"]["landing_s"] < 0.1  # nothing is landed
+    # the cell's rate and tail under the bounds every cell has, and no
+    # steady cell's
+    assert set(result["metrics"]) == {"rows_per_s", "query_p95_s", "setup_s"}
     _cell, config, _mix, _limits = cell_config()
     rows = tpch.generate(config, 2 ** 31 + 33)["lineitem"]["num_rows"]
     run = {"queries": [{"record": r} for r in engine.records]}
@@ -126,8 +157,10 @@ def test_the_files_hold_the_generators_table_type_for_type(tmp_path):
                and p.endswith(".snappy.parquet") for i, p in enumerate(paths))
     files = [pq.ParquetFile(p) for p in paths]
     for f in files:
-        assert f.metadata.num_row_groups == 1
+        assert f.metadata.num_row_groups == 2
         assert f.metadata.row_group(0).column(0).compression == "SNAPPY"
+        halves = [f.metadata.row_group(g).num_rows for g in (0, 1)]
+        assert abs(halves[0] - halves[1]) <= 1
     sizes = [f.metadata.num_rows for f in files]
     assert sum(sizes) == table["num_rows"] and max(sizes) - min(sizes) <= 1
     back = pa.concat_tables([f.read() for f in files])  # in row order
@@ -156,6 +189,72 @@ def test_the_files_hold_the_generators_table_type_for_type(tmp_path):
             assert np.array_equal(got.to_numpy(), col.values), name
     assert files[0].schema.column(10).logical_type.type == "DATE"
     assert files[0].schema.column(15).logical_type.type == "STRING"
+
+
+def test_every_part_file_and_then_the_directory_is_synced(
+        tmp_path, monkeypatch):
+    """No dirty page of the files is left for the kernel to write back
+    during the timed window."""
+    synced = []
+    real_fsync = os.fsync
+
+    def counting_fsync(fd):
+        synced.append(os.fstat(fd).st_ino)
+        real_fsync(fd)
+
+    monkeypatch.setattr(os, "fsync", counting_fsync)
+    _cell, config, _mix, _limits = cell_config(scale=0.002)
+    table = tpch.generate(config, 2 ** 31 + 35)["lineitem"]
+    paths = sut.write_parquet_parts(
+        table, str(tmp_path), config["storage"]["lineitem"])
+    assert len(paths) == 8
+    assert sorted(synced[:-1]) == sorted(os.stat(p).st_ino for p in paths)
+    assert synced[-1] == os.stat(tmp_path).st_ino
+
+
+def test_a_steady_cell_reports_its_rate_and_tail_under_both_bounds():
+    """`rows_per_s.steady` and `query_p95_s.steady` are `rows_per_s` and
+    `query_p95_s` to the bit, reported in the cells their `workloads`
+    lists and nowhere else; every cell reports the unqualified three."""
+    run = {"queries": [{"id": "q1", "answer": 1, "latency_s": s / 10}
+                       for s in range(1, 21)],
+           "rows_per_query": {"q1": 1000}, "window_s": 4.0,
+           "setup": {"setup_s": 9.0}}
+    steady = {m["name"]: set(m["workloads"]) for m in BENCH["end_to_end"]
+              if "." in m["name"]}
+    assert set(steady) == {"rows_per_s.steady", "query_p95_s.steady"}
+    assert all("workloads" not in m for m in BENCH["end_to_end"]
+               if "." not in m["name"])
+    for cell in BENCH["workloads"]:
+        metrics = bench_run.end_to_end_metrics(
+            BENCH, dict(run, cell=cell))
+        held = {n for n, cells in steady.items() if cell["name"] in cells}
+        assert set(metrics) == {"rows_per_s", "query_p95_s", "setup_s"} | held
+        assert metrics["rows_per_s"]["value"] == 20 * 1000 / 4.0
+        for name in held:
+            assert metrics[name] == metrics[name.split(".")[0]]
+    assert CELL not in steady["rows_per_s.steady"]
+    assert "q1-sf1-parquet" not in steady["rows_per_s.steady"]
+
+
+def test_every_name_the_benchmark_gives_exists():
+    """Each per-layer and end-to-end `workloads` list, each limits file
+    and each configuration entry names a cell or a configuration that
+    exists, so that no retirement leaves a name behind."""
+    cells = {w["name"]: w for w in BENCH["workloads"]}
+    configs = {c["name"]: c for c in BENCH["configs"]}
+    for metric in BENCH["per_layer"] + BENCH["end_to_end"]:
+        assert set(metric.get("workloads", ())) <= set(cells), metric["name"]
+    limits = {os.path.basename(p)[:-len(".json")] for p in glob.glob(
+        os.path.join(ROOT, "benchmarks", "limits", "*.json"))}
+    assert limits == set(cells)
+    assert {w["config"] for w in cells.values()} == set(configs)
+    files = {os.path.relpath(p, ROOT) for p in glob.glob(
+        os.path.join(ROOT, "benchmarks", "configs", "*.json"))}
+    assert {c["file"] for c in configs.values()} == files
+    for name, entry in configs.items():
+        with open(os.path.join(ROOT, entry["file"])) as f:
+            assert json.load(f)["name"] == name
 
 
 @pytest.mark.parametrize("seed", [11, 2 ** 31 + 12, 2 ** 31 + 13])
