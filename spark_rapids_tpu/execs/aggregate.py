@@ -285,6 +285,13 @@ def _over_members(kernel, gpad: int):
     return grouped
 
 
+def _sorted_program(body):
+    """The sorted-path aggregate's program: its kernel on one device, or
+    the same kernel under ``shard_map`` on each chip's rows; one name,
+    ``agg_sorted``, for both."""
+    return tpu_jit(body, name="agg_sorted")
+
+
 def _over_shards(kernel, mesh, row_axes, shards: int, rows: int, gpad: int,
                  members: int = 1):
     """A fast kernel built at ``rows`` rows (or ``_over_slices`` of one:
@@ -416,6 +423,11 @@ class TpuHashAggregateExec(TpuExec):
         self.use_split = use_split
         self.max_dict_groups = max_dict_groups
         self.max_domain_groups = max_domain_groups
+        #: set by the mesh pass (execs/mesh.py) where the grouping keys
+        #: hold the input's hash partitioning: no group spans two chips,
+        #: so the batches on the chips are aggregated on each chip
+        #: (_aggregate_on_chips) and nothing crosses
+        self.on_chips = False
 
     def output_schema(self):
         out = [(n, g.data_type) for n, g in zip(self.grouping_names, self.grouping)]
@@ -429,7 +441,15 @@ class TpuHashAggregateExec(TpuExec):
         from spark_rapids_tpu.runtime.spill import BufferCatalog, SpillableBatch
 
         from spark_rapids_tpu.parallel.mesh import MESH_SCOPE
-        it = self._pulled(iter(self.children[0].execute_masked()))
+        source = self.children[0].execute_masked()
+        if self.on_chips:
+            batches = list(source)
+            out = self._aggregate_on_chips(batches)
+            if out is not None:
+                yield out
+                return
+            source = iter(batches)
+        it = self._pulled(iter(source))
         # the node's record carries both counts of the streaming loop,
         # 0 where it read no partial's count and never waited
         self.add_metric("partialCountReads", 0)
@@ -870,14 +890,12 @@ class TpuHashAggregateExec(TpuExec):
         slices (_over_slices on each chip). Nothing here is an option:
         what decides is where the batch lies and what the expressions
         are."""
-        spec = table.shard_spec
-        if spec is None or not table.columns \
+        from spark_rapids_tpu.parallel.shards import chips_of
+        chips = chips_of(table)
+        if chips is None or table.shard_spec is None \
                 or not table.physically_sharded():
             return 0, 0, None
-        shards = int(spec.mesh.devices.size)
-        rows, rest = divmod(table.capacity, shards)
-        if shards < 2 or rest:
-            return 0, 0, None
+        shards, rows = chips.n, chips.rows
         slices = 1
         if rows > AGG_SLICE:
             slices, rest = divmod(rows, AGG_SLICE)
@@ -1068,9 +1086,9 @@ class TpuHashAggregateExec(TpuExec):
                 else:
                     fn = tpu_jit(kernel, name="agg_fast")
             else:
-                fn = tpu_jit(self._build_kernel(
+                fn = _sorted_program(self._build_kernel(
                     capacity, head.filter_preps, key_preps, val_preps,
-                    grouping, agg_specs, filters), name="agg_sorted")
+                    grouping, agg_specs, filters))
             self._traces[tkey] = fn
 
         if fast:
@@ -1095,26 +1113,9 @@ class TpuHashAggregateExec(TpuExec):
             out_arrays, ngroups = fn(*head.args)
             out_capacity = capacity
 
-        out_cols: List[DeviceColumn] = []
-        names: List[str] = []
-        for i, (g, name) in enumerate(zip(grouping, grouping_names)):
-            data, validity = out_arrays[i]
-            root = key_preps[i][-1]
-            out_cols.append(DeviceColumn(g.data_type, data, validity,
-                                         dictionary=root.out_dict,
-                                         dict_sorted=root.dict_sorted,
-                                         domain=root.out_domain))
-            names.append(name)
-        for j, (name, fnagg) in enumerate(agg_specs):
-            data, validity = out_arrays[len(grouping) + j]
-            dictionary = None
-            dict_sorted = True
-            if isinstance(fnagg.data_type, T.StringType) and val_preps[j]:
-                dictionary = val_preps[j][-1][-1].out_dict
-                dict_sorted = val_preps[j][-1][-1].dict_sorted
-            out_cols.append(DeviceColumn(fnagg.data_type, data, validity,
-                                         dictionary=dictionary, dict_sorted=dict_sorted))
-            names.append(name)
+        names, out_cols = self._output_columns(
+            out_arrays, grouping, grouping_names, agg_specs, key_preps,
+            val_preps)
         out = DeviceTable(names, out_cols, ngroups, out_capacity)
         if fast:
             # outputs are already domain-sized; the group count stays a
@@ -1155,6 +1156,131 @@ class TpuHashAggregateExec(TpuExec):
         ctx.add_flag(site, flag_fn(out.nrows_dev))
         cols = [c.sliced_rows(spec_cap) for c in out.columns]
         return DeviceTable(names, cols, out.nrows_dev, spec_cap)
+
+    @staticmethod
+    def _output_columns(out_arrays, grouping, grouping_names, agg_specs,
+                        key_preps, val_preps):
+        """(names, columns) of an aggregation's output arrays."""
+        out_cols: List[DeviceColumn] = []
+        names: List[str] = []
+        for i, (g, name) in enumerate(zip(grouping, grouping_names)):
+            data, validity = out_arrays[i]
+            root = key_preps[i][-1]
+            out_cols.append(DeviceColumn(g.data_type, data, validity,
+                                         dictionary=root.out_dict,
+                                         dict_sorted=root.dict_sorted,
+                                         domain=root.out_domain))
+            names.append(name)
+        for j, (name, fnagg) in enumerate(agg_specs):
+            data, validity = out_arrays[len(grouping) + j]
+            dictionary = None
+            dict_sorted = True
+            if isinstance(fnagg.data_type, T.StringType) and val_preps[j]:
+                dictionary = val_preps[j][-1][-1].out_dict
+                dict_sorted = val_preps[j][-1][-1].dict_sorted
+            out_cols.append(DeviceColumn(fnagg.data_type, data, validity,
+                                         dictionary=dictionary, dict_sorted=dict_sorted))
+            names.append(name)
+        return names, out_cols
+
+    def _aggregate_on_chips(self, batches) -> Optional[DeviceTable]:
+        """The aggregation of ``batches``, which lie on the chips, on each
+        chip: every chip's rows of them in one table (one batch, or
+        shards.concat_on_chips), then the sorted-path kernel under
+        ``shard_map`` on each chip's rows (``agg_sorted``, as on one
+        device: the same body under the same name). The grouping keys
+        hold the input's partitioning (execs/mesh.py), so a chip's
+        groups are whole and its output is final. One fetch reads each
+        chip's group count; each chip's groups are then cut to the
+        bucket the fullest chip needs. The output lies on the chips
+        under its mask. None where the batches do not all lie on the same
+        chips, or an expression reads a row's position: the aggregate
+        then runs as anywhere else."""
+        from jax.sharding import PartitionSpec as P
+
+        from spark_rapids_tpu.columnar import bucket_for
+        from spark_rapids_tpu.dispatch import prep_aux
+        from spark_rapids_tpu.ops.expr import shared_traces
+        from spark_rapids_tpu.parallel.mesh import MESH_SCOPE
+        from spark_rapids_tpu.parallel.shards import (
+            chip_counts_host,
+            chip_live,
+            chips_of,
+            concat_on_chips,
+            first_rows_on_chips,
+            on_chips,
+            replicated_scalar,
+        )
+        from spark_rapids_tpu.runtime.retry import retry_block
+        if not batches:
+            return None
+        # the partitioning the plan expected, as the input ran (an
+        # adaptive join may have run as a broadcast)
+        from spark_rapids_tpu.execs.mesh import hash_partitioning
+        grouped = {g.ordinal for g in self.grouping
+                   if isinstance(g, BoundReference)}
+        if not any(set(p) <= grouped
+                   for p in hash_partitioning(self.children[0])):
+            return None
+        where = [chips_of(b) for b in batches]
+        if any(c is None or c[:3] != where[0][:3] for c in where) \
+                or self._reads_positions(self.grouping, self.agg_specs,
+                                         self.filters) \
+                or any(c.is_nested for c in batches[0].columns):
+            return None
+        table = batches[0] if len(batches) == 1 else retry_block(
+            lambda: concat_on_chips(batches, where))
+        chips = chips_of(table)
+        grouping, agg_specs, filters = (self.grouping, self.agg_specs,
+                                        self.filters)
+        pctx, filter_preps, key_preps, val_preps = self._prep_all(
+            table, grouping, agg_specs, filters)
+        aux = prep_aux(pctx, chips.everywhere())
+        self._traces = shared_traces(
+            ("agg",
+             tuple(g.key() for g in grouping),
+             tuple(fn.key() for _, fn in agg_specs),
+             tuple(f.key() for f in filters),
+             table.schema_key()[0]))
+        from spark_rapids_tpu.ops import segsum as _ss
+        tkey = ("chips", chips.token(), self.use_split, _ss.trace_key(),
+                table.live is None,
+                tuple(_prep_trace_key(p) for p in filter_preps),
+                tuple(_prep_trace_key(p) for p in key_preps),
+                tuple(tuple(_prep_trace_key(p) for p in per_child)
+                      for per_child in val_preps))
+        fn = self._traces.get(tkey)
+        if fn is None:
+            rows, axes = chips.rows, chips.axes
+            kernel = self._build_kernel(rows, filter_preps, key_preps,
+                                        val_preps, grouping, agg_specs,
+                                        filters)
+
+            def on_chip(cols, aux, nrows, live):
+                outs, ngroups = kernel(cols, aux, nrows,
+                                       chip_live(live, nrows, rows, axes))
+                return (outs, jnp.arange(rows, dtype=jnp.int32) < ngroups,
+                        ngroups[None])
+            by_row = chips.by_row
+            fn = _sorted_program(on_chips(
+                on_chip, chips,
+                in_specs=(by_row, P(), P(),
+                          None if table.live is None else by_row),
+                out_specs=(by_row, by_row, by_row)))
+            self._traces[tkey] = fn
+        cols = tuple(DevVal(c.data, c.validity) for c in table.columns)
+        out_arrays, live, ngroups = retry_block(lambda: fn(
+            cols, aux, replicated_scalar(table, chips), table.live))
+        self.add_metric("meshChipAggBatches", len(batches))
+        MESH_SCOPE.add("meshChipAggBatches", len(batches))
+        counts = chip_counts_host(ngroups)
+        names, out_cols = self._output_columns(
+            out_arrays, grouping, self.grouping_names, agg_specs,
+            key_preps, val_preps)
+        out = DeviceTable(names, out_cols, int(counts.sum()), table.capacity,
+                          live=live, shard_spec=chips.row_sharding())
+        k = bucket_for(max(int(counts.max()), 1))
+        return first_rows_on_chips(out, chips, k) if k < chips.rows else out
 
     def _spec_site_key(self) -> str:
         return "agg:{}:{}:op{}".format(

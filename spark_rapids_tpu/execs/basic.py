@@ -499,6 +499,10 @@ class TpuCoalesceExec(TpuExec):
         #: rows: it would pay the compaction scatter the mask defers and
         #: shrink nothing
         self.masked_pass = masked_pass and not require_single
+        #: under an aggregate that runs on the chips (execs/mesh.py): a
+        #: batch that lies on the chips streams on as it is, where a
+        #: copy would gather it to one device
+        self.on_chips = False
 
     def output_schema(self):
         schema = self.children[0].output_schema()
@@ -510,6 +514,7 @@ class TpuCoalesceExec(TpuExec):
 
     def execute_masked(self):
         from spark_rapids_tpu.columnar.table import is_shared_view
+        from spark_rapids_tpu.parallel.shards import chips_of
         from spark_rapids_tpu.runtime.memory import MEMORY
         from spark_rapids_tpu.runtime.spill import BufferCatalog, SpillableBatch
 
@@ -530,7 +535,8 @@ class TpuCoalesceExec(TpuExec):
                 if self.columns is not None:
                     batch = batch.select_columns(self.columns)
                 if (batch.live is not None and self.masked_pass) or (
-                        is_shared_view(batch) and not self.require_single):
+                        is_shared_view(batch) and not self.require_single) \
+                        or (self.on_chips and chips_of(batch) is not None):
                     # capacity-sharing views (a local split's per-partition
                     # masks over ONE table): concatenation would only
                     # multiply capacity and pay the very scatters masking

@@ -80,6 +80,11 @@ class TpuBroadcastExchangeExec(TpuExec):
         super().__init__()
         self.children = (child,)
         self._cached = None
+        #: the build side of a join on the mesh's chips, which copies
+        #: the table to every chip itself (execs/join.py): the child's
+        #: batches are taken where they lie, not gathered first
+        #: (execs/mesh.py)
+        self.on_chips = False
 
     def execute(self):
         from spark_rapids_tpu.runtime.spill import BufferCatalog, SpillableBatch

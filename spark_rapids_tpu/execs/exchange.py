@@ -129,7 +129,8 @@ def make_partitioner(mode: str, keys: Sequence[Expression],
 class TpuShuffleExchangeExec(TpuExec):
     def __init__(self, child: TpuExec, mode: str, num_partitions: int,
                  keys: Sequence[Expression], conf: RapidsConf,
-                 target_batch_bytes: int = 1 << 30):
+                 target_batch_bytes: int = 1 << 30,
+                 reason: Optional[str] = None):
         super().__init__()
         self.children = (child,)
         self.mode = mode
@@ -140,6 +141,13 @@ class TpuShuffleExchangeExec(TpuExec):
         #: why an ICI-requested exchange demoted to the host shuffle
         #: (None while on the collective path or when never requested)
         self.ici_fallback_reason: Optional[str] = None
+        #: why the planner put this exchange in (shown by explain())
+        self.reason = reason
+        #: whether the consumer works the exchanged batches where they
+        #: lie, partition p on chip p (an input of a shuffled hash join on
+        #: the mesh, set by overrides/rules.py): execs/mesh.py then takes
+        #: the output as hash-partitioned by ``keys``
+        self.on_chips = False
 
     def output_schema(self):
         return self.children[0].output_schema()
@@ -147,6 +155,8 @@ class TpuShuffleExchangeExec(TpuExec):
     def describe(self):
         extra = (f", hostShuffleFallback={self.ici_fallback_reason!r}"
                  if self.ici_fallback_reason else "")
+        if self.reason:
+            extra += f", {self.reason}"
         return f"TpuShuffleExchange[{self.mode}, n={self.num_partitions}{extra}]"
 
     def _aqe_coalesce_enabled(self) -> bool:
@@ -207,10 +217,11 @@ class TpuShuffleExchangeExec(TpuExec):
 
     def execute(self):
         # base-contract note: execute() must yield PREFIX batches; the
-        # masked local split therefore lives in execute_masked() and
-        # mask-unaware callers get compacted tables via the base wrapper
+        # masked forms therefore live in execute_masked() and mask-unaware
+        # callers get compacted tables via the base wrapper
         if self._ici_eligible():
-            yield from self._execute_ici()
+            for b in self.exchanged(self.children[0].execute_masked()):
+                yield b.shrink()
             return
         if self._local_split_eligible():
             for b in self._execute_local_device_split():
@@ -220,7 +231,7 @@ class TpuShuffleExchangeExec(TpuExec):
 
     def execute_masked(self):
         if self._ici_eligible():
-            yield from self._execute_ici()
+            yield from self.exchanged(self.children[0].execute_masked())
             return
         if self._local_split_eligible():
             yield from self._execute_local_device_split()
@@ -283,101 +294,104 @@ class TpuShuffleExchangeExec(TpuExec):
             mark_shared_view(out, split_group)
             yield out
 
-    def _execute_ici(self):
-        """ONE all-to-all collective over the device mesh instead of the
-        host-file shuffle: coalesce input, evaluate key columns, exchange
-        every column's rows to its murmur3 partition's device, emit one
-        front-compacted batch per partition (parallel/exchange.py).
-        Input shards stay DEVICE-RESIDENT end to end — the only host
-        traffic is the per-shard live-count fetch, which doubles as the
-        AQE map-output statistic (skew/coalesce decisions see the real
-        shard distribution instead of the host path's file sizes)."""
-        from spark_rapids_tpu.columnar import DeviceColumn, bucket_for
-        from spark_rapids_tpu.columnar.table import concat_device
-        from spark_rapids_tpu.ops.expr import compile_project
+    def exchanged(self, batches):
+        """The ICI exchange: each input batch as it comes (masked or not,
+        landed row-sharded or on one device) is ONE program,
+        ``mesh_exchange`` (parallel/exchange.chip_exchange): the murmur3
+        partition of its rows and one all-to-all that leaves partition p
+        on chip p. Each batch is handed on as one batch on the chips,
+        under the mask of the rows each chip received; its row count is
+        the input's, and nothing is read to the host until the last batch
+        is out. Then the counts the device kept, a partition and batch,
+        are read once (checksummed, ``verified_counts``): the live rows
+        and bytes sent, and the AQE map-output statistics of each
+        partition. The host time is the range ``srt.mesh.exchange`` and
+        the query's ``phasesS.exchangeS``."""
+        from spark_rapids_tpu.dispatch import phase_span
         from spark_rapids_tpu.parallel.exchange import (
-            MeshExchange,
+            chip_exchange,
             interned_dict_bytes,
+            verified_counts,
         )
         from spark_rapids_tpu.parallel.mesh import MESH, MESH_SCOPE
+        from spark_rapids_tpu.parallel.shards import (
+            Chips,
+            chips_of,
+            key_columns,
+            onto_chips,
+            replicated_scalar,
+        )
         from spark_rapids_tpu.runtime.retry import retry_block
 
-        t0 = perf_counter()
-        batches = list(self.children[0].execute())
-        if not batches:
-            return
-        table = retry_block(lambda: concat_device(batches)) \
-            if len(batches) > 1 else batches[0]
         ndev = self.num_partitions
-        if table.capacity % ndev != 0:
-            # non-pow2 partition counts (or tiny tables): pad the row
-            # capacity up to a multiple of ndev with dead rows — every
-            # column extends with zero/False tails, so the collective's
-            # equal per-device shards always exist
-            table = _pad_capacity(table, -(-table.capacity // ndev) * ndev)
-
-        key_cols = compile_project(self.keys, table)
-        mesh, axis = MESH.exchange_mesh(ndev)
-        string_bytes = {}
-        for i, c in enumerate(key_cols):
-            if isinstance(c.dtype, T.StringType):
-                # replicated byte matrix, interned by dictionary
-                # identity: repeated exchanges over one dictionary pay
-                # the replication upload once
-                string_bytes[i] = interned_dict_bytes(c.dictionary, mesh)
-
-        ex = MeshExchange.get(
-            mesh,
-            tuple(str(c.dtype) for c in table.columns),
-            tuple(range(len(key_cols))),
-            tuple(c.dtype for c in key_cols),
-            tuple(sorted((i, v[0].shape) for i, v in string_bytes.items())),
-            table.capacity, axis_name=axis)
-        out_d, out_v, counts = ex.run(
-            [c.data for c in table.columns],
-            [c.validity for c in table.columns],
-            [c.data for c in key_cols],
-            [c.validity for c in key_cols],
-            table.row_mask(),
-            string_bytes)
-        self.add_metric("iciExchangeTime", perf_counter() - t0)
+        mesh, axes = MESH.exchange_mesh(ndev)
         self.add_metric("iciPartitions", ndev)
-        # exchanged payload bytes (static shapes: no device sync)
-        ici_bytes = sum(a.nbytes for a in out_d) + \
-            sum(a.nbytes for a in out_v)
-        self.add_metric("iciBytes", ici_bytes)
-        MESH_SCOPE.add("iciExchanges", 1)
-        MESH_SCOPE.add("iciBytes", ici_bytes)
+        sent = []
+        for batch in batches:
+            with phase_span("exchangeS", "exchange", "mesh"):
+                t0 = perf_counter()
+                chips = chips_of(batch)
+                if chips is None or chips.mesh != mesh \
+                        or chips.axes != axes:
+                    batch, chips = onto_chips(batch, Chips(
+                        mesh, axes, ndev, 0))
+                key_cols = key_columns(self.keys, batch)
+                string_bytes = {
+                    i: interned_dict_bytes(c.dictionary, mesh)
+                    for i, c in enumerate(key_cols)
+                    if isinstance(c.dtype, T.StringType)}
+                datas, valids, live, counts = retry_block(
+                    lambda b=batch, k=key_cols, c=chips, sb=string_bytes:
+                    chip_exchange(
+                        c, [x.data for x in b.columns],
+                        [x.validity for x in b.columns],
+                        [x.data for x in k], [x.validity for x in k],
+                        [x.dtype for x in k], b.live,
+                        replicated_scalar(b, c), sb))
+                cols = [c.with_arrays(d, v)
+                        for c, d, v in zip(batch.columns, datas, valids)]
+                nrows = batch.num_rows if batch.num_rows_known \
+                    else batch.nrows_dev
+                out = DeviceTable(batch.names, cols, nrows, live.shape[0],
+                                  live=live,
+                                  shard_spec=chips.row_sharding())
+                padded = sum(a.nbytes for a in datas) + \
+                    sum(a.nbytes for a in valids)
+                self.add_metric("iciExchangeTime", perf_counter() - t0)
+                self.add_metric("iciBytes", padded)
+                self.add_metric("meshExchanges", 1)
+                MESH_SCOPE.add("iciExchanges", 1)
+                MESH_SCOPE.add("iciBytes", padded)
+                MESH_SCOPE.add("meshExchanges", 1)
+                sent.append(counts)
+                row_bytes = self._packed_row_bytes_for(batch)
+            yield out
+        if sent:
+            with phase_span("exchangeS", "exchange", "mesh"):
+                per_part = verified_counts(sent).reshape(
+                    len(sent), ndev).sum(axis=0)
+            self._map_output_stats(per_part, row_bytes)
 
-        # AQE exchange statistics from the MEASURED per-shard live
-        # counts (MapOutputStatistics analog): rows x packed row bytes
-        # approximates per-partition output size, driving the same
-        # skew metric the host shuffle records from file sizes
-        row_bytes = max(self._packed_row_bytes_for(table), 1)
-        live = sorted(int(c) * row_bytes for c in counts if int(c) > 0)
-        if live:
-            from spark_rapids_tpu.conf import AQE_SKEW_FACTOR
-            median = live[len(live) // 2]
-            factor = float(self.conf.get_entry(AQE_SKEW_FACTOR))
-            skewed = sum(1 for b in live if b > factor * max(median, 1))
-            self.add_metric("mapOutputBytesMax", live[-1])
-            self.add_metric("mapOutputBytesMedian", median)
-            if skewed:
-                self.add_metric("skewedPartitions", skewed)
-
-        shard = len(out_d[0]) // ndev if out_d else 0
-        for p in range(ndev):
-            n = int(counts[p])
-            if n == 0:
-                continue
-            k = min(bucket_for(max(n, 1)), shard)
-            cols = []
-            for c, d, v in zip(table.columns, out_d, out_v):
-                sl = slice(p * shard, p * shard + k)
-                cols.append(DeviceColumn(c.dtype, d[sl], v[sl],
-                                         dictionary=c.dictionary,
-                                         dict_sorted=c.dict_sorted))
-            yield DeviceTable(table.names, cols, n, k)
+    def _map_output_stats(self, per_part, row_bytes: int) -> None:
+        """The exchange's live rows and bytes sent, and the AQE
+        map-output statistics (MapOutputStatistics analog) from the
+        MEASURED live rows of each partition: rows x packed row bytes
+        approximates a partition's output size, driving the same skew
+        metric the host shuffle records from file sizes."""
+        from spark_rapids_tpu.conf import AQE_SKEW_FACTOR
+        rows = int(per_part.sum())
+        self.add_metric("meshExchangeRows", rows)
+        self.add_metric("meshExchangeBytes", rows * row_bytes)
+        live = sorted(int(c) * row_bytes for c in per_part if int(c) > 0)
+        if not live:
+            return
+        median = live[len(live) // 2]
+        factor = float(self.conf.get_entry(AQE_SKEW_FACTOR))
+        skewed = sum(1 for b in live if b > factor * max(median, 1))
+        self.add_metric("mapOutputBytesMax", live[-1])
+        self.add_metric("mapOutputBytesMedian", median)
+        if skewed:
+            self.add_metric("skewedPartitions", skewed)
 
     @staticmethod
     def _packed_row_bytes_for(table: DeviceTable) -> int:

@@ -360,50 +360,76 @@ class _DirectJoinKernel:
             fn = cls._traces[key] = make()
         return fn
 
+    @staticmethod
+    def _stats_body():
+        def stats(rd, rv, live):
+            v = rv & live
+            k = rd.astype(jnp.int64)
+            big = jnp.asarray(np.iinfo(np.int64).max, jnp.int64)
+            return jnp.stack([
+                jnp.min(jnp.where(v, k, big)),
+                jnp.max(jnp.where(v, k, -big)),
+                jnp.sum(v.astype(jnp.int64)),
+                jnp.sum(live.astype(jnp.int64))])
+        return stats
+
     @classmethod
     def stats(cls, rkey: DevVal, live_r):
         """int64[4]: the live valid build keys' min, max and count, and
         the live rows' count."""
-        def build():
-            def stats(rd, rv, live):
-                v = rv & live
-                k = rd.astype(jnp.int64)
-                big = jnp.asarray(np.iinfo(np.int64).max, jnp.int64)
-                return jnp.stack([
-                    jnp.min(jnp.where(v, k, big)),
-                    jnp.max(jnp.where(v, k, -big)),
-                    jnp.sum(v.astype(jnp.int64)),
-                    jnp.sum(live.astype(jnp.int64))])
-            return stats
         rd, rv = rkey
         return cls._get(
             ("stats", rd.shape[0], str(rd.dtype)),
-            lambda: tpu_jit(build(), name="join_build_stats"))(
+            lambda: tpu_jit(cls._stats_body(), name="join_build_stats"))(
                 rd, rv, live_r)
+
+    @staticmethod
+    def _build_body(slots: int):
+        def table(rd, rv, live, keymin):
+            v = rv & live
+            cap_r = rd.shape[0]
+            pos = jnp.clip(rd.astype(jnp.int64) - keymin, 0,
+                           slots - 1).astype(jnp.int32)
+            tgt = jnp.where(v, pos, slots)
+            cnt = jnp.zeros(slots, jnp.int32).at[tgt].add(
+                1, mode="drop")
+            rowid = jnp.full(slots, -1, jnp.int32).at[tgt].max(
+                jnp.arange(cap_r, dtype=jnp.int32), mode="drop")
+            if _windowed(slots):
+                rowid = rowid.reshape(-1, WINDOW_SLOTS)
+            return rowid, jnp.max(cnt) <= 1
+        return table
 
     @classmethod
     def build(cls, rkey: DevVal, live_r, keymin, slots: int):
         """(row ids by ``key - keymin``, whether every key is unique)."""
-        def build():
-            def table(rd, rv, live, keymin):
-                v = rv & live
-                cap_r = rd.shape[0]
-                pos = jnp.clip(rd.astype(jnp.int64) - keymin, 0,
-                               slots - 1).astype(jnp.int32)
-                tgt = jnp.where(v, pos, slots)
-                cnt = jnp.zeros(slots, jnp.int32).at[tgt].add(
-                    1, mode="drop")
-                rowid = jnp.full(slots, -1, jnp.int32).at[tgt].max(
-                    jnp.arange(cap_r, dtype=jnp.int32), mode="drop")
-                if _windowed(slots):
-                    rowid = rowid.reshape(-1, WINDOW_SLOTS)
-                return rowid, jnp.max(cnt) <= 1
-            return table
         rd, rv = rkey
         return cls._get(
             ("build", rd.shape[0], str(rd.dtype), slots),
-            lambda: tpu_jit(build(), name="join_direct_build"))(
+            lambda: tpu_jit(cls._build_body(slots),
+                            name="join_direct_build"))(
                 rd, rv, live_r, keymin)
+
+    @staticmethod
+    def _probe_body(jt: str, slots: int):
+        def probe(ld, lv, live, rowid, keymin):
+            cap_l = ld.shape[0]
+            p = ld.astype(jnp.int64) - keymin
+            inb = (p >= 0) & (p < slots) & lv & live
+            ri, clustered = _direct_lookup(
+                rowid, jnp.clip(p, 0, slots - 1).astype(jnp.int32), inb)
+            matched = inb & (ri >= 0)
+            ri = jnp.where(matched, ri, 0)
+            keep = (live & ~matched) if jt == "leftanti" else matched
+            keep_i = keep.astype(jnp.int32)
+            nout = jnp.sum(keep_i)
+            if jt != "inner":
+                return ri, matched, keep, nout, None, clustered
+            tgt = jnp.where(keep, jnp.cumsum(keep_i) - 1, cap_l)
+            idx = jnp.zeros(cap_l, jnp.int32).at[tgt].set(
+                jnp.arange(cap_l, dtype=jnp.int32), mode="drop")
+            return ri, matched, keep, nout, idx, clustered
+        return probe
 
     @classmethod
     def probe(cls, jt: str, lkey: DevVal, live_l, direct: _DirectBuild):
@@ -411,63 +437,172 @@ class _DirectJoinKernel:
         for an inner join, whose output is gathered, their positions in
         probe order at the front of an int32[capacity], and whether the
         lookup found the batch clustered)."""
-        slots = direct.slots
-
-        def build():
-            def probe(ld, lv, live, rowid, keymin):
-                cap_l = ld.shape[0]
-                p = ld.astype(jnp.int64) - keymin
-                inb = (p >= 0) & (p < slots) & lv & live
-                ri, clustered = _direct_lookup(
-                    rowid, jnp.clip(p, 0, slots - 1).astype(jnp.int32), inb)
-                matched = inb & (ri >= 0)
-                ri = jnp.where(matched, ri, 0)
-                keep = (live & ~matched) if jt == "leftanti" else matched
-                keep_i = keep.astype(jnp.int32)
-                nout = jnp.sum(keep_i)
-                if jt != "inner":
-                    return ri, matched, keep, nout, None, clustered
-                tgt = jnp.where(keep, jnp.cumsum(keep_i) - 1, cap_l)
-                idx = jnp.zeros(cap_l, jnp.int32).at[tgt].set(
-                    jnp.arange(cap_l, dtype=jnp.int32), mode="drop")
-                return ri, matched, keep, nout, idx, clustered
-            return probe
         ld, lv = lkey
         return cls._get(
-            ("probe", jt, ld.shape[0], str(ld.dtype), slots),
-            lambda: tpu_jit(build(), name="join_direct_probe"))(
+            ("probe", jt, ld.shape[0], str(ld.dtype), direct.slots),
+            lambda: tpu_jit(cls._probe_body(jt, direct.slots),
+                            name="join_direct_probe"))(
                 ld, lv, live_l, direct.rowid, direct.keymin)
+
+    @staticmethod
+    def _gather_body(out_cap: int):
+        def gather(l_cols, r_cols, ri, idx, nout):
+            li = idx[:out_cap]
+            live = jnp.arange(out_cap, dtype=jnp.int32) < nout
+            rj = ri[li]
+            return ([(d[li], v[li] & live) for d, v in l_cols],
+                    [(d[rj], v[rj] & live) for d, v in r_cols])
+        return gather
 
     @classmethod
     def gather(cls, lt: DeviceTable, rt: DeviceTable, ri, idx, nout,
                out_cap: int):
         """The kept rows of an inner join in a bucket of ``out_cap``
         rows: probe columns by position, build columns by row id."""
-        def build():
-            def gather(l_cols, r_cols, ri, idx, nout):
-                li = idx[:out_cap]
-                live = jnp.arange(out_cap, dtype=jnp.int32) < nout
-                rj = ri[li]
-                return ([(d[li], v[li] & live) for d, v in l_cols],
-                        [(d[rj], v[rj] & live) for d, v in r_cols])
-            return gather
         key = ("gather", out_cap, lt.schema_key(), rt.schema_key())
         return cls._get(
-            key, lambda: tpu_jit(build(), name="join_direct_gather"))(
+            key, lambda: tpu_jit(cls._gather_body(out_cap),
+                                 name="join_direct_gather"))(
             tuple((c.data, c.validity) for c in lt.columns),
             tuple((c.data, c.validity) for c in rt.columns), ri, idx, nout)
+
+    @staticmethod
+    def _in_place_body(r_cols, ri, matched):
+        return [(d[ri], v[ri] & matched) for d, v in r_cols]
 
     @classmethod
     def in_place(cls, rt: DeviceTable, ri, matched):
         """The build columns beside the probe rows where they lie."""
-        def build():
-            def in_place(r_cols, ri, matched):
-                return [(d[ri], v[ri] & matched) for d, v in r_cols]
-            return in_place
         key = ("inplace", ri.shape[0], rt.schema_key())
         return cls._get(
-            key, lambda: tpu_jit(build(), name="join_direct_in_place"))(
+            key, lambda: tpu_jit(cls._in_place_body,
+                                 name="join_direct_in_place"))(
             tuple((c.data, c.validity) for c in rt.columns), ri, matched)
+
+    # -- the same bodies on the chips of a mesh -----------------------------
+    # ``chips`` (parallel/shards.Chips) is where the probe side lies;
+    # ``local`` says whether the build side lies there too, a part on
+    # each chip (co-partitioned: keymin is one scalar a chip), or is
+    # replicated to every chip. Each program is named `join_chips_<what>`.
+
+    @classmethod
+    def stats_on_chips(cls, chips, rkey: DevVal, live_r, nrows):
+        """int64[chips.n * 4]: ``stats`` of each chip's part of a build
+        side that lies on ``chips``, in chip order."""
+        from spark_rapids_tpu.parallel.shards import chip_live, on_chips
+        from jax.sharding import PartitionSpec as P
+        rd, rv = rkey
+        body, rows, axes = cls._stats_body(), chips.rows, chips.axes
+
+        def on_chip(rd, rv, live, nrows):
+            return body(rd, rv, chip_live(live, nrows, rows, axes))
+        by_row = chips.by_row
+        return cls._get(
+            ("stats_chips", chips.token(), str(rd.dtype), live_r is None),
+            lambda: tpu_jit(on_chips(
+                on_chip, chips,
+                in_specs=(by_row, by_row,
+                          None if live_r is None else by_row, P()),
+                out_specs=by_row), name="join_chips_stats"))(
+                rd, rv, live_r, nrows)
+
+    @classmethod
+    def build_on_chips(cls, chips, rkey: DevVal, live_r, nrows, keymin,
+                       slots: int):
+        """Each chip's direct table of its part of the build side (row
+        ids local to that part, ``keymin`` one per chip), and whether
+        each chip's keys are unique (bool[chips.n])."""
+        from spark_rapids_tpu.parallel.shards import chip_live, on_chips
+        from jax.sharding import PartitionSpec as P
+        rd, rv = rkey
+        body, rows, axes = cls._build_body(slots), chips.rows, chips.axes
+
+        def on_chip(rd, rv, live, nrows, keymin):
+            rowid, unique = body(rd, rv, chip_live(live, nrows, rows, axes),
+                                 keymin[0])
+            return rowid, unique[None]
+        by_row = chips.by_row
+        return cls._get(
+            ("build_chips", chips.token(), str(rd.dtype), slots,
+             live_r is None),
+            lambda: tpu_jit(on_chips(
+                on_chip, chips,
+                in_specs=(by_row, by_row,
+                          None if live_r is None else by_row, P(), by_row),
+                out_specs=(by_row, by_row)), name="join_chips_build"))(
+                rd, rv, live_r, nrows, keymin)
+
+    @classmethod
+    def probe_on_chips(cls, chips, local: bool, lkey: DevVal, live_l, nrows,
+                       direct: _DirectBuild):
+        """``probe`` of an inner join on each chip's rows: against the
+        chip's own direct table (``local``) or the replicated one. The
+        counts and the clustered flags come back one a chip."""
+        from spark_rapids_tpu.parallel.shards import chip_live, on_chips
+        from jax.sharding import PartitionSpec as P
+        ld, lv = lkey
+        body = cls._probe_body("inner", direct.slots)
+        rows, axes = chips.rows, chips.axes
+
+        def on_chip(ld, lv, live, nrows, rowid, keymin):
+            ri, _matched, keep, nout, idx, clustered = body(
+                ld, lv, chip_live(live, nrows, rows, axes), rowid,
+                keymin[0] if local else keymin)
+            return ri, keep, nout[None], idx, clustered[None]
+        by_row = chips.by_row
+        build_spec = by_row if local else P()
+        return cls._get(
+            ("probe_chips", chips.token(), local, str(ld.dtype),
+             direct.slots, live_l is None),
+            lambda: tpu_jit(on_chips(
+                on_chip, chips,
+                in_specs=(by_row, by_row,
+                          None if live_l is None else by_row, P(),
+                          build_spec, build_spec),
+                out_specs=(by_row,) * 5), name="join_chips_probe"))(
+                ld, lv, live_l, nrows, direct.rowid, direct.keymin)
+
+    @classmethod
+    def gather_on_chips(cls, chips, local: bool, lt: DeviceTable,
+                        rt: DeviceTable, ri, idx, nout, out_cap: int):
+        """``gather`` on each chip: its kept rows in a bucket of
+        ``out_cap`` rows a chip, under their mask."""
+        from spark_rapids_tpu.parallel.shards import on_chips
+        from jax.sharding import PartitionSpec as P
+        body = cls._gather_body(out_cap)
+
+        def on_chip(l_cols, r_cols, ri, idx, nout):
+            louts, routs = body(l_cols, r_cols, ri, idx, nout[0])
+            live = jnp.arange(out_cap, dtype=jnp.int32) < nout[0]
+            return louts, routs, live
+        by_row = chips.by_row
+        key = ("gather_chips", chips.token(), local, out_cap,
+               lt.schema_key()[0], rt.schema_key())
+        return cls._get(
+            key, lambda: tpu_jit(on_chips(
+                on_chip, chips,
+                in_specs=(by_row, by_row if local else P(), by_row, by_row,
+                          by_row),
+                out_specs=(by_row, by_row, by_row)),
+                name="join_chips_gather"))(
+            tuple((c.data, c.validity) for c in lt.columns),
+            tuple((c.data, c.validity) for c in rt.columns), ri, idx, nout)
+
+    @classmethod
+    def in_place_on_chips(cls, chips, local: bool, rt: DeviceTable, ri,
+                          keep):
+        """``in_place`` on each chip: the build columns beside the probe
+        rows where they lie."""
+        from spark_rapids_tpu.parallel.shards import on_chips
+        from jax.sharding import PartitionSpec as P
+        by_row = chips.by_row
+        key = ("inplace_chips", chips.token(), local, rt.schema_key())
+        return cls._get(
+            key, lambda: tpu_jit(on_chips(
+                cls._in_place_body, chips,
+                in_specs=(by_row if local else P(), by_row, by_row),
+                out_specs=by_row), name="join_chips_in_place"))(
+            tuple((c.data, c.validity) for c in rt.columns), ri, keep)
 
 
 class _ColumnGather:
@@ -542,6 +677,23 @@ class TpuJoinExec(TpuExec):
         self.max_subpartitions = max_subpartitions
         self._kernel = JoinKernel.get(len(self.left_keys))
         self._filter_kernel = None
+        #: how the join runs on a mesh (overrides/rules.py): None, one
+        #: device; "replicated", a broadcast build copied to every chip
+        #: and probed by the row-sharded batches where they lie;
+        #: "copartitioned", both sides hash-exchanged on the join keys,
+        #: so each chip joins its own partition (_execute_on_chips);
+        #: "adaptive" (AQE on, the build side's estimate over the
+        #: threshold): both children are exchanges, and the build side's
+        #: MEASURED bytes choose one of the two at run time, as AQE's
+        #: dynamic join selection does (_adapt)
+        self.on_chips: Optional[str] = None
+        #: the adaptive choice's threshold: ``broadcastSizeBytes``
+        self.broadcast_threshold: Optional[int] = None
+        #: which of the two the last execution ran (None: one device)
+        self.ran_as: Optional[str] = None
+        #: an adaptive join's build side, converted to a broadcast once
+        #: and kept across executions (TpuAdaptiveBuildExec's contract)
+        self._aqe_build = None
 
     def output_schema(self):
         jt = self.join_type
@@ -554,8 +706,9 @@ class TpuJoinExec(TpuExec):
 
     def describe(self):
         side = "left" if self.build_left else "right"
+        chips = f", on chips: {self.on_chips}" if self.on_chips else ""
         return (f"TpuJoin[{self.join_type}, keys={len(self.left_keys)}, "
-                f"build={side}]")
+                f"build={side}{chips}]")
 
     # -----------------------------------------------------------------------
     produces_masked = True
@@ -571,17 +724,27 @@ class TpuJoinExec(TpuExec):
         joins accumulate a build-side match bitmap across probe batches and
         emit unmatched build rows as a final batch. Probe batches may be
         MASKED (filter output). Both ranges add to ``phasesS.joinS``."""
-        from spark_rapids_tpu.dispatch import phase_span
-        from spark_rapids_tpu.runtime.retry import retry_block
-
-        jt = self.join_type
         swapped = self.build_left
         build_child = self.children[0] if swapped else self.children[1]
         probe_child = self.children[1] if swapped else self.children[0]
         self.add_metric("buildSideSwapped", int(swapped))
+        if self.on_chips is not None:
+            yield from self._execute_on_chips(build_child, probe_child,
+                                              swapped)
+            return
+        yield from self._execute_joined(
+            lambda: self._single(build_child),
+            probe_child.execute_masked(), swapped)
 
+    def _execute_joined(self, make_build, probes, swapped: bool):
+        """The join on one device: the build side ``make_build()`` made
+        ready, then each of ``probes`` against it."""
+        from spark_rapids_tpu.dispatch import phase_span
+        from spark_rapids_tpu.runtime.retry import retry_block
+
+        jt = self.join_type
         with phase_span("joinS", "build", "join"):
-            build = self._single(build_child)
+            build = make_build()
             # spill-aware threshold: a build side past the device budget's
             # chunk share sub-partitions even when the conf threshold is
             # higher — each partition rides the spill tiers independently
@@ -604,7 +767,7 @@ class TpuJoinExec(TpuExec):
                 self.add_metric("buildRows", rows)
         if nparts > 1:
             yield from self._execute_subpartitioned(
-                build, probe_child, swapped, int(nparts))
+                build, probes, swapped, int(nparts))
             return
 
         # the build side registers as a SpillableDeviceTable (ISSUE 15):
@@ -637,7 +800,7 @@ class TpuJoinExec(TpuExec):
             return outs
 
         try:
-            for pb in probe_child.execute_masked():
+            for pb in probes:
                 self.add_metric("probeBatches", 1)
                 with phase_span("joinS", "batch", "join"), \
                         build_sb.pinned_batch() as bt:
@@ -715,6 +878,298 @@ class TpuJoinExec(TpuExec):
             return None, rows
         return _DirectBuild(rowid, keymin, slots), rows
 
+    # -- on the chips of a mesh ---------------------------------------------
+    def _execute_on_chips(self, build_child, probe_child, swapped: bool):
+        """The join where its inputs lie on the mesh's chips, by the
+        direct-address body on each chip (``on_chips``, set by the
+        planner for an inner join with one integer key):
+
+        - "copartitioned": both children are ICI exchanges on the join
+          keys, so chip p holds partition p of both sides; each chip
+          builds the direct table of its part of the build side and
+          probes it with its part of each probe batch;
+        - "replicated": the build side is a broadcast, copied to every
+          chip; each chip probes the one table with the rows of each
+          probe batch it holds.
+
+        The build's key range, live rows and uniqueness are read for
+        every chip in one fetch each; each probe batch's output counts,
+        one a chip, are read as on one device (PROBE_AHEAD batches a
+        fetch). Each output batch lies on the chips under its mask: no
+        row leaves its chip. Where the body does not apply (a key range
+        too wide, a key that repeats, a batch not on the chips), the
+        join runs on one device, its inputs gathered there
+        (execs/mesh.reland)."""
+        from spark_rapids_tpu.dispatch import phase_span
+        from spark_rapids_tpu.runtime.retry import retry_block
+
+        self.ran_as = None
+        with phase_span("joinS", "build", "join"):
+            if self.on_chips == "adaptive":
+                mode, builds, probes = self._adapt(build_child, probe_child)
+            else:
+                mode = self.on_chips
+                builds = list(build_child.execute_masked())
+                probes = probe_child.execute_masked()
+            local = mode == "copartitioned"
+            ready = self._chip_build(builds, local)
+            plan = None if ready is None else self._plan_direct_on_chips(
+                *ready, local)
+        if plan is None:
+            yield from self._joined_on_one_device(builds, probes, swapped)
+            return
+        self.ran_as = mode
+        self.add_metric("joinOnChips" if local else "joinReplicatedBuild", 1)
+        build, direct = plan
+        ahead: list = []
+        whole = None
+
+        def finish_ahead():
+            with phase_span("joinS", "batch", "join"):
+                outs = self._finish_on_chips(ahead, build, local, swapped)
+            ahead.clear()
+            return outs
+
+        for pb in probes:
+            self.add_metric("probeBatches", 1)
+            with phase_span("joinS", "batch", "join"):
+                pending = retry_block(
+                    lambda b=pb: self._probe_on_chips(b, direct, local,
+                                                      swapped))
+            if pending is None:
+                # a co-partitioned probe batch that does not lie on the
+                # chips (its exchange took another path): joined whole
+                # against the whole build side on one device
+                from spark_rapids_tpu.execs.mesh import reland
+                if whole is None:
+                    whole = reland(self, build).compacted()
+                out, _ = retry_block(lambda b=pb: self._join_batch(
+                    reland(self, b), whole, swapped))
+                self.add_metric("sortJoinBatches", 1)
+                yield self._apply_condition(out)
+                continue
+            ahead.append(pending)
+            if len(ahead) >= PROBE_AHEAD:
+                for out in finish_ahead():
+                    yield self._apply_condition(out)
+        if ahead:
+            for out in finish_ahead():
+                yield self._apply_condition(out)
+
+    def _adapt(self, build_child, probe_child):
+        """AQE's dynamic join selection on the mesh, for a join whose
+        children are both ICI exchanges: the build side's input is
+        materialized and MEASURED (device bytes, as
+        TpuAdaptiveBuildExec measures); at or under
+        ``broadcast_threshold`` it becomes a broadcast, kept for later
+        executions, and the probe side is read past its exchange
+        ("replicated"); over it, the exchanges run ("copartitioned").
+        Returns (mode, the build side's batches, the probe batches)."""
+        probe_input = probe_child.children[0]
+        if self._aqe_build is not None:
+            return "replicated", [self._aqe_build], \
+                probe_input.execute_masked()
+        raw = list(build_child.children[0].execute_masked())
+        measured = sum(b.device_nbytes() for b in raw)
+        self.add_metric("aqeMeasuredBuildBytes", measured)
+        if measured > self.broadcast_threshold:
+            return "copartitioned", list(build_child.exchanged(raw)), \
+                probe_child.execute_masked()
+        self.add_metric("aqeBroadcastConverted", 1)
+        if len(raw) > 1:
+            from spark_rapids_tpu.parallel.shards import (
+                chips_of,
+                concat_on_chips,
+            )
+            where = [chips_of(b) for b in raw]
+            if all(c is not None and c[:3] == where[0][:3] for c in where):
+                raw = [concat_on_chips(raw, where)]
+            else:
+                from spark_rapids_tpu.columnar.table import concat_device
+                raw = [concat_device(raw)]
+        if raw:
+            self._aqe_build = raw[0]
+        return "replicated", raw, probe_input.execute_masked()
+
+    def _chip_build(self, builds, local: bool):
+        """(the build side made ready on the chips, its chips) or None
+        where it cannot be: a co-partitioned build is its exchanged
+        batches, each chip's rows of them in one table
+        (shards.concat_on_chips); a replicated one is the broadcast
+        table copied to every chip of the mesh."""
+        from spark_rapids_tpu.parallel.mesh import MESH
+        from spark_rapids_tpu.parallel.shards import (
+            Chips,
+            chips_of,
+            concat_on_chips,
+            replicated_table,
+        )
+        if not builds:
+            return None
+        if local:
+            chips = [chips_of(b) for b in builds]
+            if any(c is None or c[:3] != chips[0][:3] for c in chips):
+                return None
+            table = builds[0] if len(builds) == 1 else concat_on_chips(
+                builds, chips)
+            return table, chips_of(table)
+        if len(builds) != 1 or MESH.ndev < 2 or not MESH.enabled:
+            return None
+        mesh, axes = MESH.mesh(), MESH.row_axes()
+        chips = Chips(mesh, axes if len(axes) > 1 else axes[0], MESH.ndev, 0)
+        self.add_metric("replicatedBuildRows", builds[0].capacity)
+        return replicated_table(builds[0], chips), chips
+
+    def _plan_direct_on_chips(self, build: DeviceTable, chips, local: bool):
+        """_plan_direct on the chips: (the build side, its direct tables)
+        or None where the direct body does not apply on every chip. One
+        fetch reads every chip's key range and live rows, one more
+        whether each chip's keys are unique."""
+        jt = self.join_type
+        if (jt != "inner" or len(self.left_keys) != 1
+                or self.condition is not None):
+            return None
+        build_key = (self.left_keys if self.build_left
+                     else self.right_keys)[0]
+        probe_key = (self.right_keys if self.build_left
+                     else self.left_keys)[0]
+        if not (isinstance(build_key.data_type, T.IntegralType)
+                and isinstance(probe_key.data_type, T.IntegralType)):
+            return None
+        from spark_rapids_tpu.dispatch import device_scalar, host_fetch
+        from spark_rapids_tpu.parallel.shards import (
+            chip_counts_host,
+            key_columns,
+            replicated_scalar,
+        )
+        kc = key_columns([build_key], build)[0]
+        rkey = (kc.data, kc.validity)
+        if local:
+            nrows = replicated_scalar(build, chips)
+            stats = chip_counts_host(_DirectJoinKernel.stats_on_chips(
+                chips, rkey, build.live, nrows)).reshape(chips.n, 4)
+        else:
+            stats = np.asarray(host_fetch(_DirectJoinKernel.stats(
+                rkey, build.live))).reshape(1, 4)
+        kmin = np.where(stats[:, 2] > 0, stats[:, 0], 0)
+        kmax = np.where(stats[:, 2] > 0, stats[:, 1], 0)
+        self.add_metric("buildRows", int(stats[:, 3].sum()))
+        span = int((kmax - kmin).max()) + 1
+        rows = chips.rows if local else build.capacity
+        if span > max(DIRECT_TABLE_MULT.get() * rows, DIRECT_MAX_SLOTS):
+            return None
+        slots = bucket_for(max(span, 1))
+        if local:
+            from spark_rapids_tpu.parallel.mesh import shard_put
+            keymin = shard_put(kmin.astype(np.int64), chips.row_sharding())
+            rowid, unique = _DirectJoinKernel.build_on_chips(
+                chips, rkey, build.live, nrows, keymin, slots)
+        else:
+            keymin = device_scalar(int(kmin[0]), np.int64,
+                                   sharding=chips.everywhere())
+            rowid, unique = _DirectJoinKernel.build(
+                rkey, build.live, keymin, slots)
+        if not bool(np.all(host_fetch(unique))):
+            return None
+        return build, _DirectBuild(rowid, keymin, slots)
+
+    def _probe_on_chips(self, pb: DeviceTable, direct: _DirectBuild,
+                        local: bool, swapped: bool):
+        """One probe batch on the chips: a _DirectPending whose counts,
+        one a chip, _finish_on_chips reads; None for a co-partitioned
+        probe batch that does not lie on the chips. A replicated build's
+        probe batch that lies on one device is put on the chips first."""
+        from spark_rapids_tpu.parallel.mesh import MESH
+        from spark_rapids_tpu.parallel.shards import (
+            Chips,
+            chips_of,
+            key_columns,
+            onto_chips,
+            replicated_scalar,
+        )
+        chips = chips_of(pb)
+        if chips is None:
+            if local or MESH.ndev < 2:
+                return None
+            axes = MESH.row_axes()
+            pb, chips = onto_chips(pb, Chips(
+                MESH.mesh(), axes if len(axes) > 1 else axes[0],
+                MESH.ndev, 0))
+        self.add_metric("directJoinBatches", 1)
+        self.add_metric("meshChipJoins", 1)
+        probe_keys = self.right_keys if swapped else self.left_keys
+        kc = key_columns(probe_keys, pb)[0]
+        ri, keep, nout, idx, clustered = _DirectJoinKernel.probe_on_chips(
+            chips, local, (kc.data, kc.validity), pb.live,
+            replicated_scalar(pb, chips), direct)
+        return _DirectPending(pb, ri, keep, idx, nout, clustered)
+
+    def _finish_on_chips(self, ahead, rt: DeviceTable, local: bool,
+                         swapped: bool):
+        """_direct_finish on the chips: ONE fetch reads every chip's
+        output count of the batches ``ahead``; each batch's kept rows are
+        gathered on their chips into the bucket the fullest chip needs,
+        or stay where they lie under the mask where no smaller bucket
+        exists."""
+        from spark_rapids_tpu.dispatch import host_fetch
+        from spark_rapids_tpu.parallel.shards import chips_of
+        read = host_fetch([(p.nout, p.clustered) for p in ahead])
+        outs = []
+        names = self.left_names + self.right_names
+        for p, (counts, clustered) in zip(ahead, read):
+            counts = np.asarray(counts).reshape(-1)
+            n = int(counts.sum())
+            self.add_metric("joinOutputRows", n)
+            self.add_metric("clusteredProbeBatches",
+                            int(np.all(np.asarray(clustered))))
+            lt = p.table
+            chips = chips_of(lt)
+            out_cap = bucket_for(max(int(counts.max()), 1))
+            if out_cap >= chips.rows:
+                routs = _DirectJoinKernel.in_place_on_chips(
+                    chips, local, rt, p.ri, p.keep)
+                rcols = [c.with_arrays(d, v)
+                         for c, (d, v) in zip(rt.columns, routs)]
+                lcols = list(lt.columns)
+                outs.append(DeviceTable(
+                    names, rcols + lcols if swapped else lcols + rcols,
+                    n, lt.capacity, live=p.keep,
+                    shard_spec=chips.row_sharding()))
+                continue
+            louts, routs, live = _DirectJoinKernel.gather_on_chips(
+                chips, local, lt, rt, p.ri, p.idx, p.nout, out_cap)
+            lcols = [c.with_arrays(d, v)
+                     for c, (d, v) in zip(lt.columns, louts)]
+            rcols = [c.with_arrays(d, v)
+                     for c, (d, v) in zip(rt.columns, routs)]
+            outs.append(DeviceTable(
+                names, rcols + lcols if swapped else lcols + rcols, n,
+                out_cap * chips.n, live=live,
+                shard_spec=chips.row_sharding()))
+        return outs
+
+    def _joined_on_one_device(self, builds, probes, swapped: bool):
+        """The join off the chips' fast path: its build side and each
+        probe batch gathered to one device (execs/mesh.reland), then the
+        one-device join, whose build side is one table in prefix form
+        (what ``_single`` takes from its child)."""
+        from spark_rapids_tpu.columnar.table import concat_device
+        from spark_rapids_tpu.execs.mesh import reland
+        from spark_rapids_tpu.runtime.retry import retry_block
+
+        def make_build():
+            landed = [reland(self, b) for b in builds]
+            if not landed:
+                from spark_rapids_tpu.plan.nodes import _empty_table
+                child = self.children[0] if swapped else self.children[1]
+                return DeviceTable.from_host(
+                    _empty_table(child.output_schema()))
+            return landed[0].compacted() if len(landed) == 1 \
+                else retry_block(lambda: concat_device(landed))
+
+        yield from self._execute_joined(
+            make_build, (reland(self, b) for b in probes), swapped)
+
     def _direct_probe(self, lt: DeviceTable, rt: DeviceTable,
                       direct: _DirectBuild, swapped: bool):
         """One probe batch against the direct table. An inner join comes
@@ -775,7 +1230,7 @@ class TpuJoinExec(TpuExec):
                 rcols + lcols if swapped else lcols + rcols, n, out_cap))
         return outs
 
-    def _execute_subpartitioned(self, build: DeviceTable, probe_child,
+    def _execute_subpartitioned(self, build: DeviceTable, probes,
                                 swapped: bool, nparts: int):
         """Sub-partitioned escalation (GpuSubPartitionHashJoin analog): the
         build table splits by Spark-exact key hash into ``nparts`` SPILLABLE
@@ -804,7 +1259,7 @@ class TpuJoinExec(TpuExec):
         directs = {}
         from spark_rapids_tpu.dispatch import phase_span
         try:
-            for pb in probe_child.execute_masked():
+            for pb in probes:
                 self.add_metric("probeBatches", 1)
                 for p, pp in enumerate(self._split(pb, pparter)):
                     with phase_span("joinS", "batch", "join"), \

@@ -210,8 +210,107 @@ def _shard_safe_consumers() -> tuple:
         TpuProjectExec,
     )
     from spark_rapids_tpu.execs.exchange import TpuShuffleExchangeExec
+    from spark_rapids_tpu.execs.sort import TpuTakeOrderedAndProjectExec
     return (TpuFilterExec, TpuProjectExec, TpuShuffleExchangeExec,
-            TpuHashAggregateExec, TpuCoalesceExec, TpuMeshRelandExec)
+            TpuHashAggregateExec, TpuCoalesceExec, TpuMeshRelandExec,
+            TpuTakeOrderedAndProjectExec)
+
+
+def _takes_shards(node, safe: tuple) -> bool:
+    """Whether ``node`` takes sharded input as it lies: a consumer of
+    the shard-safe set (the top-k takes each chip's candidates and
+    merges them on one, execs/sort.py), a join the planner put on the
+    chips (``TpuJoinExec.on_chips``), or the broadcast under such a
+    join, which materializes what it is given for the join to copy to
+    every chip (``TpuBroadcastExchangeExec.on_chips``)."""
+    return isinstance(node, safe) or bool(getattr(node, "on_chips", None))
+
+
+def hash_partitioning(node) -> list:
+    """The ways the batches ``node`` yields lie hash-partitioned over the
+    chips, each a tuple of ordinals of its output whose values decide a
+    row's chip: rows that agree on them lie on one chip. Empty where
+    nothing is known. An ICI exchange of a shuffled join is partitioned
+    by its keys; its join by either side's keys (they are equal on every
+    output row); a replicated join keeps its probe side's partitioning;
+    filters, coalesces and projections carry it through the columns they
+    keep."""
+    from spark_rapids_tpu.execs.basic import (
+        TpuCoalesceExec,
+        TpuFilterExec,
+        TpuProjectExec,
+    )
+    from spark_rapids_tpu.execs.exchange import TpuShuffleExchangeExec
+    from spark_rapids_tpu.execs.join import TpuJoinExec
+    from spark_rapids_tpu.ops.expr import Alias, BoundReference
+
+    def ordinal(e):
+        while isinstance(e, Alias):
+            e = e.children[0]
+        return e.ordinal if isinstance(e, BoundReference) else None
+
+    def ordinals(exprs):
+        out = tuple(ordinal(e) for e in exprs)
+        return None if None in out else out
+
+    if isinstance(node, TpuShuffleExchangeExec):
+        keys = ordinals(node.keys) if node.on_chips and node.mode == "hash" \
+            else None
+        return [keys] if keys else []
+    if isinstance(node, TpuJoinExec) and node.on_chips:
+        width = len(node.left_names)
+        left, right = ordinals(node.left_keys), ordinals(node.right_keys)
+        # what the last execution ran; before one, an adaptive join is
+        # taken as the shuffled join it was planned as
+        mode = node.ran_as or ("copartitioned" if node.on_chips
+                               == "adaptive" else node.on_chips)
+        if mode == "copartitioned":
+            return [k for k in (
+                left, right and tuple(width + o for o in right)) if k]
+        probe = node.children[1] if node.build_left else node.children[0]
+        if node.on_chips == "adaptive":
+            probe = probe.children[0]  # its exchange did not run
+        below = hash_partitioning(probe)
+        if node.build_left:
+            return [tuple(width + o for o in p) for p in below]
+        return below
+    if isinstance(node, (TpuFilterExec, TpuCoalesceExec)):
+        below = hash_partitioning(node.children[0])
+        columns = getattr(node, "columns", None)
+        if columns is None:
+            return below
+        at = {old: new for new, old in enumerate(columns)}
+        return [tuple(at[o] for o in p) for p in below
+                if all(o in at for o in p)]
+    if isinstance(node, TpuProjectExec):
+        at = {}
+        for new, e in enumerate(node.exprs):
+            o = ordinal(e)
+            if o is not None:
+                at.setdefault(o, new)
+        return [tuple(at[o] for o in p)
+                for p in hash_partitioning(node.children[0])
+                if all(o in at for o in p)]
+    return []
+
+
+def _place_on_chips(node) -> None:
+    """A grouped aggregate whose keys hold a partitioning of its input
+    (hash_partitioning) aggregates each chip's rows on that chip
+    (``on_chips``): no group spans two chips. Its coalesce then hands
+    the batches on where they lie."""
+    from spark_rapids_tpu.execs.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.execs.basic import TpuCoalesceExec
+    from spark_rapids_tpu.ops.expr import BoundReference
+    if not isinstance(node, TpuHashAggregateExec) or not node.grouping:
+        return
+    grouped = {g.ordinal for g in node.grouping
+               if isinstance(g, BoundReference)}
+    if any(set(p) <= grouped for p in hash_partitioning(node.children[0])):
+        node.on_chips = True
+        child = node.children[0]
+        if isinstance(child, TpuCoalesceExec) and not child.require_single:
+            child.on_chips = True
 
 
 def insert_mesh_relands(executable):
@@ -255,7 +354,8 @@ def insert_mesh_relands(executable):
         children = tuple(getattr(node, "children", ()) or ())
         if not children:
             return
-        if isinstance(node, TpuExec) and not isinstance(node, safe):
+        _place_on_chips(node)
+        if isinstance(node, TpuExec) and not _takes_shards(node, safe):
             node.children = tuple(
                 TpuMeshRelandExec(c)
                 if isinstance(c, TpuExec)

@@ -207,19 +207,22 @@ class TpuSortExec(TpuExec):
         new_cols = [c.with_arrays(d, v) for c, (d, v) in zip(table.columns, outs)]
         return DeviceTable(table.names, new_cols, table.nrows_dev, capacity)
 
-    def _topk(self, table: DeviceTable, k: int) -> DeviceTable:
+    def _topk(self, table: DeviceTable, k: int, chips=None) -> DeviceTable:
         """Top-k rows by sort order at a k-sized capacity: sort ONLY the
         key operands + a row-index payload, then gather the k winning rows
         of every column. The reference's per-batch top-k sorts then slices
         (GpuTakeOrderedAndProjectExec), but on TPU a full-width gather at
         input capacity costs ~10-30ms per 64-bit column (PERF.md) — this
         does O(k) gather work instead and emits a small-capacity batch,
-        which also shrinks every downstream kernel."""
+        which also shrinks every downstream kernel. With ``chips`` (the
+        table lies on a mesh's chips, parallel/shards.py) each chip takes
+        the top k of its own rows, under ``shard_map``: the output is k
+        candidates a chip, on their chips, under their mask."""
         from spark_rapids_tpu.columnar import bucket_for
         from spark_rapids_tpu.ops.expr import shared_traces
         if table.live is not None and self._pos_dep():
             table = table.compacted()  # slot ids must match prefix form
-        capacity = table.capacity
+        capacity = table.capacity if chips is None else chips.rows
         kcap = min(bucket_for(max(k, 1)), capacity)
         self._traces = shared_traces(
             ("topk", kcap,
@@ -234,10 +237,12 @@ class TpuSortExec(TpuExec):
             key_preps.append(preps)
         from spark_rapids_tpu.dispatch import prep_aux
         cols = tuple(DevVal(c.data, c.validity) for c in table.columns)
-        aux = prep_aux(pctx)
+        aux = prep_aux(pctx, None if chips is None else chips.everywhere())
         has_mask = table.live is not None
         tkey = (capacity, has_mask, k,
                 tuple(_prep_trace_key(p) for p in key_preps))
+        if chips is not None:
+            tkey = ("chips", chips.token()) + tkey
         fn = self._traces.get(tkey)
         if fn is None:
             orders = self.orders
@@ -268,15 +273,46 @@ class TpuSortExec(TpuExec):
                     outs.append((d[idx], v[idx] & out_live))
                 return outs, n_out
 
-            fn = tpu_jit(run, name="sort_topk")
+            # one program name on one device and on the chips: the
+            # same body
+            fn = tpu_jit(run if chips is None else
+                         _on_chips_topk(run, chips, kcap, has_mask),
+                         name="sort_topk")
             self._traces[tkey] = fn
-        outs, n_out = fn(cols, aux, table.nrows_dev, table.live)
+        if chips is None:
+            outs, n_out = fn(cols, aux, table.nrows_dev, table.live)
+            new_cols = [c.with_arrays(d, v)
+                        for c, (d, v) in zip(table.columns, outs)]
+            return DeviceTable(table.names, new_cols, n_out, kcap)
+        from spark_rapids_tpu.parallel.shards import replicated_scalar
+        outs, live, nrows = fn(cols, aux, replicated_scalar(table, chips),
+                               table.live)
         new_cols = [c.with_arrays(d, v)
                     for c, (d, v) in zip(table.columns, outs)]
-        return DeviceTable(table.names, new_cols, n_out, kcap)
+        return DeviceTable(table.names, new_cols, nrows, kcap * chips.n,
+                           live=live, shard_spec=chips.row_sharding())
 
     def describe(self):
         return f"TpuSort[{len(self.orders)} keys]"
+
+
+def _on_chips_topk(run, chips, kcap: int, has_mask: bool):
+    """A top-k body ``run`` under ``shard_map`` on each chip's rows:
+    (the chip's k candidates a column, their mask)."""
+    from jax.sharding import PartitionSpec as P
+
+    from spark_rapids_tpu.parallel.shards import chip_live, on_chips
+    rows, axes = chips.rows, chips.axes
+
+    def on_chip(cols, aux, nrows, live):
+        outs, n_out = run(cols, aux, nrows,
+                          chip_live(live, nrows, rows, axes))
+        return (outs, jnp.arange(kcap, dtype=jnp.int32) < n_out,
+                jax.lax.psum(n_out, axes))
+    by_row = chips.by_row
+    return on_chips(on_chip, chips,
+                    in_specs=(by_row, P(), P(), by_row if has_mask else None),
+                    out_specs=(by_row, by_row, P()))
 
 
 class TpuTakeOrderedAndProjectExec(TpuExec):
@@ -308,18 +344,30 @@ class TpuTakeOrderedAndProjectExec(TpuExec):
         from spark_rapids_tpu.columnar import bucket_for
         from spark_rapids_tpu.columnar.table import concat_device
         from spark_rapids_tpu.ops.expr import compile_project
+        from spark_rapids_tpu.parallel.shards import chips_of, on_one_chip
         from spark_rapids_tpu.runtime.retry import retry_block
 
         k = self.limit
         tops = []
         for batch in self.children[0].execute_masked():
+            # a batch on the chips: each chip takes its top k, and only
+            # those candidates go to one device (k a chip, not the rows);
+            # a batch that lies sharded otherwise is gathered first
+            chips = chips_of(batch)
+            if chips is not None and not self._sorter._pos_dep():
+                tops.append(on_one_chip(retry_block(
+                    lambda b=batch, c=chips: self._sorter._topk(b, k, c))))
+                self.add_metric("chipTopKBatches", 1)
+                continue
+            from spark_rapids_tpu.execs.mesh import reland
+            batch = reland(self, batch)
             tops.append(retry_block(lambda b=batch: self._sorter._topk(b, k)))
 
         if not tops:
             return
         merged = tops[0] if len(tops) == 1 else retry_block(
             lambda: concat_device(tops))
-        if len(tops) == 1:
+        if len(tops) == 1 and merged.live is None:
             final = merged  # a single _topk batch is already sorted
         else:
             final = retry_block(lambda: self._sorter._sort(merged))

@@ -200,8 +200,8 @@ _DECLS: Tuple[LockDecl, ...] = (
              "Lock", "mesh topology config, generation, identity token"),
     LockDecl("mesh.dict_intern", 540,
              "spark_rapids_tpu/parallel/exchange.py:_DICT_INTERN_LOCK",
-             "Lock", "replicated-dictionary intern table + MeshExchange "
-                     "cache (epoch-guarded late-publish rejection)"),
+             "Lock", "replicated-dictionary intern table + exchange "
+                     "program cache (epoch-guarded late-publish rejection)"),
     LockDecl("profiler", 550,
              "spark_rapids_tpu/runtime/profiler.py:TpuProfiler._lock",
              "Lock", "profiler session state + sample buffers"),

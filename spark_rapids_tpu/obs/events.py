@@ -167,7 +167,17 @@ EVENT_LOG_DIR = str_conf(
 #: ``gc.callbacks`` hook of obs/spans.py, which also opens each
 #: collection as the range ``srt.gc.gen<N>``. It overlaps whichever
 #: phase the collection interrupted.
-EVENT_SCHEMA_VERSION = 16
+#: v17 (mesh join PR): phasesS gains exchangeS — host seconds inside the
+#: ICI exchanges (range ``srt.mesh.exchange``: the key projection, the
+#: ``mesh_exchange`` enqueue of each input batch, which dispatchS counts
+#: too, and the one read of the live counts after an exchange's last
+#: batch); 0.0 for a query without one. The record gains meshExchanges
+#: (input batches those exchanges moved), meshExchangeRows and
+#: meshExchangeBytes (the live rows and bytes they sent, from that read;
+#: iciBytes stays the padded blocks) and meshRelandRows (row slots gathered from the chips to one
+#: device at wide-kernel boundaries, execs/mesh.py): sums of the plan
+#: tree's exec metrics, all 0 off the mesh.
+EVENT_SCHEMA_VERSION = 17
 
 
 def plan_tree(executable) -> dict:
@@ -250,6 +260,18 @@ def collect_exchanges(executable) -> List[dict]:
     return out
 
 
+def collect_metric_sums(executable, keys) -> Dict[str, int]:
+    """Each of ``keys`` summed over the executed tree's exec metrics (0
+    where no exec has it)."""
+    totals = dict.fromkeys(keys, 0)
+    for e in _walk_exec_tree(executable):
+        m = getattr(e, "metrics", None)
+        if m:
+            for k in keys:
+                totals[k] += int(m.get(k, 0))
+    return totals
+
+
 def collect_aqe(executable) -> Dict[str, int]:
     """AQE runtime re-plan summary (measured broadcast conversions,
     coalesced partitions) aggregated over the tree."""
@@ -314,6 +336,9 @@ def build_query_record(*, query_index: int, wall_s: float,
     still record the fields as null/false so the schema is stable."""
     service = service or {}
     exchanges = collect_exchanges(executable)
+    mesh_counts = collect_metric_sums(
+        executable, ("meshExchanges", "meshExchangeRows",
+                     "meshExchangeBytes", "meshRelandRows"))
     # per-shard skew of this query's ICI exchanges (measured from the
     # collective's live counts, not file sizes): max over exchanges of
     # max/median per-shard map-output bytes
@@ -348,6 +373,10 @@ def build_query_record(*, query_index: int, wall_s: float,
         "commitRetries": int(commit_retries),
         "meshShape": mesh_shape,
         "iciBytes": int(ici_bytes),
+        "meshExchanges": mesh_counts["meshExchanges"],
+        "meshExchangeRows": mesh_counts["meshExchangeRows"],
+        "meshExchangeBytes": mesh_counts["meshExchangeBytes"],
+        "meshRelandRows": mesh_counts["meshRelandRows"],
         "shardSkew": round(float(shard_skew), 4),
         "meshDegradations": int(mesh_degradations),
         "shardRetries": int(shard_retries),

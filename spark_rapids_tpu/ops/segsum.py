@@ -416,14 +416,20 @@ def segment_sum_f64(v, gid, num_segments: int, capacity: int,
     ``gid`` must be int32 in [0, num_segments). Non-f64 dtypes and
     disabled split configurations take the plain jax.ops.segment_sum
     path; oversized configurations (num_segments*blocks would outgrow
-    the input) take the guarded UNBLOCKED split path. ``counts``: an
-    optional caller-scattered per-segment row-count upper bound — the
-    unblocked guard reuses it instead of scattering its own."""
+    the input) and a segment a row (the sorted-path aggregate, whose
+    groups are a few rows each) take the guarded UNBLOCKED split path:
+    per-block f32 partials would round a group's sum once in f32, as
+    float32 arithmetic does, where the unblocked split keeps it to the
+    pair's precision (at 2^16 rows and fewer the blocked partials were
+    taken, 64 times the input's slots). ``counts``: an optional
+    caller-scattered per-segment row-count upper bound — the unblocked
+    guard reuses it instead of scattering its own."""
     if v.dtype != jnp.float64 or not use_split:
         return jax.ops.segment_sum(v, gid, num_segments=num_segments)
     block = min(BLOCK, capacity)
     nb = max(capacity // block, 1)
-    if nb * block != capacity or nb * num_segments > MAX_PARTIALS:
+    if nb * block != capacity or nb * num_segments > MAX_PARTIALS \
+            or num_segments >= capacity:
         if counts is not None:
             return _batched_unblocked_split([v], gid, num_segments,
                                             counts=counts)[:, 0]

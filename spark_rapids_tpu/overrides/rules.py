@@ -403,6 +403,14 @@ def _tag_join(meta, conf):
     if jt not in _SUPPORTED_JOIN_TYPES:
         meta.reasons.append(f"join type {node.join_type} is not supported on TPU")
         return
+    on_mesh = _mesh_join_plan(node, conf)
+    if on_mesh is not None:
+        kind, ndev, why = on_mesh
+        meta.notes.append(
+            f"on the {ndev}-chip mesh: {why}" if kind == "replicated" else
+            f"on the {ndev}-chip mesh: both sides under a hash "
+            f"TpuShuffleExchange[n={ndev}] over ICI, each chip joining its "
+            f"own partition ({why})")
     if len(node.left_keys) != len(node.right_keys):
         meta.reasons.append(
             f"join key count mismatch: {len(node.left_keys)} vs {len(node.right_keys)}")
@@ -595,6 +603,50 @@ def _convert_exchange(node: P.Exchange, children, conf):
                                   target_batch_bytes=conf.batch_size_bytes)
 
 
+def _mesh_join_plan(node: P.Join, conf):
+    """How a keyed inner join runs on the mesh, as Spark plans it with
+    one executor per chip: (mode, chips, why), the mode the join's
+    ``on_chips``. ``"replicated"`` where its build side's estimate is at
+    or under ``broadcastSizeBytes`` (a broadcast copied to every chip,
+    probed where the probe rows lie); over it ``"copartitioned"`` (a
+    shuffled hash join: both sides hash-exchanged on their keys over
+    ICI, one partition a chip), or ``"adaptive"`` under AQE (the join
+    measures its build side first, execs/join.py ``_adapt``). None: the
+    mesh is off, the join is of another kind, or a side has no
+    collective layout. The same answer for explain() and for the
+    conversion."""
+    from spark_rapids_tpu.conf import BROADCAST_SIZE_BYTES
+    from spark_rapids_tpu.parallel.mesh import MESH
+    ndev = MESH.effective_ndev()
+    jt = node.join_type.lower().replace("_", "")
+    if not ndev or ndev < 2 or jt != "inner" or not node.left_keys:
+        return None
+    l_est = node.children[0].estimate_bytes()
+    r_est = node.children[1].estimate_bytes()
+    swapped = l_est is not None and (r_est is None or l_est < r_est)
+    est = l_est if swapped else r_est
+    threshold = conf.get_entry(BROADCAST_SIZE_BYTES)
+    if est is not None and est <= threshold:
+        return ("replicated", ndev,
+                f"build side estimate {est} B <= broadcastSizeBytes "
+                f"{threshold} B: broadcast, replicated to every chip")
+    from spark_rapids_tpu.execs.exchange import ici_demotion_reason
+    for child in node.children:
+        if ici_demotion_reason(conf, "hash", ndev,
+                               child.output_schema()) is not None:
+            return None
+    shown = "unknown" if est is None else f"{est} B"
+    from spark_rapids_tpu.conf import ADAPTIVE_ENABLED
+    if conf.get_entry(ADAPTIVE_ENABLED):
+        return ("adaptive", ndev,
+                f"shuffled hash join: build side estimate {shown} > "
+                f"broadcastSizeBytes {threshold} B; AQE makes it a "
+                f"broadcast if the build side measures at most that")
+    return ("copartitioned", ndev,
+            f"shuffled hash join: build side estimate {shown} > "
+            f"broadcastSizeBytes {threshold} B")
+
+
 def _convert_join(node: P.Join, children, conf):
     from spark_rapids_tpu.execs.join import TpuJoinExec
     from spark_rapids_tpu.ops.cast import Cast
@@ -618,6 +670,10 @@ def _convert_join(node: P.Join, children, conf):
     )
 
     jt = node.join_type.lower().replace("_", "")
+    on_mesh = _mesh_join_plan(node, conf)
+    if on_mesh is not None and on_mesh[0] != "replicated":
+        return _convert_shuffled_join(node, children, conf, lkeys, rkeys,
+                                      on_mesh)
     swapped = jt in ("right", "rightouter")
     if jt == "inner" and lkeys:
         # an inner equi join builds its SMALLER side (Spark's
@@ -661,13 +717,18 @@ def _convert_join(node: P.Join, children, conf):
             return TpuAdaptiveBuildExec(child, threshold)
         return TpuCoalesceExec(child, require_single=True)
 
+    def wrap_probe(child):
+        if on_mesh is not None:
+            # on the mesh each probe batch is joined on the chips where
+            # it lies: a coalesce would gather what it concatenates
+            return child
+        return TpuCoalesceExec(child, target_bytes=target, masked_pass=True)
+
     if swapped:
         left = wrap_build(children[0])
-        right = TpuCoalesceExec(children[1], target_bytes=target,
-                                masked_pass=True)
+        right = wrap_probe(children[1])
     else:
-        left = TpuCoalesceExec(children[0], target_bytes=target,
-                               masked_pass=True)
+        left = wrap_probe(children[0])
         right = wrap_build(children[1])
     from spark_rapids_tpu.conf import JOIN_MAX_SUBPARTITIONS
     join = TpuJoinExec(left, right, node.join_type, lkeys, rkeys,
@@ -677,11 +738,53 @@ def _convert_join(node: P.Join, children, conf):
                        subpartition_bytes=conf.get_entry(JOIN_SUBPARTITION_BYTES),
                        max_subpartitions=conf.get_entry(JOIN_MAX_SUBPARTITIONS),
                        build_left=swapped)
+    if on_mesh is not None:
+        join.on_chips = "replicated"
+        if broadcast:
+            (left if swapped else right).on_chips = True
     from spark_rapids_tpu.conf import DPP_ENABLED
     if broadcast and conf.get_entry(DPP_ENABLED) and not swapped:
         # only inner/leftsemi qualify (checked inside), so the probe is
         # always the LEFT side here
         _maybe_install_dpp(jt, left, right, lkeys, rkeys)
+    return join
+
+
+def _convert_shuffled_join(node: P.Join, children, conf, lkeys, rkeys,
+                           on_mesh):
+    """Spark's shuffled hash join on the mesh: each child under a hash
+    ``TpuShuffleExchangeExec`` on its join keys with a partition a chip,
+    so both sides' rows of a key meet on one chip and each chip joins its
+    own partition (``on_chips`` is ``"copartitioned"``, or
+    ``"adaptive"`` under AQE: _mesh_join_plan)."""
+    from spark_rapids_tpu.conf import (
+        BROADCAST_SIZE_BYTES,
+        JOIN_MAX_SUBPARTITIONS,
+        JOIN_SUBPARTITION_BYTES,
+    )
+    from spark_rapids_tpu.execs.exchange import TpuShuffleExchangeExec
+    from spark_rapids_tpu.execs.join import TpuJoinExec
+    mode, ndev, why = on_mesh
+    l_est = node.children[0].estimate_bytes()
+    r_est = node.children[1].estimate_bytes()
+    swapped = l_est is not None and (r_est is None or l_est < r_est)
+    left, right = (
+        TpuShuffleExchangeExec(child, "hash", ndev, keys, conf,
+                               target_batch_bytes=conf.batch_size_bytes,
+                               reason=why)
+        for child, keys in ((children[0], lkeys), (children[1], rkeys)))
+    left.on_chips = right.on_chips = True
+    join = TpuJoinExec(left, right, node.join_type, lkeys, rkeys,
+                       node.condition,
+                       node.children[0].output_schema(),
+                       node.children[1].output_schema(),
+                       subpartition_bytes=conf.get_entry(
+                           JOIN_SUBPARTITION_BYTES),
+                       max_subpartitions=conf.get_entry(
+                           JOIN_MAX_SUBPARTITIONS),
+                       build_left=swapped)
+    join.on_chips = mode
+    join.broadcast_threshold = conf.get_entry(BROADCAST_SIZE_BYTES)
     return join
 
 

@@ -2,14 +2,15 @@
 all-to-all collective.
 
 Reference mapping (SURVEY.md §2.6): GpuShuffleExchangeExec's UCX fast path
-becomes ``jax.lax.all_to_all`` over the mesh axis — each device bucketizes
-its row shard by Spark-exact murmur3 target, pads buckets to the static
-shard size, and the collective delivers every device its partition. All
-shapes are static (bucket = local shard capacity, the worst case); validity
-masks carry the live counts. The plan-integrated entry point is
-``MeshExchange`` (used by TpuShuffleExchangeExec when
-spark.rapids.shuffle.mode=ICI and the partition count fits the mesh);
-the host-file shuffle covers every other case.
+becomes ``jax.lax.all_to_all`` over the mesh axis. ``chip_exchange`` is the
+one program (``mesh_exchange``) an input batch: on each chip the
+Spark-exact murmur3 target of its rows, and one all-to-all that hands chip
+``d`` the rows of partition ``d`` under a mask. All shapes are static (each
+chip receives every chip's block, the worst case); the masks carry the live
+rows, and the device keeps each chip's count. TpuShuffleExchangeExec runs it
+when spark.rapids.shuffle.mode=ICI or the mesh is on and the partition
+count fits the mesh, and reads the counts once, after its last batch
+(``verified_counts``); the host-file shuffle covers every other case.
 
 String keys hash by their dictionary BYTE matrix (replicated across the
 mesh — O(dict) bytes), so Spark-exact murmur3 applies to strings too.
@@ -18,7 +19,7 @@ mesh — O(dict) bytes), so Spark-exact murmur3 applies to strings too.
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import jax
 from spark_rapids_tpu.dispatch import tpu_jit
@@ -82,10 +83,10 @@ _MESH_CACHE_EPOCH = 0
 
 def clear_mesh_caches() -> int:
     """Drop every mesh-exchange cache that references device state: the
-    interned replicated dictionary matrices ARE device arrays and a
-    MeshExchange instance holds the mesh's Device objects plus a jitted
-    program compiled against them. Both key on device IDS, which
-    survive a device-loss backend reinit unchanged — without this hook
+    interned replicated dictionary matrices ARE device arrays, and the
+    exchange programs are compiled against the mesh's Device objects.
+    Both key on device IDS, which survive a device-loss backend reinit
+    unchanged — without this hook
     a recovered backend would keep serving buffers of the dead one
     (runtime/health.py calls here alongside the exec/kernel/const/scan
     caches) — and the OOM eviction path (runtime/retry.py) frees the
@@ -95,10 +96,13 @@ def clear_mesh_caches() -> int:
     with _DICT_INTERN_LOCK:
         n = len(_DICT_INTERN)
         _DICT_INTERN.clear()
-        n += len(MeshExchange._cache)
-        MeshExchange._cache.clear()
         n += len(_DIGEST_CACHE)
         _DIGEST_CACHE.clear()
+        n += len(_CHIP_EXCHANGES)
+        _CHIP_EXCHANGES.clear()
+        from spark_rapids_tpu.parallel import shards
+        n += len(shards._PROGRAMS)
+        shards._PROGRAMS.clear()
         # reject in-flight builders' late publishes (their device state
         # predates the invalidation)
         _MESH_CACHE_EPOCH += 1
@@ -183,246 +187,175 @@ def interned_dict_bytes(dictionary: np.ndarray, mesh) -> tuple:
         ev.set()
 
 
-def _bucketize(pid, live, ndev: int, cap: int):
-    """Per-row scatter target into a (ndev*cap) padded send buffer:
-    pid*cap + rank-within-bucket; dead rows drop."""
-    spid = jnp.where(live, pid, ndev)
-    order = jnp.argsort(spid, stable=True)
-    sorted_pid = spid[order]
-    idx = jnp.arange(cap, dtype=jnp.int32)
-    is_first = jnp.concatenate([jnp.ones(1, jnp.bool_),
-                                sorted_pid[1:] != sorted_pid[:-1]])
-    run_start = jnp.where(is_first, idx, 0)
-    run_start = jax.lax.associative_scan(jnp.maximum, run_start)
-    slot_sorted = idx - run_start
-    slot = jnp.zeros(cap, jnp.int32).at[order].set(slot_sorted)
-    return jnp.where(live, pid * cap + slot, ndev * cap)
+def verified_counts(counts_dev) -> np.ndarray:
+    """The live-row counts an ICI exchange's programs kept on the device
+    (one per partition and batch), read to the host ONCE, after the
+    exchange's last batch: they are its AQE map-output statistics.
+    Fetched CHECKSUMMED (TPAK-v2 pattern): a device-side uint32 word-sum
+    digest rides the same fetch, the host recomputes it over the fetched
+    bytes, and a mismatch (a corrupted wire fetch; the
+    ``mesh.ici.exchange`` corrupt kind injects exactly this) refetches
+    the intact device value, bounded by spark.rapids.mesh.maxShardRetries
+    and counted in gatherChecksFailed/shardRetries, instead of feeding
+    the statistics garbage counts. ``counts_dev``: the counts arrays, in
+    order; returns them concatenated as int32."""
+    from spark_rapids_tpu.errors import MeshGatherError
+    from spark_rapids_tpu.parallel import mesh as PM
+    from spark_rapids_tpu.parallel.mesh import mesh_gather, wordsum_u32
+    from spark_rapids_tpu.runtime.faults import fault_point
+
+    counts_i32 = jnp.concatenate(
+        [c.astype(jnp.int32).reshape(-1) for c in counts_dev])
+    if not PM.GATHER_VERIFY:
+        return mesh_gather(counts_i32).astype(np.int32)
+    digest = jax.lax.bitcast_convert_type(
+        wordsum_u32(counts_i32), jnp.int32).reshape(1)
+    packed = jnp.concatenate([counts_i32, digest])
+    retries = 0
+    while True:
+        # count the COUNTS as gathered elements, not the digest word
+        # riding along (meshGatherRows stays comparable with
+        # pre-verification artifact rounds)
+        arr = mesh_gather(packed,
+                          rows=int(packed.shape[0]) - 1).astype(np.int32)
+        raw = fault_point("mesh.ici.exchange", data=arr.tobytes())
+        arr = np.frombuffer(raw, dtype=np.int32)
+        counts, got = arr[:-1], arr[-1:].view(np.uint32)[0]
+        want = np.uint32(
+            counts.view(np.uint32).sum(dtype=np.uint64) & 0xFFFFFFFF)
+        if got == want:
+            return counts
+        MESH_SCOPE.add("gatherChecksFailed", 1)
+        if retries >= PM.MAX_SHARD_RETRIES:
+            raise MeshGatherError(
+                f"ICI exchange live-count fetch failed its checksum "
+                f"{retries + 1} times (device digest {int(got)} vs "
+                f"recomputed {int(want)})")
+        retries += 1
+        MESH_SCOPE.add("shardRetries", 1)
 
 
-class MeshExchange:
-    """Plan-integrated all-to-all exchange over a device mesh.
+_CHIP_EXCHANGES: Dict[tuple, object] = {}
 
-    One instance is built per (mesh, column dtypes, key layout) — the
-    jitted shard_map program is cached on the instance. ``run`` takes the
-    coalesced input table's column arrays plus the live-row mask and
-    returns, per partition, front-compacted output arrays + live counts.
-    """
 
-    _cache: Dict[tuple, "MeshExchange"] = {}
+def chip_exchange(chips, datas, valids, key_datas, key_valids, key_dtypes,
+                  live, nrows, string_bytes: Optional[Dict[int, tuple]] = None):
+    """Hash-partition the rows of a table that lies on ``chips`` (see
+    parallel/shards.py) over those chips, in ONE program, ``mesh_exchange``:
+    on each chip the Spark-exact murmur3 partition of every row, then one
+    ``all_to_all`` over the row axes that hands chip ``d`` every column
+    of every chip's rows under the mask of the live rows whose partition
+    is ``d``. The sender keeps its rows where they lie, so nothing is
+    scattered or compacted on either side: a received block is the
+    sender's rows in their order, which keeps a key-ordered table's
+    lookups clustered (execs/join.py ``_direct_lookup``). What crosses is
+    padded to the worst case (every row of a chip to one partition):
+    ``chips.n`` blocks of ``chips.rows`` rows a chip.
 
-    @classmethod
-    def get(cls, mesh, col_dtypes: Tuple[str, ...], key_cols: Tuple[int, ...],
-            key_dtypes, string_key_shapes: tuple, cap: int,
-            axis_name: str = "data"):
-        dev_ids = tuple(d.id for d in mesh.devices.flat)
-        key = (dev_ids, col_dtypes, key_cols, tuple(map(str, key_dtypes)),
-               string_key_shapes, cap, axis_name)
+    ``datas``/``valids``/``key_*``: the columns' and the key columns'
+    global arrays, row-sharded over ``chips``; ``live``: the table's
+    mask (same layout) or None for a landed table of ``nrows`` rows (a
+    scalar every chip holds). Returns (datas, valids, live, counts): the
+    received columns and their mask, ``chips.n * chips.rows`` rows a
+    chip, and each chip's live rows received (int32[chips.n], chip
+    order; the device keeps them)."""
+    string_bytes = string_bytes or {}
+    has_sbytes = tuple(i in string_bytes for i in range(len(key_datas)))
+    key = (chips.token(), tuple((str(a.dtype), a.shape[1:]) for a in datas),
+           tuple(map(str, key_dtypes)), has_sbytes,
+           tuple(string_bytes[i][0].shape for i in sorted(string_bytes)),
+           live is None)
+    with _DICT_INTERN_LOCK:
+        fn = _CHIP_EXCHANGES.get(key)
+        epoch = _MESH_CACHE_EPOCH
+    if fn is None:
+        fn = tpu_jit(chip_exchange_program(chips, key_dtypes, has_sbytes,
+                                           live is None),
+                     name="mesh_exchange")
         with _DICT_INTERN_LOCK:
-            inst = cls._cache.get(key)
-            epoch = _MESH_CACHE_EPOCH
-        if inst is None:
-            inst = cls(mesh, key_dtypes, axis_name)
-            with _DICT_INTERN_LOCK:
-                if epoch == _MESH_CACHE_EPOCH:
-                    cls._cache[key] = inst
-                # else: clear_mesh_caches ran mid-build (device-loss
-                # reinit) — the instance holds the dead backend's mesh;
-                # serve it to this caller only, never publish
-        return inst
+            if epoch == _MESH_CACHE_EPOCH:
+                fn = _CHIP_EXCHANGES.setdefault(key, fn)
+    sbytes = tuple(string_bytes[i] for i in sorted(string_bytes))
+    from spark_rapids_tpu.runtime.faults import fault_point
+    fault_point("mesh.ici.exchange")
+    from spark_rapids_tpu.runtime.cluster import dcn_exchange_point
+    dcn_exchange_point(chips.mesh)
+    return fn(tuple(datas), tuple(valids), tuple(key_datas),
+              tuple(key_valids), live, nrows, sbytes)
 
-    def __init__(self, mesh, key_dtypes, axis_name="data"):
-        self.mesh = mesh
-        #: a single axis name, or a tuple of names for the hierarchical
-        #: (dcn, ici) mesh — the all-to-all then rides the fast inner
-        #: axis within each dcn group (one collective, two mesh dims)
-        self.axis_name = axis_name
-        self.ndev = _axis_size(mesh, axis_name)
-        self.key_dtypes = list(key_dtypes)
-        self._fn = None
 
-    def _build(self, ncols: int, nkeys: int, has_sbytes: Tuple[bool, ...]):
-        from jax.sharding import PartitionSpec as P_
+def chip_exchange_program(chips, key_dtypes, has_sbytes, landed: bool):
+    """The per-chip body of :func:`chip_exchange` under ``shard_map`` over
+    ``chips``, not yet compiled: (datas, valids, key datas, key valids,
+    live, nrows, string key bytes) -> (datas, valids, live, counts)."""
+    from jax.sharding import PartitionSpec as P_
 
-        ndev = self.ndev
-        axis = self.axis_name
-        key_dts = self.key_dtypes
+    from spark_rapids_tpu.parallel.shards import chip_live, on_chips
 
-        def shard_fn(*flat):
-            pos = 0
-            datas = flat[pos:pos + ncols]; pos += ncols
-            valids = flat[pos:pos + ncols]; pos += ncols
-            kdatas = flat[pos:pos + nkeys]; pos += nkeys
-            kvalids = flat[pos:pos + nkeys]; pos += nkeys
-            live = flat[pos]; pos += 1
-            sbytes = {}
-            for i, has in enumerate(has_sbytes):
-                if has:
-                    sbytes[i] = (flat[pos], flat[pos + 1])
-                    pos += 2
-            cap = datas[0].shape[0] if datas else kdatas[0].shape[0]
+    n, rows, axes = chips.n, chips.rows, chips.axes
+    key_dts = list(key_dtypes)
 
-            keys = [(kdatas[i], kvalids[i], key_dts[i]) for i in range(nkeys)]
-            h = murmur3_hash_device(keys, SPARK_SEED, sbytes)
-            pid = h % jnp.int32(ndev)
-            pid = jnp.where(pid < 0, pid + ndev, pid)
-            tgt = _bucketize(pid, live, ndev, cap)
+    def on_chip(datas, valids, kdatas, kvalids, live, nrows, sbytes):
+        live = chip_live(live, nrows, rows, axes)
+        keys = [(d, v, dt) for d, v, dt in zip(kdatas, kvalids, key_dts)]
+        by_key = {i: sb for i, sb in zip(
+            [i for i, has in enumerate(has_sbytes) if has], sbytes)}
+        h = murmur3_hash_device(keys, SPARK_SEED, by_key)
+        pid = h % jnp.int32(n)
+        pid = jnp.where(pid < 0, pid + n, pid)
+        parts = jnp.arange(n, dtype=pid.dtype)[:, None]
+        send_live = live[None, :] & (pid[None, :] == parts)
 
-            def exchange(arr):
-                """Scatter into the (ndev, cap) send buffer and run the
-                all-to-all — trailing dims (the decimal128 two-limb
-                layout) ride along, indexed on the row axis only."""
-                tail = arr.shape[1:]
-                send = jnp.zeros((ndev * cap,) + tail, arr.dtype).at[
-                    tgt].set(arr, mode="drop").reshape((ndev, cap) + tail)
-                return jax.lax.all_to_all(send, axis, 0, 0).reshape(
-                    (ndev * cap,) + tail)
+        def to_all(a):
+            """Every destination gets this chip's rows: a block of
+            ``rows`` rows from each chip comes back, in chip order.
+            A mask crosses as bytes."""
+            flag = a.dtype == jnp.bool_
+            send = jnp.broadcast_to(
+                (a.astype(jnp.uint8) if flag else a)[None],
+                (n,) + a.shape)
+            out = jax.lax.all_to_all(send, axes, 0, 0).reshape(
+                (n * rows,) + a.shape[1:])
+            return out.astype(jnp.bool_) if flag else out
 
-            recv_live = jnp.zeros((ndev * cap,), jnp.bool_).at[tgt].set(
-                True, mode="drop").reshape(ndev, cap)
-            recv_live = jax.lax.all_to_all(recv_live, axis, 0, 0)
+        recv_live = jax.lax.all_to_all(
+            send_live.astype(jnp.uint8), axes, 0, 0).reshape(
+                n * rows).astype(jnp.bool_)
+        count = jnp.sum(recv_live.astype(jnp.int32))[None]
+        return ([to_all(d) for d in datas], [to_all(v) for v in valids],
+                recv_live, count)
 
-            out_datas, out_valids = [], []
-            for d, v in zip(datas, valids):
-                out_datas.append(exchange(d))
-                out_valids.append(exchange(v))
-
-            # per-shard compaction: received blocks are front-compacted per
-            # source device but gapped between blocks; one scatter compacts
-            # the whole shard and counts the live rows
-            flat_live = recv_live.reshape(ndev * cap)
-            cpos = jnp.cumsum(flat_live.astype(jnp.int32)) - 1
-            ctgt = jnp.where(flat_live, cpos, ndev * cap)
-            n_live = jnp.sum(flat_live.astype(jnp.int32))
-            comp_d, comp_v = [], []
-            for d, v in zip(out_datas, out_valids):
-                comp_d.append(jnp.zeros_like(d).at[ctgt].set(d, mode="drop"))
-                comp_v.append(jnp.zeros_like(v).at[ctgt].set(v, mode="drop"))
-            return tuple(comp_d) + tuple(comp_v) + (n_live[None],)
-
-        n_row_args = 2 * ncols + 2 * nkeys + 1
-        in_specs = [P_(axis)] * n_row_args
-        for has in has_sbytes:
-            if has:
-                in_specs += [P_(), P_()]  # replicated dictionary bytes
-        out_specs = [P_(axis)] * (2 * ncols) + [P_(axis)]
-        return tpu_jit(jax.shard_map(shard_fn, mesh=self.mesh,
-                                     in_specs=tuple(in_specs),
-                                     out_specs=tuple(out_specs)),
-                       name="ici_exchange")
-
-    def run(self, datas, valids, key_datas, key_valids, live,
-            string_bytes: Optional[Dict[int, tuple]] = None):
-        """All arrays are GLOBAL row arrays (length divisible by the mesh
-        size). Returns (out_datas, out_valids, counts) where each output is
-        global with per-device shards front-compacted and ``counts`` holds
-        one live count per partition."""
-        from jax.sharding import NamedSharding, PartitionSpec as P_
-
-        from spark_rapids_tpu.parallel.mesh import shard_put
-
-        string_bytes = string_bytes or {}
-        has_sbytes = tuple(i in string_bytes for i in range(len(key_datas)))
-        if self._fn is None:
-            self._fn = self._build(len(datas), len(key_datas), has_sbytes)
-        sharding = NamedSharding(self.mesh, P_(self.axis_name))
-        rep = NamedSharding(self.mesh, P_())
-        # shard_put counts host uploads: on a warm mesh query every
-        # input is already device-resident (scans landed sharded, the
-        # previous exchange's outputs never left the device), so the
-        # puts below are device-side reshards only
-        flat = [shard_put(x, sharding)
-                for x in (*datas, *valids, *key_datas, *key_valids, live)]
-        for i, has in enumerate(has_sbytes):
-            if has:
-                mat, lens = string_bytes[i]
-                flat.append(shard_put(mat, rep))
-                flat.append(shard_put(lens, rep))
-        # the collective's fault site: crash exercises the replay path,
-        # device_lost the partial-loss degradation ladder; corrupt is
-        # consumed by the verified counts fetch below (it needs bytes)
-        from spark_rapids_tpu.runtime.faults import fault_point
-        fault_point("mesh.ici.exchange")
-        # cross-HOST marker: when this exchange's mesh spans more than
-        # one cluster host group the all-to-all crosses the DCN axis —
-        # the host.dcn.exchange fault point fires there (device_lost
-        # raises HostLostError into the host ladder) and dcnExchanges
-        # counts (runtime/cluster.py; no-op without an active cluster)
-        from spark_rapids_tpu.runtime.cluster import dcn_exchange_point
-        dcn_exchange_point(self.mesh)
-        out = self._fn(*flat)
-        ncols = len(datas)
-        return (list(out[:ncols]), list(out[ncols:2 * ncols]),
-                self._verified_counts(out[2 * ncols]))
-
-    def _verified_counts(self, counts_dev):
-        """The ONE host materialization an ICI exchange pays — the
-        per-partition live counts (they double as the AQE map-output
-        statistic) — fetched CHECKSUMMED (TPAK-v2 pattern): a device-
-        side uint32 word-sum digest rides the same fetch, the host
-        recomputes it over the fetched bytes, and a mismatch (a
-        corrupted wire fetch; the ``mesh.ici.exchange`` corrupt kind
-        injects exactly this) refetches the intact device value —
-        bounded by spark.rapids.mesh.maxShardRetries, counted in
-        gatherChecksFailed/shardRetries — instead of feeding AQE and
-        the batch slicer garbage counts."""
-        import jax
-
-        from spark_rapids_tpu.errors import MeshGatherError
-        from spark_rapids_tpu.parallel import mesh as PM
-        from spark_rapids_tpu.parallel.mesh import mesh_gather, wordsum_u32
-        from spark_rapids_tpu.runtime.faults import fault_point
-
-        if not PM.GATHER_VERIFY:
-            return mesh_gather(counts_dev)
-        counts_i32 = counts_dev.astype(jnp.int32).reshape(-1)
-        digest = jax.lax.bitcast_convert_type(
-            wordsum_u32(counts_i32), jnp.int32).reshape(1)
-        packed = jnp.concatenate([counts_i32, digest])
-        retries = 0
-        while True:
-            # count the COUNTS as gathered elements, not the digest
-            # word riding along (meshGatherRows stays comparable with
-            # pre-verification artifact rounds)
-            arr = mesh_gather(packed,
-                              rows=int(packed.shape[0]) - 1).astype(np.int32)
-            raw = fault_point("mesh.ici.exchange", data=arr.tobytes())
-            arr = np.frombuffer(raw, dtype=np.int32)
-            counts, got = arr[:-1], arr[-1:].view(np.uint32)[0]
-            want = np.uint32(
-                counts.view(np.uint32).sum(dtype=np.uint64) & 0xFFFFFFFF)
-            if got == want:
-                return counts
-            MESH_SCOPE.add("gatherChecksFailed", 1)
-            if retries >= PM.MAX_SHARD_RETRIES:
-                raise MeshGatherError(
-                    f"ICI exchange live-count fetch failed its checksum "
-                    f"{retries + 1} times (device digest {int(got)} vs "
-                    f"recomputed {int(want)})")
-            retries += 1
-            MESH_SCOPE.add("shardRetries", 1)
+    by_row = chips.by_row
+    return on_chips(
+        on_chip, chips,
+        in_specs=(by_row, by_row, by_row, by_row,
+                  None if landed else by_row, P_(), P_()),
+        out_specs=(by_row, by_row, by_row, by_row))
 
 
 def mesh_hash_exchange(mesh, dtypes: Sequence[T.DataType],
                        key_idx: Sequence[int], axis_name: str = "data"):
-    """Back-compat wrapper over MeshExchange for non-string columns where
-    the hash keys are table columns (older tests / dryrun helper)."""
+    """:func:`chip_exchange` over bare arrays whose hash keys are some of
+    the columns (no string keys): ``run(datas, valids)`` takes global
+    arrays of a length the mesh's devices divide and returns the received
+    (datas, valids, live), chip ``d``'s block holding partition ``d``."""
+    from spark_rapids_tpu.parallel.mesh import shard_put
+    from spark_rapids_tpu.parallel.shards import Chips
     dts = list(dtypes)
     kset = list(key_idx)
+    ndev = _axis_size(mesh, axis_name)
 
     def run(datas: List[jax.Array], valids: List[jax.Array]):
-        ex = MeshExchange(mesh, [dts[i] for i in kset], axis_name)
-        live = jnp.ones(datas[0].shape[0], jnp.bool_)
-        out_d, out_v, counts = ex.run(
-            datas, valids, [datas[i] for i in kset],
-            [valids[i] for i in kset], live)
-        ndev = mesh.shape[axis_name]
-        cap = datas[0].shape[0] // ndev
-        out_live = []
-        shard = ndev * cap
-        liv = np.zeros(ndev * shard, dtype=bool)
-        for d in range(ndev):
-            liv[d * shard:d * shard + int(counts[d])] = True
-        return out_d, out_v, jnp.asarray(liv)
+        chips = Chips(mesh, axis_name, ndev, datas[0].shape[0] // ndev)
+        where = chips.row_sharding()
+        datas = [shard_put(d, where) for d in datas]
+        valids = [shard_put(v, where) for v in valids]
+        nrows = jax.device_put(jnp.asarray(datas[0].shape[0], jnp.int64),
+                               chips.everywhere())
+        out_d, out_v, live, _counts = chip_exchange(
+            chips, datas, valids, [datas[i] for i in kset],
+            [valids[i] for i in kset], [dts[i] for i in kset], None, nrows)
+        return out_d, out_v, live
 
     return run
 

@@ -156,6 +156,24 @@ register_metric("meshAggBatches", "count", "MODERATE",
 register_metric("meshAggShards", "count", "MODERATE",
                 "row shards those batches held (meshAggBatches x the "
                 "mesh's devices): the partial tables exchanged")
+register_metric("meshExchanges", "count", "ESSENTIAL",
+                "input batches a shuffled join's ICI exchange moved to "
+                "their partitions' chips, one mesh_exchange program each "
+                "(execs/exchange.py TpuShuffleExchangeExec.exchanged)")
+register_metric("meshExchangeRows", "count", "ESSENTIAL",
+                "live rows those exchanges sent (iciBytes counts the "
+                "padded blocks the all-to-all carries)")
+register_metric("meshExchangeBytes", "bytes", "ESSENTIAL",
+                "live bytes those exchanges sent: live rows x the row's "
+                "column words and validity")
+register_metric("meshChipJoins", "count", "MODERATE",
+                "probe batches a join ran on the chips where they lay "
+                "(execs/join.py: a replicated build against row-sharded "
+                "probes, or a co-partitioned build and probe)")
+register_metric("meshChipAggBatches", "count", "MODERATE",
+                "batches a grouped aggregate took on the chips where "
+                "they lay: its keys hold the partitioning keys, so no "
+                "group spans two chips (execs/aggregate.py)")
 
 MESH_SCOPE = metric_scope("mesh")
 

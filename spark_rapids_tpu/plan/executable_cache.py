@@ -110,6 +110,8 @@ def _reset_for_reuse(executable) -> None:
             m.clear()
         if getattr(e, "_obs_pending_rows", None):
             e._obs_pending_rows = []
+        if getattr(e, "_obs_pending_metrics", None):
+            e._obs_pending_metrics = []
 
 
 #: converted trees retained per (template, literal variant): exec

@@ -141,9 +141,10 @@ FAULT_POINTS: Dict[str, tuple] = {
         "passes through here, before the transfer"),
     "mesh.ici.exchange": (
         "spark_rapids_tpu/parallel/exchange.py",
-        "the ICI all-to-all: a data-less site before the collective "
+        "the ICI all-to-all: a data-less site before each collective "
         "dispatch (crash/device_lost/slow) plus the checksummed "
-        "per-partition live-count fetch (corrupt flips the fetched "
+        "per-partition live-count fetch after the exchange's last "
+        "batch (corrupt flips the fetched "
         "bytes; the TPAK-v2 digest riding the same fetch catches the "
         "damage and the intact device value is refetched)"),
     "mesh.gather": (

@@ -40,9 +40,20 @@ def is_device_oom(exc: BaseException) -> bool:
         return True
     name = type(exc).__name__
     msg = str(exc)
-    return ("XlaRuntimeError" in name and
-            ("RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg
-             or "out of memory" in msg))
+    if name in _DEVICE_ERROR_TYPES:
+        # the runtime's own error: XlaRuntimeError on older JAX, renamed
+        # JaxRuntimeError (an enqueue that finds the device full raises
+        # it as RESOURCE_EXHAUSTED)
+        return ("RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg
+                or "out of memory" in msg)
+    # a program that does not fit where it is loaded: the runtime raises
+    # ValueError("RESOURCE_EXHAUSTED: Error loading program ...")
+    return isinstance(exc, ValueError) and msg.startswith(
+        "RESOURCE_EXHAUSTED") and "Error loading program" in msg
+
+
+#: the type names the device runtime raises its errors under
+_DEVICE_ERROR_TYPES = ("XlaRuntimeError", "JaxRuntimeError")
 
 
 class RetryStateMachine:

@@ -1010,6 +1010,11 @@ class QueryService:
             # cached serve; meshShape reflects the mesh now active
             "meshShape": _mesh_shape(),
             "iciBytes": 0,
+            # v17: nothing exchanged or re-landed either
+            "meshExchanges": 0,
+            "meshExchangeRows": 0,
+            "meshExchangeBytes": 0,
+            "meshRelandRows": 0,
             "shardSkew": 0.0,
             # v7 mesh fault-domain fields: a cached serve gathers
             # nothing, so it can neither retry nor trip a checksum

@@ -166,6 +166,28 @@ def test_split_segment_sum_unit():
     np.testing.assert_allclose(got, ref, rtol=1e-7, atol=1e-4)
 
 
+@pytest.mark.parametrize("cap", [1 << 12, 1 << 16, 1 << 17])
+def test_split_segment_sum_a_segment_a_row_keeps_the_pairs_precision(cap):
+    """The sorted-path aggregate's segments are its groups, a few rows
+    each (num_segments == capacity): the split keeps a group's sum to
+    the pair's precision at every capacity, where per-block f32
+    partials (taken at 2^16 rows and fewer) rounded it once in f32."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.segsum import segment_sum_f64
+
+    rng = np.random.default_rng(42)
+    # Q3's shape: up to 7 products of a price and (1 - discount) a group
+    price = np.round(rng.uniform(900, 105000, cap), 2)
+    vals = price * (1 - np.round(rng.uniform(0, 0.1, cap), 2))
+    gid = np.minimum(np.arange(cap) // 7, cap - 1).astype(np.int32)
+    got = np.asarray(segment_sum_f64(
+        jnp.asarray(vals), jnp.asarray(gid), cap, cap, use_split=True))
+    ref = np.zeros(cap)
+    np.add.at(ref, gid, vals)
+    live = ref != 0
+    assert np.max(np.abs(got[live] - ref[live]) / np.abs(ref[live])) < 1e-12
+
+
 def test_sorted_path_nulls_in_keys(session, cpu_session):
     gens = {"s": StringGen(cardinality=4), "b": BooleanGen(),
             "v": IntGen(min_val=-50, max_val=50, null_prob=0.3)}

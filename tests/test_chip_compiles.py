@@ -288,3 +288,83 @@ def test_assemble_splits_doubles_from_their_words(one_chip, mesh4, on_mesh):
     # the columns (a DOUBLE is its f32 pair) and the row masks
     assert memory.output_size_in_bytes >= rows * (4 * 8 + 3 * 4)
     assert memory.temp_size_in_bytes < rows * 64, memory.temp_size_in_bytes
+
+
+def test_mesh_exchange_of_a_lineitem_batch_fits_each_chip(mesh4):
+    """`mesh_exchange` over one of `q3-mesh4`'s lineitem batches (2^21
+    rows a chip of l_orderkey, l_extendedprice, l_discount under the
+    filter's mask): one all-to-all a column word, each chip receiving
+    every chip's rows (4 x 2^21 a chip), a DOUBLE crossing as its two
+    f32 halves."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.parallel.exchange import chip_exchange_program
+    from spark_rapids_tpu.parallel.shards import Chips
+    mesh, by_row, same = mesh4
+    rows = 1 << 21
+    n = 4 * rows
+    chips = Chips(mesh, by_row.spec[0], 4, rows)
+    datas = (_shape(by_row, (n,), jnp.int64),
+             _shape(by_row, (n,), jnp.float64),
+             _shape(by_row, (n,), jnp.float64))
+    valids = tuple(_shape(by_row, (n,), jnp.bool_) for _ in datas)
+    compiled = _compile(
+        chip_exchange_program(chips, [T.LONG], (False,), False),
+        datas, valids, datas[:1], valids[:1],
+        _shape(by_row, (n,), jnp.bool_), _shape(same, (), jnp.int32), ())
+    text = compiled.as_text()
+    assert "all-to-all" in text and "X64Combine" in text
+    memory = compiled.memory_analysis()
+    # a chip's received block: 4 x 2^21 rows x (3 x 8 + 4) bytes
+    assert memory.output_size_in_bytes < 0.25 * GB
+    assert memory.temp_size_in_bytes < 0.25 * GB
+
+
+def test_chip_join_probe_and_build_fit_each_chip(mesh4):
+    """The lineitem join of `q3-mesh4` on each chip: the direct table of
+    the chip's part of customer ⋈ orders (2^20 received rows into 2^25
+    slots) and the probe of a received lineitem batch (2^23 slots a
+    chip, looked up by windows)."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from spark_rapids_tpu.execs.join import WINDOW_SLOTS, _DirectJoinKernel
+    from spark_rapids_tpu.parallel.shards import chip_live, on_chips
+    mesh, by_row, same = mesh4
+    axes = by_row.spec[0]
+    slots, build_rows, probe_rows = 1 << 25, 1 << 20, 1 << 23
+    build = _DirectJoinKernel._build_body(slots)
+    probe = _DirectJoinKernel._probe_body("inner", slots)
+    from spark_rapids_tpu.parallel.shards import Chips
+
+    def build_on_chip(rd, rv, live, nrows, keymin):
+        rowid, unique = build(rd, rv, chip_live(live, nrows, build_rows,
+                                                axes), keymin[0])
+        return rowid, unique[None]
+
+    def probe_on_chip(ld, lv, live, nrows, rowid, keymin):
+        ri, _m, keep, nout, idx, clustered = probe(
+            ld, lv, chip_live(live, nrows, probe_rows, axes), rowid,
+            keymin[0])
+        return ri, keep, nout[None], idx, clustered[None]
+
+    row, rep = P(axes), P()
+    chips = Chips(mesh, axes, 4, build_rows)
+    built = _compile(
+        on_chips(build_on_chip, chips, (row, row, row, rep, row),
+                 (row, row)),
+        _shape(by_row, (4 * build_rows,), jnp.int64),
+        _shape(by_row, (4 * build_rows,), jnp.bool_),
+        _shape(by_row, (4 * build_rows,), jnp.bool_),
+        _shape(same, (), jnp.int32), _shape(by_row, (4,), jnp.int64))
+    assert built.memory_analysis().output_size_in_bytes < 0.2 * GB
+    probed = _compile(
+        on_chips(probe_on_chip, chips._replace(rows=probe_rows),
+                 (row, row, row, rep, row, row), (row,) * 5),
+        _shape(by_row, (4 * probe_rows,), jnp.int64),
+        _shape(by_row, (4 * probe_rows,), jnp.bool_),
+        _shape(by_row, (4 * probe_rows,), jnp.bool_),
+        _shape(same, (), jnp.int32),
+        _shape(by_row, (4 * slots // WINDOW_SLOTS, WINDOW_SLOTS),
+               jnp.int32),
+        _shape(by_row, (4,), jnp.int64))
+    assert probed.memory_analysis().temp_size_in_bytes < 1 * GB

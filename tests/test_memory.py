@@ -333,7 +333,7 @@ def test_memory_ladder_end_to_end_cpu_demotion(tmp_path):
                for b in mem_bundles)
     # event record carries the demotion map (explain() convention)
     rec = s.last_event_record
-    assert rec["schema"] == 16
+    assert rec["schema"] == 17
     assert any(op in rec["demotions"] for op in demoted)
     assert rec["oomRetries"] > 0
 
@@ -559,7 +559,7 @@ def test_event_log_v10_memory_fields(tmp_path):
     }))
     _join_q(s, left, right)
     rec = s.last_event_record
-    assert rec["schema"] == 16
+    assert rec["schema"] == 17
     assert rec["spillBytes"] > 0
     assert rec["unspills"] > 0
     assert rec["budgetPeak"] > 0

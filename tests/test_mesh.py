@@ -400,7 +400,7 @@ def test_backend_reinit_rebuilds_mesh():
 
 def test_clear_mesh_caches_drops_interned_device_state():
     """The mesh-exchange caches (interned replicated dictionary
-    matrices, MeshExchange instances with their jitted programs) key on
+    matrices, the exchange programs compiled against the mesh) key on
     device IDS, which survive a device-loss backend reinit unchanged —
     so device-loss recovery (runtime/health.py) and the OOM eviction
     path (runtime/retry.py) clear them through clear_mesh_caches like
@@ -417,7 +417,7 @@ def test_clear_mesh_caches_drops_interned_device_state():
     assert EX.clear_mesh_caches() >= 1
     with EX._DICT_INTERN_LOCK:
         assert not EX._DICT_INTERN
-    assert not EX.MeshExchange._cache
+    assert not EX._CHIP_EXCHANGES
 
 
 def test_dict_intern_single_upload_under_concurrency(monkeypatch):

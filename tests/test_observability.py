@@ -486,7 +486,10 @@ def test_event_log_written_and_valid(tmp_path):
     lines = open(s.last_event_path).read().strip().splitlines()
     assert len(lines) == 1
     rec = json.loads(lines[0])
-    # schema v16: phasesS gains gcS (the query thread's seconds in
+    # schema v17: phasesS gains exchangeS (a shuffled join's ICI
+    # exchanges on the mesh) and the record meshExchanges,
+    # meshExchangeRows, meshExchangeBytes and meshRelandRows (all 0 off
+    # the mesh); v16: phasesS gains gcS (the query thread's seconds in
     # Python's collector); v15: joinS (the join execs' build and probe
     # batches); v14: relandS (mesh re-lands); v13: coalesceS
     # (the coalesce exec's multi-batch flushes); v12: the tracing PR added hostSyncs and the
@@ -501,7 +504,9 @@ def test_event_log_written_and_valid(tmp_path):
     # fault-domain fields, v6's mesh-native fields, v5's
     # transactional-write fields and v4's survivability fields — see
     # obs/events.py
-    assert rec["schema"] == 16
+    assert rec["schema"] == 17
+    assert rec["meshExchanges"] == 0 and rec["meshExchangeRows"] == 0
+    assert rec["meshExchangeBytes"] == 0 and rec["meshRelandRows"] == 0
     assert rec["healthState"] == "HEALTHY"
     assert rec["quarantined"] is False
     assert rec["deviceReinits"] == 0 and rec["workerRestarts"] == 0
@@ -621,7 +626,14 @@ def test_event_log_golden_schema(tmp_path):
     a join);
     v16 = phasesS gains gcS (host seconds the query's thread spent in
     Python's collector, each collection also the range srt.gc.gen<N>;
-    0.0 for a query no collection interrupted).
+    0.0 for a query no collection interrupted);
+    v17 = phasesS gains exchangeS (host seconds inside a shuffled join's
+    ICI exchanges on the mesh, the range srt.mesh.exchange; 0.0 for a
+    query without one) and the record meshExchanges, meshExchangeRows,
+    meshExchangeBytes (input batches those exchanges moved, the live
+    rows and bytes they sent) and meshRelandRows (row slots gathered
+    from the chips to one device), sums of the plan tree's exec
+    metrics, all 0 off the mesh.
     Exec metrics in the plan tree are no schema fields (no bump): every
     TpuHashAggregateExec node carries partialCountReads (partials whose
     row count the streaming loop read to shrink them) and runAheadWaits
@@ -704,7 +716,7 @@ def test_record_counts_the_grouped_aggregates(tmp_path, monkeypatch, cap,
 
 _NEW_PHASES = ("parseS", "dispatchS", "syncWaitS", "fetchWaitS",
                "fetchUnpackS", "semaphoreWaitS", "coalesceS", "relandS",
-               "joinS", "gcS")
+               "joinS", "gcS", "exchangeS")
 
 
 def _check_phases(rec):

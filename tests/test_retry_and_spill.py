@@ -15,6 +15,7 @@ from spark_rapids_tpu.columnar import DeviceTable, HostTable
 from spark_rapids_tpu.errors import FatalDeviceOOM, RetryOOM
 from spark_rapids_tpu.runtime.retry import (
     RMM_TPU,
+    is_device_oom,
     retry_block,
     split_device_table_in_half,
     with_retry,
@@ -369,3 +370,49 @@ def test_semaphore_timeout():
     t.join()
     assert err
     sem.release_if_held()
+
+
+def _runtime_error(type_name: str, message: str) -> Exception:
+    """An exception whose type carries the device runtime's name, as
+    jaxlib raises it (its class lives in the extension module)."""
+    return type(type_name, (RuntimeError,), {})(message)
+
+
+@pytest.mark.parametrize("make, oom", [
+    (lambda: _runtime_error(
+        "JaxRuntimeError",
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting "
+        "to allocate 1.20G. That was not possible."), True),
+    (lambda: _runtime_error(
+        "XlaRuntimeError", "RESOURCE_EXHAUSTED: Out of memory while "
+        "trying to allocate 1073741824 bytes."), True),
+    (lambda: ValueError(
+        "RESOURCE_EXHAUSTED: Error loading program 'jit_mesh_exchange': "
+        "Attempting to reserve 2.1G at the bottom of memory."), True),
+    (lambda: _runtime_error("JaxRuntimeError", "INTERNAL: core halted"),
+     False),
+    (lambda: ValueError("RESOURCE_EXHAUSTED is a word here"), False),
+], ids=["jax-runtime-error", "xla-runtime-error", "program-load",
+        "other-runtime-error", "other-value-error"])
+def test_device_oom_is_recognised_in_each_form_the_runtime_raises(
+        catalog, make, oom):
+    """A device OOM surfaces at the enqueue as JaxRuntimeError on this
+    JAX (XlaRuntimeError before it was renamed), or as a ValueError
+    where a program cannot be loaded: each is replayed after a spill,
+    and nothing else is."""
+    assert is_device_oom(make()) is oom
+    calls = []
+
+    def fails_once():
+        calls.append(1)
+        if len(calls) == 1:
+            raise make()
+        return "done"
+
+    if oom:
+        assert retry_block(fails_once, catalog=catalog) == "done"
+        assert len(calls) == 2
+    else:
+        with pytest.raises(Exception):
+            retry_block(fails_once, catalog=catalog)
+        assert len(calls) == 1

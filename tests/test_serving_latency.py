@@ -335,7 +335,7 @@ def test_event_log_carries_compile_fields(tmp_path):
     cold = s.last_event_record
     q.collect_table()
     warm = s.last_event_record
-    assert cold["schema"] == 16
+    assert cold["schema"] == 17
     assert cold["executableCacheHit"] is False
     assert warm["executableCacheHit"] is True
     assert warm["compileMs"] == 0.0

@@ -577,7 +577,7 @@ def test_event_log_write_fields(tmp_path):
                     "spark.rapids.sql.eventLog.dir": str(tmp_path / "ev")})
     _df(s).write_parquet(str(tmp_path / "w"), partition_by=["k"])
     rec = s.last_event_record
-    assert rec["schema"] == 16
+    assert rec["schema"] == 17
     assert rec["filesWritten"] == 3
     assert rec["bytesWritten"] > 0
     assert rec["commitRetries"] == 0
